@@ -103,6 +103,16 @@ def _build_net(model_path: str):
     return net.init()
 
 
+def _announce_device(cmd: str) -> None:
+    """One start-up line naming the platform this process GOT (a worker
+    that lost the chip runs on the CPU without any other sign — see
+    `runtime.device`).  On stderr: `dl4j lm -generate` writes its text
+    to stdout, and worker logs capture both streams."""
+    from deeplearning4j_tpu.runtime.device import device_line
+
+    print(f"{cmd}: {device_line()}", file=sys.stderr, flush=True)
+
+
 # --------------------------------------------------------------------------
 # Subcommands
 
@@ -110,6 +120,7 @@ def cmd_train(args) -> int:
     from deeplearning4j_tpu.runtime import save_model
     from deeplearning4j_tpu.runtime.checkpoint import save_params
 
+    _announce_device("train")
     props = load_properties(args.conf)
     ds = _load_dataset(args.input, props)
     net = _build_net(args.model)
@@ -342,6 +353,21 @@ def cmd_train(args) -> int:
     return 0
 
 
+def _placement_line(runtime: str, **trees) -> str:
+    """Where a mesh runtime's arrays actually live: distinct devices per
+    tree and `bytes_in_use` on every visible device (a layout that
+    silently landed on one chip trains just as well — only this line
+    shows it)."""
+    import jax
+
+    from deeplearning4j_tpu.runtime.device import bytes_in_use, devices_of
+
+    held = ", ".join(f"{name} on {len(devices_of(tree))} devices"
+                     for name, tree in trees.items())
+    return (f"{runtime}: placement {held}; bytes_in_use="
+            f"{bytes_in_use(jax.devices())}")
+
+
 def _lm_mesh_layout(runtime: str, n: int, S: int, n_heads: int,
                     n_layers: int, B: int):
     """Pure layout choice for the lm mesh runtimes (unit-tested).
@@ -420,6 +446,10 @@ def _lm_mesh_train(args, cfg, ids, B, S):
         # async step (JIT107): the loss stays on device so step k+1's
         # dispatch overlaps step k; only a due report forces the sync
         loss = trainer.fit_batch_async(tokens, targets)
+        if k == 0:
+            params = (trainer.params if args.runtime == "hybrid" else
+                      (trainer.stage_params, trainer.io_params))
+            print(_placement_line(args.runtime, params=params, loss=loss))
         if args.verbose and (k + 1) % 20 == 0:
             print(f"step {k + 1}/{steps} loss {float(loss):.4f}")
     final_loss = float(loss)   # sync BEFORE reading the clock, or the
@@ -462,6 +492,7 @@ def cmd_serve(args) -> int:
 
     if not args.model and not args.lm:
         raise SystemExit("serve needs -model and/or -lm")
+    _announce_device("serve")
     max_queue = args.max_queue if args.max_queue > 0 else None
     deadline_s = args.deadline_ms / 1e3 if args.deadline_ms > 0 else None
     breaker_n = (args.breaker_threshold if args.breaker_threshold > 0
@@ -552,9 +583,16 @@ def cmd_serve(args) -> int:
         lm_srv = srv.state.lm_server
         # -warmup opts the LM pool into pre-traffic compiles too, same
         # contract as the classifier path: without it each program
-        # compiles on its first dispatch
-        warmed = (lm_srv.warmup() if lm_srv is not None and args.warmup
-                  else 0)
+        # compiles on its first dispatch.  A program that fails to
+        # compile ends the worker here, not one request at a time.
+        warmed = 0
+        if lm_srv is not None and args.warmup:
+            try:
+                warmed = lm_srv.warmup()
+            except Exception as e:  # noqa: BLE001 - whatever the compiler raised, the worker must not serve
+                lm_srv.stop()
+                raise SystemExit(f"serve: -warmup failed, not serving: "
+                                 f"{type(e).__name__}: {e}")
         warm_note = (f"{warmed} programs warm" if warmed
                      else "programs compile on first use")
         if lm_srv is not None and args.lm_kv == "paged":
@@ -940,6 +978,7 @@ def cmd_lm(args) -> int:
         tree_to_npz,
     )
 
+    _announce_device("lm")
     out = pathlib.Path(args.output or "dl4j-lm")
     cfg_path, params_path = out / "lm_config.json", out / "lm_params.npz"
 
@@ -1004,24 +1043,22 @@ def cmd_lm(args) -> int:
         params = _master_f32(tfm.init_params(cfg, jax.random.PRNGKey(0)))
         compute_cfg = (dataclasses.replace(cfg, dtype="bfloat16")
                        if on_tpu else cfg)
-        step, init_opt = make_accum_train_step(
-            compute_cfg, lr=args.lr, accum=args.accum,
-            updater=args.updater)
-        opt_state = init_opt(params)
 
         spmd_mesh = None
         if args.runtime == "spmd":
             # Data parallelism by GSPMD: the batch arrives sharded over
             # the mesh's data axis, params stay replicated, and XLA
-            # inserts the gradient allreduce — no code change to `step`.
+            # inserts the gradient allreduce.  The step gets the mesh
+            # (all devices on `data`) so the attention kernel runs under
+            # shard_map — GSPMD cannot partition a Mosaic kernel.
             from deeplearning4j_tpu.parallel import make_mesh
             from deeplearning4j_tpu.parallel.mesh import (
                 round_batch_to_mesh,
                 shard_batch,
             )
 
-            spmd_mesh = make_mesh()  # 1-D 'data' mesh over all devices
-            n = spmd_mesh.devices.size
+            n = len(jax.devices())
+            spmd_mesh = make_mesh((n, 1, 1), ("data", "seq", "model"))
             if n == 1:
                 print("spmd: only 1 device visible — equivalent to local")
             rounded = round_batch_to_mesh(B, spmd_mesh)
@@ -1033,6 +1070,10 @@ def cmd_lm(args) -> int:
         if args.accum > 1 and B % args.accum:
             raise SystemExit(f"-batch {B} (after any spmd rounding) must "
                              f"be divisible by -accum {args.accum}")
+        step, init_opt = make_accum_train_step(
+            compute_cfg, lr=args.lr, accum=args.accum,
+            updater=args.updater, mesh=spmd_mesh)
+        opt_state = init_opt(params)
         rng = np.random.default_rng(0)
         steps = max(1, args.epochs * (len(ids) // max(B * S, 1)))
         t0, loss = time.time(), None
@@ -1043,12 +1084,13 @@ def cmd_lm(args) -> int:
             if spmd_mesh is not None:
                 # one sharded host transfer, not asarray + reshard
                 tokens, targets = shard_batch(spmd_mesh, (tokens, targets))
-                if k == 0:
-                    print(f"spmd: batch sharded over {n} devices")
             else:
                 tokens, targets = jnp.asarray(tokens), jnp.asarray(targets)
             params, opt_state, loss = step(params, opt_state, tokens,
                                            targets)
+            if spmd_mesh is not None and k == 0:
+                print(_placement_line("spmd", batch=tokens, params=params,
+                                      loss=loss))
             if args.verbose and (k + 1) % 20 == 0:
                 print(f"step {k + 1}/{steps} loss {float(loss):.4f}")
         tok_rate = steps * B * S / max(time.time() - t0, 1e-9)
@@ -1680,4 +1722,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.runtime.device import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

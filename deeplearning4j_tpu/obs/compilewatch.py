@@ -56,8 +56,6 @@ class CompileWatcher:
         self._counts: Dict[str, int] = {}
         self._total_duration = 0.0
         self._events = collections.deque(maxlen=recent)  # (t_end, dur, key)
-        self._installed = False   # fallback guard when jax's listener
-        #                           list cannot be introspected
 
     # ---- listener ---------------------------------------------------------
 
@@ -74,33 +72,18 @@ class CompileWatcher:
         """Register the jax.monitoring listener; safe to call anywhere
         (idempotent, and re-installs after clear_event_listeners).
 
-        The membership check MUST consult the listener list: the public
-        ``jax.monitoring`` module does not re-export
-        ``get_event_duration_listeners`` (only ``jax._src.monitoring``
-        has it), and a getattr miss that silently skips the check would
-        register a duplicate listener on EVERY call — each compile then
-        counts once per listener and every /metrics scrape leaks one
-        more.  When no introspection exists at all, fall back to a
-        register-once flag (loses clear_event_listeners survival, never
-        double-counts)."""
+        The membership check MUST consult the listener list: skipping
+        it would register a duplicate listener on EVERY call — each
+        compile then counts once per listener and every /metrics scrape
+        leaks one more.  The public ``jax.monitoring`` module does not
+        re-export ``get_event_duration_listeners``; ``jax._src.monitoring``
+        has it."""
         import jax.monitoring as monitoring
+        from jax._src.monitoring import get_event_duration_listeners
 
-        get = getattr(monitoring, "get_event_duration_listeners", None)
-        if get is None:
-            try:
-                from jax._src import monitoring as src_monitoring
-
-                get = getattr(src_monitoring,
-                              "get_event_duration_listeners", None)
-            except ImportError:
-                get = None
-        if get is not None:
-            if self._listener in get():
-                return
-        elif self._installed:
+        if self._listener in get_event_duration_listeners():
             return
         monitoring.register_event_duration_secs_listener(self._listener)
-        self._installed = True
 
     # ---- reading ----------------------------------------------------------
 

@@ -42,17 +42,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 from deeplearning4j_tpu.parallel import partition as part_lib
-from deeplearning4j_tpu.parallel.mesh import shard_map_compat
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    """Thin alias over the package's single jax-version shim
-    (`mesh.shard_map_compat`); kept for its importers (hybrid,
-    transformer) and the check_rep-style signature."""
-    del check_rep  # replication checking is always off (see the shim)
-    return shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs)
-
+from deeplearning4j_tpu.parallel.mesh import shard_map
 from deeplearning4j_tpu.models.multi_layer_network import (
     MultiLayerNetwork,
     _as_batches,
@@ -234,7 +224,6 @@ class DataParallelTrainer:
             in_specs=(pspec, pspec, pspec, pspec, dspec, dspec, pspec,
                       dspec, pspec),
             out_specs=(pspec, pspec, pspec, pspec, pspec, pspec),
-            check_rep=False,
         )
         return jax.jit(fn)
 
@@ -349,7 +338,7 @@ class DataParallelTrainer:
                 shard_chunk, mesh=self.mesh,
                 in_specs=(pspec, pspec, pspec, pspec, cspec, cspec, cspec,
                           cspec, pspec, pspec),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs))
             return fn
 
         def no_mask(params, state, upd, sc, xs, ys, ws, it0, lr_scale):
@@ -360,7 +349,7 @@ class DataParallelTrainer:
             no_mask, mesh=self.mesh,
             in_specs=(pspec, pspec, pspec, pspec, cspec, cspec, cspec,
                       pspec, pspec),
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs))
         return lambda p, s, u, sc, xs, ys, ws, masks, it0, lr: fn(
             p, s, u, sc, xs, ys, ws, it0, lr)
 
@@ -632,7 +621,6 @@ class DataParallelTrainer:
             in_specs=(pspec, pspec, sspec, pspec, dspec, dspec, pspec,
                       dspec, pspec),
             out_specs=(pspec, pspec, sspec, pspec, pspec, pspec),
-            check_rep=False,
         )
         return jax.jit(fn)
 
@@ -764,7 +752,7 @@ class DataParallelTrainer:
                 shard_chunk, mesh=self.mesh,
                 in_specs=(pspec, pspec, sspec, pspec, cspec, cspec, cspec,
                           cspec, pspec, pspec),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs))
             return fn
 
         def no_mask(params, state, upd, sc, xs, ys, ws, it0, lr_scale):
@@ -775,7 +763,7 @@ class DataParallelTrainer:
             no_mask, mesh=self.mesh,
             in_specs=(pspec, pspec, sspec, pspec, cspec, cspec, cspec,
                       pspec, pspec),
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs))
         return lambda p, s, u, sc, xs, ys, ws, masks, it0, lr: fn(
             p, s, u, sc, xs, ys, ws, it0, lr)
 
@@ -956,7 +944,6 @@ class DataParallelTrainer:
             mesh=self.mesh,
             in_specs=(rspec, rspec, rspec, dspec, dspec, P(), dspec, P()),
             out_specs=(rspec, rspec, rspec, P(), P()),
-            check_rep=False,
         )
         return jax.jit(fn)
 
@@ -1112,12 +1099,12 @@ class DataParallelTrainer:
 
                 self._avg_fn = jax.jit(shard_map(
                     avg, mesh=self.mesh, in_specs=(P(self.axis),) * 3,
-                    out_specs=(P(self.axis),) * 3, check_rep=False))
+                    out_specs=(P(self.axis),) * 3))
             else:
                 self._avg_fn = jax.jit(shard_map(
                     lambda p, s, u: (avg_tree(p), avg_tree(s), avg_tree(u)),
                     mesh=self.mesh, in_specs=(P(self.axis),) * 3,
-                    out_specs=(P(self.axis),) * 3, check_rep=False))
+                    out_specs=(P(self.axis),) * 3))
         return self._avg_fn(*self._rep)
 
     def _publish_rep(self, rep) -> None:

@@ -13,7 +13,7 @@ mesh, annotate shardings, let XLA insert collectives):
   stacked and sharded over `stage` (pp), GPipe microbatching via
   scan+ppermute (`pipeline.py`) under shard_map. The loss is computed on
   the last stage, masked elsewhere, and psum'd; with shard_map's
-  psum-transposes-to-psum semantics (check_rep=False) every gradient then
+  psum-transposes-to-psum semantics (check_vma=False) every gradient then
   carries a uniform n_stages factor, removed by one normalization, and
   io-param gradients (stage-partial by construction) are psum'd across
   stages. A test asserts step-for-step equality with the single-device
@@ -34,7 +34,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel import transformer as tfm
-from deeplearning4j_tpu.parallel.data_parallel import shard_map
+from deeplearning4j_tpu.parallel.mesh import shard_map
 from deeplearning4j_tpu.parallel.pipeline import gpipe_apply, zero1_flat_update
 
 
@@ -79,7 +79,7 @@ def make_accum_train_step(cfg: tfm.TransformerConfig, lr: float = 1e-3,
                           accum: int = 1, updater: str = "sgd",
                           clip_norm: float = None,
                           weight_decay: float = 0.0,
-                          lr_schedule=None):
+                          lr_schedule=None, mesh: Mesh = None):
     """Single-chip flagship train step: donated f32 master params, bf16
     compute when the config says so, gradient accumulation over `accum`
     sequential microbatches via lax.scan (activation memory of ONE
@@ -88,6 +88,13 @@ def make_accum_train_step(cfg: tfm.TransformerConfig, lr: float = 1e-3,
     optimizer state lives in f32 beside the master params).  Decoupled
     `weight_decay` requires updater='adamw' or 'lion' — make_updater
     raises for updaters that would silently ignore it.
+
+    `mesh` (axes data/seq/model, as `HybridParallelTrainer`'s) is for a
+    batch that arrives sharded over the data axis (`dl4j lm -runtime
+    spmd`): the model then runs its attention under shard_map.  GSPMD
+    cannot partition a Mosaic kernel — without the mesh a sharded batch
+    does not lower on a TPU ("Mosaic kernels cannot be automatically
+    partitioned").
 
     Returns (step, init_state):
       init_state(params) -> opt_state
@@ -109,7 +116,7 @@ def make_accum_train_step(cfg: tfm.TransformerConfig, lr: float = 1e-3,
     def loss_fn(p32, tok, tgt):
         p = (_cast_floating(p32, compute_dtype)
              if compute_dtype != jnp.float32 else p32)
-        return tfm.lm_loss(cfg, p, tok, tgt)
+        return tfm.lm_loss(cfg, p, tok, tgt, mesh)
 
     def step(params, opt_state, tokens, targets):
         if tokens.shape[0] % accum:
@@ -523,8 +530,7 @@ class PipelineParallelTrainer:
             in_specs=(P(stage_axis), P(), stage_opt_spec, io_opt_spec,
                       P(data_axis), P(data_axis)),
             out_specs=(P(stage_axis), P(), stage_opt_spec, io_opt_spec,
-                       P()),
-            check_rep=False)
+                       P()))
         return jax.jit(fn, donate_argnums=(0, 1, 2, 3))
 
     def fit_batch_async(self, tokens, targets):

@@ -28,11 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.6); accept both so
-# the kernels import on either side of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -71,10 +66,21 @@ def flash_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _pick_block(s: int, target: int = None, kind: str = "q") -> int:
+class FlashBlockError(ValueError):
+    """The sequence length admits no block the TPU compiler can tile."""
+
+
+def _pick_block(s: int, target: int = None, kind: str = "q",
+                interpret: bool = True) -> int:
     """Largest divisor of s that is <= target (block sizes must tile S).
-    Tunable per-axis via DL4J_TPU_FLASH_BQ / DL4J_TPU_FLASH_BK (the VMEM
-    residency/occupancy trade-off differs per chip generation)."""
+    Mosaic tiles the sublane dim in rows of 8 and must prove every
+    in-kernel row offset aligned, so a COMPILED call (``interpret=False``)
+    takes only multiples of 8 and raises `FlashBlockError` at trace time
+    when S has none, instead of handing Mosaic a shape it refuses —
+    S=1000 gets 40, S=100 or S=1001 the error.  The interpreter takes
+    any divisor.  Tunable per-axis via DL4J_TPU_FLASH_BQ /
+    DL4J_TPU_FLASH_BK (the VMEM residency/occupancy trade-off differs
+    per chip generation)."""
     import os
 
     if target is None:
@@ -86,10 +92,16 @@ def _pick_block(s: int, target: int = None, kind: str = "q") -> int:
                     f"DL4J_TPU_FLASH_B{kind.upper()}={env}: block size "
                     f"target must be a positive integer")
             target = int(env)
-    b = min(s, target)
-    while s % b:
-        b -= 1
-    return b
+    divisors = [b for b in range(min(s, target), 0, -1) if s % b == 0]
+    if interpret:
+        return divisors[0]
+    tiled = [b for b in divisors if b % 8 == 0]
+    if not tiled:
+        raise FlashBlockError(
+            f"flash attention: sequence length {s} has no block <= "
+            f"{target} that divides it and is a multiple of 8 (the TPU "
+            f"sublane tile); pad the sequence to a multiple of 8")
+    return tiled[0]
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
@@ -111,8 +123,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
 
     def body(j, carry):
         m, l, acc = carry                                 # [bq,1]x2,[bq,d]
-        k_blk = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k_blk = k_ref[0, rows, :].astype(jnp.float32)
+        v_blk = v_ref[0, rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bq, bk]
@@ -153,6 +166,21 @@ def _unfold(x, b, s, h, d):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+# Mosaic's default scoped-VMEM budget on the chips this package targets.
+_DEFAULT_SCOPED_VMEM = 16 << 20
+
+
+def _resident_kv_vmem(s: int, d: int, itemsize: int):
+    """Scoped-VMEM limit for the forward, which keeps one head's whole
+    K and V resident: two operands, double-buffered, lanes padded to
+    128.  None (the compiler's default) while that fits with 4 MiB to
+    spare for the q/o/lse blocks and the score tiles; past it — S=16384
+    at d=64 needs 16 MiB for K/V alone — the limit is raised to what
+    the shape needs."""
+    need = 2 * 2 * s * max(d, 128) * itemsize + (4 << 20)
+    return None if need <= _DEFAULT_SCOPED_VMEM else need
+
+
 def _flash_forward(q, k, v, causal: bool, interpret: bool):
     """Returns (out [B,S,H,D], lse [B*H, S]).
 
@@ -161,8 +189,8 @@ def _flash_forward(q, k, v, causal: bool, interpret: bool):
     fused backward's residuals) is lane 0.
     """
     b, s, h, d = q.shape
-    bq = _pick_block(s, kind="q")
-    bk = _pick_block(s, kind="k")
+    bq = _pick_block(s, kind="q", interpret=interpret)
+    bk = _pick_block(s, kind="k", interpret=interpret)
     n_kv_blocks = s // bk
     scale = 1.0 / (d ** 0.5)
 
@@ -187,8 +215,9 @@ def _flash_forward(q, k, v, causal: bool, interpret: bool):
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, s, REP), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_resident_kv_vmem(s, d, k.dtype.itemsize)),
         interpret=interpret,
     )(qf, kf, vf)
     return _unfold(out, b, s, h, d), lse_rep[..., 0]
@@ -313,8 +342,8 @@ def _bwd_block(q, k, v, g, lse, delta, causal: bool, interpret: bool):
     ring attention's distributed backward.
     """
     b, s, h, d = q.shape
-    bq = _pick_block(s, kind="q")
-    bk = _pick_block(s, kind="k")
+    bq = _pick_block(s, kind="q", interpret=interpret)
+    bk = _pick_block(s, kind="k", interpret=interpret)
     scale = 1.0 / (d ** 0.5)
 
     qf, kf, vf, gf = (_fold(x, b, s, h, d) for x in (q, k, v, g))
@@ -344,7 +373,7 @@ def _bwd_block(q, k, v, g, lse, delta, causal: bool, interpret: bool):
                         pltpu.VMEM((bk, d), jnp.float32)],
         # Inner q dim is sequential (scratch accumulation); outer two are
         # independent, letting Mosaic pipeline/parallelize them.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, gf, lse_rep, delta_rep)
@@ -364,7 +393,7 @@ def _bwd_block(q, k, v, g, lse, delta, causal: bool, interpret: bool):
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, jb: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, gf, lse_rep, delta_rep)

@@ -67,20 +67,12 @@ def replicate(mesh: Mesh, tree):
     return jax.tree_util.tree_map(lambda a: jax.device_put(a, sh), tree)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions (the keyword for disabling
-    replication checking was renamed check_rep -> check_vma in jax 0.8);
-    single shim shared by every shard_map user in the package."""
-    try:
-        from jax import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """`jax.shard_map` with replication checking off — the one form every
+    shard_map user in the package calls (psum'd gradients transpose to
+    psum only without the check)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def round_batch_to_mesh(batch_size: int, mesh: Mesh) -> int:
@@ -131,5 +123,4 @@ def sparse_allgather_step(mesh: Optional[Mesh], deltas_fn, apply_fn,
 
     in_specs = ((P(),) * (n_state + n_scalar) + (P(axis),) * n_sharded
                 + ((P(),) if with_key else ()))
-    return shard_map_compat(sharded, mesh=mesh, in_specs=in_specs,
-                            out_specs=P())
+    return shard_map(sharded, mesh=mesh, in_specs=in_specs, out_specs=P())

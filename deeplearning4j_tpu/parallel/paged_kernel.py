@@ -45,7 +45,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.parallel.kernels import (
     REP,
-    _CompilerParams,
     _resolve_interpret,
     mask_value,
 )
@@ -77,20 +76,30 @@ def resolve_paged_kernel(paged_kernel) -> bool:
 def _paged_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
                        o_ref, m_acc, l_acc, acc, *, scale, ps, c, mp,
                        neg):
-    """Grid program: one (lane, head, logical_page) triple, the page
-    dimension sequential (online-softmax accumulation in VMEM scratch).
+    """Grid program: one (lane, logical_page) pair, the page dimension
+    sequential (online-softmax accumulation in VMEM scratch).
 
     table_ref/pos_ref/nf_ref are the scalar-prefetch operands — already
     resident when the body runs, and consumed by the K/V index maps to
     turn logical page ``lp`` into a physical pool address.  q_ref
-    ``[1, C, 1, K]`` is revisited across the page steps; k_ref/v_ref
-    ``[1, ps, 1, K]`` is THIS lane's page ``lp`` (or a clamped repeat of
-    its last live page on dead steps — same block index, so the
-    pipeline issues no new DMA).  Row stats live lane-replicated
-    ``[C, REP]`` (see kernels.REP) so every scratch block stays
-    sublane-tileable.
+    ``[C, H, K]`` is revisited across the page steps; k_ref/v_ref
+    ``[ps, H, K]`` is ALL heads of this lane's page ``lp`` (or a clamped
+    repeat of its last live page on dead steps — same block index, so
+    the pipeline issues no new DMA).  Taking every head of a page per
+    step is what makes the blocks legal for Mosaic: the last two block
+    dims are the full ``(H, K)`` of the pool, where a one-head
+    ``(1, K)`` slice of a 12-row sublane dim is neither tile-aligned
+    nor full.
+
+    The body stays in the pool's own ``[., H, K]`` layout — heads on
+    sublanes, head_dim on lanes — and only ever indexes LEADING dims
+    (query column, page row): scores are a lane reduction of ``q * k``
+    and the value mix a leading-dim reduction of ``p * v``, so there is
+    no per-head slice, transpose or matmul for the compiler to refuse.
+    Row stats live lane-replicated ``[C, H, REP]`` (see kernels.REP).
     """
-    b, lp = pl.program_id(0), pl.program_id(2)
+    b, lp = pl.program_id(0), pl.program_id(1)
+    h = q_ref.shape[1]
 
     @pl.when(lp == 0)
     def _init():
@@ -105,36 +114,30 @@ def _paged_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
 
     @pl.when(lp * ps <= wmax)
     def _page():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale   # [C, K]
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)       # [ps, K]
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [C, ps]
-        # key t = lp*ps + col is visible to query column c iff
-        # t <= pos + c — the oracle's causal mask, intra-chunk included
-        t = lp * ps + jax.lax.broadcasted_iota(jnp.int32, (c, ps), 1)
-        wpos = pos_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (c, ps), 0)
-        live = t <= wpos
-        s = jnp.where(live, s, neg)
-        m = m_acc[:, :1]                                    # [C, 1]
-        blk_m = jnp.max(s, axis=1, keepdims=True)
-        new_m = jnp.maximum(m, blk_m)
-        p = jnp.where(live, jnp.exp(s - new_m), 0.0)
-        scale_old = jnp.exp(m - new_m)
-        new_l = l_acc[:, :1] * scale_old + jnp.sum(
-            p, axis=1, keepdims=True)
-        acc[...] = acc[...] * scale_old + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [C, K]
-        m_acc[...] = jnp.broadcast_to(new_m, (c, REP))
-        l_acc[...] = jnp.broadcast_to(new_l, (c, REP))
+        k_blk = k_ref[...].astype(jnp.float32)              # [ps, H, K]
+        v_blk = v_ref[...].astype(jnp.float32)
+        # key t = lp*ps + row is visible to query column ci iff
+        # t <= pos + ci — the oracle's causal mask, intra-chunk included
+        t = lp * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, h, 1), 0)
+        for ci in range(c):
+            q = q_ref[ci].astype(jnp.float32) * scale       # [H, K]
+            s = jnp.sum(q[None] * k_blk, axis=-1,
+                        keepdims=True)                      # [ps, H, 1]
+            live = t <= pos_ref[b] + ci
+            s = jnp.where(live, s, neg)
+            m = m_acc[ci][:, :1]                            # [H, 1]
+            new_m = jnp.maximum(m, jnp.max(s, axis=0))
+            p = jnp.where(live, jnp.exp(s - new_m[None]), 0.0)
+            scale_old = jnp.exp(m - new_m)
+            new_l = l_acc[ci][:, :1] * scale_old + jnp.sum(p, axis=0)
+            acc[ci] = acc[ci] * scale_old + jnp.sum(p * v_blk, axis=0)
+            m_acc[ci] = jnp.broadcast_to(new_m, (h, REP))
+            l_acc[ci] = jnp.broadcast_to(new_l, (h, REP))
 
     @pl.when(lp == mp - 1)
     def _flush():
-        l = l_acc[:, :1]
-        o_ref[0, :, 0, :] = (acc[...] / jnp.maximum(l, 1e-30)).astype(
+        l = l_acc[...][:, :, :1]                            # [C, H, 1]
+        o_ref[...] = (acc[...] / jnp.maximum(l, 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -165,30 +168,30 @@ def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
     scale = 1.0 / (kd ** 0.5)
     neg = float(jnp.finfo(jnp.float32).min / 2)
 
-    def _page_map(bi, hi, lp, tbl, pos_, nf):
+    def _lane_map(bi, lp, tbl, pos_, nf):
+        return (bi, 0, 0, 0)
+
+    def _page_map(bi, lp, tbl, pos_, nf):
         # Clamp dead grid steps onto the lane's last live logical page:
         # the repeated block index means the pipeline re-uses the
         # already-resident page instead of DMAing a dead one.
         wmax = pos_[bi] + jnp.maximum(nf[bi], 1) - 1
         live_lp = jnp.minimum(lp, wmax // ps)
-        return (tbl[bi, live_lp], 0, hi, 0)
+        return (tbl[bi, live_lp], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, h, mp),
+        grid=(b, mp),
         in_specs=[
-            pl.BlockSpec((1, c, 1, kd),
-                         lambda bi, hi, lp, tbl, pos_, nf: (bi, 0, hi, 0)),
-            pl.BlockSpec((1, ps, 1, kd), _page_map),
-            pl.BlockSpec((1, ps, 1, kd), _page_map),
+            pl.BlockSpec((None, c, h, kd), _lane_map),
+            pl.BlockSpec((None, ps, h, kd), _page_map),
+            pl.BlockSpec((None, ps, h, kd), _page_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, c, 1, kd),
-            lambda bi, hi, lp, tbl, pos_, nf: (bi, 0, hi, 0)),
+        out_specs=pl.BlockSpec((None, c, h, kd), _lane_map),
         scratch_shapes=[
-            pltpu.VMEM((c, REP), jnp.float32),    # running max
-            pltpu.VMEM((c, REP), jnp.float32),    # running denominator
-            pltpu.VMEM((c, kd), jnp.float32),     # output accumulator
+            pltpu.VMEM((c, h, REP), jnp.float32),   # running max
+            pltpu.VMEM((c, h, REP), jnp.float32),   # running denominator
+            pltpu.VMEM((c, h, kd), jnp.float32),    # output accumulator
         ],
     )
     kernel = functools.partial(_paged_attn_kernel, scale=scale, ps=ps,
@@ -197,8 +200,8 @@ def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, h, kd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_resolve_interpret(interpret),
     )(table, pos, n_feed, q, k_pages, v_pages)
 

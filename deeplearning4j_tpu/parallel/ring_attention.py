@@ -165,7 +165,7 @@ def _ring_flash_fwd_pass(q, k, v, axis_name, causal, interpret):
         k_blk, v_blk, kv_idx, o, lse = carry
 
         def skip(_):
-            return jnp.zeros_like(o), jnp.full_like(lse, NEG_INF)
+            return jnp.zeros_like(q), jnp.full_like(lse, NEG_INF)
 
         def diag(_):
             return _flash_block_fwd(q, k_blk, v_blk, True, interpret)
@@ -183,16 +183,18 @@ def _ring_flash_fwd_pass(q, k, v, axis_name, causal, interpret):
         new_lse = jnp.logaddexp(lse, blse)
         w_old = jnp.exp(lse - new_lse)
         w_new = jnp.exp(blse - new_lse)
-        o = o * w_old[..., None] + bo * w_new[..., None]
+        o = o * w_old[..., None] + bo * w_new[..., None]   # f32 carry
         k_n = lax.ppermute(k_blk, axis_name, perm)
         v_n = lax.ppermute(v_blk, axis_name, perm)
         i_n = lax.ppermute(kv_idx, axis_name, perm)
         return (k_n, v_n, i_n, o, new_lse), None
 
-    init = (k, v, my_idx, jnp.zeros_like(q),
+    # the combine weights are f32, so the running output is too: a
+    # q.dtype carry would change type across the scan for bf16 inputs
+    init = (k, v, my_idx, jnp.zeros(q.shape, jnp.float32),
             jnp.full((b, s_local, h), NEG_INF, jnp.float32))
     (_, _, _, o, lse), _ = lax.scan(body, init, None, length=axis_size)
-    return o, lse
+    return o.astype(q.dtype), lse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel.data_parallel import shard_map
+from deeplearning4j_tpu.parallel.mesh import shard_map
 from deeplearning4j_tpu.parallel.ring_attention import attention, ring_attention
 
 
@@ -274,8 +274,7 @@ def _attn(p, x, mesh: Optional[Mesh], axes: MeshAxes, causal: bool):
         spec = P(axes.data, axes.seq, axes.model, None)
         ring = shard_map(
             lambda q, k, v: inner(q, k, v, axes.seq, causal=causal),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         o = ring(q, k, v)
     return out_proj(p, o)
 

@@ -1,7 +1,6 @@
 """Step watchdog: bound the wall-clock of a device step.
 
-A wedged TPU runtime (stuck collective, dead tunnel, deadlocked host
-callback) hangs `fit_batch` forever — the reference's failure story for
+A wedged TPU runtime (stuck collective, deadlocked host callback) hangs `fit_batch` forever — the reference's failure story for
 this was the heartbeat reaper in the scaleout tier.  Per-process the
 equivalent is a watchdog: the step runs on a worker thread and the caller
 joins with a timeout; blowing the timeout raises a structured

@@ -535,6 +535,8 @@ class ContinuousLMServer:
             collections.OrderedDict())
         self._session_capacity = 1024
         self._warm_req: Optional[threading.Event] = None
+        self._warming: Optional[threading.Event] = None  # warm in flight
+        self._warm_error: Optional[BaseException] = None
         self._slots = [_Slot() for _ in range(self.n_slots)]
         self._steps = 0
 
@@ -1047,7 +1049,12 @@ class ContinuousLMServer:
         The warm dispatches run on the WORKER's live cache (inactive
         lanes write only the reserved null page), not a throwaway copy:
         a pool sized to fill device memory must not transiently double
-        during startup or a rolling swap."""
+        during startup or a rolling swap.
+
+        A warm dispatch that raises (the compiler refusing a program)
+        is re-raised HERE: a server whose programs do not compile must
+        not report a warm count and stay up to fail request by
+        request."""
         with self._cond:
             if not self._running:
                 self._start_locked()
@@ -1060,6 +1067,10 @@ class ContinuousLMServer:
             # device is wedged): report 0, not a count the zero-compile
             # contract would falsely promise
             return 0
+        with self._cond:
+            err, self._warm_error = self._warm_error, None
+        if err is not None:
+            raise err
         return self.compiled_programs()
 
     def _warm_programs(self) -> None:
@@ -2132,13 +2143,18 @@ class ContinuousLMServer:
             idle = not any(s.active for s in self._slots)
             if warm is not None and (idle or self.kv == "paged"):
                 self._warm_req = None
+                # a warm dispatch that raises lands in the worker's
+                # fault arm (`_run`), which rebuilds the donated pool
+                # and hands the exception to the waiting warmup()
+                # through `_warming`
+                self._warming = warm
             else:
                 warm = None
         if warm is not None:
-            try:
-                self._warm_programs()
-            finally:
-                warm.set()
+            self._warm_programs()
+            with self._cond:
+                self._warming = None
+            warm.set()
             return True
         with self._cond:
             self._admit_locked()
@@ -2523,6 +2539,11 @@ class ContinuousLMServer:
                 if self.breaker is not None:
                     self.breaker.record_failure()
                 with self._cond:
+                    if self._warming is not None:
+                        # the failed dispatch was warmup()'s: it re-raises
+                        self._warm_error = e
+                        self._warming.set()
+                        self._warming = None
                     victims = [s for s in self._slots if s.active]
                     for s in victims:
                         s.req.error = e

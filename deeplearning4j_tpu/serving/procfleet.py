@@ -242,6 +242,10 @@ class WorkerSpec:
     # stamps onto every incarnation's Replica — a resurrected prefill
     # worker comes back AS a prefill worker
     role: str = "both"
+    # the spawned worker's environment (None = inherit the
+    # supervisor's).  A chip belongs to one process: a supervisor that
+    # holds it states JAX_PLATFORMS=cpu (or the worker's own chip) here
+    env: Optional[Dict[str, str]] = None
 
     def host_port(self):
         parsed = urllib.parse.urlparse(self.url)
@@ -406,7 +410,8 @@ class FleetSupervisor:
         try:
             proc = spawn_logged(command, worker.spec.log_path,
                                 host=host, port=port,
-                                on_bind_retry=self._count_spawn_retry)
+                                on_bind_retry=self._count_spawn_retry,
+                                env=worker.spec.env)
         except (WorkerSpawnError, OSError) as e:
             # an unspawnable worker is a death at incarnation start —
             # same backoff/quarantine path as a boot crash

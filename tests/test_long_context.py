@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.parallel import make_mesh
-from deeplearning4j_tpu.parallel.data_parallel import shard_map
+from deeplearning4j_tpu.parallel.mesh import shard_map
 from deeplearning4j_tpu.parallel.ring_attention import (
     attention,
     ring_attention,
@@ -45,8 +45,7 @@ def mesh():
 def _ring(fn, mesh_, **kw):
     return shard_map(
         lambda q, k, v: fn(q, k, v, "seq", **kw), mesh=mesh_,
-        in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"),
-        check_rep=False)
+        in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"))
 
 
 class TestRingAtScale:

@@ -22,7 +22,7 @@ from deeplearning4j_tpu.parallel.ring_attention import (
     ring_attention,
     ring_flash_attention,
 )
-from deeplearning4j_tpu.parallel.data_parallel import shard_map
+from deeplearning4j_tpu.parallel.mesh import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -45,8 +45,7 @@ class TestRingAttention:
             lambda q, k, v: ring_attention(q, k, v, "seq", causal=causal),
             mesh=mesh,
             in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq")),
-            out_specs=P(None, "seq"),
-            check_rep=False)
+            out_specs=P(None, "seq"))
         got = jax.jit(ring)(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                    atol=2e-5)
@@ -65,8 +64,7 @@ class TestRingAttention:
             lambda q, k, v: ring_attention(q, k, v, "seq", causal=True),
             mesh=mesh,
             in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"),
-            check_rep=False)
+            out_specs=P(None, "seq"))
 
         def ring_loss(q, k, v):
             return jnp.sum(ring(q, k, v) ** 2)
@@ -95,8 +93,7 @@ class TestRingFlashAttention:
                                                  causal=causal),
             mesh=mesh,
             in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"),
-            check_rep=False)
+            out_specs=P(None, "seq"))
         got = jax.jit(ring)(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                    atol=2e-5)
@@ -114,8 +111,7 @@ class TestRingFlashAttention:
                                                  causal=causal),
             mesh=mesh,
             in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"),
-            check_rep=False)
+            out_specs=P(None, "seq"))
 
         ge = jax.grad(lambda q, k, v: jnp.sum(
             attention(q, k, v, causal=causal) ** 2), (0, 1, 2))(q, k, v)
@@ -620,12 +616,11 @@ class TestGPipeMemoryHygiene:
         new_f = jax.jit(shard_map(
             lambda sp, xl: gpipe_apply(stage_fn, sp, xl, "stage", m),
             mesh=mesh, in_specs=(P("stage"), P("stage")),
-            out_specs=P("stage"), check_rep=False))
+            out_specs=P("stage")))
         old_f = jax.jit(shard_map(
             lambda sp, xf: self._replicated_gpipe(
                 stage_fn, sp, xf, "stage")[None],
-            mesh=mesh, in_specs=(P("stage"), P()), out_specs=P("stage"),
-            check_rep=False))
+            mesh=mesh, in_specs=(P("stage"), P()), out_specs=P("stage")))
         return w, x, new_f, old_f
 
     @pytest.mark.parametrize("m", [8, 6])  # m=6/P=4: mixed real+padding
@@ -663,8 +658,7 @@ class TestGPipeMemoryHygiene:
 
             return jax.jit(shard_map(
                 jax.grad(loss), mesh=mesh,
-                in_specs=(P("stage"), P("stage")), out_specs=P("stage"),
-                check_rep=False))
+                in_specs=(P("stage"), P("stage")), out_specs=P("stage")))
 
         g_remat = make(True)
         g_plain = make(False)

@@ -17,7 +17,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from deeplearning4j_tpu.parallel import make_mesh  # noqa: E402
-from deeplearning4j_tpu.parallel.data_parallel import shard_map  # noqa: E402
+from deeplearning4j_tpu.parallel.mesh import shard_map  # noqa: E402
 from deeplearning4j_tpu.parallel.ring_attention import (  # noqa: E402
     attention,
     ring_flash_attention,
@@ -36,7 +36,7 @@ def main() -> None:
     ring = shard_map(
         lambda q, k, v: ring_flash_attention(q, k, v, "seq", causal=True),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3,
-        out_specs=P(None, "seq"), check_rep=False)
+        out_specs=P(None, "seq"))
 
     def loss_ring(q, k, v):
         return jnp.sum(ring(q, k, v) ** 2)
@@ -78,8 +78,7 @@ def main() -> None:
     def make(fn):
         return jax.jit(shard_map(
             lambda q, k, v: fn(q, k, v, "seq", causal=True), mesh=mesh,
-            in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"),
-            check_rep=False))
+            in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq")))
 
     rf, rd = make(ring_flash_attention), make(ring_attention)
     t0 = time.perf_counter()

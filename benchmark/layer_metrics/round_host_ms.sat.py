@@ -1,0 +1,13 @@
+"""Host time of one scheduling round of the LM worker: the program's own
+phase seconds (`serving_lm_round_seconds_total`, every phase but `sync`,
+which is the wait for the device) across the window, over the rounds it
+dispatched.  The inside view of the device's between-round gaps."""
+
+from benchmark import rounds
+
+NAME, UNIT, BETTER = "round_host_ms.sat", "ms", "lower"
+LAYER, MOVES, SOURCE = "LM scheduler", "serve_tokens_per_s", "program_counter"
+
+
+def read(run):
+    return rounds.round_host_ms(run)

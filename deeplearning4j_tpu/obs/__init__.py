@@ -9,6 +9,8 @@ doing right now, and where did this one slow request spend its time":
 - `trace` — request ids, the bounded `TraceRecorder` ring behind
   ``GET /trace/recent``, and Chrome trace-event (Perfetto-loadable)
   export; request ids propagate across the fleet via ``X-Request-Id``;
+  `PhaseClock` / `annotate` put a loop's phases on the profiler's host
+  plane and in counters at once;
 - `compilewatch` — first-class ``compiles_total{program_key=...}``
   fed by ``jax.monitoring`` compile events, plus the recent-event ring
   the tracer uses to attach ``xla_compile`` spans to the request that
@@ -40,7 +42,9 @@ from deeplearning4j_tpu.obs.registry import (
 )
 from deeplearning4j_tpu.obs.telemetry import TrainingTelemetry
 from deeplearning4j_tpu.obs.trace import (
+    PhaseClock,
     TraceRecorder,
+    annotate,
     chrome_trace,
     new_request_id,
     span,
@@ -57,9 +61,11 @@ __all__ = [
     "LATENCY_BUCKETS",
     "MetricsRegistry",
     "MetricsServer",
+    "PhaseClock",
     "STEP_TIME_BUCKETS",
     "TraceRecorder",
     "TrainingTelemetry",
+    "annotate",
     "chrome_trace",
     "compile_scope",
     "compile_watcher",

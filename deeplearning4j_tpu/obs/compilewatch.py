@@ -31,6 +31,8 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from deeplearning4j_tpu.obs.trace import annotate
+
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _scope: contextvars.ContextVar = contextvars.ContextVar(
@@ -40,10 +42,14 @@ _scope: contextvars.ContextVar = contextvars.ContextVar(
 @contextlib.contextmanager
 def compile_scope(key: str):
     """Attribute any XLA compile triggered by this thread inside the
-    block to ``program_key=key`` (contextvars: thread/task local)."""
+    block to ``program_key=key`` (contextvars: thread/task local), and
+    name the block on the profiler's host plane by the same key: every
+    launched program then reads under its stable key beside the device
+    trace, whatever the profiler calls the program itself."""
     token = _scope.set(str(key))
     try:
-        yield
+        with annotate(str(key)):
+            yield
     finally:
         _scope.reset(token)
 

@@ -10,6 +10,14 @@ under the SAME id (queue wait, dispatch, device compute).
 - `new_request_id()` — 16-hex-char id.
 - `span(name, t0, t1, **attrs)` — one completed span (perf_counter
   seconds; monotonic and process-wide comparable).
+- `annotate(name)` — the package's one wrapper of
+  ``jax.profiler.TraceAnnotation``: a named span on the profiler's host
+  plane, on the device trace's clock; inert unless a profiler session
+  is open.
+- `PhaseClock` — one thread's wall time split into consecutive named
+  phases: each phase's ``perf_counter`` seconds and an `annotate` span
+  of the same name over the same interval, so the host-clock counter
+  and the device-clock span cannot drift apart.
 - `TraceRecorder` — bounded ring buffer of completed traces (oldest
   evicted), queried by ``recent()``/``find()`` and served at
   ``GET /trace/recent``.
@@ -20,7 +28,8 @@ under the SAME id (queue wait, dispatch, device compute).
   serving planes appear as ``xla_compile`` spans inside the request
   that paid for them.
 
-Stdlib-only, like the rest of obs/.
+Stdlib-only at import, like the rest of obs/ (`annotate` imports jax
+when first called).
 """
 
 from __future__ import annotations
@@ -51,6 +60,52 @@ def span(name: str, t0: float, t1: float, **attrs) -> Dict:
     if attrs:
         s["attrs"] = {k: v for k, v in attrs.items() if v is not None}
     return s
+
+
+def annotate(name: str):
+    """Named span on the profiler's host plane (a context manager)."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+class PhaseClock:
+    """One thread's wall time as consecutive named phases.
+
+    ``to(name)`` ends the open phase and opens ``name`` with ONE
+    ``perf_counter`` stamp, so the phases partition the time between the
+    first ``to`` and the last with no gap: what `seconds` holds always
+    sums to the wall time spanned.  The open phase also holds an
+    `annotate` span named ``<prefix><name>``, so a profiler trace shows
+    on the host plane, on the device's clock, exactly the intervals the
+    counters summed.  ``take()`` hands over the seconds accumulated
+    since the last take (the owner adds them to its counters, once a
+    round) and starts anew.  Not thread-safe: one clock per thread."""
+
+    __slots__ = ("prefix", "seconds", "_name", "_t", "_span")
+
+    def __init__(self, prefix: str = ""):
+        self.prefix = str(prefix)
+        self.seconds: Dict[str, float] = {}
+        self._name: Optional[str] = None
+        self._t = 0.0
+        self._span = None
+
+    def to(self, name: Optional[str]) -> None:
+        """End the open phase, open ``name`` (None: open nothing)."""
+        now = time.perf_counter()
+        if self._name is not None:
+            self.seconds[self._name] = (self.seconds.get(self._name, 0.0)
+                                        + now - self._t)
+            self._span.__exit__(None, None, None)
+        self._name, self._t = name, now
+        if name is not None:
+            self._span = annotate(self.prefix + name)
+            self._span.__enter__()
+
+    def take(self) -> Dict[str, float]:
+        out, self.seconds = self.seconds, {}
+        return out
 
 
 def trace(request_id: str, kind: str, spans: List[Dict],

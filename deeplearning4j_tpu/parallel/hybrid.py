@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu.obs.compilewatch import compile_scope
 from deeplearning4j_tpu.parallel import transformer as tfm
 from deeplearning4j_tpu.parallel.mesh import shard_map
 from deeplearning4j_tpu.parallel.pipeline import gpipe_apply, zero1_flat_update
@@ -161,12 +162,18 @@ class HybridParallelTrainer:
     `data`-divisible dimension over the data axis: the update math is
     elementwise, so XLA partitions the optimizer step across the dp
     axis and each replica persists only 1/N of the moments (arXiv
-    2004.13336 expressed the GSPMD way — placement, not collectives)."""
+    2004.13336 expressed the GSPMD way — placement, not collectives).
+
+    `params` (the `transformer.init_params` layout) are the weights to
+    train from, e.g. a checkpoint's; they become the float32 masters and
+    are placed on the mesh.  Without them the trainer initializes its
+    own from `seed`."""
 
     def __init__(self, cfg: tfm.TransformerConfig, mesh: Mesh,
                  lr: float = 1e-2, seed: int = 0,
                  axes: tfm.MeshAxes = tfm.MeshAxes(),
-                 updater: str = "sgd", shard_update: bool = True):
+                 updater: str = "sgd", shard_update: bool = True,
+                 params=None):
         from deeplearning4j_tpu.ops.updaters import (
             UpdaterConfig,
             apply_updates,
@@ -179,9 +186,16 @@ class HybridParallelTrainer:
         self.axes = axes
         self.shard_update = bool(shard_update)
         self._pspecs = tfm.param_specs(cfg, axes.model)
-        self.params = place_params(
-            mesh, _master_f32(tfm.init_params(cfg, jax.random.PRNGKey(seed))),
-            self._pspecs)
+        given = params is not None
+        if not given:
+            params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
+        self.params = place_params(mesh, _master_f32(params), self._pspecs)
+        if given:
+            # a placed shard can share its buffer with the array it came
+            # from (`device_put` onto the device that holds it), and the
+            # step donates its masters: copy, so that the first step does
+            # not delete the caller's weights
+            self.params = jax.tree_util.tree_map(jnp.copy, self.params)
         transform = make_updater(UpdaterConfig(
             updater=updater, learning_rate=lr, epsilon=1e-8))
         self.opt_state = transform.init(self.params)
@@ -280,8 +294,10 @@ class HybridParallelTrainer:
         dsh = NamedSharding(self.mesh, P(self.axes.data, self.axes.seq))
         tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), dsh)
         targets = jax.device_put(jnp.asarray(targets, jnp.int32), dsh)
-        self.params, self.opt_state, loss = self._step(
-            self.params, self.opt_state, tokens, targets)
+        # named on the profiler's host plane; compiles counted by key
+        with compile_scope("train:hybrid"):
+            self.params, self.opt_state, loss = self._step(
+                self.params, self.opt_state, tokens, targets)
         return loss
 
     def fit_batch(self, tokens, targets) -> float:

@@ -12,8 +12,8 @@ first-class, per the survey's recommendation:
 - `LatencyRecorder`: thread-safe reservoir of request latencies with
   p50/p95/p99 summaries — the serving subsystem's per-request metric
   primitive (`serving/metrics.py`).
-- `annotate(name)`: named span visible inside the device trace
-  (jax.profiler.TraceAnnotation).
+- named spans inside the device trace: `obs.trace.annotate` (the
+  package's one wrapper of jax.profiler.TraceAnnotation).
 - `device_memory_stats()`: per-device live/peak HBM bytes where the
   backend exposes them.
 """
@@ -94,13 +94,6 @@ def trace(logdir: str, create_perfetto_link: bool = False):
         yield logdir
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named span for the device timeline (use as a context manager)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 def device_memory_stats() -> List[Dict]:

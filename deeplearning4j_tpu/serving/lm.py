@@ -181,6 +181,7 @@ from deeplearning4j_tpu.obs.compilewatch import (
 )
 from deeplearning4j_tpu.obs.registry import MetricsRegistry
 from deeplearning4j_tpu.obs.trace import (
+    PhaseClock,
     TraceRecorder,
     new_request_id,
     span,
@@ -257,7 +258,8 @@ class _LMRequest:
                  "import_pages", "stream", "session_id", "t_first",
                  "priority", "rank", "swap_key", "swap_restore",
                  "swap_error", "stream_pushed", "preempted",
-                 "tenant", "vft", "cost")
+                 "tenant", "vft", "cost", "prefill_rounds",
+                 "prefill_wide_rounds")
 
     def __init__(self, prompt: List[int], max_new: int, temperature: float,
                  seed: int, deadline: Optional[float] = None,
@@ -297,6 +299,10 @@ class _LMRequest:
         self.tenant = DEFAULT_TENANT       # normalized tenant name
         self.vft = 0.0                     # WFQ virtual finish time
         self.cost = self.max_new + len(self.prompt)  # token cost charged
+        # rounds this request's lane rode while prefilling, and how many
+        # of them dispatched the wide program (the `prefill` span's attrs)
+        self.prefill_rounds = 0
+        self.prefill_wide_rounds = 0
 
 
 class _Slot:
@@ -539,6 +545,9 @@ class ContinuousLMServer:
         self._warm_error: Optional[BaseException] = None
         self._slots = [_Slot() for _ in range(self.n_slots)]
         self._steps = 0
+        # the worker's wall time by phase of the round (its thread only)
+        self._clock = PhaseClock("lm:")
+        self._warmup_stats: Optional[Dict] = None
 
     # ---- client side ------------------------------------------------------
 
@@ -1005,7 +1014,8 @@ class ContinuousLMServer:
     def _trace_request(self, req: _LMRequest, done: float,
                        status: str) -> None:
         """The LM request's lifecycle trace: queue_wait (admission to
-        slot install) then decode (install to completion), plus any XLA
+        slot install) then decode (install to completion) with prefill
+        (install to first committed token) inside it, plus any XLA
         compiles that landed inside the decode window."""
         if self.tracer is None:
             return
@@ -1024,6 +1034,15 @@ class ContinuousLMServer:
                 accepted=(req.accepted if req.drafted else None),
                 preempted=req.preempted or None,
                 swap_error=req.swap_error))
+            if (req.t_first is not None
+                    and req.t_first >= req.t_installed):
+                # a preempted lane's first token predates its last
+                # install: its prefill is not this residency's
+                spans.append(span(
+                    "prefill", req.t_installed, req.t_first,
+                    fed_tokens=len(req.prompt) - req.prefix_matched,
+                    rounds=req.prefill_rounds,
+                    wide_rounds=req.prefill_wide_rounds))
             if self._compile_watch.any_since(req.t_installed):
                 for c_end, c_dur, key in (self._compile_watch
                                           .events_between(req.t_installed,
@@ -1080,54 +1099,73 @@ class ContinuousLMServer:
         dense step's pos-0 write lands in lanes that restart at pos 0
         on admission anyway — so cache contents stay serviceable and no
         second pool is ever allocated."""
+        import jax
+
         if self._cache is None:
             self._reset_cache()
-        zi = np.zeros((self.n_slots,), np.int32)
-        zf = np.zeros((self.n_slots,), np.float32)
-        if self.kv == "dense":
-            with compile_scope("lm:dense"):
-                _, k, v = self._step(self.params, *self._cache, zi, zi,
-                                     zf, zi, zi)
-            self._cache = (k, v)
-            return
-        table = np.zeros((self.n_slots, self.max_pages), np.int32)
-        if self.speculate != "off":
-            widths = [1, self.spec_width]
-            for w in widths:
-                tok = np.zeros((self.n_slots, w), np.int32)
-                with compile_scope(f"lm:paged[w{w}]"):
-                    out = self._step(self.params, *self._cache, table,
-                                     zi, zi, zi, tok, zf, zi, zi)
-                self._cache = (out[-2], out[-1])
-            if hasattr(self._drafter, "warmup"):
-                self._drafter.warmup()
-        else:
-            widths = [1] + ([self.prefill_chunk]
-                            if self.prefill_chunk > 1 else [])
-            for w in widths:
-                tok = np.zeros((self.n_slots, w), np.int32)
-                with compile_scope(f"lm:paged[w{w}]"):
-                    _, k, v = self._step(self.params, *self._cache,
-                                         table, zi, zi, tok, zf, zi, zi)
+        t_start = time.perf_counter()
+        compiles = self._compile_watch.total()
+        programs: Dict[str, float] = {}
+
+        def warm(key, call):
+            """One program under its key, waited for: what it costs to
+            compile or load and to run once."""
+            t0 = time.perf_counter()
+            with compile_scope(key):
+                out = jax.block_until_ready(call())
+            programs[key] = time.perf_counter() - t0
+            return out
+
+        try:
+            zi = np.zeros((self.n_slots,), np.int32)
+            zf = np.zeros((self.n_slots,), np.float32)
+            if self.kv == "dense":
+                _, k, v = warm("lm:dense", lambda: self._step(
+                    self.params, *self._cache, zi, zi, zf, zi, zi))
                 self._cache = (k, v)
-        with compile_scope("lm:page_copy"):
-            k, v = self._copy(*self._cache, np.int32(0), np.int32(0))
-        self._cache = (k, v)
-        if self.ship or self.preempt or self.hibernate:
-            # the shipping/swap/hibernate pair: a gather out of the live
-            # pool (not donated — the row of nulls reads only the null
-            # page) and an n=0 install whose every row lands on the
-            # null page
-            zrow = np.zeros((self.max_pages,), np.int32)
-            with compile_scope("lm:page_gather"):
-                self._gather(*self._cache, zrow)
-            shape = (self.cfg.n_layers, self.max_pages, self.page_size,
-                     self.cfg.n_heads, self.cfg.head_dim)
-            zp = np.zeros(shape, np.dtype(self.cfg.dtype))
-            with compile_scope("lm:page_install"):
-                k, v = self._install(*self._cache, zp, zp, zrow,
-                                     np.int32(0))
+                return
+            table = np.zeros((self.n_slots, self.max_pages), np.int32)
+            if self.speculate != "off":
+                widths = [1, self.spec_width]
+                for w in widths:
+                    tok = np.zeros((self.n_slots, w), np.int32)
+                    out = warm(f"lm:paged[w{w}]", lambda: self._step(
+                        self.params, *self._cache, table, zi, zi, zi, tok,
+                        zf, zi, zi))
+                    self._cache = (out[-2], out[-1])
+                if hasattr(self._drafter, "warmup"):
+                    warm("lm:drafter", self._drafter.warmup)
+            else:
+                widths = [1] + ([self.prefill_chunk]
+                                if self.prefill_chunk > 1 else [])
+                for w in widths:
+                    tok = np.zeros((self.n_slots, w), np.int32)
+                    _, k, v = warm(f"lm:paged[w{w}]", lambda: self._step(
+                        self.params, *self._cache, table, zi, zi, tok, zf,
+                        zi, zi))
+                    self._cache = (k, v)
+            k, v = warm("lm:page_copy", lambda: self._copy(
+                *self._cache, np.int32(0), np.int32(0)))
             self._cache = (k, v)
+            if self.ship or self.preempt or self.hibernate:
+                # the shipping/swap/hibernate pair: a gather out of the live
+                # pool (not donated — the row of nulls reads only the null
+                # page) and an n=0 install whose every row lands on the
+                # null page
+                zrow = np.zeros((self.max_pages,), np.int32)
+                warm("lm:page_gather",
+                     lambda: self._gather(*self._cache, zrow))
+                shape = (self.cfg.n_layers, self.max_pages, self.page_size,
+                         self.cfg.n_heads, self.cfg.head_dim)
+                zp = np.zeros(shape, np.dtype(self.cfg.dtype))
+                k, v = warm("lm:page_install", lambda: self._install(
+                    *self._cache, zp, zp, zrow, np.int32(0)))
+                self._cache = (k, v)
+        finally:
+            self._warmup_stats = {
+                "programs": programs,
+                "compiles": self._compile_watch.total() - compiles,
+                "total_s": time.perf_counter() - t_start}
 
     def compiled_programs(self) -> int:
         if self.kv == "dense":
@@ -1289,6 +1327,10 @@ class ContinuousLMServer:
         # first-class compile accounting (ISSUE-8): XLA compiles the
         # watcher attributed to the LM pool's dispatch scopes
         out["compiles_total"] = compile_watcher().total(prefix="lm:")
+        with self._cond:
+            if self._warmup_stats is not None:
+                # what warmup() cost, by program key (seconds, waited)
+                out["warmup"] = dict(self._warmup_stats)
         return out
 
     # ---- worker side ------------------------------------------------------
@@ -2132,6 +2174,8 @@ class ContinuousLMServer:
         """One scheduling round: admit, build the step inputs, dispatch,
         fold the sampled tokens back into each lane.  Returns False when
         idle (nothing active, nothing queued)."""
+        clock = self._clock
+        clock.to("admit")                   # the wait for the lock too
         with self._cond:
             # a pending warmup runs on the worker's own cache, inside
             # this protected loop (a failing warm dispatch rides the
@@ -2151,10 +2195,15 @@ class ContinuousLMServer:
             else:
                 warm = None
         if warm is not None:
+            # the warm-up is `yield` time, but no round's: counted at
+            # once, so the next round's host time does not carry it
+            clock.to("yield")
             self._warm_programs()
             with self._cond:
                 self._warming = None
             warm.set()
+            clock.to("yield")
+            self.metrics.record_phase_seconds(clock.take())
             return True
         with self._cond:
             self._admit_locked()
@@ -2192,6 +2241,7 @@ class ContinuousLMServer:
                         self._free_slot_pages(s)
                         s.req = None
             return True
+        clock.to("pages")
         if self._cache is None:
             # a failed step consumed its donated k/v buffers and set the
             # cache aside; rebuild INSIDE the protected loop so a failing
@@ -2204,6 +2254,9 @@ class ContinuousLMServer:
         return self._dispatch_dense(active)
 
     def _dispatch_dense(self, active) -> bool:
+        clock = self._clock
+        clock.to("marshal")
+        fed = dict.fromkeys(("prefill", "decode"), 0)
         token = np.zeros((self.n_slots,), np.int32)
         pos = np.zeros((self.n_slots,), np.int32)
         temp = np.zeros((self.n_slots,), np.float32)
@@ -2215,19 +2268,25 @@ class ContinuousLMServer:
             req = slot.req
             if slot.fed < len(req.prompt):     # prefill: teacher-force
                 token[i] = req.prompt[slot.fed]
+                fed["prefill"] += 1
+                req.prefill_rounds += 1
             else:                              # decode: feed last sample
                 token[i] = slot.generated[-1]
+                fed["decode"] += 1
             pos[i] = slot.pos
             temp[i] = req.temperature
             seeds[i] = req.seed
             counts[i] = len(slot.generated)
+        clock.to("dispatch")
         with compile_scope("lm:dense"):
             nxt, k, v = self._step(self.params, *self._cache, pos, token,
                                    temp, seeds, counts)
         if self.breaker is not None:
             self.breaker.record_success()
         self._cache = (k, v)
+        clock.to("sync")
         nxt = np.asarray(nxt)
+        clock.to("fold")
         self._steps += 1
         emitted = 0
         for i, slot in enumerate(self._slots):
@@ -2246,6 +2305,8 @@ class ContinuousLMServer:
         self.metrics.record_dispatch(len(active), self.n_slots)
         if emitted:
             self.metrics.record_tokens(emitted)
+        clock.to("yield")
+        self.metrics.record_round(clock.take(), 1, self.n_slots, fed, 0)
         return True
 
     def _draft_proposals(self) -> Dict[int, List[int]]:
@@ -2377,6 +2438,8 @@ class ContinuousLMServer:
         # that compute belongs to survival); level 2 additionally
         # shrinks the prefill ride-along width so active decode lanes
         # commit more often while admission throughput pays.
+        clock = self._clock
+        clock.to("plan")
         drafts = (self._draft_proposals()
                   if self._drafter is not None and level < 1 else {})
         chunk_eff = (max(1, self.prefill_chunk // 2) if level >= 2
@@ -2397,6 +2460,9 @@ class ContinuousLMServer:
                 width = self.spec_width
         elif self._chunk_step is not None and full_chunk:
             width = self.prefill_chunk
+        clock.to("marshal")
+        fed = dict.fromkeys(("prefill", "decode", "draft"), 0)
+        live_pages = 0
         tokens = np.zeros((self.n_slots, width), np.int32)
         pos = np.zeros((self.n_slots,), np.int32)
         n_feed = np.zeros((self.n_slots,), np.int32)
@@ -2414,20 +2480,29 @@ class ContinuousLMServer:
                 f = min(remaining, width, chunk_eff)
                 tokens[i, :f] = req.prompt[slot.fed:slot.fed + f]
                 n_feed[i] = f
+                fed["prefill"] += f
+                req.prefill_rounds += 1
+                req.prefill_wide_rounds += width > 1
             elif width > 1 and i in drafts:    # speculative verify
                 prop = drafts[i]
                 tokens[i, 0] = slot.generated[-1]
                 tokens[i, 1:1 + len(prop)] = prop
                 n_feed[i] = 1 + len(prop)
                 n_draft[i] = len(prop)
+                fed["decode"] += 1
+                fed["draft"] += len(prop)
             else:                              # decode: feed last sample
                 tokens[i, 0] = slot.generated[-1]
                 n_feed[i] = 1
+                fed["decode"] += 1
+            # pages the attention reads for this lane: history and feed
+            live_pages += -(-(slot.pos + int(n_feed[i])) // self.page_size)
             pos[i] = slot.pos
             temp[i] = req.temperature
             seeds[i] = req.seed
             counts[i] = len(slot.generated)
             table[i] = slot.table
+        clock.to("dispatch")
         with compile_scope(f"lm:paged[w{width}]"):
             if self.speculate != "off":
                 nxt, acc, k, v = self._step(
@@ -2443,8 +2518,10 @@ class ContinuousLMServer:
         self._cache = (k, v)
         # ONE host sync per round: the bonus tokens and the per-lane
         # accepted counts arrive together, never per token
+        clock.to("sync")
         nxt = np.asarray(nxt)
         acc = np.asarray(acc) if acc is not None else None
+        clock.to("fold")
         self._steps += 1
         emitted = 0
         for i, slot in enumerate(self._slots):
@@ -2495,6 +2572,9 @@ class ContinuousLMServer:
             self.metrics.record_tokens(emitted)
         self.metrics.set_pages(self._pool.in_use, self._pool.free,
                                self.kv_pages)
+        clock.to("yield")
+        self.metrics.record_round(clock.take(), width, self.n_slots, fed,
+                                  live_pages)
         return True
 
     def _run(self) -> None:
@@ -2532,6 +2612,7 @@ class ContinuousLMServer:
                         r.error = ServingUnavailableError(
                             "LM server stopped")
                         r.event.set()
+                    self._clock.to(None)
                     return
             try:
                 busy = self._drain_step()
@@ -2560,11 +2641,18 @@ class ContinuousLMServer:
                     self._reset_pool_locked()
                     self._cache = None
                 busy = True
+                self._clock.to("yield")
             if not busy:
+                # no lane active: the wait is no phase's, counted beside
+                # them, with the admit that found nothing to do
+                self._clock.to("idle")
                 with self._cond:
                     if not self._running:
+                        self._clock.to(None)
                         return
                     if not self._queue:
                         self._cond.wait(0.05)
+                self._clock.to("yield")
+                self.metrics.record_phase_seconds(self._clock.take())
             else:
                 time.sleep(0)  # yield: let submitters enqueue mid-decode

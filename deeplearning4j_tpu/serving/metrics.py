@@ -46,6 +46,19 @@ _CLASS_EVENTS = ("requests", "rejected", "shed", "deadline_missed",
 # created lazily on first record (see `record_tenant`).
 _TENANT_EVENTS = _CLASS_EVENTS + ("throttled",)
 
+# the phases of one scheduling round of the LM worker (ISSUE-24), in the
+# order a round passes through them; `lm.py` switches a `PhaseClock`
+# between them and the host plane of a profiler trace shows the same
+# intervals as `lm:<phase>` (docs/observability.md, "A round's phases")
+ROUND_PHASES = ("admit", "pages", "plan", "marshal", "dispatch", "sync",
+                "fold", "yield")
+# what a fed token was: a prompt token, a committed token fed back, or a
+# speculative draft riding the verify round
+FEED_KINDS = ("prefill", "decode", "draft")
+# host time of one round (everything but `sync`): milliseconds matter
+_ROUND_HOST_BUCKETS = (0.00025, 0.0005, 0.001, 0.002, 0.003, 0.004, 0.005,
+                       0.0075, 0.01, 0.02, 0.05, 0.1, 0.25, 1.0)
+
 # breaker state -> gauge value (the exposition's numeric encoding;
 # the string stays in /serving/stats)
 _BREAKER_VALUES = {"closed": 0, "open": 1, "half_open": 2}
@@ -228,6 +241,36 @@ class ServingMetrics:
         self.brownout_shed_total = Counter(
             "serving_brownout_shed_total",
             "best_effort admissions refused by ladder level 4")
+        # the LM worker's round ledger (ISSUE-24): where a round's wall
+        # time went by phase, what width it dispatched, what it fed and
+        # what the program paid for — the scheduler seen from inside
+        self.round_seconds = {
+            phase: Counter("serving_lm_round_seconds_total",
+                           "LM worker wall seconds by phase of the round")
+            for phase in ROUND_PHASES}
+        self.idle_seconds_total = Counter(
+            "serving_lm_idle_seconds_total",
+            "LM worker seconds waiting with no lane active (no phase "
+            "counts them)")
+        self.fed_tokens = {
+            kind: Counter("serving_lm_fed_tokens_total",
+                          "tokens fed to the step program by kind")
+            for kind in FEED_KINDS}
+        self.feed_capacity_total = Counter(
+            "serving_lm_feed_capacity_total",
+            "lanes x width of each round: token columns the program "
+            "paid for")
+        self.live_pages_total = Counter(
+            "serving_lm_live_pages_total",
+            "per round over active lanes, KV pages the attention has "
+            "to read")
+        self.round_host_hist = Histogram(
+            "serving_lm_round_host_seconds",
+            "host time of one round: every phase but sync",
+            buckets=_ROUND_HOST_BUCKETS)
+        # width is an open vocabulary (1, prefill_chunk, spec_width):
+        # cells are created on first use, like the tenants' below
+        self.rounds_by_width: Dict = {}      # width -> Counter
         # multi-tenant ledger (ISSUE-16): tenant names are an OPEN
         # vocabulary (fixed by the registry at serve time, unknown
         # here), so the per-tenant cells are created lazily on first
@@ -291,17 +334,26 @@ class ServingMetrics:
                   self.brownout_transitions_total,
                   self.brownout_shed_total,
                   self.latency_hist, self.queue_wait_hist,
-                  self.compute_hist):
+                  self.compute_hist,
+                  self.idle_seconds_total, self.feed_capacity_total,
+                  self.live_pages_total, self.round_host_hist):
             registry.register(m, **labels)
         for (_event, cls), m in self.class_counters.items():
             registry.register(m, priority=cls, **labels)
+        for phase, m in self.round_seconds.items():
+            registry.register(m, phase=phase, **labels)
+        for kind, m in self.fed_tokens.items():
+            registry.register(m, kind=kind, **labels)
         with self._lock:
             self._tenant_registrations.append((registry, dict(labels)))
             tenant_cells = ([(tn, m) for (_e, tn), m
                              in self.tenant_counters.items()]
                             + list(self.tenant_burn_gauges.items()))
+            width_cells = list(self.rounds_by_width.items())
         for tn, m in tenant_cells:
             registry.register(m, tenant=tn, **labels)
+        for width, m in width_cells:
+            registry.register(m, width=width, **labels)
         return self
 
     # ---- recording --------------------------------------------------------
@@ -328,6 +380,63 @@ class ServingMetrics:
                 self._queue_depth = int(queue_depth)
                 self.queue_depth_gauge.set(queue_depth)
             self._max_occupancy = max(self._max_occupancy, int(n_real))
+
+    def record_phase_seconds(self, seconds: Dict[str, float]) -> None:
+        """Worker wall seconds by phase (a `PhaseClock.take()`), added to
+        the phase counters; ``idle`` is the wait with no lane active,
+        kept beside the phases.  Phases plus idle partition the worker's
+        wall time: their sum over an interval is the interval."""
+        for phase, sec in seconds.items():
+            cell = self.round_seconds.get(phase)
+            if cell is not None:
+                cell.inc(sec)
+            elif phase == "idle":
+                self.idle_seconds_total.inc(sec)
+
+    def _late_cell(self, store: Dict, key, make, **label):
+        """The cell at `store[key]`, made on first use and published
+        under `label` on every registry this plane already registered
+        into (open vocabularies: widths, tenants)."""
+        c = store.get(key)  # noqa: LCK101 — DCL fast path; creation is locked below
+        if c is None:
+            regs = None
+            with self._lock:
+                c = store.get(key)
+                if c is None:
+                    c = make()
+                    regs = list(self._tenant_registrations)
+                    store[key] = c
+            if regs is not None:
+                # publish outside the lock: registry.register takes the
+                # registry's own lock, and this cell is already visible
+                for registry, labels in regs:
+                    registry.register(c, **label, **labels)
+        return c
+
+    def _width_counter(self, width: int) -> Counter:
+        return self._late_cell(
+            self.rounds_by_width, width,
+            lambda: Counter("serving_lm_rounds_total",
+                            "LM rounds by the width dispatched"),
+            width=width)
+
+    def record_round(self, seconds: Dict[str, float], width: int,
+                     lanes: int, fed: Dict[str, int],
+                     live_pages: int) -> None:
+        """One dispatched round of the LM worker, next to
+        `record_dispatch`: the phase seconds since the last call, the
+        width dispatched over `lanes` lanes, the tokens fed by kind and
+        the KV pages the active lanes' attention reads."""
+        self.record_phase_seconds(seconds)
+        self.round_host_hist.observe(sum(
+            sec for phase, sec in seconds.items()
+            if phase in self.round_seconds and phase != "sync"))
+        self._width_counter(int(width)).inc()
+        self.feed_capacity_total.inc(int(lanes) * int(width))
+        for kind, n in fed.items():
+            if n:
+                self.fed_tokens[kind].inc(int(n))
+        self.live_pages_total.inc(int(live_pages))
 
     def record_request(self, latency_s: float,
                        queue_wait_s: Optional[float] = None,
@@ -417,23 +526,11 @@ class ServingMetrics:
         self.record_class("preempted", priority)
 
     def _tenant_counter(self, event: str, tenant: str) -> Counter:
-        key = (event, tenant)
-        c = self.tenant_counters.get(key)  # noqa: LCK101 — DCL fast path; creation is locked below
-        if c is None:
-            regs = None
-            with self._lock:
-                c = self.tenant_counters.get(key)
-                if c is None:
-                    c = Counter(f"serving_lm_tenant_{event}_total",
-                                f"LM {event} by tenant")
-                    regs = list(self._tenant_registrations)
-                    self.tenant_counters[key] = c
-            if regs is not None:
-                # publish outside the lock: registry.register takes the
-                # registry's own lock, and this cell is already visible
-                for registry, labels in regs:
-                    registry.register(c, tenant=tenant, **labels)
-        return c
+        return self._late_cell(
+            self.tenant_counters, (event, tenant),
+            lambda: Counter(f"serving_lm_tenant_{event}_total",
+                            f"LM {event} by tenant"),
+            tenant=tenant)
 
     def record_tenant(self, event: str, tenant: str, n: int = 1) -> None:
         """Per-tenant traffic-shaping accounting (ISSUE-16): `event` is
@@ -453,21 +550,12 @@ class ServingMetrics:
         budget — > 1.0 means the tenant is burning budget and is first
         in line when the brownout ladder picks victims (ISSUE-16)."""
         tenant = str(tenant)
-        g = self.tenant_burn_gauges.get(tenant)  # noqa: LCK101 — DCL fast path; creation is locked below
-        if g is None:
-            regs = None
-            with self._lock:
-                g = self.tenant_burn_gauges.get(tenant)
-                if g is None:
-                    g = Gauge("serving_lm_tenant_slo_burn_rate",
-                              "per-tenant SLO burn rate (>1 = burning "
-                              "error budget)")
-                    regs = list(self._tenant_registrations)
-                    self.tenant_burn_gauges[tenant] = g
-            if regs is not None:
-                for registry, labels in regs:
-                    registry.register(g, tenant=tenant, **labels)
-        g.set(float(value))
+        self._late_cell(
+            self.tenant_burn_gauges, tenant,
+            lambda: Gauge("serving_lm_tenant_slo_burn_rate",
+                          "per-tenant SLO burn rate (>1 = burning "
+                          "error budget)"),
+            tenant=tenant).set(float(value))
 
     def record_swap(self, direction: str, pages: int,
                     nbytes: int) -> None:
@@ -581,7 +669,6 @@ class ServingMetrics:
         dispatches = int(self.dispatches_total.value)
         requests = int(self.requests_total.value)
         rows = int(self.rows_total.value)
-        padded = int(self.padded_rows_total.value)
         tokens = int(self.tokens_total.value)
         pq = int(self.prefix_queries_total.value)
         out = {
@@ -701,6 +788,25 @@ class ServingMetrics:
                 "transitions": int(
                     self.brownout_transitions_total.value),
                 "shed": int(self.brownout_shed_total.value)}
+        with self._lock:
+            width_cells = dict(self.rounds_by_width)
+        if width_cells:
+            # seconds and counts unrounded: readers take differences
+            host = self.round_host_hist.summary()
+            out["rounds"] = {
+                "count": sum(int(m.value) for m in width_cells.values()),
+                "by_width": {str(w): int(m.value)
+                             for w, m in sorted(width_cells.items())},
+                "seconds": {phase: float(m.value)
+                            for phase, m in self.round_seconds.items()},
+                "idle_s": float(self.idle_seconds_total.value),
+                "fed_tokens": {kind: int(m.value)
+                               for kind, m in self.fed_tokens.items()},
+                "feed_capacity": int(self.feed_capacity_total.value),
+                "live_pages": int(self.live_pages_total.value),
+                "host_ms": {"mean": 1e3 * host["mean"],
+                            "p50": 1e3 * host["p50"],
+                            "p99": 1e3 * host["p99"]}}
         if pq:
             out["prefix_queries"] = pq
             out["prefix_hits"] = int(self.prefix_hits_total.value)
@@ -714,8 +820,6 @@ class ServingMetrics:
         if dispatches:
             out["mean_batch_occupancy"] = round(rows / dispatches, 3)
             out["max_batch_occupancy"] = max_occ
-            # fraction of dispatched device rows that were real examples
-            out["pad_efficiency"] = round(rows / max(padded, 1), 3)
         if elapsed > 0:
             out["requests_per_sec"] = round(requests / elapsed, 1)
             if tokens:

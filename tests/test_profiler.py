@@ -4,9 +4,9 @@ import os
 
 import numpy as np
 
+from deeplearning4j_tpu.obs.trace import annotate
 from deeplearning4j_tpu.runtime.profiler import (
     StepTimer,
-    annotate,
     device_memory_stats,
     trace,
 )

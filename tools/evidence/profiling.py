@@ -18,9 +18,9 @@ from deeplearning4j_tpu.nn.conf import (  # noqa: E402
     NeuralNetConfiguration,
     OutputLayerConf,
 )
+from deeplearning4j_tpu.obs.trace import annotate  # noqa: E402
 from deeplearning4j_tpu.runtime.profiler import (  # noqa: E402
     StepTimer,
-    annotate,
     device_memory_stats,
     trace,
 )

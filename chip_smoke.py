@@ -288,10 +288,12 @@ def post(url: str, body: dict):
 
 def serve_phase(cfg, params, sz: Sizes, on_tpu: bool, cache_dir: str):
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    from deeplearning4j_tpu.parallel.generation import make_paged_step
+    from deeplearning4j_tpu.parallel.generation import (
+        init_paged_cache,
+        make_paged_step,
+    )
     from deeplearning4j_tpu.ui import UiServer
 
     srv = UiServer(port=0)
@@ -359,9 +361,8 @@ def serve_phase(cfg, params, sz: Sizes, on_tpu: bool, cache_dir: str):
 
     # the programs the server dispatched, from its own program factory
     # (same lru-cached jit objects; the persistent cache makes this cheap)
-    k = jax.ShapeDtypeStruct(
-        (cfg.n_layers, total_pages, ps, cfg.n_heads, cfg.head_dim),
-        jnp.dtype(cfg.dtype))
+    k = jax.eval_shape(
+        lambda: init_paged_cache(cfg, total_pages, ps))["k"]
     lanes = lm.n_slots
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
     for name, width in (("decode step", 1), ("chunk step", chunk)):

@@ -276,7 +276,9 @@ def slot_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
 def _compiled_slot_step(cfg: TransformerConfig):
     """ONE jitted program per config for the whole serving lifetime: the
     slot count is baked into the cache shapes, `pos` is a traced vector,
-    and the KV buffers are donated so the pool updates in place.
+    and the KV buffers are donated.  (Donated, not yet updated in place:
+    `slot_decode_step` still slices a layer and restacks with
+    `jnp.stack`, the idiom the paged step lost in PR 25; ROADMAP S4.)
 
     Per-slot sampling happens on device: `temperature[b] == 0` rows take
     the argmax, sampled rows draw from `fold_in(PRNGKey(seed[b]),
@@ -313,10 +315,10 @@ def make_slot_step(cfg: TransformerConfig):
 # The dense slot cache above provisions `slots * max_len` KV positions
 # whether or not any lane ever fills them — the serving-state memory
 # ceiling.  The paged variant replaces it with ONE fixed pool of
-# `[pages, page_size, H, K]` pages per layer plus a per-slot page list
+# `[layers, pages, page_size, H*K]` rows plus a per-slot page list
 # (`[slots, max_pages]` int32 block table) carried through the jitted
-# step: a lane's logical position `t` lives at
-# `pool[table[slot, t // page_size], t % page_size]`, so device capacity
+# step: a lane's logical position `t` of layer `i` lives at
+# `pool[i, table[slot, t // page_size], t % page_size]`, so device capacity
 # is sum-of-actual-lengths, pages are refcount-shared between lanes with
 # a common prompt prefix (radix cache, `serving/paged.py`), and a prompt
 # can feed up to `chunk` tokens per dispatch (chunked prefill) without a
@@ -324,6 +326,15 @@ def make_slot_step(cfg: TransformerConfig):
 # padding columns write there, and unallocated block-table entries point
 # there — its contents are garbage by design and every read of it is
 # masked.  One jitted program per (config, pages, page_size, chunk).
+#
+# Heads and head size share the pool's LAST axis (`H*K`, 1280 lanes for
+# GPT-2-large): a page is `page_size` rows of full 128-lane tiles, so the
+# buffer as it rests on the device, the rows the step scatters into it
+# and the blocks the paged kernel reads have one physical layout.  That
+# is what lets the donated buffers be updated in place: with `(H, K)` as
+# the minor dims the TPU compiler pads `(20, 64)` to `(24, 128)` for the
+# kernel, lays the scatter's rows out a third way, and converts between
+# the three by copying the pool (PERF.md section 4).
 
 
 def pages_per_seq(cfg: TransformerConfig, page_size: int) -> int:
@@ -333,25 +344,32 @@ def pages_per_seq(cfg: TransformerConfig, page_size: int) -> int:
 
 def init_paged_cache(cfg: TransformerConfig, pages: int,
                      page_size: int) -> dict:
-    """Paged KV pool: `pages` pages of `page_size` positions per layer
-    (page 0 reserved as the null page)."""
+    """Paged KV pool `[L, pages, page_size, H*K]`: `pages` pages of
+    `page_size` positions per layer (page 0 reserved as the null page),
+    each position one lane-dense row of all heads."""
     dt = jnp.dtype(cfg.dtype)
-    shape = (int(pages), int(page_size), cfg.n_heads, cfg.head_dim)
-    return {"k": jnp.zeros((cfg.n_layers,) + shape, dt),
-            "v": jnp.zeros((cfg.n_layers,) + shape, dt)}
+    shape = (cfg.n_layers, int(pages), int(page_size),
+             cfg.n_heads * cfg.head_dim)
+    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
-def _paged_attn(p, x, layer_k, layer_v, table, pos, n_feed,
+def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
                 paged_kernel: bool = False):
-    """Block-table paged attention for one layer.
+    """Block-table paged attention for layer `layer` (a Python int).
 
     x: [B, C, d] (C = prefill chunk width; decode dispatches use C=1);
-    layer_k/v: [P, ps, H, K] page pool; table: [B, MP] int32 page ids;
-    pos: [B] start positions; n_feed: [B] real columns this dispatch.
+    cache_k/v: the WHOLE stacked pool [L, P, ps, H*K]; table: [B, MP]
+    int32 page ids; pos: [B] start positions; n_feed: [B] real columns
+    this dispatch.  Returns (out [B, C, d], cache_k, cache_v): the same
+    stacked buffers with this layer's fed rows written.
 
-    Each lane scatters its fed tokens' k/v into its OWN pages (padding
-    columns and inactive lanes write the null page 0), then attends
-    over its logical history.  Two history paths share that scatter:
+    Each lane scatters its fed tokens' k/v rows into its OWN pages of
+    this layer (padding columns and inactive lanes write the layer's
+    null page 0) at flat row `(layer*P + page)*ps + off` of the stacked
+    buffer — no per-layer slice is taken and nothing is restacked, so
+    under `donate_argnums` the pool is updated where it lies.  Then the
+    lane attends over its logical history.  Two history paths share
+    that scatter:
 
     - ``paged_kernel=False`` — the gather ORACLE: materialize the full
       ``[B, MP*ps, H, K]`` history through the block table and run
@@ -360,14 +378,16 @@ def _paged_attn(p, x, layer_k, layer_v, table, pos, n_feed,
       dense pool.  Kept as the parity reference (and guarded against
       re-growth by dl4jlint PGD301 — this is the baselined occurrence).
     - ``paged_kernel=True`` — `paged_flash_attention` walks the block
-      table INSIDE the kernel: no contiguous history buffer, K/V
-      streamed page-by-page, beyond-``pos`` pages skipped, so HBM
-      traffic scales with live pages instead of ``MP*ps``.  Identical
-      math at every fed column (padding columns are never consumed).
+      table INSIDE the kernel, reading `[ps, H*K]` blocks of the
+      stacked pool through the table offset to this layer's pages: no
+      contiguous history buffer, K/V streamed page-by-page,
+      beyond-``pos`` pages skipped, so HBM traffic scales with live
+      pages instead of ``MP*ps``.  Identical math at every fed column
+      (padding columns are never consumed).
     """
     q, k, v = qkv_proj(p, x)                              # [B, C, H, K]
     b, c, h, kd = q.shape
-    pages, ps = layer_k.shape[0], layer_k.shape[1]
+    _, pages, ps, hkd = cache_k.shape
     mp = table.shape[1]
     j = jnp.arange(c)[None, :]                            # [1, C]
     wpos = pos[:, None] + j                               # [B, C] write pos
@@ -376,28 +396,29 @@ def _paged_attn(p, x, layer_k, layer_v, table, pos, n_feed,
     page = jnp.take_along_axis(table, lpage, axis=1)      # physical page
     page = jnp.where(real, page, 0)                       # padding -> null
     off = jnp.where(real, wpos % ps, 0)
-    idx = (page * ps + off).reshape(-1)                   # [B*C] flat rows
-    fk = layer_k.reshape(pages * ps, h, kd).at[idx].set(
-        k.reshape(b * c, h, kd))
-    fv = layer_v.reshape(pages * ps, h, kd).at[idx].set(
-        v.reshape(b * c, h, kd))
-    fk4 = fk.reshape(pages, ps, h, kd)
-    fv4 = fv.reshape(pages, ps, h, kd)
+    base = layer * pages                                  # this layer's pages
+    idx = ((base + page) * ps + off).reshape(-1)          # [B*C] flat rows
+    fk = cache_k.reshape(-1, hkd).at[idx].set(k.reshape(b * c, hkd))
+    fv = cache_v.reshape(-1, hkd).at[idx].set(v.reshape(b * c, hkd))
+    cache_k = fk.reshape(cache_k.shape)
+    cache_v = fv.reshape(cache_v.shape)
     if paged_kernel:
-        o = paged_flash_attention(q, fk4, fv4, table, pos, n_feed)
-        return out_proj(p, o), fk4, fv4
+        o = paged_flash_attention(q, cache_k, cache_v, table, pos, n_feed,
+                                  layer=layer)
+        return out_proj(p, o), cache_k, cache_v
     # gather each lane's logical history: [B, S, H, K], S = MP * ps
-    gidx = (table[:, :, None] * ps
+    gidx = ((base + table)[:, :, None] * ps
             + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
-    hk, hv = fk[gidx], fv[gidx]
-    s = jnp.einsum("bqhk,bshk->bqhs", q, hk) / jnp.sqrt(
+    hist_k = fk[gidx].reshape(b, mp * ps, h, kd)
+    hist_v = fv[gidx].reshape(b, mp * ps, h, kd)
+    s = jnp.einsum("bqhk,bshk->bqhs", q, hist_k) / jnp.sqrt(
         jnp.asarray(kd, q.dtype))
     causal = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]
     s = jnp.where(causal[:, :, None, :], s,
                   mask_value(s.dtype))                    # [B, C, H, S]
     w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bqhs,bshk->bqhk", w, hv)
-    return out_proj(p, o), fk4, fv4
+    o = jnp.einsum("bqhs,bshk->bqhk", w, hist_v)
+    return out_proj(p, o), cache_k, cache_v
 
 
 def paged_forward(cfg: TransformerConfig, params: dict, cache: dict,
@@ -411,29 +432,28 @@ def paged_forward(cfg: TransformerConfig, params: dict, cache: dict,
     Identical math to `slot_decode_step` per position — the chunk's own
     writes land in the pool before the gather, so intra-chunk causal
     attention rides the same masked-softmax path as the history.  The
-    all-column logits are what the speculative verify step consumes
-    (`make_spec_step`): column j scores the token that should FOLLOW
-    fed token j."""
+    stacked pool `[L, P, ps, H*K]` is carried from layer to layer, each
+    writing its own rows into it; what comes back is that buffer, not a
+    stack of per-layer copies.  The all-column logits are what the
+    speculative verify step consumes (`make_spec_step`): column j scores
+    the token that should FOLLOW fed token j."""
     c = tokens.shape[1]
     wpos = pos[:, None] + jnp.arange(c)[None, :]
     pidx = jnp.minimum(wpos, cfg.max_len - 1)             # clip padding
     x = params["embed"][tokens] + params["pos"][pidx]     # [B, C, d]
-    ks, vs = [], []
+    ck, cv = cache["k"], cache["v"]
     for i, layer in enumerate(params["layers"]):
-        a, nk, nv = _paged_attn(layer["attn"],
+        a, ck, cv = _paged_attn(layer["attn"],
                                 _layer_norm(layer["ln1"], x),
-                                cache["k"][i], cache["v"][i],
-                                table, pos, n_feed,
+                                ck, cv, i, table, pos, n_feed,
                                 paged_kernel=paged_kernel)
-        ks.append(nk)
-        vs.append(nv)
         x = x + a
         hh = _layer_norm(layer["ln2"], x)
         x = x + (_moe(layer["moe"], hh, top_k=cfg.moe_top_k)
                  if "moe" in layer else _mlp(layer["mlp"], hh))
     x = _layer_norm(params["ln_f"], x)
     logits = jnp.einsum("bcd,dv->bcv", x, lm_head(params))
-    return logits, {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+    return logits, {"k": ck, "v": cv}
 
 
 def paged_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
@@ -455,7 +475,10 @@ def _compiled_paged_step(cfg: TransformerConfig, pages: int,
                          paged_kernel: bool = False):
     """One jitted paged program per (config, pages, page_size, chunk):
     the pool shape and block-table width are baked in, the k/v buffers
-    are donated, and sampling is the SAME device-side per-slot automaton
+    `[L, P, ps, H*K]` are donated and come back as the SAME buffers with
+    `B*C` rows a layer written (`memory_analysis().alias_size_in_bytes`
+    is the pool's bytes; tests/test_paged_inplace.py holds it there),
+    and sampling is the SAME device-side per-slot automaton
     as `_compiled_slot_step` (greedy/temperature, fold_in(seed, count))
     so paged and dense lanes sample byte-identically.  `paged_kernel`
     arrives pre-resolved to a bool (see `resolve_paged_kernel`) so the
@@ -636,8 +659,14 @@ def _compiled_page_gather(cfg: TransformerConfig, pages: int,
     def gather(cache_k, cache_v, table_row):
         # table_row: [MP] int32 physical page ids; entries past the
         # shipped count point at the null page and the host slices them
-        # off before serialization
-        return cache_k[:, table_row], cache_v[:, table_row]
+        # off before serialization.  The stack leaves in the shipped
+        # [L, MP, ps, H, K] form: a reshape of the small stack, not of
+        # the pool
+        def pick(buf):
+            got = buf[:, table_row]
+            return got.reshape(got.shape[:3] + (cfg.n_heads, cfg.head_dim))
+
+        return pick(cache_k), pick(cache_v)
 
     return gather
 
@@ -662,8 +691,12 @@ def _compiled_page_install(cfg: TransformerConfig, pages: int,
     def install(cache_k, cache_v, pages_k, pages_v, table_row, n):
         mp = table_row.shape[0]
         dst = jnp.where(jnp.arange(mp) < n, table_row, 0)
-        return cache_k.at[:, dst].set(pages_k), cache_v.at[:, dst].set(
-            pages_v)
+
+        def put(buf, stack):
+            # the shipped [L, MP, ps, H, K] stack folded to the pool's rows
+            return buf.at[:, dst].set(stack.reshape(stack.shape[:3] + (-1,)))
+
+        return put(cache_k, pages_k), put(cache_v, pages_v)
 
     return install
 

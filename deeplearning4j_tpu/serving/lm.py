@@ -17,8 +17,9 @@ KV state comes in two modes (ISSUE-7):
 - `kv="dense"` — the original one `[L, slots, max_len, H, K]` cache:
   every lane provisions max_len positions whether it uses them or not
   (`parallel.generation.make_slot_step`).
-- `kv="paged"` (default) — block-table paged KV: one fixed pool of
-  `[pages, page_size, H, K]` pages per layer, per-slot page lists
+- `kv="paged"` (default) — block-table paged KV: one fixed pool
+  `[L, pages, page_size, H*K]` (lane-dense rows, updated in place by the
+  donating step), per-slot page lists
   carried as a `[slots, max_pages]` int32 block table inside the jitted
   step (`parallel.generation.make_paged_step`).  Pages are allocated on
   admission and refcount-freed on completion (`serving/paged.py`), so

@@ -1,7 +1,7 @@
 """Host-side state for the paged KV cache: page allocator + radix cache.
 
 The device side (`parallel.generation.make_paged_step`) addresses one
-fixed pool of `[pages, page_size, H, K]` KV pages per layer through a
+fixed pool `[layers, pages, page_size, H*K]` of KV pages through a
 per-slot block table.  This module owns which physical page holds what:
 
 - `PagePool` — a refcounted free-list allocator over the page ids.

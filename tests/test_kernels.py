@@ -421,10 +421,17 @@ class TestPagedKernelFullStack:
                 b, c, h, kd, ps, mp, np.array([5, 2], np.int32),
                 seed=seed)
             nf = jnp.full((b,), c, jnp.int32)
-            oo, ko, vo = _paged_attn(layer, x, kp, vp, table, pos, nf,
+            # the layer works on the stacked pool [L, P, ps, H*K]; its
+            # pages are layer 1 of 2 here (layer 0 must stay untouched)
+            kp = jnp.stack([kp[::-1], kp]).reshape(2, -1, ps, h * kd)
+            vp = jnp.stack([vp[::-1], vp]).reshape(2, -1, ps, h * kd)
+            oo, ko, vo = _paged_attn(layer, x, kp, vp, 1, table, pos, nf,
                                      paged_kernel=False)
-            ok, kk, vk = _paged_attn(layer, x, kp, vp, table, pos, nf,
+            ok, kk, vk = _paged_attn(layer, x, kp, vp, 1, table, pos, nf,
                                      paged_kernel=True)
+            assert ko.shape == kp.shape
+            np.testing.assert_array_equal(np.asarray(ko[0]),
+                                          np.asarray(kp[0]))
             np.testing.assert_allclose(np.asarray(ok), np.asarray(oo),
                                        atol=1e-5)
             np.testing.assert_array_equal(np.asarray(kk), np.asarray(ko))

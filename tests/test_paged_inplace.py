@@ -1,0 +1,299 @@
+"""The serve step updates the KV pool in place (ISSUE 25).
+
+The pool rests on the device as ``[L, P, ps, H*K]``: one lane-dense
+layout that the step's scatter writes and the paged kernel reads, so the
+donated buffers come back as themselves.  These tests hold that:
+
+- the new formulation (stacked pool carried layer to layer, rows written
+  at ``(layer*P + page)*ps + off``) is bit-equal to the old one written
+  out here (per-layer slice of an ``[L, P, ps, H, K]`` pool, scatter,
+  ``jnp.stack``) on the oracle path;
+- the kernel reading layer ``i`` of the stacked pool equals the 4-D call
+  on layer ``i``'s pages and the gather oracle;
+- page copy / gather / install keep their ``[L, MP, ps, H, K]`` contract;
+- the lowered ``step`` aliases both pool arguments to its outputs, on the
+  CPU and, compile-only, at GPT-2-large's width for a TPU v5e, where the
+  step's temporaries must not hold a copy of the pool.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.parallel import generation as gen
+from deeplearning4j_tpu.parallel import paged_kernel as pk
+from deeplearning4j_tpu.parallel import transformer as tfm
+from deeplearning4j_tpu.parallel.kernels import mask_value
+
+
+def _toy(n_layers=3, max_len=32):
+    cfg = tfm.TransformerConfig(vocab_size=50, d_model=16, n_heads=2,
+                                n_layers=n_layers, d_ff=32,
+                                max_len=max_len)
+    return cfg, tfm.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _random_pool(cfg, pages, ps, seed):
+    rng = np.random.default_rng(seed)
+    cache = gen.init_paged_cache(cfg, pages, ps)
+    return {n: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+            for n, a in cache.items()}
+
+
+def _parent_paged_forward(cfg, params, cache5, table, pos, n_feed, tokens):
+    """`paged_forward` as it was before the pool changed shape, oracle
+    path: `cache5` is ``[L, P, ps, H, K]``; every layer takes its slice,
+    scatters into a flattened copy, gathers the history, and the slices
+    are stacked at the end."""
+    c = tokens.shape[1]
+    wpos = pos[:, None] + jnp.arange(c)[None, :]
+    x = (params["embed"][tokens]
+         + params["pos"][jnp.minimum(wpos, cfg.max_len - 1)])
+    ks, vs = [], []
+    for i, layer in enumerate(params["layers"]):
+        q, k, v = tfm.qkv_proj(layer["attn"],
+                               tfm._layer_norm(layer["ln1"], x))
+        b, _, h, kd = q.shape
+        layer_k, layer_v = cache5["k"][i], cache5["v"][i]
+        pages, ps = layer_k.shape[:2]
+        mp = table.shape[1]
+        real = jnp.arange(c)[None, :] < n_feed[:, None]
+        lpage = jnp.minimum(wpos // ps, mp - 1)
+        page = jnp.where(real, jnp.take_along_axis(table, lpage, axis=1), 0)
+        off = jnp.where(real, wpos % ps, 0)
+        idx = (page * ps + off).reshape(-1)
+        fk = layer_k.reshape(pages * ps, h, kd).at[idx].set(
+            k.reshape(b * c, h, kd))
+        fv = layer_v.reshape(pages * ps, h, kd).at[idx].set(
+            v.reshape(b * c, h, kd))
+        gidx = (table[:, :, None] * ps
+                + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
+        s = jnp.einsum("bqhk,bshk->bqhs", q, fk[gidx]) / jnp.sqrt(
+            jnp.asarray(kd, q.dtype))
+        causal = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]
+        s = jnp.where(causal[:, :, None, :], s, mask_value(s.dtype))
+        o = jnp.einsum("bqhs,bshk->bqhk", jax.nn.softmax(s, axis=-1),
+                       fv[gidx])
+        x = x + tfm.out_proj(layer["attn"], o)
+        x = x + tfm._mlp(layer["mlp"], tfm._layer_norm(layer["ln2"], x))
+        ks.append(fk.reshape(pages, ps, h, kd))
+        vs.append(fv.reshape(pages, ps, h, kd))
+    x = tfm._layer_norm(params["ln_f"], x)
+    logits = jnp.einsum("bcd,dv->bcv", x, tfm.lm_head(params))
+    return logits, {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_paged_forward_bit_equal_to_slice_scatter_stack(width):
+    """Oracle path, 3 layers: logits and pool bit-equal to the parent's
+    formulation, with a lane that feeds fewer columns than the width (a
+    padding column, which writes the null page) and an idle lane."""
+    cfg, params = _toy()
+    ps, b = 4, 4
+    mp = gen.pages_per_seq(cfg, ps)
+    pages = 1 + b * mp
+    cache = _random_pool(cfg, pages, ps, seed=width)
+    rng = np.random.default_rng(width)
+    table = np.stack([1 + i * mp + rng.permutation(mp) for i in range(b)])
+    table[3] = 0                                  # the idle lane
+    table = jnp.asarray(table, jnp.int32)
+    pos = jnp.asarray([0, 6, 13, 0], jnp.int32)
+    n_feed = jnp.asarray([width, max(width - 3, 1), 1, 0], jnp.int32)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, width)),
+                         jnp.int32)
+    h, kd = cfg.n_heads, cfg.head_dim
+    cache5 = {n: a.reshape(a.shape[:3] + (h, kd)) for n, a in cache.items()}
+
+    got_logits, got = jax.jit(
+        lambda p, ch: gen.paged_forward(cfg, p, ch, table, pos, n_feed,
+                                        tokens, paged_kernel=False)
+    )(params, cache)
+    want_logits, want = jax.jit(
+        lambda p, ch: _parent_paged_forward(cfg, p, ch, table, pos,
+                                            n_feed, tokens)
+    )(params, cache5)
+
+    assert got["k"].shape == (cfg.n_layers, pages, ps, h * kd)
+    np.testing.assert_array_equal(np.asarray(got_logits),
+                                  np.asarray(want_logits))
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(got[n]), np.asarray(want[n]).reshape(got[n].shape))
+    # something was written, and only where the table says
+    assert not np.array_equal(np.asarray(got["k"]), np.asarray(cache["k"]))
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_kernel_reads_its_layer_of_the_stacked_pool(layer):
+    """`paged_flash_attention(..., layer=i)` on ``[L, P, ps, H*K]`` equals
+    the 4-D call on layer i's pages ``[P, ps, H, K]`` and the gather
+    oracle, at tests/test_kernels.py's tolerance."""
+    from test_kernels import (
+        _assert_fed_columns_match,
+        _gather_oracle,
+        _paged_state,
+    )
+
+    n_layers, b, c, h, kd, ps, mp = 3, 3, 4, 2, 8, 4, 6
+    pos = np.array([2, 9, 14], np.int32)
+    rng = np.random.default_rng(11)
+    q, kp, _, table, posj = _paged_state(b, c, h, kd, ps, mp, pos, seed=5)
+    pool_k = jnp.asarray(rng.standard_normal((n_layers,) + kp.shape),
+                         jnp.float32)
+    pool_v = jnp.asarray(rng.standard_normal((n_layers,) + kp.shape),
+                         jnp.float32)
+    nf = jnp.asarray([4, 2, 3], jnp.int32)
+
+    def flat(a):
+        return a.reshape(a.shape[:3] + (h * kd,))
+
+    got = pk.paged_flash_attention(q, flat(pool_k), flat(pool_v), table,
+                                   posj, nf, layer=layer)
+    four_d = pk.paged_flash_attention(q, pool_k[layer], pool_v[layer],
+                                      table, posj, nf)
+    want = _gather_oracle(q, pool_k[layer], pool_v[layer], table, posj)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(four_d))
+    _assert_fed_columns_match(got, want, nf)
+    other = _gather_oracle(q, pool_k[(layer + 1) % n_layers],
+                           pool_v[(layer + 1) % n_layers], table, posj)
+    assert not np.allclose(np.asarray(got)[0, 0], np.asarray(other)[0, 0],
+                           atol=1e-3)
+
+
+def test_page_copy_gather_install_keep_their_contract():
+    """Copy, gather and install round-trip a page through the
+    ``[L, P, ps, H*K]`` pool; the page stack that crosses the program's
+    edge is still ``[L, MP, ps, H, K]`` (serving/transfer.py's format)."""
+    cfg, _ = _toy()
+    ps, b = 4, 2
+    mp = gen.pages_per_seq(cfg, ps)
+    pages = 1 + b * mp
+    h, kd, n_layers = cfg.n_heads, cfg.head_dim, cfg.n_layers
+    cache = _random_pool(cfg, pages, ps, seed=3)
+    k0, v0 = np.asarray(cache["k"]), np.asarray(cache["v"])
+
+    copy = gen.make_page_copy(cfg, pages, ps)
+    k, v = copy(cache["k"], cache["v"], np.int32(3), np.int32(7))
+    assert k.shape == (n_layers, pages, ps, h * kd)
+    np.testing.assert_array_equal(np.asarray(k)[:, 7], k0[:, 3])
+    np.testing.assert_array_equal(np.asarray(v)[:, 7], v0[:, 3])
+    np.testing.assert_array_equal(np.asarray(k)[:, :7], k0[:, :7])
+
+    gather = gen.make_page_gather(cfg, pages, ps)
+    row = jnp.asarray(np.r_[[7, 2, 5], np.zeros(mp - 3)], jnp.int32)
+    pages_k, pages_v = gather(k, v, row)
+    assert pages_k.shape == (n_layers, mp, ps, h, kd)
+    np.testing.assert_array_equal(
+        np.asarray(pages_k)[:, :3],
+        np.asarray(k)[:, [7, 2, 5]].reshape(n_layers, 3, ps, h, kd))
+
+    # install what was gathered into a fresh pool at other page ids: the
+    # first two pages land, the rest of the row goes to the null page
+    fresh = gen.init_paged_cache(cfg, pages, ps)
+    install = gen.make_page_install(cfg, pages, ps)
+    dst = jnp.asarray(np.r_[[4, 9, 11], np.zeros(mp - 3)], jnp.int32)
+    k2, v2 = install(fresh["k"], fresh["v"], pages_k, pages_v, dst,
+                     np.int32(2))
+    assert k2.shape == (n_layers, pages, ps, h * kd)
+    np.testing.assert_array_equal(np.asarray(k2)[:, 4], np.asarray(k)[:, 7])
+    np.testing.assert_array_equal(np.asarray(v2)[:, 9], np.asarray(v)[:, 2])
+    assert not np.asarray(k2)[:, 11].any()        # past n: not installed
+    back_k, _ = gather(k2, v2, dst)
+    np.testing.assert_array_equal(np.asarray(back_k)[:, :2],
+                                  np.asarray(pages_k)[:, :2])
+
+
+def _step_args(cfg, lanes, width, ps, sds):
+    mp = gen.pages_per_seq(cfg, ps)
+    pages = lanes * mp + 1
+    pool = jax.eval_shape(lambda: gen.init_paged_cache(cfg, pages, ps))["k"]
+    pool = sds(pool.shape, pool.dtype)
+
+    def i32(*s):
+        return sds(s, np.int32)
+
+    return pages, pool, (pool, pool, i32(lanes, mp), i32(lanes), i32(lanes),
+                         i32(lanes, width), sds((lanes,), np.float32),
+                         i32(lanes), i32(lanes))
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_lowered_step_aliases_both_pool_arguments(width):
+    """The jitted `step` donates k and v and its outputs 1 and 2 alias
+    them: by the lowered text, and by the compiled program's alias bytes
+    (the pool's, exactly)."""
+    cfg, params = _toy()
+    ps, lanes = 4, 3
+    pages, pool, args = _step_args(cfg, lanes, width, ps,
+                                   jax.ShapeDtypeStruct)
+    step = gen.make_paged_step(cfg, pages, ps, width, paged_kernel=False)
+    lowered = step.lower(params, *args)
+    assert sorted(re.findall(r"tf\.aliasing_output = (\d+)",
+                             lowered.as_text())) == ["1", "2"]
+    pool_bytes = 2 * int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert (lowered.compile().memory_analysis().alias_size_in_bytes
+            == pool_bytes)
+
+
+# ---- compile-only for a TPU v5e that is described and not attached -------
+# (`on-chip-measurement` guide, section 2: the topology is described inside
+# a fixture, never at import, and everything that needs it lives in this one
+# file.)
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means: no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("width", [1, 8])
+def test_v5e_step_holds_no_copy_of_the_pool(one_chip, width, monkeypatch):
+    """GPT-2-large's width (20 heads x 64, bf16, page 16, 16 lanes) cut to
+    two layers, compiled for a v5e with the Mosaic kernel: both pool
+    arguments are aliased whole and the step's temporaries stay far under
+    one copy of the pool (a 5-D ``[L, P, ps, H, K]`` pool compiles to two
+    to three copies, PERF.md section 4).  The kernel's result keeps the
+    signature the benchmark's trace readers match."""
+    # the program asks `jax.default_backend()`, which is the CPU here
+    monkeypatch.setattr(pk, "_resolve_interpret", lambda interpret: False)
+    cfg = dataclasses.replace(tfm.gpt2_large(), n_layers=2,
+                              dtype="bfloat16")
+    ps, lanes = 16, 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    pages, pool, args = _step_args(cfg, lanes, width, ps, sds)
+    # an uncached build: the cached one belongs to the process's own runs
+    step = gen._compiled_paged_step.__wrapped__(cfg, pages, ps, width, True)
+    compiled = step.lower(params, *args).compile()
+    ma = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert ma.alias_size_in_bytes == pool_bytes
+    assert ma.temp_size_in_bytes < pool_bytes // 8
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == cfg.n_layers
+    for ln in calls:
+        assert re.search(r"= bf16\[%d,%d,\d+,\d+\]\S* custom-call\("
+                         % (lanes, width), ln), ln
+    # no copy and no restacking of a pool-sized buffer is left
+    big = r"bf16\[%d,%d,%d,\d+(,\d+)?\]" % (cfg.n_layers, pages, ps)
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= %s\S* (copy|concatenate)\(" % big, ln)]

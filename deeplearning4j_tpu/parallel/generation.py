@@ -14,6 +14,7 @@ honored here).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -23,22 +24,28 @@ from jax import lax
 
 from deeplearning4j_tpu.parallel.kernels import mask_value
 from deeplearning4j_tpu.parallel.paged_kernel import (
+    latent_paged_attention,
     paged_flash_attention,
     resolve_paged_kernel,
 )
 from deeplearning4j_tpu.parallel.transformer import (
     TransformerConfig,
-    _layer_norm,
-    _mlp,
-    _moe,
+    block,
+    embed_tokens,
+    feed_forward,
+    latent_proj,
+    latent_softmax_scale,
     lm_head,
+    norm,
     out_proj,
     qkv_proj,
+    require_classic,
 )
 
 
 def init_cache(cfg: TransformerConfig, batch: int) -> dict:
     """Fixed-shape KV cache: one [B, max_len, H, K] pair per layer."""
+    require_classic(cfg, "the dense KV cache (generate / beam_search)")
     dt = jnp.dtype(cfg.dtype)
     shape = (batch, cfg.max_len, cfg.n_heads, cfg.head_dim)
     return {
@@ -75,19 +82,16 @@ def decode_step(cfg: TransformerConfig, params: dict, cache: dict,
         params["pos"], pos, 1, axis=0)[None]
     ks, vs = [], []
     for i, layer in enumerate(params["layers"]):
-        a, nk, nv = _cached_attn(layer["attn"],
-                                 _layer_norm(layer["ln1"], x),
-                                 cache["k"][i], cache["v"][i], pos)
-        ks.append(nk)
-        vs.append(nv)
-        x = x + a
-        h = _layer_norm(layer["ln2"], x)
-        # Dense-masked MoE (capacity_factor=0): exact, no drops — matches
-        # apply()'s inference default, preserving this module's
-        # cache-path == full-recompute contract for MoE configs.
-        x = x + (_moe(layer["moe"], h, top_k=cfg.moe_top_k)
-                 if "moe" in layer else _mlp(layer["mlp"], h))
-    x = _layer_norm(params["ln_f"], x)
+        def attend(p, h, i=i):
+            a, nk, nv = _cached_attn(p, h, cache["k"][i], cache["v"][i], pos)
+            ks.append(nk)
+            vs.append(nv)
+            return a
+
+        # experts at inference are dropless (`transformer._moe`): the
+        # same function `apply` computes, so cache path == full recompute
+        x = block(cfg, layer, x, attend)
+    x = norm(cfg, params["ln_f"], x)
     logits = jnp.einsum("bsd,dv->bsv", x, lm_head(params))[:, 0]
     new_cache = {"k": jnp.stack(ks), "v": jnp.stack(vs), "pos": pos + 1}
     return logits, new_cache
@@ -236,6 +240,7 @@ def _slot_attn(p, x, layer_k, layer_v, pos):
 
 def init_slot_cache(cfg: TransformerConfig, slots: int) -> dict:
     """Slot KV cache: `init_cache` with a [B] per-slot position vector."""
+    require_classic(cfg, "the dense slot cache (kv='dense')")
     dt = jnp.dtype(cfg.dtype)
     shape = (slots, cfg.max_len, cfg.n_heads, cfg.head_dim)
     return {"k": jnp.zeros((cfg.n_layers,) + shape, dt),
@@ -257,16 +262,14 @@ def slot_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
          + jnp.take(params["pos"], pos, axis=0)[:, None, :])
     ks, vs = [], []
     for i, layer in enumerate(params["layers"]):
-        a, nk, nv = _slot_attn(layer["attn"],
-                               _layer_norm(layer["ln1"], x),
-                               cache["k"][i], cache["v"][i], pos)
-        ks.append(nk)
-        vs.append(nv)
-        x = x + a
-        h = _layer_norm(layer["ln2"], x)
-        x = x + (_moe(layer["moe"], h, top_k=cfg.moe_top_k)
-                 if "moe" in layer else _mlp(layer["mlp"], h))
-    x = _layer_norm(params["ln_f"], x)
+        def attend(p, h, i=i):
+            a, nk, nv = _slot_attn(p, h, cache["k"][i], cache["v"][i], pos)
+            ks.append(nk)
+            vs.append(nv)
+            return a
+
+        x = block(cfg, layer, x, attend)
+    x = norm(cfg, params["ln_f"], x)
     logits = jnp.einsum("bsd,dv->bsv", x, lm_head(params))[:, 0]
     new_cache = {"k": jnp.stack(ks), "v": jnp.stack(vs), "pos": pos + 1}
     return logits, new_cache
@@ -290,13 +293,8 @@ def _compiled_slot_step(cfg: TransformerConfig):
              counts):
         cache = {"k": cache_k, "v": cache_v, "pos": pos}
         logits, cache = slot_decode_step(cfg, params, cache, token)
-        logits = logits.astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1)
-        keys = jax.vmap(lambda s, c: jax.random.fold_in(
-            jax.random.PRNGKey(s), c))(seeds, counts)
-        temp = jnp.maximum(temperature, 1e-6)[:, None]
-        sampled = jax.vmap(jax.random.categorical)(keys, logits / temp)
-        nxt = jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+        nxt = _sample(logits.astype(jnp.float32), temperature, seeds,
+                      counts)
         return nxt, cache["k"], cache["v"]
 
     return step
@@ -342,15 +340,72 @@ def pages_per_seq(cfg: TransformerConfig, page_size: int) -> int:
     return -(-int(cfg.max_len) // int(page_size))
 
 
+@dataclasses.dataclass(frozen=True)
+class PoolLayout:
+    """What the paged pool keeps a token and layer, the ONE place every
+    reader takes it from (the step programs, `serving/lm.py`'s stats,
+    gather/install shapes and warm-up, `serving/transfer.py`,
+    `serving/hibernate.py`): `pools` arrays `[L, P, ps, heads * width]`,
+    named `names`; a row leaves the pool (shipping, swap, hibernation)
+    as `[heads, width]`."""
+
+    names: Tuple[str, ...]
+    heads: int
+    width: int
+
+    @property
+    def row(self) -> int:
+        return self.heads * self.width
+
+
+def pool_layout(cfg: TransformerConfig) -> PoolLayout:
+    """Full heads: a key pool and a value pool, a row `[H, K]`.  Latent
+    attention: ONE pool, a row `[c_kv | k_rope]` for all heads, key and
+    value at once (576 values for DeepSeek-V2), held in whole 128-lane
+    tiles (640 lanes: the device pads the minor dim to them whatever is
+    declared, and the kernel's DMA takes whole tiles), the tail zero."""
+    if cfg.latent is None:
+        return PoolLayout(("k", "v"), cfg.n_heads, cfg.head_dim)
+    values = cfg.latent.row_values
+    return PoolLayout(("kv",), 1,
+                      -(-values // 128) * 128 if values > 128 else values)
+
+
+def pool_token_bytes(cfg: TransformerConfig) -> int:
+    """Bytes the pool holds a cached token, all layers."""
+    lay = pool_layout(cfg)
+    return (len(lay.names) * cfg.n_layers * lay.row
+            * jnp.dtype(cfg.dtype).itemsize)
+
+
 def init_paged_cache(cfg: TransformerConfig, pages: int,
                      page_size: int) -> dict:
-    """Paged KV pool `[L, pages, page_size, H*K]`: `pages` pages of
-    `page_size` positions per layer (page 0 reserved as the null page),
-    each position one lane-dense row of all heads."""
+    """The paged pool, `{name: [L, pages, page_size, row]}` by
+    `pool_layout` (page 0 reserved as the null page): `k` and `v` with
+    each position one lane-dense row of all heads, or the one latent
+    pool `kv`."""
     dt = jnp.dtype(cfg.dtype)
-    shape = (cfg.n_layers, int(pages), int(page_size),
-             cfg.n_heads * cfg.head_dim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    lay = pool_layout(cfg)
+    shape = (cfg.n_layers, int(pages), int(page_size), lay.row)
+    return {name: jnp.zeros(shape, dt) for name in lay.names}
+
+
+def _fed_rows(table, pos, n_feed, c: int, pages: int, ps: int, layer: int):
+    """Where a dispatch writes: (flat pool rows [B*C] of the fed columns,
+    write positions [B, C]).  Lane b's column j lands at position
+    `pos[b] + j` of its own pages of layer `layer`, flat row
+    `(layer*P + page)*ps + off` of the stacked pool; padding columns and
+    inactive lanes write the layer's null page 0."""
+    mp = table.shape[1]
+    j = jnp.arange(c)[None, :]                            # [1, C]
+    wpos = pos[:, None] + j                               # [B, C] write pos
+    real = j < n_feed[:, None]                            # [B, C]
+    lpage = jnp.minimum(wpos // ps, mp - 1)               # logical page
+    page = jnp.take_along_axis(table, lpage, axis=1)      # physical page
+    page = jnp.where(real, page, 0)                       # padding -> null
+    off = jnp.where(real, wpos % ps, 0)
+    base = layer * pages                                  # this layer's pages
+    return ((base + page) * ps + off).reshape(-1), wpos
 
 
 def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
@@ -389,15 +444,8 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
     b, c, h, kd = q.shape
     _, pages, ps, hkd = cache_k.shape
     mp = table.shape[1]
-    j = jnp.arange(c)[None, :]                            # [1, C]
-    wpos = pos[:, None] + j                               # [B, C] write pos
-    real = j < n_feed[:, None]                            # [B, C]
-    lpage = jnp.minimum(wpos // ps, mp - 1)               # logical page
-    page = jnp.take_along_axis(table, lpage, axis=1)      # physical page
-    page = jnp.where(real, page, 0)                       # padding -> null
-    off = jnp.where(real, wpos % ps, 0)
     base = layer * pages                                  # this layer's pages
-    idx = ((base + page) * ps + off).reshape(-1)          # [B*C] flat rows
+    idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
     fk = cache_k.reshape(-1, hkd).at[idx].set(k.reshape(b * c, hkd))
     fv = cache_v.reshape(-1, hkd).at[idx].set(v.reshape(b * c, hkd))
     cache_k = fk.reshape(cache_k.shape)
@@ -421,52 +469,183 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
     return out_proj(p, o), cache_k, cache_v
 
 
-def paged_forward(cfg: TransformerConfig, params: dict, cache: dict,
-                  table: jax.Array, pos: jax.Array, n_feed: jax.Array,
-                  tokens: jax.Array,
-                  paged_kernel: bool = False) -> Tuple[jax.Array, dict]:
-    """tokens: [B, C] int32, lane b feeding its first n_feed[b] columns
-    at positions pos[b].. -> (logits [B, C, V] at EVERY fed column,
-    cache with the fed k/v scattered into the page pool).
+def _latent_paged_attn(cfg: TransformerConfig, p, x, pool, layer: int,
+                       table, pos, n_feed, paged_kernel: bool = False):
+    """`_paged_attn` for latent attention, in the ABSORBED form (an
+    identity, not an approximation): the fed tokens' rows
+    `[c_kv | k_rope]` (normed, rotated) are scattered into the one pool
+    `[L, P, ps, R]`; the queries are taken into the row's space,
+    `q_abs_h = [q_nope_h W_uk_h^T | q_rope_h]`, so a score is
+    `q_abs_h . row` and every head reads the SAME row; the value mix is
+    formed in latent space and leaves through `W_uv_h`.  Wide rounds and
+    width 1 alike: the history is never up-projected.  With
+    `paged_kernel` the block table is walked by
+    `latent_paged_attention`; without, the gather oracle.
+    -> (out [B, C, d], pool)."""
+    la = cfg.latent
+    b, c, _ = x.shape
+    _, pages, ps, r = pool.shape
+    with jax.named_scope("attn:latent"):
+        idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
+        q_nope, q_rope, c_kv, k_rope = latent_proj(cfg, p, x, wpos)
+        def to_row(a):      # the tail of a row's 128-lane tiles, zero
+            return jnp.pad(a, [(0, 0)] * (a.ndim - 1)
+                           + [(0, r - la.row_values)])
 
-    Identical math to `slot_decode_step` per position — the chunk's own
-    writes land in the pool before the gather, so intra-chunk causal
-    attention rides the same masked-softmax path as the history.  The
-    stacked pool `[L, P, ps, H*K]` is carried from layer to layer, each
-    writing its own rows into it; what comes back is that buffer, not a
-    stack of per-layer copies.  The all-column logits are what the
-    speculative verify step consumes (`make_spec_step`): column j scores
-    the token that should FOLLOW fed token j."""
+        row = to_row(jnp.concatenate([c_kv, k_rope], axis=-1))
+        flat = pool.reshape(-1, r).at[idx].set(row.reshape(b * c, r))
+        pool = flat.reshape(pool.shape)
+        w_uk = p["wukv"][:, :, :la.nope_dim]                  # [rank, H, nope]
+        w_uv = p["wukv"][:, :, la.nope_dim:]                  # [rank, H, v]
+        q_lat = jnp.einsum("bchk,rhk->bchr", q_nope, w_uk)
+        q_abs = to_row(jnp.concatenate([q_lat, q_rope], axis=-1))
+        scale = latent_softmax_scale(cfg)
+        if paged_kernel:
+            o_lat = latent_paged_attention(
+                q_abs, pool, table, pos, n_feed, layer=layer,
+                v_width=la.kv_rank, scale=scale)
+        else:
+            mp = table.shape[1]
+            gidx = ((layer * pages + table)[:, :, None] * ps
+                    + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
+            hist = flat[gidx]                                 # [B, S, R]
+            sc = jnp.einsum("bchr,bsr->bchs", q_abs, hist
+                            ).astype(jnp.float32) * scale
+            seen = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]
+            sc = jnp.where(seen[:, :, None, :], sc, mask_value(sc.dtype))
+            o_lat = jnp.einsum("bchs,bsr->bchr",
+                               jax.nn.softmax(sc, axis=-1).astype(x.dtype),
+                               hist[..., :la.kv_rank])
+        o = jnp.einsum("bchr,rhk->bchk", o_lat, w_uv)
+        return jnp.einsum("bchk,hkd->bcd", o, p["wo"]), pool
+
+
+def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
+                  table: jax.Array, pos: jax.Array, n_feed: jax.Array,
+                  tokens: jax.Array, paged_kernel: bool = False,
+                  loads: Optional[list] = None) -> Tuple[jax.Array, dict]:
+    """tokens: [B, C] int32, lane b feeding its first n_feed[b] columns
+    at positions pos[b].. -> (the last layer's output [B, C, d] at EVERY
+    fed column, before the final norm and the head, cache with the fed
+    rows scattered into the page pool).
+
+    Every family's layer is `transformer.block`; what differs is the
+    attention it is handed (`_paged_attn` over the k and v pools,
+    `_latent_paged_attn` over the one latent pool).  Identical math to
+    `slot_decode_step` per position — the chunk's own writes land in the
+    pool before the gather, so intra-chunk causal attention rides the
+    same masked-softmax path as the history.  The stacked pool
+    `[L, P, ps, row]` is carried from layer to layer, each writing its
+    own rows into it; what comes back is that buffer, not a stack of
+    per-layer copies.  `loads` collects each `RoutedExperts` layer's
+    load counts."""
     c = tokens.shape[1]
     wpos = pos[:, None] + jnp.arange(c)[None, :]
     pidx = jnp.minimum(wpos, cfg.max_len - 1)             # clip padding
-    x = params["embed"][tokens] + params["pos"][pidx]     # [B, C, d]
-    ck, cv = cache["k"], cache["v"]
+    x = embed_tokens(cfg, params, tokens, pidx)           # [B, C, d]
+    pools = dict(cache)
+    ffn = None
+    if cfg.experts is not None:
+        fed = jnp.arange(c)[None, :] < n_feed[:, None]
+
+        def ffn(layer, h):
+            return feed_forward(cfg, layer, h, fed, loads)
+
     for i, layer in enumerate(params["layers"]):
-        a, ck, cv = _paged_attn(layer["attn"],
-                                _layer_norm(layer["ln1"], x),
-                                ck, cv, i, table, pos, n_feed,
-                                paged_kernel=paged_kernel)
-        x = x + a
-        hh = _layer_norm(layer["ln2"], x)
-        x = x + (_moe(layer["moe"], hh, top_k=cfg.moe_top_k)
-                 if "moe" in layer else _mlp(layer["mlp"], hh))
-    x = _layer_norm(params["ln_f"], x)
-    logits = jnp.einsum("bcd,dv->bcv", x, lm_head(params))
-    return logits, {"k": ck, "v": cv}
+        def attend(p, h, i=i):
+            if cfg.latent is not None:
+                a, pools["kv"] = _latent_paged_attn(
+                    cfg, p, h, pools["kv"], i, table, pos, n_feed,
+                    paged_kernel=paged_kernel)
+            else:
+                a, pools["k"], pools["v"] = _paged_attn(
+                    p, h, pools["k"], pools["v"], i, table, pos, n_feed,
+                    paged_kernel=paged_kernel)
+            return a
+
+        x = block(cfg, layer, x, attend, ffn)
+    return x, pools
+
+
+def _head(cfg: TransformerConfig, params: dict, x: jax.Array) -> jax.Array:
+    """Final norm and head: [B, C, d] -> logits [B, C, V]."""
+    return jnp.einsum("bcd,dv->bcv", norm(cfg, params["ln_f"], x),
+                      lm_head(params))
+
+
+def _last_fed(a: jax.Array, n_feed: jax.Array) -> jax.Array:
+    """[B, C, ...] -> [B, ...] at each lane's last fed column."""
+    return jnp.take_along_axis(
+        a, jnp.maximum(n_feed - 1, 0)[:, None, None], axis=1)[:, 0]
+
+
+def paged_forward(cfg: TransformerConfig, params: dict, cache: dict,
+                  table: jax.Array, pos: jax.Array, n_feed: jax.Array,
+                  tokens: jax.Array, paged_kernel: bool = False,
+                  loads: Optional[list] = None) -> Tuple[jax.Array, dict]:
+    """`_paged_hidden` under the head: logits [B, C, V] at EVERY fed
+    column, what the speculative verify step consumes
+    (`make_spec_step`): column j scores the token that should FOLLOW fed
+    token j."""
+    x, pools = _paged_hidden(cfg, params, cache, table, pos, n_feed,
+                             tokens, paged_kernel=paged_kernel, loads=loads)
+    return _head(cfg, params, x), pools
+
+
+def expert_load(cfg: TransformerConfig, loads: list) -> jax.Array:
+    """A round's expert load as int32 [3], what the step programs of a
+    `RoutedExperts` configuration append to the sampled tokens: routed
+    pairs that fell on experts held here (all layers), pairs that fell
+    on absent ones, and 1000 x the largest share any layer gave one held
+    expert over the mean share (1000 = even)."""
+    held = sum(ld[0] for ld in loads)
+    absent = sum(ld[1] for ld in loads)
+    peak = jnp.max(jnp.stack([
+        ld[2] * (1000 * cfg.experts.n_held) // jnp.maximum(ld[0], 1)
+        for ld in loads]))
+    return jnp.stack([held, absent, peak]).astype(jnp.int32)
 
 
 def paged_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
                       table: jax.Array, pos: jax.Array, n_feed: jax.Array,
-                      tokens: jax.Array,
-                      paged_kernel: bool = False) -> Tuple[jax.Array, dict]:
-    """`paged_forward` with logits taken at each lane's LAST fed column
-    (-> [B, V]) — the chunked-prefill/decode entry point."""
-    logits, cache = paged_forward(cfg, params, cache, table, pos, n_feed,
-                                  tokens, paged_kernel=paged_kernel)
-    last = jnp.take_along_axis(
-        logits, jnp.maximum(n_feed - 1, 0)[:, None, None], axis=1)[:, 0]
-    return last, cache
+                      tokens: jax.Array, paged_kernel: bool = False,
+                      loads: Optional[list] = None
+                      ) -> Tuple[jax.Array, dict]:
+    """Logits at each lane's LAST fed column (-> [B, V]) — the
+    chunked-prefill/decode entry point.  The new families take the
+    column before the head.  GPT-2's layer still pays the head at every
+    column of a wide round and keeps one: its step programs lower to the
+    HLO they had, and taking the column first there is a `perf_opt`'s,
+    with its pairs (PERF.md section 7)."""
+    x, cache = _paged_hidden(cfg, params, cache, table, pos, n_feed,
+                             tokens, paged_kernel=paged_kernel, loads=loads)
+    if cfg.classic:
+        return _last_fed(_head(cfg, params, x), n_feed), cache
+    return _head(cfg, params, _last_fed(x, n_feed)[:, None])[:, 0], cache
+
+
+def _pooled(cfg: TransformerConfig, run):
+    """`jax.jit` of `run(params, pools: tuple, *rest)` as
+    `step(params, *pools, *rest)`, the pools donated: two for full
+    heads (`k`, `v`: the call the serving plane has always made), one
+    for latent rows."""
+    n = len(pool_layout(cfg).names)
+
+    def step(params, *args):
+        return run(params, args[:n], *args[n:])
+
+    return jax.jit(step, donate_argnums=tuple(range(1, 1 + n)))
+
+
+def _sample(logits, temperature, seeds, counts):
+    """The device-side per-slot sampling automaton: greedy rows take the
+    argmax, sampled rows draw from `fold_in(PRNGKey(seed), count)`."""
+    greedy = jnp.argmax(logits, axis=-1)
+    keys = jax.vmap(lambda s, c: jax.random.fold_in(
+        jax.random.PRNGKey(s), c))(seeds, counts)
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    sampled = jax.vmap(jax.random.categorical)(keys, logits / temp)
+    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
 
 
 @functools.lru_cache(maxsize=16)
@@ -474,8 +653,8 @@ def _compiled_paged_step(cfg: TransformerConfig, pages: int,
                          page_size: int, chunk: int,
                          paged_kernel: bool = False):
     """One jitted paged program per (config, pages, page_size, chunk):
-    the pool shape and block-table width are baked in, the k/v buffers
-    `[L, P, ps, H*K]` are donated and come back as the SAME buffers with
+    the pool shape and block-table width are baked in, the pool buffers
+    `[L, P, ps, row]` are donated and come back as the SAME buffers with
     `B*C` rows a layer written (`memory_analysis().alias_size_in_bytes`
     is the pool's bytes; tests/test_paged_inplace.py holds it there),
     and sampling is the SAME device-side per-slot automaton
@@ -483,25 +662,25 @@ def _compiled_paged_step(cfg: TransformerConfig, pages: int,
     so paged and dense lanes sample byte-identically.  `paged_kernel`
     arrives pre-resolved to a bool (see `resolve_paged_kernel`) so the
     auto-detected default and an explicit matching flag share ONE cache
-    entry — the compile ladder keeps its size either way."""
+    entry — the compile ladder keeps its size either way.  A
+    `RoutedExperts` configuration's program returns `[B + 3]` int32: the
+    sampled tokens and then `expert_load`, in the one array the host
+    already waits for."""
+    names = pool_layout(cfg).names
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step(params, cache_k, cache_v, table, pos, n_feed, tokens,
-             temperature, seeds, counts):
-        cache = {"k": cache_k, "v": cache_v}
-        logits, cache = paged_decode_step(cfg, params, cache, table, pos,
-                                          n_feed, tokens,
-                                          paged_kernel=paged_kernel)
-        logits = logits.astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1)
-        keys = jax.vmap(lambda s, c: jax.random.fold_in(
-            jax.random.PRNGKey(s), c))(seeds, counts)
-        temp = jnp.maximum(temperature, 1e-6)[:, None]
-        sampled = jax.vmap(jax.random.categorical)(keys, logits / temp)
-        nxt = jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
-        return nxt, cache["k"], cache["v"]
+    def run(params, pools, table, pos, n_feed, tokens, temperature, seeds,
+            counts):
+        loads = [] if cfg.experts is not None else None
+        logits, cache = paged_decode_step(
+            cfg, params, dict(zip(names, pools)), table, pos, n_feed,
+            tokens, paged_kernel=paged_kernel, loads=loads)
+        nxt = _sample(logits.astype(jnp.float32), temperature, seeds,
+                      counts)
+        if loads:
+            nxt = jnp.concatenate([nxt, expert_load(cfg, loads)])
+        return (nxt,) + tuple(cache[n] for n in names)
 
-    return step
+    return _pooled(cfg, run)
 
 
 def make_paged_step(cfg: TransformerConfig, pages: int, page_size: int,
@@ -542,7 +721,8 @@ def make_paged_step(cfg: TransformerConfig, pages: int, page_size: int,
 def spec_verify_step(cfg: TransformerConfig, params: dict, cache: dict,
                      table: jax.Array, pos: jax.Array, n_feed: jax.Array,
                      n_draft: jax.Array, tokens: jax.Array,
-                     paged_kernel: bool = False
+                     paged_kernel: bool = False,
+                     loads: Optional[list] = None
                      ) -> Tuple[jax.Array, jax.Array, dict]:
     """tokens: [B, W] int32; lane b feeds its first n_feed[b] columns.
     Two lane shapes are supported, and the accept mask assumes them:
@@ -564,7 +744,8 @@ def spec_verify_step(cfg: TransformerConfig, params: dict, cache: dict,
     produced there, so greedy parity is byte-exact and a sampled lane
     (n_draft = 0) sees precisely its last-fed column."""
     logits, cache = paged_forward(cfg, params, cache, table, pos, n_feed,
-                                  tokens, paged_kernel=paged_kernel)
+                                  tokens, paged_kernel=paged_kernel,
+                                  loads=loads)
     logits = logits.astype(jnp.float32)                    # [B, W, V]
     pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B, W]
     w = tokens.shape[1]
@@ -589,24 +770,23 @@ def _compiled_spec_step(cfg: TransformerConfig, pages: int,
     page_size, width): forward + in-jit accept/rollback + the SAME
     per-slot sampling automaton as `_compiled_paged_step` applied at
     the bonus column, so a sampled lane riding this wide dispatch with
-    n_draft = 0 samples byte-identically to the 1-wide program."""
+    n_draft = 0 samples byte-identically to the 1-wide program (and a
+    `RoutedExperts` configuration's tokens carry `expert_load` the same
+    way)."""
+    names = pool_layout(cfg).names
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step(params, cache_k, cache_v, table, pos, n_feed, n_draft,
-             tokens, temperature, seeds, counts):
-        cache = {"k": cache_k, "v": cache_v}
+    def run(params, pools, table, pos, n_feed, n_draft, tokens,
+            temperature, seeds, counts):
+        loads = [] if cfg.experts is not None else None
         blog, accepted, cache = spec_verify_step(
-            cfg, params, cache, table, pos, n_feed, n_draft, tokens,
-            paged_kernel=paged_kernel)
-        greedy = jnp.argmax(blog, axis=-1)
-        keys = jax.vmap(lambda s, c: jax.random.fold_in(
-            jax.random.PRNGKey(s), c))(seeds, counts)
-        temp = jnp.maximum(temperature, 1e-6)[:, None]
-        sampled = jax.vmap(jax.random.categorical)(keys, blog / temp)
-        nxt = jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
-        return nxt, accepted, cache["k"], cache["v"]
+            cfg, params, dict(zip(names, pools)), table, pos, n_feed,
+            n_draft, tokens, paged_kernel=paged_kernel, loads=loads)
+        nxt = _sample(blog, temperature, seeds, counts)
+        if loads:
+            nxt = jnp.concatenate([nxt, expert_load(cfg, loads)])
+        return (nxt, accepted) + tuple(cache[n] for n in names)
 
-    return step
+    return _pooled(cfg, run)
 
 
 def make_spec_step(cfg: TransformerConfig, pages: int, page_size: int,
@@ -624,25 +804,26 @@ def make_spec_step(cfg: TransformerConfig, pages: int, page_size: int,
 @functools.lru_cache(maxsize=16)
 def _compiled_page_copy(cfg: TransformerConfig, pages: int,
                         page_size: int):
-    """Copy-on-write primitive: duplicate ONE page (all layers, k and v)
-    inside the donated pool.  Host-side admission calls this once per
-    divergence page — a request whose prompt shares a cached prefix that
-    ends mid-page copies that page and overwrites from the divergence
-    offset, instead of re-prefilling the whole page."""
+    """Copy-on-write primitive: duplicate ONE page (all layers, every
+    pool) inside the donated pool.  Host-side admission calls this once
+    per divergence page — a request whose prompt shares a cached prefix
+    that ends mid-page copies that page and overwrites from the
+    divergence offset, instead of re-prefilling the whole page."""
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def copy(cache_k, cache_v, src, dst):
+    def copy(pools, src, dst):
         def dup(buf):
             page = lax.dynamic_slice_in_dim(buf, src, 1, axis=1)
             return lax.dynamic_update_slice_in_dim(buf, page, dst, axis=1)
 
-        return dup(cache_k), dup(cache_v)
+        return tuple(dup(buf) for buf in pools)
 
-    return copy
+    n = len(pool_layout(cfg).names)
+    return jax.jit(lambda *a: copy(a[:n], *a[n:]),
+                   donate_argnums=tuple(range(n)))
 
 
 def make_page_copy(cfg: TransformerConfig, pages: int, page_size: int):
-    """Compiled page-copy entry: fn(k, v, src, dst) -> (k, v)."""
+    """Compiled page-copy entry: fn(*pools, src, dst) -> pools."""
     return _compiled_page_copy(cfg, int(pages), int(page_size))
 
 
@@ -654,26 +835,28 @@ def _compiled_page_gather(cfg: TransformerConfig, pages: int,
     whole disaggregated serving lifetime runs one compiled program.  The
     pool is NOT donated — the exporting lane keeps serving from it (and
     the radix tree keeps the prefix for local reuse)."""
+    lay = pool_layout(cfg)
 
-    @jax.jit
-    def gather(cache_k, cache_v, table_row):
+    def gather(*args):
         # table_row: [MP] int32 physical page ids; entries past the
         # shipped count point at the null page and the host slices them
-        # off before serialization.  The stack leaves in the shipped
-        # [L, MP, ps, H, K] form: a reshape of the small stack, not of
-        # the pool
+        # off before serialization.  A stack leaves in the shipped
+        # [L, MP, ps, heads, width] form: a reshape of the small stack,
+        # not of the pool
+        table_row = args[-1]
+
         def pick(buf):
             got = buf[:, table_row]
-            return got.reshape(got.shape[:3] + (cfg.n_heads, cfg.head_dim))
+            return got.reshape(got.shape[:3] + (lay.heads, lay.width))
 
-        return pick(cache_k), pick(cache_v)
+        return tuple(pick(buf) for buf in args[:-1])
 
-    return gather
+    return jax.jit(gather)
 
 
 def make_page_gather(cfg: TransformerConfig, pages: int, page_size: int):
-    """Compiled page-gather entry: fn(k, v, table_row [MP]) ->
-    (pages_k [L, MP, ps, H, K], pages_v)."""
+    """Compiled page-gather entry: fn(*pools, table_row [MP]) ->
+    one page stack [L, MP, ps, heads, width] a pool."""
     return _compiled_page_gather(cfg, int(pages), int(page_size))
 
 
@@ -681,29 +864,31 @@ def make_page_gather(cfg: TransformerConfig, pages: int, page_size: int):
 def _compiled_page_install(cfg: TransformerConfig, pages: int,
                            page_size: int):
     """Import half of KV page shipping: batched page install on top of
-    the `make_page_copy` idea — scatter a shipped [L, MP, ps, H, K] page
-    stack INTO the donated pool at the block-table row's physical ids,
-    all pages in ONE dispatch.  Rows past `n` land on the reserved null
-    page (whose contents are garbage by design), so the program shape
-    never depends on how many pages actually shipped."""
+    the `make_page_copy` idea — scatter shipped [L, MP, ps, heads, width]
+    page stacks INTO the donated pools at the block-table row's physical
+    ids, all pages in ONE dispatch.  Rows past `n` land on the reserved
+    null page (whose contents are garbage by design), so the program
+    shape never depends on how many pages actually shipped."""
+    n_pools = len(pool_layout(cfg).names)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def install(cache_k, cache_v, pages_k, pages_v, table_row, n):
+    def install(*args):
+        pools, stacks = args[:n_pools], args[n_pools:2 * n_pools]
+        table_row, n = args[2 * n_pools:]
         mp = table_row.shape[0]
         dst = jnp.where(jnp.arange(mp) < n, table_row, 0)
 
         def put(buf, stack):
-            # the shipped [L, MP, ps, H, K] stack folded to the pool's rows
+            # the shipped stack folded to the pool's rows
             return buf.at[:, dst].set(stack.reshape(stack.shape[:3] + (-1,)))
 
-        return put(cache_k, pages_k), put(cache_v, pages_v)
+        return tuple(put(b, st) for b, st in zip(pools, stacks))
 
-    return install
+    return jax.jit(install, donate_argnums=tuple(range(n_pools)))
 
 
 def make_page_install(cfg: TransformerConfig, pages: int, page_size: int):
-    """Compiled page-install entry: fn(k, v, pages_k [L, MP, ps, H, K],
-    pages_v, table_row [MP], n) -> (k, v)."""
+    """Compiled page-install entry: fn(*pools, *stacks, table_row [MP],
+    n) -> pools."""
     return _compiled_page_install(cfg, int(pages), int(page_size))
 
 

@@ -102,6 +102,7 @@ def make_accum_train_step(cfg: tfm.TransformerConfig, lr: float = 1e-3,
       step(params, opt_state, tokens, targets) -> (params, opt_state,
       mean_loss); tokens/targets are [accum * mb, S].
     This is the bench_gpt2 / GPT-2-small-class training path."""
+    tfm.require_classic(cfg, "make_accum_train_step")
     from deeplearning4j_tpu.ops.updaters import (
         UpdaterConfig,
         apply_updates,
@@ -174,6 +175,7 @@ class HybridParallelTrainer:
                  axes: tfm.MeshAxes = tfm.MeshAxes(),
                  updater: str = "sgd", shard_update: bool = True,
                  params=None):
+        tfm.require_classic(cfg, "HybridParallelTrainer")
         from deeplearning4j_tpu.ops.updaters import (
             UpdaterConfig,
             apply_updates,
@@ -319,6 +321,7 @@ class PipelineParallelTrainer:
                  n_microbatches: int = 4, lr: float = 1e-2, seed: int = 0,
                  data_axis: str = "data", stage_axis: str = "stage",
                  updater: str = "sgd", shard_update: bool = True):
+        tfm.require_classic(cfg, "PipelineParallelTrainer")
         if cfg.n_experts:
             # Documented boundary (PARITY): MoE rides the dp/sp/tp/ep
             # mesh (HybridParallelTrainer); pipeline stages here are

@@ -338,3 +338,147 @@ def paged_hbm_bytes(n_layers: int, lanes: int, live_pages: int,
     excluded."""
     rows = (live_pages if kernel else max_pages) * page_size
     return 2 * n_layers * lanes * rows * n_heads * head_dim * itemsize
+
+
+# ---------------------------------------------------------------------------
+# Latent pages (multi-head latent attention, absorbed form)
+#
+# A latent pool row is `[c_kv | k_rope]`: one row a token and layer for
+# ALL heads, key and value at once (the value is the row's first
+# `v_width` lanes).  Every query head of a lane reads the same page, so
+# the kernel's work per cached byte is `2 * H` matmul rows and not one:
+# 128 heads put it at the v5e's ridge where `_paged_attn_kernel` is
+# bound by bytes alone.  The block table is walked INSIDE the body, a
+# `fori_loop` over the lane's live pages with the next page's DMA in
+# flight under the current page's matmuls, so a dispatch costs its live
+# pages and nothing for the table's width (a grid over `max_pages` pays
+# for every dead step; at 16k positions that is most of them).
+
+
+def _latent_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, pool_ref, o_ref,
+                        buf, sem, acc, *, scale, ps, cq, h, vw, neg):
+    """Grid program (lane b, query block j): `cq` fed columns times `h`
+    heads as `cq*h` query rows `[., R]` against the lane's pages
+    `[ps, R]`, streamed HBM -> VMEM two buffers deep.  Key `t` is
+    visible to column `ci` iff `t <= pos + ci` (the oracle's mask,
+    intra-chunk included).  A block past the lane's fed columns reads
+    nothing and writes zeros."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows = cq * h
+    nf = jnp.maximum(nf_ref[b], 1)
+    first = j * cq
+    last = pos_ref[b] + jnp.minimum(first + cq, nf) - 1
+    n = jnp.where(first < nf, last // ps + 1, 0)
+
+    def fetch(i, slot):
+        return pltpu.make_async_copy(
+            pool_ref.at[table_ref[b, i]], buf.at[slot], sem.at[slot])
+
+    @pl.when(n > 0)
+    def _first():
+        fetch(0, 0).start()
+
+    q = q_ref[...].reshape(rows, q_ref.shape[-1])
+    acc[...] = jnp.zeros_like(acc)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) // h
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+    horizon = pos_ref[b] + first + ci
+    exact = None if q.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+
+    def page_step(i, carry):
+        m, l = carry
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n)
+        def _next():
+            fetch(i + 1, 1 - slot).start()
+
+        fetch(i, slot).wait()
+        page = buf[slot]                                     # [ps, R]
+        s = jax.lax.dot_general(
+            q, page, (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32) * scale      # [rows, ps]
+        live = i * ps + col <= horizon
+        s = jnp.where(live, s, neg)
+        new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s - new_m), 0.0)
+        alpha = jnp.exp(m - new_m)
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(page.dtype), page[:, :vw], (((1,), (0,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32)
+        return new_m, l * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+    _, l = jax.lax.fori_loop(
+        0, n, page_step, (jnp.full((rows, 1), neg, jnp.float32),
+                          jnp.zeros((rows, 1), jnp.float32)))
+    o_ref[...] = (acc[...] / jnp.maximum(l, 1e-30)).reshape(
+        cq, h, vw).astype(o_ref.dtype)
+
+
+def latent_paged_attention(q, pool, table, pos, n_feed=None, *, layer: int,
+                           v_width: int, scale: float,
+                           interpret: bool | None = None) -> jax.Array:
+    """Absorbed latent attention over the block table.
+
+    q: [B, C, H, R] queries already in the pool row's space
+    (`[q_nope W_uk^T | q_rope]`, R = latent rank + rotary width);
+    pool: the latent pool `[L, P, ps, R]` AFTER this dispatch's scatter;
+    table [B, MP], pos [B], n_feed [B] as for `paged_flash_attention`.
+    Returns the value mix in latent space `[B, C, H, v_width]` (q.dtype),
+    to be taken through `W_uv` by the caller.  One kernel serves width 1
+    (128 query rows a lane) and the wide rounds (blocks of 8 columns,
+    1,024 rows): the mask is the same and only the block of queries held
+    in VMEM differs."""
+    b, c, h, r = q.shape
+    ps = pool.shape[2]
+    table = jnp.asarray(table, jnp.int32) + layer * pool.shape[1]
+    n_feed = (jnp.full((b,), c, jnp.int32) if n_feed is None
+              else jnp.asarray(n_feed, jnp.int32))
+    return _latent_call(table, jnp.asarray(pos, jnp.int32), n_feed, q,
+                        pool.reshape(-1, ps, r), vw=int(v_width),
+                        scale=float(scale),
+                        interpret=_resolve_interpret(interpret))
+
+
+def _query_block(c: int, h: int) -> int:
+    """Fed columns per query block: as many as give about 1,024 query
+    rows (the MXU's rows are then full and the accumulator is 2 MB),
+    dividing the width."""
+    cq = max(1, min(c, 1024 // max(h, 1)))
+    while c % cq:
+        cq -= 1
+    return cq
+
+
+@functools.partial(jax.jit, static_argnames=("vw", "scale", "interpret"))
+def _latent_call(table, pos, n_feed, q, pool, *, vw, scale, interpret):
+    b, c, h, r = q.shape
+    ps = pool.shape[1]
+    cq = _query_block(c, h)
+
+    def _q_map(bi, ji, tbl, pos_, nf):
+        return (bi, ji, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, c // cq),
+        in_specs=[pl.BlockSpec((None, cq, h, r), _q_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, cq, h, vw), _q_map),
+        scratch_shapes=[pltpu.VMEM((2, ps, r), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((cq * h, vw), jnp.float32)],
+    )
+    kernel = functools.partial(_latent_attn_kernel, scale=scale, ps=ps,
+                               cq=cq, h=h, vw=vw, neg=_NEG)
+    # as for `_paged_call`: the block table first, the result 4-D with the
+    # feed width second; the trace's readers find the kernel by that
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, c, h, vw), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="latent_paged_attention",
+    )(table, pos, n_feed, q, pool)

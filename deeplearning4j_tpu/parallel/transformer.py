@@ -26,7 +26,9 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional
+from typing import Callable, Optional, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +37,81 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import shard_map
 from deeplearning4j_tpu.parallel.ring_attention import attention, ring_attention
+
+
+class UnsupportedLayerKind(ValueError):
+    """A path was handed a configuration whose layer kinds it does not
+    compute (raised where the path is built, never a wrong answer)."""
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """Rotary positions with YaRN's blended frequencies (Peng et al.
+    2023, as `deepseek_v2` configures it): `theta` and the six numbers
+    of `rope_scaling`."""
+
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max_len: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention's five sizes (DeepSeek-V2,
+    arXiv:2405.04434): the query's and the key/value's compressed ranks
+    and, a head, the unrotated key width, the rotary width (ONE rotary
+    key for all heads) and the value width."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+    @property
+    def row_values(self) -> int:
+        """What the cache keeps a token and layer: `[c_kv | k_rope]`."""
+        return self.kv_rank + self.rope_dim
+
+
+@dataclass(frozen=True)
+class RoutedExperts:
+    """A SwiGLU expert layer routed over `published` experts of which
+    the slice `held = (lo, hi)` has its weights HERE (one chip's share
+    of an expert-parallel deployment; `(0, published)` holds them all).
+    The router keeps its published width; what the absent experts would
+    have added is left out."""
+
+    published: int
+    held: Tuple[int, int]
+    per_token: int
+    width: int                  # one routed expert's hidden width
+    groups: int = 1             # group-limited routing: experts in
+    groups_kept: int = 1        # `groups` groups, the best `groups_kept`
+    score: str = "softmax"
+    scale: float = 1.0          # routed_scaling_factor
+    renormalize: bool = False   # norm_topk_prob
+    shared_width: int = 0       # the always-on expert's width, 0 = none
+
+    def __post_init__(self):
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.published:
+            raise ValueError(f"experts held {self.held} is no slice of "
+                             f"{self.published}")
+        if self.published % self.groups or not (
+                1 <= self.groups_kept <= self.groups):
+            raise ValueError(f"{self.groups_kept} of {self.groups} groups "
+                             f"over {self.published} experts")
+        if self.score != "softmax":
+            raise ValueError(f"router score {self.score!r}: softmax only")
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0]
 
 
 @dataclass(frozen=True)
@@ -70,12 +147,44 @@ class TransformerConfig:
     # (jax.checkpoint): activation memory drops from O(L*B*S*d) to the
     # block boundaries, the standard trade for long-context training.
     remat: bool = False
+    # --- the layer vocabulary past GPT-2's (all defaults are GPT-2) ---
+    norm: str = "layer"         # "layer" (gain and bias) | "rms" (gain)
+    norm_eps: float = 1e-5
+    rope: Optional[YarnRope] = None     # None = learned positions
+    mlp: str = "gelu"           # "gelu" (biases) | "swiglu" (none)
+    latent: Optional[LatentAttention] = None    # None = full heads
+    # `experts` layers follow `dense_layers` leading dense ones
+    # (first_k_dense_replace); the dense MLP's width is `d_ff`
+    experts: Optional[RoutedExperts] = None
+    dense_layers: int = 0
 
     def __post_init__(self):
         if self.n_experts and not (1 <= self.moe_top_k <= self.n_experts):
             raise ValueError(
                 f"moe_top_k={self.moe_top_k} must be in [1, "
                 f"n_experts={self.n_experts}]")
+        if self.norm not in ("layer", "rms") or self.mlp not in (
+                "gelu", "swiglu"):
+            raise ValueError(f"norm {self.norm!r} / mlp {self.mlp!r}")
+        if self.experts is not None and self.n_experts:
+            raise ValueError("`experts` and `n_experts` are two expert "
+                             "layers; a configuration has one")
+
+    @property
+    def classic(self) -> bool:
+        """GPT-2's layer and nothing else: what the trainers, the mesh
+        runtimes and the dense slot cache compute."""
+        return (self.norm == "layer" and self.rope is None
+                and self.mlp == "gelu" and self.latent is None
+                and self.experts is None)
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's feed-forward kind: "dense", "moe" (Switch /
+        GShard, GELU) or "experts" (`RoutedExperts`)."""
+        if self.experts is not None:
+            return tuple("dense" if i < self.dense_layers else "experts"
+                         for i in range(self.n_layers))
+        return ("moe" if self.n_experts else "dense",) * self.n_layers
 
     @property
     def head_dim(self) -> int:
@@ -96,29 +205,60 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
     """Full (unsharded) parameter tree; place with `param_specs`."""
     dt = jnp.dtype(cfg.dtype)
     d, h, dh, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
-    keys = iter(jax.random.split(key, 4 + 8 * cfg.n_layers))
+    keys = iter(jax.random.split(
+        key, 4 + (8 if cfg.classic else 16) * cfg.n_layers))
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, dt) / jnp.sqrt(
             jnp.asarray(fan_in, dt)))
 
+    def gain(n):
+        if cfg.norm == "rms":
+            return {"scale": jnp.ones((n,), dt)}
+        return {"scale": jnp.ones((n,), dt), "bias": jnp.zeros((n,), dt)}
+
+    def swiglu(width, lead=()):
+        return {"wg": dense(next(keys), lead + (d, width), d),
+                "wu": dense(next(keys), lead + (d, width), d),
+                "wd": dense(next(keys), lead + (width, d), width)}
+
     layers = []
-    for _ in range(cfg.n_layers):
-        layer = {
-            "ln1": {"scale": jnp.ones((d,), dt), "bias": jnp.zeros((d,), dt)},
-            "ln2": {"scale": jnp.ones((d,), dt), "bias": jnp.zeros((d,), dt)},
-            "attn": {
+    for kind in cfg.layer_kinds():
+        layer = {"ln1": gain(d), "ln2": gain(d)}
+        if cfg.latent is not None:
+            la = cfg.latent
+            layer["attn"] = {
+                "wdq": dense(next(keys), (d, la.q_rank), d),
+                "q_norm": {"scale": jnp.ones((la.q_rank,), dt)},
+                "wuq": dense(next(keys),
+                             (la.q_rank, h, la.nope_dim + la.rope_dim),
+                             la.q_rank),
+                "wdkv": dense(next(keys), (d, la.kv_rank + la.rope_dim), d),
+                "kv_norm": {"scale": jnp.ones((la.kv_rank,), dt)},
+                "wukv": dense(next(keys),
+                              (la.kv_rank, h, la.nope_dim + la.v_dim),
+                              la.kv_rank),
+                "wo": dense(next(keys), (h, la.v_dim, d), h * la.v_dim),
+            }
+        else:
+            layer["attn"] = {
                 "wq": dense(next(keys), (d, h, dh), d),
                 "wk": dense(next(keys), (d, h, dh), d),
                 "wv": dense(next(keys), (d, h, dh), d),
                 "wo": dense(next(keys), (h, dh, d), d),
-            },
-        }
+            }
         if cfg.attn_bias:
             layer["attn"].update(
                 bq=jnp.zeros((h, dh), dt), bk=jnp.zeros((h, dh), dt),
                 bv=jnp.zeros((h, dh), dt), bo=jnp.zeros((d,), dt))
-        if cfg.n_experts:
+        if kind == "experts":
+            ex = cfg.experts
+            layer["experts"] = {
+                "gate": dense(next(keys), (d, ex.published), d),
+                **swiglu(ex.width, (ex.n_held,))}
+            if ex.shared_width:
+                layer["experts"]["shared"] = swiglu(ex.shared_width)
+        elif kind == "moe":
             e = cfg.n_experts
             layer["moe"] = {
                 "gate": dense(next(keys), (d, e), d),
@@ -127,6 +267,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
                 "w2": dense(next(keys), (e, f, d), f),
                 "b2": jnp.zeros((e, d), dt),
             }
+        elif cfg.mlp == "swiglu":
+            layer["mlp"] = swiglu(f)
         else:
             layer["mlp"] = {
                 "w1": dense(next(keys), (d, f), d),
@@ -143,19 +285,34 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
     out = {
         "embed": dense(next(keys), (cfg.vocab_size, d),
                        d if cfg.tie_embeddings else 1),
-        "pos": dense(next(keys), (cfg.max_len, d), 1) * 0.02,
-        "ln_f": {"scale": jnp.ones((d,), dt), "bias": jnp.zeros((d,), dt)},
+        "ln_f": gain(d),
         "layers": layers,
     }
+    if cfg.rope is None:
+        out["pos"] = dense(next(keys), (cfg.max_len, d), 1) * 0.02
     if not cfg.tie_embeddings:
         out["head"] = dense(next(keys), (d, cfg.vocab_size), d)
     return out
+
+
+def require_classic(cfg: TransformerConfig, who: str) -> None:
+    """Raise `UnsupportedLayerKind` unless `cfg` is GPT-2's layer: the
+    gate of every path that computes nothing else."""
+    if not cfg.classic:
+        raise UnsupportedLayerKind(
+            f"{who} computes LayerNorm / learned positions / GELU / full "
+            f"heads only; this configuration has norm={cfg.norm!r} "
+            f"rope={cfg.rope is not None} mlp={cfg.mlp!r} "
+            f"latent={cfg.latent is not None} "
+            f"experts={cfg.experts is not None} (serve it through the "
+            f"paged pool)")
 
 
 def param_specs(cfg: TransformerConfig, model_axis: Optional[str]) -> dict:
     """PartitionSpec tree: tp dims sharded over the model axis, rest
     replicated. wq/wk/wv/wo shard the HEAD dim; mlp the HIDDEN dim; moe
     the EXPERT dim (expert parallelism rides the model axis)."""
+    require_classic(cfg, "param_specs (the mesh placement)")
     t = model_axis
     layer_spec = {
         "ln1": {"scale": P(), "bias": P()},
@@ -225,10 +382,158 @@ def gpt2_large(max_len: int = 1024, dtype: str = "bfloat16"
         tie_embeddings=True, remat=True)
 
 
+def deepseek_v2(layers: int = 60, experts_held: Tuple[int, int] = (0, 160),
+                vocab: int = 102400, max_len: int = 163840,
+                dtype: str = "bfloat16") -> TransformerConfig:
+    """DeepSeek-V2 at its published widths (`deepseek_v2`,
+    arXiv:2405.04434): d 5120, 128 heads of latent attention (ranks
+    1536 / 512, 128 + 64 rotary a head, values 128), YaRN over 4096
+    positions by 40, RMSNorm 1e-6, one dense SwiGLU layer of 12288 then
+    expert layers of 160 routed experts of 1536 (6 a token, the best 3
+    of 8 groups, softmax scores times 16, not renormalised) beside a
+    shared expert of 2 x 1536, untied head.  What a chip of an
+    expert-parallel deployment holds is given by the arguments: how
+    many `layers`, which `experts_held`, its slice of the vocabulary and
+    the context served.  Served through the paged pool only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=5120, n_heads=128, n_layers=layers,
+        d_ff=12288, max_len=max_len, dtype=dtype, norm="rms",
+        norm_eps=1e-6, mlp="swiglu",
+        rope=YarnRope(theta=10000.0, factor=40.0, original_max_len=4096,
+                      beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                      mscale_all_dim=0.707),
+        latent=LatentAttention(q_rank=1536, kv_rank=512, nope_dim=128,
+                               rope_dim=64, v_dim=128),
+        experts=RoutedExperts(published=160, held=tuple(experts_held),
+                              per_token=6, width=1536, groups=8,
+                              groups_kept=3, scale=16.0,
+                              shared_width=2 * 1536),
+        dense_layers=1)
+
+
 def _layer_norm(p, x, eps=1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def _rms_norm(p, x, eps):
+    """x / rms(x) * gain, the statistics in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y.astype(x.dtype) * p["scale"]
+
+
+def norm(cfg: TransformerConfig, p, x):
+    """The configuration's normalisation of x's last axis."""
+    if cfg.norm == "rms":
+        return _rms_norm(p, x, cfg.norm_eps)
+    return _layer_norm(p, x, cfg.norm_eps)
+
+
+def embed_tokens(cfg: TransformerConfig, params: dict, tokens, positions):
+    """tokens [B, S] at `positions` [B, S] (or [S]) -> [B, S, d]: learned
+    positions are added here, rotary ones are applied inside attention."""
+    x = params["embed"][tokens]
+    if cfg.rope is None:
+        x = x + params["pos"][positions]
+    return x
+
+
+def yarn_inv_freq(rope: YarnRope, dim: int) -> np.ndarray:
+    """The `dim // 2` inverse frequencies: each blended between the
+    interpolated one (`/ factor`) and the unchanged one by a linear ramp
+    over the dimensions that rotate `beta_fast` .. `beta_slow` times in
+    `original_max_len` positions."""
+    i = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / rope.theta ** i
+    inter = extra / rope.factor
+
+    def at(rotations):     # the dimension that turns so often
+        return (dim * math.log(rope.original_max_len
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(at(rope.beta_fast)), 0)
+    high = min(math.ceil(at(rope.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp       # 1 where the frequency stays as it is
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_cos_sin(rope: YarnRope, dim: int, positions):
+    """cos, sin `[..., dim // 2]` (float32) at integer `positions`."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        yarn_inv_freq(rope, dim))
+    m = (_yarn_mscale(rope.factor, rope.mscale)
+         / _yarn_mscale(rope.factor, rope.mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the pairs `(x[i], x[i + dim/2])` of x's last axis."""
+    half = x.shape[-1] // 2
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def latent_softmax_scale(cfg: TransformerConfig) -> float:
+    """`(nope + rope) ** -0.5`, times YaRN's `mscale_all_dim` factor
+    squared where the rotary is scaled."""
+    la, rope = cfg.latent, cfg.rope
+    scale = (la.nope_dim + la.rope_dim) ** -0.5
+    if rope is not None and rope.mscale_all_dim:
+        scale *= _yarn_mscale(rope.factor, rope.mscale_all_dim) ** 2
+    return scale
+
+
+def latent_proj(cfg: TransformerConfig, p, x, positions):
+    """x [B, S, d] at `positions` [B, S] -> (q_nope [B,S,H,nope],
+    q_rope [B,S,H,rope] rotated, c_kv [B,S,kv_rank] after its norm,
+    k_rope [B,S,rope] rotated, one for all heads).  `[c_kv | k_rope]`
+    is the row the paged pool keeps; keys are rotated BEFORE they are
+    kept, so a shipped or reused page stays valid."""
+    la = cfg.latent
+    c_q = _rms_norm(p["q_norm"], x @ p["wdq"], cfg.norm_eps)
+    q = jnp.einsum("bsr,rhk->bshk", c_q, p["wuq"])
+    down = x @ p["wdkv"]
+    c_kv = _rms_norm(p["kv_norm"], down[..., :la.kv_rank], cfg.norm_eps)
+    cos, sin = rope_cos_sin(cfg.rope, la.rope_dim, positions)
+    q_rope = apply_rope(q[..., la.nope_dim:], cos[..., None, :],
+                        sin[..., None, :])
+    k_rope = apply_rope(down[..., la.kv_rank:], cos, sin)
+    return q[..., :la.nope_dim], q_rope, c_kv, k_rope
+
+
+def _latent_attn(cfg: TransformerConfig, p, x, causal: bool):
+    """Whole-sequence latent attention, NOT absorbed: keys and values
+    are up-projected from `c_kv` a head (the published form; the paged
+    path's absorbed form is the same function)."""
+    la = cfg.latent
+    s = x.shape[1]
+    with jax.named_scope("attn:latent"):
+        q_nope, q_rope, c_kv, k_rope = latent_proj(
+            cfg, p, x, jnp.broadcast_to(jnp.arange(s), x.shape[:2]))
+        kv = jnp.einsum("bsr,rhk->bshk", c_kv, p["wukv"])
+        k_nope, v = kv[..., :la.nope_dim], kv[..., la.nope_dim:]
+        sc = (jnp.einsum("bshk,bthk->bhst", q_nope, k_nope)
+              + jnp.einsum("bshk,btk->bhst", q_rope, k_rope)
+              ).astype(jnp.float32) * latent_softmax_scale(cfg)
+        if causal:
+            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc,
+                           jnp.finfo(jnp.float32).min / 2)
+        w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
+        o = jnp.einsum("bhst,bthk->bshk", w, v)
+        return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
 
 
 def qkv_proj(p, x):
@@ -282,6 +587,11 @@ def _attn(p, x, mesh: Optional[Mesh], axes: MeshAxes, causal: bool):
 def _mlp(p, x):
     h = jax.nn.gelu(jnp.einsum("bsd,df->bsf", x, p["w1"]) + p["b1"])
     return jnp.einsum("bsf,fd->bsd", h, p["w2"]) + p["b2"]
+
+
+def _swiglu(p, x):
+    """(silu(x W_gate) * x W_up) W_down, no biases."""
+    return (jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])) @ p["wd"]
 
 
 def _router_weights(probs, top_k):
@@ -368,13 +678,126 @@ def _moe_dispatch(p, x, capacity_factor: float,
     return jnp.sum(out.reshape(N, top_k, d), axis=1).reshape(B, S, d)
 
 
+def _grouped(xf, idx, weights, held, n_groups: int, experts):
+    """Dropless grouped dispatch, the inference path of every expert
+    layer.  xf [N, d] tokens; idx [N, k] the expert (0-based among the
+    `n_groups` held here) of each (token, choice) pair; weights [N, k]
+    its combine weight; held [N, k] whether the pair is computed here.
+    The held pairs are sorted by expert and `experts(rows, sizes,
+    expert_of_row)` runs the experts' matmuls over the sorted rows
+    (`lax.ragged_dot`: each expert reads its own rows and nothing else,
+    so the work is the pairs routed and the weights read are those of
+    the experts hit).  Pairs that are not held sort past the last group
+    and add nothing.  No capacity, no drops: a token's result is its own
+    rows' and does not depend on what shares the batch.
+    -> (y [N, d], sizes int32 [n_groups]: the held pairs of each expert)."""
+    n, k = idx.shape
+    key = jnp.where(held, idx, n_groups).reshape(-1)            # [A]
+    order = jnp.argsort(key)                                    # stable
+    sizes = jnp.zeros((n_groups + 1,), jnp.int32).at[key].add(1)[:n_groups]
+    ys = experts(xf[order // k], sizes, jnp.minimum(key[order],
+                                                    n_groups - 1))
+    w = jnp.where(held, weights, 0.0).reshape(-1)[order]
+    ys = jnp.where((key[order] < n_groups)[:, None],
+                   ys.astype(jnp.float32) * w[:, None], 0.0)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    return (jnp.sum(ys[back].reshape(n, k, -1), axis=1).astype(xf.dtype),
+            sizes)
+
+
+def _moe_dropless(p, x, top_k: int = 1):
+    """`_moe_dense`'s function (Switch / GShard top-k, GELU experts with
+    biases) computed by `_grouped`: equal to the oracle to rounding, at
+    the cost of the pairs routed instead of every expert on every
+    token."""
+    b, s, d = x.shape
+    xf = x.reshape(b * s, d)
+    e = p["w1"].shape[0]
+    idx, w = _router_weights(jax.nn.softmax(xf @ p["gate"], axis=-1), top_k)
+
+    def experts(rows, sizes, which):
+        hmid = jax.nn.gelu(lax.ragged_dot(rows, p["w1"], sizes)
+                           + p["b1"][which])
+        return lax.ragged_dot(hmid, p["w2"], sizes) + p["b2"][which]
+
+    y, _ = _grouped(xf, idx, w, jnp.ones_like(idx, bool), e, experts)
+    return y.reshape(b, s, d)
+
+
+def group_limited_top_k(scores, ex: RoutedExperts):
+    """scores [N, E] -> (idx [N, k], weights [N, k]): a group's score is
+    its largest expert score; only experts of the `groups_kept` best
+    groups stand; the `per_token` largest of those, weighted by their
+    own score times `scale` (renormalised first only where the
+    configuration says so)."""
+    n, e = scores.shape
+    if ex.groups > 1:
+        per = e // ex.groups
+        best = jnp.max(scores.reshape(n, ex.groups, per), axis=-1)
+        _, kept = lax.top_k(best, ex.groups_kept)               # [N, kept]
+        stands = jnp.any(kept[:, :, None] == jnp.arange(ex.groups), axis=1)
+        scores = jnp.where(jnp.repeat(stands, per, axis=1), scores, 0.0)
+    w, idx = lax.top_k(scores, ex.per_token)
+    if ex.renormalize and ex.per_token > 1:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * ex.scale
+
+
+def _routed_experts(ex: RoutedExperts, p, x, valid=None):
+    """The `RoutedExperts` layer on x [B, S, d]: route every token over
+    all `published` experts (router in float32), compute the pairs that
+    fall on the experts held here, add the shared expert.  `valid`
+    [B, S] marks the rows that carry a token (a wide round's padding
+    routes nowhere).  -> (y [B, S, d], load int32 [3]: pairs held,
+    pairs absent, the most pairs any held expert got)."""
+    b, s, d = x.shape
+    xf = x.reshape(b * s, d)
+    lo, hi = ex.held
+    with jax.named_scope("moe:route"):
+        logits = jnp.dot(xf.astype(jnp.float32),
+                         p["gate"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        idx, w = group_limited_top_k(jax.nn.softmax(logits, axis=-1), ex)
+        here = (idx >= lo) & (idx < hi)
+        real = (jnp.ones_like(here) if valid is None
+                else jnp.broadcast_to(valid.reshape(-1, 1), here.shape))
+        held = here & real
+
+    def experts(rows, sizes, which):
+        hmid = (jax.nn.silu(lax.ragged_dot(rows, p["wg"], sizes))
+                * lax.ragged_dot(rows, p["wu"], sizes))
+        return lax.ragged_dot(hmid, p["wd"], sizes)
+
+    with jax.named_scope("moe:experts"):
+        y, sizes = _grouped(xf, idx - lo, w, held, ex.n_held, experts)
+    if "shared" in p:
+        with jax.named_scope("moe:shared"):
+            y = y + _swiglu(p["shared"], xf)
+    load = jnp.stack([jnp.sum(sizes), jnp.sum(real & ~here),
+                      jnp.max(sizes)]).astype(jnp.int32)
+    return y.reshape(b, s, d), load
+
+
 def _moe(p, x, capacity_factor: float = 0.0,
          mesh: Optional[Mesh] = None, axes: MeshAxes = MeshAxes(),
          top_k: int = 1):
-    """MoE block: capacity-based dispatch when capacity_factor > 0
-    (the FLOP-saving default), dense-masked oracle otherwise."""
+    """The Switch / GShard expert block.  Three paths compute an expert
+    layer in this module, and which one runs is decided here and in
+    `feed_forward`:
+
+    - `_moe_dispatch`, capacity dispatch: training (`lm_loss`,
+      `apply(train=True)`) and on a mesh.  Static `[E, C, d]` buffers,
+      pairs over an expert's capacity are dropped.
+    - `_grouped` (through `_moe_dropless` for these GELU experts,
+      through `_routed_experts` for a `RoutedExperts` layer), dropless
+      grouped matmuls: inference on one device, the whole-sequence
+      `apply` and the cached decode paths alike, so that they agree.
+    - `_moe_dense`, every expert on every token: the ORACLE the two are
+      tested against; nothing serves through it."""
     if capacity_factor > 0:
         return _moe_dispatch(p, x, capacity_factor, mesh, axes, top_k)
+    if mesh is None:
+        return _moe_dropless(p, x, top_k)
     return _moe_dense(p, x, top_k)
 
 
@@ -391,6 +814,36 @@ def _moe_aux_loss(p, x):
     return e * jnp.sum(f * pbar)
 
 
+def feed_forward(cfg: TransformerConfig, layer: dict, h, valid=None,
+                 loads: Optional[list] = None):
+    """A layer's feed-forward half on the normed h [B, S, d], by the
+    kind its parameters have, at inference on one device (`apply` hands
+    `block` its own where it trains or runs on a mesh).  A
+    `RoutedExperts` layer appends its load counts to `loads`."""
+    if "experts" in layer:
+        y, load = _routed_experts(cfg.experts, layer["experts"], h, valid)
+        if loads is not None:
+            loads.append(load)
+        return y
+    if "moe" in layer:
+        return _moe(layer["moe"], h, top_k=cfg.moe_top_k)
+    if cfg.mlp == "swiglu":
+        return _swiglu(layer["mlp"], h)
+    return _mlp(layer["mlp"], h)
+
+
+def block(cfg: TransformerConfig, layer: dict, x, attend: Callable,
+          ffn: Optional[Callable] = None, between: Callable = lambda a: a):
+    """ONE pre-norm layer for every family and every path: the caller
+    brings `attend(p_attn, normed x) -> [B, S, d]` (whole-sequence,
+    ring, cached or paged attention, which may carry a cache in its
+    closure) and, where `feed_forward`'s choice is not its own, `ffn`."""
+    x = between(x + attend(layer["attn"], norm(cfg, layer["ln1"], x)))
+    h = norm(cfg, layer["ln2"], x)
+    return between(x + (ffn(layer, h) if ffn is not None
+                        else feed_forward(cfg, layer, h)))
+
+
 def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
           mesh: Optional[Mesh] = None, axes: MeshAxes = MeshAxes(),
           causal: bool = True, train: bool = False,
@@ -399,11 +852,16 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
 
     MoE routing: `train=True` (the lm_loss path) uses capacity-based
     dispatch — FLOP-saving but drops overflow tokens, so logits can
-    depend on batch composition.  The inference default is the exact
-    dense-masked path, keeping scoring deterministic per sequence and
-    bit-compatible with the KV-cached `generation.decode_step`.
+    depend on batch composition.  At inference on one device the
+    dropless grouped dispatch runs (see `_moe`), per token exact and the
+    same function the cached decode paths compute.
     `return_aux=True` additionally returns the mean-over-layers Switch
-    load-balancing loss (0 for dense configs)."""
+    load-balancing loss (0 for dense configs).  Latent attention and
+    `RoutedExperts` run here whole-sequence on one device, at inference
+    (the path the tests hold the paged one against); they do not train
+    and do not shard."""
+    if not cfg.classic and (mesh is not None or train):
+        require_classic(cfg, "apply(train=True) / apply(mesh=...)")
 
     def constrain(a):
         if mesh is None:
@@ -413,28 +871,33 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
 
     cf = cfg.moe_capacity_factor if train else 0.0
 
-    def block(layer, x):
-        x = x + _attn(layer["attn"], _layer_norm(layer["ln1"], x),
-                      mesh, axes, causal)
-        x = constrain(x)
-        h = _layer_norm(layer["ln2"], x)
-        if "moe" in layer:
-            x = x + _moe(layer["moe"], h, cf, mesh, axes, cfg.moe_top_k)
-            aux = _moe_aux_loss(layer["moe"], h)
-        else:
-            x = x + _mlp(layer["mlp"], h)
-            aux = jnp.zeros((), x.dtype)
-        return constrain(x), aux
+    def attend(p, h):
+        if cfg.latent is not None:
+            return _latent_attn(cfg, p, h, causal)
+        return _attn(p, h, mesh, axes, causal)
+
+    def one(layer, x):
+        aux = [jnp.zeros((), x.dtype)]
+
+        def ffn(layer, h):
+            if "moe" not in layer:
+                return feed_forward(cfg, layer, h)
+            aux[0] = _moe_aux_loss(layer["moe"], h)
+            return _moe(layer["moe"], h, cf, mesh, axes, cfg.moe_top_k)
+
+        return block(cfg, layer, x, attend, ffn, constrain), aux[0]
 
     if cfg.remat:
-        block = jax.checkpoint(block)
-    x = params["embed"][tokens] + params["pos"][None, :tokens.shape[1], :]
+        one = jax.checkpoint(one)
+    x = params["embed"][tokens]
+    if cfg.rope is None:
+        x = x + params["pos"][None, :tokens.shape[1], :]
     x = constrain(x)
     auxs = []
     for layer in params["layers"]:
-        x, aux = block(layer, x)
+        x, aux = one(layer, x)
         auxs.append(aux)
-    x = _layer_norm(params["ln_f"], x)
+    x = norm(cfg, params["ln_f"], x)
     logits = jnp.einsum("bsd,dv->bsv", x, lm_head(params))
     if return_aux:
         return logits, jnp.mean(jnp.stack(auxs))

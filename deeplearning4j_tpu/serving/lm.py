@@ -362,6 +362,14 @@ class ContinuousLMServer:
                              f"{max_queue_depth}")
         if kv not in ("paged", "dense"):
             raise ValueError(f"kv must be 'paged' or 'dense', got {kv!r}")
+        if kv == "dense":
+            from deeplearning4j_tpu.parallel.transformer import (
+                require_classic,
+            )
+
+            # typed, here: the slot cache keeps `[H, K]` rows of full
+            # heads and its step computes GPT-2's layer only
+            require_classic(cfg, "ContinuousLMServer(kv='dense')")
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if prefill_chunk < 1:
@@ -1121,9 +1129,9 @@ class ContinuousLMServer:
             zi = np.zeros((self.n_slots,), np.int32)
             zf = np.zeros((self.n_slots,), np.float32)
             if self.kv == "dense":
-                _, k, v = warm("lm:dense", lambda: self._step(
+                out = warm("lm:dense", lambda: self._step(
                     self.params, *self._cache, zi, zi, zf, zi, zi))
-                self._cache = (k, v)
+                self._cache = tuple(out[1:])
                 return
             table = np.zeros((self.n_slots, self.max_pages), np.int32)
             if self.speculate != "off":
@@ -1133,7 +1141,7 @@ class ContinuousLMServer:
                     out = warm(f"lm:paged[w{w}]", lambda: self._step(
                         self.params, *self._cache, table, zi, zi, zi, tok,
                         zf, zi, zi))
-                    self._cache = (out[-2], out[-1])
+                    self._cache = tuple(out[2:])
                 if hasattr(self._drafter, "warmup"):
                     warm("lm:drafter", self._drafter.warmup)
             else:
@@ -1141,13 +1149,12 @@ class ContinuousLMServer:
                                 if self.prefill_chunk > 1 else [])
                 for w in widths:
                     tok = np.zeros((self.n_slots, w), np.int32)
-                    _, k, v = warm(f"lm:paged[w{w}]", lambda: self._step(
+                    out = warm(f"lm:paged[w{w}]", lambda: self._step(
                         self.params, *self._cache, table, zi, zi, tok, zf,
                         zi, zi))
-                    self._cache = (k, v)
-            k, v = warm("lm:page_copy", lambda: self._copy(
-                *self._cache, np.int32(0), np.int32(0)))
-            self._cache = (k, v)
+                    self._cache = tuple(out[1:])
+            self._cache = tuple(warm("lm:page_copy", lambda: self._copy(
+                *self._cache, np.int32(0), np.int32(0))))
             if self.ship or self.preempt or self.hibernate:
                 # the shipping/swap/hibernate pair: a gather out of the live
                 # pool (not donated — the row of nulls reads only the null
@@ -1156,12 +1163,10 @@ class ContinuousLMServer:
                 zrow = np.zeros((self.max_pages,), np.int32)
                 warm("lm:page_gather",
                      lambda: self._gather(*self._cache, zrow))
-                shape = (self.cfg.n_layers, self.max_pages, self.page_size,
-                         self.cfg.n_heads, self.cfg.head_dim)
-                zp = np.zeros(shape, np.dtype(self.cfg.dtype))
-                k, v = warm("lm:page_install", lambda: self._install(
-                    *self._cache, zp, zp, zrow, np.int32(0)))
-                self._cache = (k, v)
+                zp = self._padded_stacks(None, 0)
+                self._cache = tuple(warm(
+                    "lm:page_install", lambda: self._install(
+                        *self._cache, *zp, zrow, np.int32(0))))
         finally:
             self._warmup_stats = {
                 "programs": programs,
@@ -1252,9 +1257,10 @@ class ContinuousLMServer:
         the bench (a dense pool's provisioned bytes are paid whether or
         not any lane fills them; the paged pool's actual bytes follow
         the refcounted pages, radix-shared prefixes counted once)."""
+        from deeplearning4j_tpu.parallel.generation import pool_token_bytes
+
         cfg = self.cfg
-        per_tok = (2 * cfg.n_layers * cfg.n_heads * cfg.head_dim
-                   * np.dtype(cfg.dtype).itemsize)
+        per_tok = pool_token_bytes(cfg)
         if self.kv == "dense":
             provisioned = self.n_slots * cfg.max_len * per_tok
             active = per_tok * sum(s.pos for s in self._slots if s.active)
@@ -1357,7 +1363,8 @@ class ContinuousLMServer:
 
             cache = init_paged_cache(self.cfg, self.kv_pages + 1,
                                      self.page_size)
-        self._cache = (cache["k"], cache["v"])
+        # k and v, or the one latent pool (`generation.pool_layout`)
+        self._cache = tuple(v for k, v in cache.items() if k != "pos")
 
     def _reset_pool_locked(self) -> None:
         """Fresh allocator + radix tree + slot page bookkeeping.  Called
@@ -1413,6 +1420,7 @@ class ContinuousLMServer:
                     make_page_copy,
                     make_paged_step,
                     make_spec_step,
+                    pool_layout,
                 )
 
                 total = self.kv_pages + 1
@@ -1453,33 +1461,36 @@ class ContinuousLMServer:
                         self.speculate, self.cfg, self.params,
                         self.n_slots, draft_model=self._draft_model)
 
+                # the pool arrays lead every call (k and v, or the one
+                # latent pool: `generation.pool_layout`)
+                n_pools = len(pool_layout(self.cfg).names)
                 if self.speculate != "off":
-                    def dispatch(params, k, v, table, pos, n_feed,
-                                 n_draft, tokens, temperature, seeds,
-                                 counts):
+                    def dispatch(params, *args):
                         # speculative signature: every dispatch carries
                         # n_draft and returns per-lane accepted counts
                         # (zeros on the 1-wide plain-decode program)
+                        pools = args[:n_pools]
+                        (table, pos, n_feed, n_draft, tokens, temperature,
+                         seeds, counts) = args[n_pools:]
                         if tokens.shape[1] == 1:
-                            nxt, k, v = self._decode_step(
-                                params, k, v, table, pos, n_feed,
+                            out = self._decode_step(
+                                params, *pools, table, pos, n_feed,
                                 tokens, temperature, seeds, counts)
-                            return nxt, np.zeros(
-                                (self.n_slots,), np.int32), k, v
+                            return (out[0], np.zeros(
+                                (self.n_slots,), np.int32)) + tuple(out[1:])
                         return self._chunk_step(
-                            params, k, v, table, pos, n_feed, n_draft,
+                            params, *pools, table, pos, n_feed, n_draft,
                             tokens, temperature, seeds, counts)
                 else:
-                    def dispatch(params, k, v, table, pos, n_feed,
-                                 tokens, temperature, seeds, counts):
+                    def dispatch(params, *args):
                         # ONE entry point for every paged dispatch
                         # (decode and prefill-chunk widths) so
                         # fault-injection tests that stub `self._step`
                         # intercept them all
+                        tokens = args[n_pools + 3]
                         fn = (self._decode_step if tokens.shape[1] == 1
                               else self._chunk_step)
-                        return fn(params, k, v, table, pos, n_feed,
-                                  tokens, temperature, seeds, counts)
+                        return fn(params, *args)
 
                 self._step = dispatch
             self._reset_pool_locked()
@@ -1713,13 +1724,7 @@ class ContinuousLMServer:
             slot.pos = int(ex.pos)
             slot.generated = list(ex.committed)
             n_ship = ex.n_pages
-            mp = self.max_pages
-            shape = (self.cfg.n_layers, mp, self.page_size,
-                     self.cfg.n_heads, self.cfg.head_dim)
-            pk = np.zeros(shape, np.dtype(self.cfg.dtype))
-            pv = np.zeros(shape, np.dtype(self.cfg.dtype))
-            pk[:, :n_ship] = ex.pages_k
-            pv[:, :n_ship] = ex.pages_v
+            stacks = self._padded_stacks(ex, n_ship)
             # radix-matched prefix pages are NOT re-installed: their
             # rows in the install target the null page, so the shared
             # pages (other lanes may be reading them) are never
@@ -1728,7 +1733,7 @@ class ContinuousLMServer:
             irow = row.copy()
             irow[:len(plan["full"])] = 0
             self._pending_install.append(
-                {"pk": pk, "pv": pv, "row": irow, "n": n_ship,
+                {"stacks": stacks, "row": irow, "n": n_ship,
                  "nbytes": wire_nbytes, "swap": req.swap_restore})
             self.metrics.record_prefix_query(plan["matched"])
             n_full_prompt = len(req.prompt) // self.page_size
@@ -1760,18 +1765,12 @@ class ContinuousLMServer:
             # other lanes may be reading are never rewritten.
             ex = res["ex"]
             n_hib = int(res["n_hib"])
-            mp = self.max_pages
-            shape = (self.cfg.n_layers, mp, self.page_size,
-                     self.cfg.n_heads, self.cfg.head_dim)
-            pk = np.zeros(shape, np.dtype(self.cfg.dtype))
-            pv = np.zeros(shape, np.dtype(self.cfg.dtype))
-            pk[:, :n_hib] = ex.pages_k
-            pv[:, :n_hib] = ex.pages_v
+            stacks = self._padded_stacks(ex, n_hib)
             irow = row.copy()
             irow[:n_full] = 0
             irow[n_hib:] = 0
             self._pending_install.append(
-                {"pk": pk, "pv": pv, "row": irow, "n": n_hib,
+                {"stacks": stacks, "row": irow, "n": n_hib,
                  "nbytes": res["nbytes"],
                  "exact_nbytes": res["exact_nbytes"],
                  "pages": n_hib, "hibernate": True})
@@ -1912,14 +1911,11 @@ class ContinuousLMServer:
                 continue
             row = np.zeros((self.max_pages,), np.int32)
             row[:n_full] = full
-            with compile_scope("lm:page_gather"):
-                pk, pv = self._gather(*self._cache, row)
-            pk = np.asarray(pk)[:, :n_full]
-            pv = np.asarray(pv)[:, :n_full]
             ex = PageExport(
+                **self._gathered(row, n_full),
                 prompt=covered, max_new=1, temperature=0.0, seed=0,
                 committed=[], pos=n_full * self.page_size,
-                page_size=self.page_size, pages_k=pk, pages_v=pv,
+                page_size=self.page_size,
                 model=model_signature(self.cfg, self.page_size),
                 session_id=sid)
             exact = ex.exact_nbytes()
@@ -2057,15 +2053,12 @@ class ContinuousLMServer:
         if (mid_decode and self._swap is not None
                 and self._gather is not None and self._cache is not None):
             n = -(-slot.pos // self.page_size)
-            with compile_scope("lm:page_gather"):
-                pk, pv = self._gather(*self._cache, slot.table)
-            pk = np.asarray(pk)[:, :n]
-            pv = np.asarray(pv)[:, :n]
             ex = PageExport(
+                **self._gathered(slot.table, n),
                 prompt=list(req.prompt), max_new=req.max_new,
                 temperature=req.temperature, seed=req.seed,
                 committed=list(slot.generated), pos=int(slot.pos),
-                page_size=self.page_size, pages_k=pk, pages_v=pv,
+                page_size=self.page_size,
                 model=model_signature(self.cfg, self.page_size),
                 session_id=req.session_id, priority=req.priority,
                 tenant=req.tenant)
@@ -2371,6 +2364,36 @@ class ContinuousLMServer:
             req.stream_pushed = max(req.stream_pushed,
                                     len(slot.generated))
 
+    def _gathered(self, row, n: int) -> Dict:
+        """A block-table row's first `n` pages out of every pool (one
+        fixed-shape dispatch, one host sync), as `PageExport`'s page
+        fields."""
+        with compile_scope("lm:page_gather"):
+            # the worker thread owns `_cache` between dispatches (as in
+            # `_dispatch_paged`); the sweep and the preemption call
+            # this under the lock, the export lane from the worker
+            stacks = self._gather(*self._cache, row)  # noqa: LCK101
+        stacks = [np.asarray(st)[:, :n] for st in stacks]
+        return {"pages_k": stacks[0],
+                "pages_v": stacks[1] if len(stacks) > 1 else None}
+
+    def _padded_stacks(self, ex, n: int):
+        """An export's page stacks (every pool's) padded to the install
+        program's fixed `[L, max_pages, ps, heads, width]`; `ex=None`
+        gives the all-zero stacks the warm-up installs."""
+        from deeplearning4j_tpu.parallel.generation import pool_layout
+
+        lay = pool_layout(self.cfg)
+        shape = (self.cfg.n_layers, self.max_pages, self.page_size,
+                 lay.heads, lay.width)
+        out = []
+        for i in range(len(lay.names)):
+            st = np.zeros(shape, np.dtype(self.cfg.dtype))
+            if ex is not None:
+                st[:, :n] = ex.stacks[i]
+            out.append(st)
+        return tuple(out)
+
     def _export_slot(self, slot: _Slot) -> None:
         """Prefill just completed on an export lane: gather its pages
         out of the pool (one fixed-shape dispatch + one host sync),
@@ -2380,16 +2403,13 @@ class ContinuousLMServer:
         content is only ever recycled through the allocator."""
         req = slot.req
         t0 = time.perf_counter()
-        with compile_scope("lm:page_gather"):
-            pk, pv = self._gather(*self._cache, slot.table)
         n = -(-slot.pos // self.page_size)
-        pk = np.asarray(pk)[:, :n]
-        pv = np.asarray(pv)[:, :n]
         ex = PageExport(
+            **self._gathered(slot.table, n),
             prompt=list(req.prompt), max_new=req.max_new,
             temperature=req.temperature, seed=req.seed,
             committed=list(slot.generated), pos=int(slot.pos),
-            page_size=self.page_size, pages_k=pk, pages_v=pv,
+            page_size=self.page_size,
             model=model_signature(self.cfg, self.page_size),
             session_id=req.session_id, priority=req.priority,
             tenant=req.tenant)
@@ -2407,10 +2427,9 @@ class ContinuousLMServer:
         for item in installs:
             t0 = time.perf_counter()
             with compile_scope("lm:page_install"):
-                k, v = self._install(*self._cache, item["pk"],
-                                     item["pv"], item["row"],
-                                     np.int32(item["n"]))
-            self._cache = (k, v)
+                self._cache = tuple(self._install(
+                    *self._cache, *item["stacks"], item["row"],
+                    np.int32(item["n"])))
             if item.get("swap"):
                 # a preempted lane restoring from the host store — the
                 # swap ledger, not the wire-shipping one
@@ -2429,9 +2448,9 @@ class ContinuousLMServer:
                                          time.perf_counter() - t0)
         for item in cow:
             with compile_scope("lm:page_copy"):
-                k, v = self._copy(*self._cache, np.int32(item["src"]),
-                                  np.int32(item["dst"]))
-            self._cache = (k, v)
+                self._cache = tuple(self._copy(
+                    *self._cache, np.int32(item["src"]),
+                    np.int32(item["dst"])))
             self._pool.release([item["src"]])
         # brownout ladder effects (ISSUE-15, docs/robustness.md "The
         # degradation ladder"): level 1 turns speculation off (drafts
@@ -2463,7 +2482,7 @@ class ContinuousLMServer:
             width = self.prefill_chunk
         clock.to("marshal")
         fed = dict.fromkeys(("prefill", "decode", "draft"), 0)
-        live_pages = 0
+        live_pages = attn_rows = attn_pairs = 0
         tokens = np.zeros((self.n_slots, width), np.int32)
         pos = np.zeros((self.n_slots,), np.int32)
         n_feed = np.zeros((self.n_slots,), np.int32)
@@ -2496,8 +2515,12 @@ class ContinuousLMServer:
                 tokens[i, 0] = slot.generated[-1]
                 n_feed[i] = 1
                 fed["decode"] += 1
-            # pages the attention reads for this lane: history and feed
-            live_pages += -(-(slot.pos + int(n_feed[i])) // self.page_size)
+            # pages the attention reads for this lane: history and feed;
+            # the rows in them, and the (fed column, visible row) pairs
+            f = int(n_feed[i])
+            live_pages += -(-(slot.pos + f) // self.page_size)
+            attn_rows += slot.pos + f
+            attn_pairs += f * slot.pos + f * (f + 1) // 2
             pos[i] = slot.pos
             temp[i] = req.temperature
             seeds[i] = req.seed
@@ -2506,22 +2529,26 @@ class ContinuousLMServer:
         clock.to("dispatch")
         with compile_scope(f"lm:paged[w{width}]"):
             if self.speculate != "off":
-                nxt, acc, k, v = self._step(
+                nxt, acc, *pools = self._step(
                     self.params, *self._cache, table, pos, n_feed,
                     n_draft, tokens, temp, seeds, counts)
             else:
-                nxt, k, v = self._step(self.params, *self._cache, table,
-                                       pos, n_feed, tokens, temp, seeds,
-                                       counts)
+                nxt, *pools = self._step(
+                    self.params, *self._cache, table, pos, n_feed, tokens,
+                    temp, seeds, counts)
                 acc = None
         if self.breaker is not None:
             self.breaker.record_success()
-        self._cache = (k, v)
+        self._cache = tuple(pools)
         # ONE host sync per round: the bonus tokens and the per-lane
         # accepted counts arrive together, never per token
         clock.to("sync")
         nxt = np.asarray(nxt)
         acc = np.asarray(acc) if acc is not None else None
+        if self.cfg.experts is not None:
+            # a `RoutedExperts` program's tokens carry the round's expert
+            # load behind them (`generation.expert_load`): no second sync
+            self.metrics.record_expert_load(*(int(x) for x in nxt[-3:]))
         clock.to("fold")
         self._steps += 1
         emitted = 0
@@ -2575,7 +2602,7 @@ class ContinuousLMServer:
                                self.kv_pages)
         clock.to("yield")
         self.metrics.record_round(clock.take(), width, self.n_slots, fed,
-                                  live_pages)
+                                  live_pages, attn_rows, attn_pairs)
         return True
 
     def _run(self) -> None:

@@ -55,6 +55,9 @@ ROUND_PHASES = ("admit", "pages", "plan", "marshal", "dispatch", "sync",
 # what a fed token was: a prompt token, a committed token fed back, or a
 # speculative draft riding the verify round
 FEED_KINDS = ("prefill", "decode", "draft")
+# a round is width 1 or wide; where a routed pair's expert lies
+ROUND_KINDS = ("w1", "wide")
+EXPERT_PLACES = ("held", "absent")
 # host time of one round (everything but `sync`): milliseconds matter
 _ROUND_HOST_BUCKETS = (0.00025, 0.0005, 0.001, 0.002, 0.003, 0.004, 0.005,
                        0.0075, 0.01, 0.02, 0.05, 0.1, 0.25, 1.0)
@@ -264,6 +267,32 @@ class ServingMetrics:
             "serving_lm_live_pages_total",
             "per round over active lanes, KV pages the attention has "
             "to read")
+        # what the attention of a round has to read and to score, by
+        # the round's kind (ISSUE-26): rows = history and feed of every
+        # active lane; pairs = (fed column, visible row) pairs, a head
+        self.attn_rows = {
+            kind: Counter("serving_lm_attn_rows_total",
+                          "cache rows the round's attention reads, over "
+                          "active lanes")
+            for kind in ROUND_KINDS}
+        self.attn_pairs = {
+            kind: Counter("serving_lm_attn_pairs_total",
+                          "query-row pairs the round's attention scores, "
+                          "a head")
+            for kind in ROUND_KINDS}
+        # a `RoutedExperts` layer's load, from the step program itself
+        self.expert_pairs = {
+            place: Counter("serving_lm_expert_pairs_total",
+                           "routed (token, expert) pairs by where the "
+                           "expert's weights are")
+            for place in EXPERT_PLACES}
+        self.expert_peak_total = Counter(
+            "serving_lm_expert_load_peak_total",
+            "per round, the largest share a held expert got over the "
+            "mean share (summed; divide by the rounds)")
+        self.expert_rounds_total = Counter(
+            "serving_lm_expert_rounds_total",
+            "rounds that reported an expert load")
         self.round_host_hist = Histogram(
             "serving_lm_round_host_seconds",
             "host time of one round: every phase but sync",
@@ -336,8 +365,14 @@ class ServingMetrics:
                   self.latency_hist, self.queue_wait_hist,
                   self.compute_hist,
                   self.idle_seconds_total, self.feed_capacity_total,
-                  self.live_pages_total, self.round_host_hist):
+                  self.live_pages_total, self.round_host_hist,
+                  self.expert_peak_total, self.expert_rounds_total):
             registry.register(m, **labels)
+        for cells, label in ((self.attn_rows, "round"),
+                             (self.attn_pairs, "round"),
+                             (self.expert_pairs, "place")):
+            for value, m in cells.items():
+                registry.register(m, **{label: value}, **labels)
         for (_event, cls), m in self.class_counters.items():
             registry.register(m, priority=cls, **labels)
         for phase, m in self.round_seconds.items():
@@ -422,11 +457,13 @@ class ServingMetrics:
 
     def record_round(self, seconds: Dict[str, float], width: int,
                      lanes: int, fed: Dict[str, int],
-                     live_pages: int) -> None:
+                     live_pages: int, attn_rows: int = 0,
+                     attn_pairs: int = 0) -> None:
         """One dispatched round of the LM worker, next to
         `record_dispatch`: the phase seconds since the last call, the
-        width dispatched over `lanes` lanes, the tokens fed by kind and
-        the KV pages the active lanes' attention reads."""
+        width dispatched over `lanes` lanes, the tokens fed by kind, the
+        KV pages the active lanes' attention reads, and the rows it
+        reads and the (column, row) pairs it scores."""
         self.record_phase_seconds(seconds)
         self.round_host_hist.observe(sum(
             sec for phase, sec in seconds.items()
@@ -437,6 +474,19 @@ class ServingMetrics:
             if n:
                 self.fed_tokens[kind].inc(int(n))
         self.live_pages_total.inc(int(live_pages))
+        kind = "w1" if int(width) == 1 else "wide"
+        self.attn_rows[kind].inc(int(attn_rows))
+        self.attn_pairs[kind].inc(int(attn_pairs))
+
+    def record_expert_load(self, held: int, absent: int,
+                           peak_x1000: int) -> None:
+        """One round's `generation.expert_load`: routed pairs that fell
+        on experts held here and on absent ones (all layers), and 1000 x
+        the largest-over-mean load among held experts."""
+        self.expert_pairs["held"].inc(int(held))
+        self.expert_pairs["absent"].inc(int(absent))
+        self.expert_peak_total.inc(peak_x1000 / 1000.0)
+        self.expert_rounds_total.inc()
 
     def record_request(self, latency_s: float,
                        queue_wait_s: Optional[float] = None,
@@ -804,9 +854,19 @@ class ServingMetrics:
                                for kind, m in self.fed_tokens.items()},
                 "feed_capacity": int(self.feed_capacity_total.value),
                 "live_pages": int(self.live_pages_total.value),
+                "attn_rows": {k: int(m.value)
+                              for k, m in self.attn_rows.items()},
+                "attn_pairs": {k: int(m.value)
+                               for k, m in self.attn_pairs.items()},
                 "host_ms": {"mean": 1e3 * host["mean"],
                             "p50": 1e3 * host["p50"],
                             "p99": 1e3 * host["p99"]}}
+        if int(self.expert_rounds_total.value):
+            out["experts"] = {
+                "rounds": int(self.expert_rounds_total.value),
+                "pairs": {k: int(m.value)
+                          for k, m in self.expert_pairs.items()},
+                "load_peak_sum": float(self.expert_peak_total.value)}
         if pq:
             out["prefix_queries"] = pq
             out["prefix_hits"] = int(self.prefix_hits_total.value)

@@ -76,12 +76,21 @@ def model_signature(cfg, page_size: int) -> Dict:
     """The geometry a shipped page stack is only meaningful under.
     `max_len`/`vocab_size` ride along for request re-validation on the
     importing side; the KV-shape fields are the hard compatibility
-    gate."""
-    return {"n_layers": int(cfg.n_layers), "n_heads": int(cfg.n_heads),
-            "head_dim": int(cfg.head_dim), "dtype": str(cfg.dtype),
-            "max_len": int(cfg.max_len),
-            "vocab_size": int(cfg.vocab_size),
-            "page_size": int(page_size)}
+    gate.  A shipped row is `[n_heads, head_dim]` as the pool's one
+    layout function gives it (`generation.pool_layout`: full heads, or
+    one latent row for all heads); a pool that is not the k and v pair
+    says how many arrays it has under `pools`."""
+    from deeplearning4j_tpu.parallel.generation import pool_layout
+
+    lay = pool_layout(cfg)
+    sig = {"n_layers": int(cfg.n_layers), "n_heads": int(lay.heads),
+           "head_dim": int(lay.width), "dtype": str(cfg.dtype),
+           "max_len": int(cfg.max_len),
+           "vocab_size": int(cfg.vocab_size),
+           "page_size": int(page_size)}
+    if len(lay.names) != 2:
+        sig["pools"] = len(lay.names)
+    return sig
 
 
 @dataclasses.dataclass
@@ -95,8 +104,8 @@ class PageExport:
     committed: List[int]        # tokens generated so far (>= 1)
     pos: int                    # next cache position (== len(prompt))
     page_size: int
-    pages_k: np.ndarray         # [L, n_pages, ps, H, K]
-    pages_v: np.ndarray
+    pages_k: np.ndarray         # [L, n_pages, ps, heads, width]
+    pages_v: Optional[np.ndarray]   # None: a one-pool (latent) export
     model: Dict                 # model_signature of the exporting pool
     session_id: Optional[str] = None
     # admission class (ISSUE-15): rides the frame so a shipped or
@@ -120,24 +129,35 @@ class PageExport:
         return int(self.pages_k.shape[1])
 
     @property
+    def stacks(self) -> tuple:
+        """The page stacks, one a pool, in the pool's order."""
+        return ((self.pages_k,) if self.pages_v is None
+                else (self.pages_k, self.pages_v))
+
+    @property
+    def scales(self) -> tuple:
+        return ((self.scales_k,) if self.pages_v is None
+                else (self.scales_k, self.scales_v))
+
+    @property
     def quantized(self) -> bool:
         return self.quant is not None
 
     def nbytes(self) -> int:
         """Bytes this export actually carries (the at-rest/wire size):
         int8 pages + scales when quantized, raw pages when exact."""
-        n = int(self.pages_k.nbytes + self.pages_v.nbytes)
+        n = sum(int(st.nbytes) for st in self.stacks)
         if self.scales_k is not None:
-            n += int(self.scales_k.nbytes + self.scales_v.nbytes)
+            n += sum(int(sc.nbytes) for sc in self.scales)
         return n
 
     def exact_nbytes(self) -> int:
         """Bytes the same pages occupy un-quantized (the 4x-denominator
         the compression ledger reports against)."""
         if self.quant is None:
-            return int(self.pages_k.nbytes + self.pages_v.nbytes)
+            return sum(int(st.nbytes) for st in self.stacks)
         itemsize = np.dtype(self.quant["exact_dtype"]).itemsize
-        return int(2 * self.pages_k.size * itemsize)
+        return int(len(self.stacks) * self.pages_k.size * itemsize)
 
     def dequantized(self) -> "PageExport":
         """A new exact PageExport with pages restored to
@@ -151,10 +171,11 @@ class PageExport:
         )
 
         dt = np.dtype(self.quant["exact_dtype"])
+        exact = [dequantize_kv_pages(st, sc, dt)
+                 for st, sc in zip(self.stacks, self.scales)]
         return dataclasses.replace(
-            self,
-            pages_k=dequantize_kv_pages(self.pages_k, self.scales_k, dt),
-            pages_v=dequantize_kv_pages(self.pages_v, self.scales_v, dt),
+            self, pages_k=exact[0],
+            pages_v=exact[1] if len(exact) > 1 else None,
             quant=None, scales_k=None, scales_v=None)
 
 
@@ -168,7 +189,8 @@ def quantize_export(ex: PageExport) -> PageExport:
     from deeplearning4j_tpu.precision.quantize import quantize_kv_pages
 
     qk, sk = quantize_kv_pages(ex.pages_k, valid=ex.pos)
-    qv, sv = quantize_kv_pages(ex.pages_v, valid=ex.pos)
+    qv, sv = ((None, None) if ex.pages_v is None
+              else quantize_kv_pages(ex.pages_v, valid=ex.pos))
     return dataclasses.replace(
         ex, pages_k=qk, pages_v=qv, scales_k=sk, scales_v=sv,
         quant={"mode": "int8", "exact_dtype": str(ex.pages_k.dtype)})
@@ -181,15 +203,15 @@ def serialize_export(ex: PageExport) -> bytes:
     header's sha256 covers the payload bytes exactly as framed.  Exact
     exports frame as v1 — byte-identical to the pre-ISSUE-19 format —
     so quantize-off pools interoperate with old readers unchanged."""
-    pk = np.ascontiguousarray(ex.pages_k)
-    pv = np.ascontiguousarray(ex.pages_v)
-    if pk.shape != pv.shape:
-        raise ValueError(f"pages_k {pk.shape} != pages_v {pv.shape}")
-    payload = pk.tobytes() + pv.tobytes()
+    stacks = [np.ascontiguousarray(st) for st in ex.stacks]
+    pk = stacks[0]
+    if any(st.shape != pk.shape for st in stacks):
+        raise ValueError(f"pages_k {pk.shape} != pages_v "
+                         f"{stacks[-1].shape}")
+    payload = b"".join(st.tobytes() for st in stacks)
     if ex.quant is not None:
-        sk = np.ascontiguousarray(ex.scales_k, np.float32)
-        sv = np.ascontiguousarray(ex.scales_v, np.float32)
-        payload += sk.tobytes() + sv.tobytes()
+        payload += b"".join(np.ascontiguousarray(sc, np.float32).tobytes()
+                            for sc in ex.scales)
     header = {
         "version": WIRE_VERSION if ex.quant is not None else 1,
         "prompt": [int(t) for t in ex.prompt],
@@ -209,6 +231,9 @@ def serialize_export(ex: PageExport) -> bytes:
         header["quant"] = {"mode": str(ex.quant["mode"]),
                            "exact_dtype": str(ex.quant["exact_dtype"]),
                            "scale_shape": list(ex.scales_k.shape)}
+    if len(stacks) != 2:
+        # a one-pool (latent) frame: the payload is one stack, not k then v
+        header["pools"] = len(stacks)
     if ex.session_id is not None:
         header["session_id"] = str(ex.session_id)
     if ex.priority != "interactive":
@@ -283,21 +308,26 @@ def deserialize_export(data: bytes) -> PageExport:
         sbytes = int(np.prod(sshape)) * 4
     else:
         sbytes = 0
+    pools = int(header.get("pools", 2))
+    if pools not in (1, 2):
+        raise PageShipError(f"shipment of {pools} pools: 1 or 2 only")
     half = int(np.prod(shape)) * dt.itemsize
-    want = 2 * half + 2 * sbytes
+    want = pools * (half + sbytes)
     if len(payload) != want:
         raise PageShipError(
             f"shipment payload {len(payload)} bytes != {want} for "
-            f"2 x {shape} {dt}"
-            + (f" + 2 x {sshape} float32 scales" if quant else ""))
+            f"{pools} x {shape} {dt}"
+            + (f" + {pools} x {sshape} float32 scales" if quant else ""))
     pk = np.frombuffer(payload[:half], dt).reshape(shape)
-    pv = np.frombuffer(payload[half:2 * half], dt).reshape(shape)
+    pv = (np.frombuffer(payload[half:2 * half], dt).reshape(shape)
+          if pools == 2 else None)
     if quant is not None:
-        sk = np.frombuffer(
-            payload[2 * half:2 * half + sbytes], np.float32
-        ).reshape(sshape)
-        sv = np.frombuffer(payload[2 * half + sbytes:], np.float32
+        at = pools * half
+        sk = np.frombuffer(payload[at:at + sbytes], np.float32
                            ).reshape(sshape)
+        if pools == 2:
+            sv = np.frombuffer(payload[at + sbytes:], np.float32
+                               ).reshape(sshape)
         quant = {"mode": "int8",
                  "exact_dtype": str(quant["exact_dtype"])}
     return PageExport(
@@ -344,6 +374,10 @@ def check_compatible(ex: PageExport, cfg, page_size: int,
         raise PageShipError(
             f"shipment page stack {tuple(ex.pages_k.shape)} != "
             f"{want} for this pool's geometry")
+    if len(ex.stacks) != local.get("pools", 2):
+        raise PageShipError(
+            f"shipment of {len(ex.stacks)} page stacks for a pool of "
+            f"{local.get('pools', 2)} arrays")
     if prefix:
         if ex.pos != len(ex.prompt):
             raise PageShipError(

@@ -297,3 +297,51 @@ def test_v5e_step_holds_no_copy_of_the_pool(one_chip, width, monkeypatch):
     big = r"bf16\[%d,%d,%d,\d+(,\d+)?\]" % (cfg.n_layers, pages, ps)
     assert not [ln for ln in text.splitlines()
                 if re.search(r"= %s\S* (copy|concatenate)\(" % big, ln)]
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("width", [1, 512])
+def test_v5e_latent_step_updates_its_one_pool_in_place(one_chip, width,
+                                                       monkeypatch):
+    """DeepSeek-V2's published widths (128 heads, ranks 1536 / 512, 64
+    rotary, 40 held of 160 experts of 1536) at the benchmark's 8 lanes and
+    page 128, cut to the dense layer and one expert layer and a small
+    vocabulary, compiled for a v5e with the Mosaic kernel: ONE pool
+    `[L, P, ps, 640]`, aliased whole; a kernel call a layer whose result
+    `bf16[lanes, width, 128, 512]` the trace readers match; the experts'
+    grouped matmuls as the compiler's `ragged-dot` calls."""
+    monkeypatch.setattr(pk, "_resolve_interpret", lambda interpret: False)
+    cfg = tfm.deepseek_v2(layers=2, experts_held=(0, 40), vocab=1024,
+                          max_len=16384)
+    ps, lanes, pages = 128, 8, 1 + 8 * 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = sds((2, pages, ps, 640), jnp.bfloat16)
+    assert gen.pool_layout(cfg).row == 640
+    mp = gen.pages_per_seq(cfg, ps)
+
+    def i32(*s):
+        return sds(s, np.int32)
+
+    step = gen._compiled_paged_step.__wrapped__(cfg, pages, ps, width, True)
+    compiled = step.lower(
+        params, pool, i32(lanes, mp), i32(lanes), i32(lanes),
+        i32(lanes, width), sds((lanes,), np.float32), i32(lanes),
+        i32(lanes)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == int(np.prod(pool.shape)) * 2
+    text = compiled.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "latent_paged_attention" in ln]
+    assert len(kernels) == cfg.n_layers
+    for ln in kernels:
+        assert re.search(r"= bf16\[%d,%d,128,512\]\S* custom-call\("
+                         % (lanes, width), ln), ln[:300]
+        # the block table is the first operand
+        assert "operand_layout_constraints={s32[%d,%d]" % (lanes, mp) in ln
+    assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 3

@@ -125,10 +125,11 @@ def check_flash(cfg, sz: Sizes, on_tpu: bool) -> None:
     """Flash attention forward and backward against `attention()` at the
     train step's micro-batch shape.
 
-    Tolerance: the kernel computes in f32 and stores bf16, so against an
-    exact reference each output is off by up to half a bf16 ulp,
-    2^-9 * |x|, plus the MXU's rounding of the f32 probabilities; one ulp
-    of the largest reference value, 2^-7 * max|ref|, bounds both.  A wrong
+    Tolerance: the kernel feeds the MXU bf16 operands (the probabilities
+    among them), accumulates in f32 and stores bf16, so against an exact
+    reference each output is off by up to half a bf16 ulp, 2^-9 * |x|,
+    plus the rounding of the probabilities; one ulp of the largest
+    reference value, 2^-7 * max|ref|, bounds both.  A wrong
     mask, a dropped block or a mis-scaled softmax is an error of the order
     of the output itself and fails it.
     """

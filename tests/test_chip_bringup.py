@@ -107,15 +107,15 @@ def test_chip_smoke_without_tiny_fails_off_chip():
 def test_flash_block_error_is_raised_at_trace_time_for_compiled_calls():
     from deeplearning4j_tpu.parallel.kernels import (
         FlashBlockError,
-        _pick_block,
+        _blocks,
     )
 
-    assert _pick_block(1024, interpret=False) == 128
-    assert _pick_block(1000, interpret=False) == 40    # not 125
-    assert _pick_block(1000) == 125                    # interpreter: any
+    assert _blocks(1024, 128, interpret=False)[0] == 128
+    assert _blocks(1000, 128, interpret=False) == [40, 8]   # not 125
+    assert _blocks(1000, 128)[0] == 125                     # interpreter: any
     for s in (4, 100, 1001):
         with pytest.raises(FlashBlockError, match=f"sequence length {s}"):
-            _pick_block(s, interpret=False)
+            _blocks(s, 128, interpret=False)
 
 
 def test_supervised_workers_get_the_environment_their_spec_states(

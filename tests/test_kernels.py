@@ -6,8 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeplearning4j_tpu.parallel import kernels
 from deeplearning4j_tpu.parallel.kernels import (
-    _pick_block,
+    FlashBlockError,
+    _blocks,
+    _bwd_block,
+    _dense_grads,
+    _plan,
+    _vmem_need,
     flash_attention,
     flash_enabled,
 )
@@ -52,7 +58,8 @@ class TestFlashAttention:
 
     def test_bf16_inputs_forward_and_grads(self):
         """bf16 q/k/v — the dtype the TPU bench rows actually run.  The
-        kernel upcasts to f32 internally and stores bf16 outputs, so it
+        operands enter every matmul as bf16 (p and dS too), products
+        accumulate in f32 and the outputs are stored bf16, so the kernel
         should track the f32 oracle to bf16 resolution (~1e-2)."""
         q32, k32, v32 = _qkv(s=32, d=16, seed=3)
         q, k, v = (x.astype(jnp.bfloat16) for x in (q32, k32, v32))
@@ -108,11 +115,12 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-6)
 
-    def test_pick_block(self):
-        assert _pick_block(256) == 128
-        assert _pick_block(24) == 24
-        assert _pick_block(100) == 100
-        assert _pick_block(384) == 128
+    def test_blocks_are_divisors_largest_first(self):
+        assert _blocks(256, 128)[0] == 128
+        assert _blocks(24, 128)[0] == 24
+        assert _blocks(100, 128)[0] == 100
+        assert _blocks(384, 128) == [128, 96, 64, 48, 32, 24, 16, 12, 8, 6,
+                                     4, 3, 2, 1]
 
     def test_flash_enabled_env_override(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU_FLASH", "1")
@@ -166,6 +174,141 @@ class TestFlashAttention:
                         jax.tree_util.tree_leaves(g0)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# One parity test over what the kernels adapt to (ISSUE 27): operand dtype,
+# mask, and the block structure — one block, several owner blocks, several
+# tiles a chunk, several chunks a head (the clamped index maps), owner and
+# tile sizes that do not divide each other, and head sizes 64 and 128.
+
+# name: (S, head size, DL4J_TPU_FLASH_BQ, DL4J_TPU_FLASH_BK, scoped VMEM)
+_PARITY_SHAPES = {
+    "one_block": (8, 8, None, None, None),
+    "owner_blocks": (64, 8, 16, 64, None),           # 4 owners | 4 tiles
+    "tiles": (64, 8, 16, 8, None),                   # 8 / 4 tiles a chunk
+    "chunks": (64, 8, 8, 16, 1),                     # a tile a chunk
+    "odd_blocks": (120, 8, 40, 24, None),            # S 1000 -> 40's kind
+    "odd_chunks": (120, 8, 24, 40, 1),
+    "d64": (32, 64, None, None, None),               # half a lane tile
+    "d128": (32, 128, None, None, None),
+}
+
+
+def _set_blocks(monkeypatch, bq, bk, vmem):
+    for side, val in (("Q", bq), ("K", bk)):
+        if val is None:
+            monkeypatch.delenv(f"DL4J_TPU_FLASH_B{side}", raising=False)
+        else:
+            monkeypatch.setenv(f"DL4J_TPU_FLASH_B{side}", str(val))
+    if vmem is not None:
+        monkeypatch.setattr(kernels, "_DEFAULT_SCOPED_VMEM", vmem)
+
+
+def _tolerances(dtype):
+    """(forward atol, gradient atol, gradient rtol): f32 at the values the
+    suite has always held it to; bf16 at its resolution, 2^-8 of values
+    up to 3 forward and up to 8 in a gradient, against the f32 oracle on
+    the same rounded inputs."""
+    if dtype == jnp.float32:
+        return 2e-6, 2e-5, 0.0
+    return 2e-2, 6e-2, 2e-2
+
+
+@pytest.mark.parametrize("shape", list(_PARITY_SHAPES))
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_parity_forward_and_all_gradients(shape, causal, dtype,
+                                                monkeypatch):
+    s, d, bq, bk, vmem = _PARITY_SHAPES[shape]
+    _set_blocks(monkeypatch, bq, bk, vmem)
+    q, k, v = (x.astype(dtype) for x in _qkv(b=1, s=s, h=2, d=d, seed=s + d))
+    w = _qkv(b=1, s=s, h=2, d=d, seed=1)[0]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    fwd_atol, atol, rtol = _tolerances(dtype)
+
+    got = flash_attention(q, k, v, causal)
+    assert got.dtype == dtype
+    want = attention(*f32, causal=causal)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=fwd_atol)
+
+    grads = jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal).astype(jnp.float32) * w),
+        (0, 1, 2))(q, k, v)
+    ref = jax.grad(lambda q, k, v: jnp.sum(
+        attention(q, k, v, causal=causal) * w), (0, 1, 2))(*f32)
+    for a, b in zip(grads, ref):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b), atol=atol, rtol=rtol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("blocks", [(None, None, None), (8, 16, 1)],
+                         ids=["derived", "tiles_and_chunks"])
+def test_bwd_block_takes_the_rings_global_stats(dtype, blocks, monkeypatch):
+    """The ring's case: the second of two sequence shards holds q rows
+    S/2..S and meets k/v block 0 whole (non-causal) and block 1 on the
+    diagonal (causal), each through `_bwd_block` with the GLOBAL lse and
+    delta of its q rows; the pieces add up to dense attention's grads."""
+    _set_blocks(monkeypatch, *blocks)
+    s, h, d = 64, 2, 8
+    half = s // 2
+    q, k, v = (x.astype(dtype) for x in _qkv(b=1, s=s, h=h, d=d, seed=9))
+    g = _qkv(b=1, s=s, h=h, d=d, seed=10)[0].astype(dtype)
+    g = g.at[:, :half].set(0)                  # only shard 1's rows count
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+    want_dq, want_dk, want_dv = _dense_grads(*f32[:3], True, f32[3])
+
+    scores = jnp.einsum("bqhd,bkhd->bhqk", f32[0], f32[1]) / d ** 0.5
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -1e30)
+    lse = jax.nn.logsumexp(scores, axis=-1)[0, :, half:]       # [H, S/2]
+    out = attention(*f32[:3], causal=True)
+    delta = jnp.sum(out * f32[3], -1)[0, half:].T              # [H, S/2]
+
+    q1, g1 = q[:, half:], g[:, half:]
+    dq0, dk0, dv0 = _bwd_block(q1, k[:, :half], v[:, :half], g1, lse, delta,
+                               False, True)
+    dq1, dk1, dv1 = _bwd_block(q1, k[:, half:], v[:, half:], g1, lse, delta,
+                               True, True)
+    _, atol, rtol = _tolerances(dtype)
+    for got, want in (
+            (dq0.astype(jnp.float32) + dq1.astype(jnp.float32),
+             want_dq[:, half:]),
+            (dk0, want_dk[:, :half]), (dk1, want_dk[:, half:]),
+            (dv0, want_dv[:, :half]), (dv1, want_dv[:, half:])):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), atol=atol, rtol=rtol)
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [512, 1000, 1024, 4096, 16384])
+def test_block_plan_divides_tiles_and_fits(s, d, itemsize):
+    """The block derivation alone, as a compiled call makes it: every size
+    divides S and is a multiple of 8, the streamed chunk is whole tiles,
+    and the reckoned VMEM is inside the limit handed to the compiler —
+    the default scoped budget unless the smallest legal blocks pass it."""
+    for kind in ("fwd", "dkv", "dq"):
+        bo, ts, bs, limit = _plan(kind, s, d, itemsize, interpret=False)
+        for b in (bo, ts, bs):
+            assert s % b == 0 and b % 8 == 0, (kind, bo, ts, bs)
+        assert bs % ts == 0
+        need = _vmem_need(kind, d, itemsize, bo, ts, bs)
+        assert need <= limit, (kind, bo, ts, bs, need, limit)
+        assert limit == kernels._DEFAULT_SCOPED_VMEM or bs == ts
+        assert limit <= 64 << 20
+
+
+@pytest.mark.parametrize("s", [100, 1001])
+def test_block_plan_refuses_a_length_with_no_sublane_tile(s):
+    for kind in ("fwd", "dkv", "dq"):
+        with pytest.raises(FlashBlockError, match=f"sequence length {s}"):
+            _plan(kind, s, 64, 2, interpret=False)
+    assert _plan("fwd", s, 64, 2, interpret=True)[0] > 0   # the interpreter
 
 
 # ---------------------------------------------------------------------------

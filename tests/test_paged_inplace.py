@@ -345,3 +345,47 @@ def test_v5e_latent_step_updates_its_one_pool_in_place(one_chip, width,
         # the block table is the first operand
         assert "operand_layout_constraints={s32[%d,%d]" % (lanes, mp) in ln
     assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 3
+
+
+# The flash attention kernels at the shapes the benchmark's train cells and
+# the suite run them, compiled by the real Mosaic compiler (ISSUE 27): the
+# interpreter takes blocks, slices and layouts that Mosaic refuses.
+_FLASH_SHAPES = {
+    "train_cell": ((4, 1024, 16, 64), jnp.bfloat16, True),
+    "ring_diagonal": ((8, 512, 10, 64), jnp.bfloat16, True),
+    "ring_full_block": ((8, 512, 10, 64), jnp.bfloat16, False),
+    "odd_length": ((2, 1000, 4, 64), jnp.bfloat16, True),
+    "long_context": ((1, 16384, 2, 64), jnp.bfloat16, True),
+    "f32_inputs": ((1, 4096, 4, 64), jnp.float32, True),
+    "head_size_128": ((1, 2048, 4, 128), jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_FLASH_SHAPES))
+def test_v5e_flash_kernels_compile_and_keep_their_signature(one_chip, case):
+    """Forward and both backward kernels lower for a v5e; their results are
+    what the benchmark's trace readers match (`custom-call`s on 3-D
+    ``[B*H, S, d]`` in the input dtype: one result and the row stats
+    forward, two for dK/dV, one for dQ), and no lane-replicated
+    ``f32[B*H, S, 128]`` copy of the row stats is left in the program."""
+    from deeplearning4j_tpu.parallel.kernels import flash_attention
+
+    shape, dtype, causal = _FLASH_SHAPES[case]
+    b, s, h, d = shape
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal, False).astype(jnp.float32)), (0, 1, 2)))
+    text = grad.lower(x, x, x).compile().as_text()
+    name = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    one = r"%s\[%d,%d,%d\]\S*" % (name, b * h, s, d)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3, calls
+    assert len([ln for ln in calls
+                if re.search(r"= \(%s, f32\[\d+,\d+,1,\d+\]" % one, ln)]) == 1
+    assert len([ln for ln in calls
+                if re.search(r"= \(%s, %s\) " % (one, one), ln)]) == 1
+    assert len([ln for ln in calls
+                if re.search(r"= %s custom-call\(" % one, ln)]) == 1
+    if d != 128:        # an f32 [.., S, 128] there is the operands' own shape
+        assert not re.search(r"f32\[%d,%d,128\]" % (b * h, s), text)

@@ -3,11 +3,11 @@
 
 .PHONY: test test-serving test-precision test-fleet test-paged \
 	test-procfleet dryrun bench smoke serving-smoke bench-precision \
-	bench-fleet bench-paged bench-procfleet test-obs bench-obs \
+	bench-fleet bench-procfleet test-obs bench-obs \
 	obs-smoke evidence lint test-lint test-elastic bench-elastic \
 	test-spec bench-spec test-disagg bench-disagg test-pressure \
 	bench-pressure test-tenancy bench-tenants test-zero bench-zero \
-	test-paged-kernel bench-paged-kernel test-hibernate \
+	test-paged-kernel test-hibernate \
 	bench-hibernate chip-smoke
 
 # lint first: the four-pass static sweep is ~1s and fails fast on a
@@ -47,12 +47,6 @@ bench-procfleet:
 test-paged:
 	python -m pytest tests/ -q -m paged
 
-# Paged-KV bench row: shared-prefix storm, paged (half-size pool) vs
-# dense — tokens/s ratio, KV bytes at equal traffic, prefix hit rate
-# (docs/performance.md "The KV memory cost model").
-bench-paged:
-	BENCH_ONLY=paged python bench.py
-
 # Paged-attention KERNEL plane only (fused block-table-walk flash
 # attention: kernel-vs-gather-oracle parity incl. C>1 chunks, page
 # straddles, null lanes, bf16/fp16 finite masks, serving byte-parity,
@@ -60,10 +54,6 @@ bench-paged:
 # kernel cost model").
 test-paged-kernel:
 	python -m pytest tests/ -q -m paged_kernel
-
-# The kernel leg rides the paged row (kernel-vs-gather decode-step wall
-# time + modeled HBM bytes/step columns and the live-pages acceptance).
-bench-paged-kernel: bench-paged
 
 # Speculative-decode tests only (drafter plane: n-gram property suite +
 # small-model drafter, wide verify with in-jit accept/rollback, greedy
@@ -192,10 +182,9 @@ smoke:
 	BENCH_ONLY=lenet,transformer python bench.py
 
 # Serving throughput rows only (micro-batched classifier + continuous LM
-# + the overload/admission-control row + the fleet mid-storm-kill row +
-# the paged-KV shared-prefix row).
+# + the overload/admission-control row + the fleet mid-storm-kill row).
 serving-smoke:
-	BENCH_ONLY=serving,servinglm,servingoverload,servingfleet,paged,speculative,disagg,pressure,tenants python bench.py
+	BENCH_ONLY=serving,servinglm,servingoverload,servingfleet,speculative,disagg,pressure,tenants python bench.py
 
 # Precision-plane tests only (bf16-mixed parity/determinism, loss-scaler
 # overflow recovery, int8 serving agreement, dtype round-trips).

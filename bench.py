@@ -293,9 +293,7 @@ def bench_iris() -> dict:
 
 def bench_lstm() -> dict:
     """#4: character-level LSTM LM (GravesLSTM.java:47 parity config) —
-    examples/sec/chip at batch 32, seq 64, vocab 80, hidden 256.  On TPU
-    the lax.scan path is A/B'd against the Pallas fused-LSTM kernel
-    (`nn/layers/lstm_kernel.py`) and the faster one is the row value."""
+    examples/sec/chip at batch 32, seq 64, vocab 80, hidden 256."""
     import jax
 
     from deeplearning4j_tpu.models import MultiLayerNetwork, char_lstm
@@ -309,48 +307,21 @@ def bench_lstm() -> dict:
                    np.eye(V, dtype=np.float32)[np.roll(ids, -1, axis=1)])
     steps = max(20, STEPS // 2)
 
-    def timed(fused: bool) -> float:
-        import dataclasses
+    def make_net():
+        return MultiLayerNetwork(
+            char_lstm(vocab_size=V, hidden=H, compute_dtype=dtype)).init()
 
-        conf = char_lstm(vocab_size=V, hidden=H, compute_dtype=dtype)
-        # Pin the path via the layer conf (no env/jit-cache interplay).
-        conf = dataclasses.replace(conf, layers=tuple(
-            dataclasses.replace(lc, fused=fused) if hasattr(lc, "fused")
-            else lc for lc in conf.layers))
-        net = MultiLayerNetwork(conf).init()
-        return _time_steps(lambda: net.fit_batch_async(x, y), WARMUP, steps)
-
-    sec_scan = timed(False)
+    net = make_net()
+    sec_scan = _time_steps(lambda: net.fit_batch_async(x, y), WARMUP, steps)
     result = {"path": "scan", "scan_ms": round(sec_scan * 1e3, 3)}
     sec = sec_scan
     # Fused multi-step driver on the scan path: K steps per dispatch.
-    import dataclasses as _dc
-
-    conf_c = char_lstm(vocab_size=V, hidden=H, compute_dtype=dtype)
-    conf_c = _dc.replace(conf_c, layers=tuple(
-        _dc.replace(lc, fused=False) if hasattr(lc, "fused") else lc
-        for lc in conf_c.layers))
-    net_c = MultiLayerNetwork(conf_c).init()
+    net_c = make_net()
     sec_chunked, syncs = _time_fused_steps(net_c, x, y, steps)
     if sec_chunked < sec:
         sec, result["path"] = sec_chunked, "scan+chunked"
     result.update(chunked_ms=round(sec_chunked * 1e3, 3),
                   **_fused_fields(sec_chunked, sec_scan, syncs, steps))
-    if on_tpu:  # interpret-mode kernel off-TPU is not a perf path
-        try:
-            sec_fused = timed(True)
-            result["fused_ms"] = round(sec_fused * 1e3, 3)
-            # NOT bit-identical arithmetic: the scan leg computes gates in
-            # the compute dtype (bf16 on TPU) while the fused kernel keeps
-            # gates+carry in f32 internally and stores bf16 outputs.  The
-            # A/B picks the faster wall-clock path; this field records
-            # what each leg computed so the winner's precision is explicit
-            # (recorded only once the fused leg actually ran).
-            result["numerics"] = {"scan": dtype, "fused": "f32-internal"}
-            if sec_fused < sec_scan:
-                sec, result["path"] = sec_fused, "fused-pallas"
-        except Exception as e:  # noqa: BLE001 - fused is optional
-            result["fused_error"] = f"{type(e).__name__}: {e}"
     # per-timestep MACs: input proj V*4H + recurrent H*4H + head H*V
     flops = 3.0 * 2 * B * T * (V * 4 * H + H * 4 * H + H * V)
     return {"metric": "charLSTM train examples/sec/chip",
@@ -1577,194 +1548,6 @@ def bench_serving_lm() -> dict:
             "slots": slots}
 
 
-def bench_paged_kv() -> dict:
-    """Paged-KV row (ISSUE-7 acceptance): a shared-prefix request storm
-    — every prompt opens with the same system prefix, the traffic shape
-    a prefix-affinity router concentrates on one replica — served by
-    the dense slot pool vs the paged pool (radix prefix reuse + chunked
-    prefill) provisioned with HALF the dense pool's KV bytes.
-
-    The dense leg re-prefills the shared prefix for every request, one
-    token per dispatch; the paged leg prefills it once, every later
-    request reuses the cached pages and feeds only its distinct tail
-    (chunked).  Acceptance: >= 2x tokens/s OR >= 2x effective KV
-    capacity at equal memory (the half-size pool serving the same
-    traffic is exactly that), prefix_hit_rate > 0.5, and ZERO XLA
-    compiles across the storm after warmup."""
-    import dataclasses
-
-    import jax
-    import jax.monitoring
-
-    from deeplearning4j_tpu.parallel import transformer as tfm
-    from deeplearning4j_tpu.serving import ContinuousLMServer
-
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        cfg = tfm.gpt2_small(max_len=256)
-        slots, n_req, new, sys_len, ps, chunk = 8, 16, 32, 128, 16, 16
-    else:
-        cfg = dataclasses.replace(
-            tfm.gpt2_small(max_len=80), vocab_size=256, d_model=128,
-            n_heads=4, n_layers=2, d_ff=512, dtype="float32", remat=False)
-        slots, n_req, new, sys_len, ps, chunk = 8, 16, 16, 48, 16, 8
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    system = rng.integers(0, cfg.vocab_size, (sys_len,)).tolist()
-    prompts = [system + rng.integers(0, cfg.vocab_size, (3,)).tolist()
-               for _ in range(n_req)]
-    conc = min(8, n_req)
-
-    def storm(srv):
-        return min(_serving_storm(
-            conc, prompts, lambda p: srv.generate(list(p), new,
-                                                  timeout=600))
-            for _ in range(2))
-
-    # ---- dense baseline (the pre-ISSUE-7 pool) ----------------------------
-    dense = ContinuousLMServer(cfg, params, slots=slots, kv="dense")
-    try:
-        dense.generate(prompts[0], new, timeout=600)     # compile
-        from deeplearning4j_tpu.serving import ServingMetrics
-
-        dense.metrics = ServingMetrics()                 # drop warmup
-        sec_dense = storm(dense)
-        dense_stats = dense.stats()
-    finally:
-        dense.stop()
-
-    # ---- paged pool at HALF the dense KV bytes ----------------------------
-    from deeplearning4j_tpu.parallel.generation import pages_per_seq
-
-    max_pages = pages_per_seq(cfg, ps)
-    half_pages = max(max_pages, slots * max_pages // 2)
-    paged = ContinuousLMServer(cfg, params, slots=slots, kv="paged",
-                               page_size=ps, pages=half_pages,
-                               prefill_chunk=chunk)
-    compiles = []
-
-    def listener(event, duration, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiles.append(event)
-
-    try:
-        paged.warmup()              # decode + chunk + CoW compiled here
-        jax.monitoring.register_event_duration_secs_listener(listener)
-        try:
-            sec_paged = storm(paged)
-        finally:
-            jax.monitoring.clear_event_listeners()
-        paged_stats = paged.stats()
-    finally:
-        paged.stop()
-
-    # ---- kernel-vs-gather decode-step column (ISSUE-18) -------------------
-    # One 1-wide decode dispatch at a representative post-prefill depth,
-    # timed on both `_paged_attn` paths, plus the modeled K/V HBM bytes
-    # each reads: the gather path touches every block-table row (MP*ps
-    # pool rows per lane per layer), the fused kernel only live pages.
-    # The storm above rode the default path, so this column never moves
-    # the row's wall time; on CPU the kernel leg runs in Pallas
-    # interpret mode and its ms value measures the interpreter, not the
-    # TPU win — the bytes model is the backend-independent signal.
-    import jax.numpy as jnp
-
-    from deeplearning4j_tpu.parallel.generation import (
-        init_paged_cache,
-        make_paged_step,
-    )
-    from deeplearning4j_tpu.parallel.paged_kernel import paged_hbm_bytes
-
-    total = half_pages + 1
-    depth = sys_len + 3                    # every decode starts here
-    live_pages = depth // ps + 1
-    iters = 20 if on_tpu else 3
-
-    def _decode_step_ms(kernel_on: bool) -> float:
-        step = make_paged_step(cfg, total, ps, 1,
-                               paged_kernel=kernel_on)
-        cache = init_paged_cache(cfg, total, ps)
-        k, v = cache["k"], cache["v"]
-        table = np.zeros((slots, max_pages), np.int32)
-        for b in range(slots):
-            table[b, :live_pages] = 1 + (
-                b * live_pages + np.arange(live_pages)) % half_pages
-        args = (jnp.asarray(table),
-                jnp.full((slots,), depth, jnp.int32),
-                jnp.ones((slots,), jnp.int32),
-                jnp.zeros((slots, 1), jnp.int32),
-                jnp.zeros((slots,), jnp.float32),
-                jnp.zeros((slots,), jnp.int32),
-                jnp.zeros((slots,), jnp.int32))
-        nxt, k, v = step(params, k, v, *args)      # compile + warm
-        nxt.block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            nxt, k, v = step(params, k, v, *args)
-        nxt.block_until_ready()
-        return (time.perf_counter() - t0) / iters * 1e3
-
-    gather_ms = _decode_step_ms(False)
-    kernel_ms = _decode_step_ms(True)
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    bytes_gather = paged_hbm_bytes(
-        cfg.n_layers, slots, live_pages, max_pages, ps, cfg.n_heads,
-        cfg.head_dim, itemsize, kernel=False)
-    bytes_kernel = paged_hbm_bytes(
-        cfg.n_layers, slots, live_pages, max_pages, ps, cfg.n_heads,
-        cfg.head_dim, itemsize, kernel=True)
-
-    toks = n_req * new
-    speedup = round(sec_dense / sec_paged, 2)
-    kv_ratio = round(dense_stats["kv_bytes"]["provisioned"]
-                     / paged_stats["kv_bytes"]["provisioned"], 2)
-    hit_rate = paged_stats.get("prefix_hit_rate", 0.0)
-    lat = paged_stats.get("latency", {})
-    return {"metric": "TransformerLM paged-KV serving tokens/sec "
-                      f"(shared {sys_len}-token prefix storm, "
-                      f"{slots} slots, half-size pool)",
-            "unit": "tokens/sec", "value": round(toks / sec_paged, 1),
-            "requests": n_req, "new_tokens": new,
-            "prompt_len": sys_len + 3, "shared_prefix_tokens": sys_len,
-            "page_size": ps, "pages": half_pages,
-            "prefill_chunk": chunk,
-            **_mem_fields(params=params),
-            "dense_tokens_per_sec": round(toks / sec_dense, 1),
-            "paged_vs_dense": speedup,
-            "kv_bytes_dense": dense_stats["kv_bytes"]["provisioned"],
-            "kv_bytes_paged": paged_stats["kv_bytes"]["provisioned"],
-            "kv_capacity_vs_dense_at_equal_traffic": kv_ratio,
-            "prefix_hit_rate": hit_rate,
-            "prefix_tokens_saved":
-                paged_stats.get("prefix_tokens_saved", 0),
-            "dense_decode_steps": dense_stats["decode_steps"],
-            "paged_decode_steps": paged_stats["decode_steps"],
-            "p50_ms": lat.get("p50_ms"), "p99_ms": lat.get("p99_ms"),
-            "ttft_p50_ms": paged_stats.get("ttft", {}).get("p50_ms"),
-            "ttft_p99_ms": paged_stats.get("ttft", {}).get("p99_ms"),
-            "compiled_programs": paged_stats["compiled_programs"],
-            "off_ladder_compiles": len(compiles),
-            "kernel_decode_step_ms": round(kernel_ms, 3),
-            "gather_decode_step_ms": round(gather_ms, 3),
-            "kernel_vs_gather_wall": round(gather_ms / kernel_ms, 2),
-            "kernel_live_pages": live_pages,
-            "kernel_backend": ("compiled" if on_tpu
-                               else "pallas-interpret"),
-            "hbm_bytes_per_step_gather": bytes_gather,
-            "hbm_bytes_per_step_kernel": bytes_kernel,
-            "hbm_bytes_kernel_vs_gather": round(
-                bytes_kernel / bytes_gather, 3),
-            "meets_kernel_acceptance": bool(
-                bytes_kernel * max_pages <= bytes_gather * live_pages),
-            "meets_acceptance": bool(
-                (speedup >= 2.0 or (kv_ratio >= 2.0 and speedup >= 1.2))
-                and (hit_rate or 0) > 0.5 and not compiles),
-            "note": "paged pool holds HALF the dense pool's KV bytes "
-                    "and serves the same storm: the capacity ratio is "
-                    "measured at equal traffic, the tokens/s ratio on "
-                    "top of it"}
-
-
 def bench_pressure() -> dict:
     """Overload-survival row (ISSUE-15 acceptance): a mixed-priority
     storm whose total KV page demand is sized to >2x the paged pool's
@@ -1871,7 +1654,7 @@ def bench_pressure() -> dict:
         return round(float(np.percentile(xs, 99)) * 1e3, 1)
 
     # ---- baseline: all-FIFO, no survival plane ---------------------------
-    base = ContinuousLMServer(cfg, params, slots=slots, kv="paged",
+    base = ContinuousLMServer(cfg, params, slots=slots,
                               page_size=ps, pages=pool_pages,
                               prefill_chunk=4)
     try:
@@ -1881,7 +1664,7 @@ def bench_pressure() -> dict:
         base.stop()
 
     # ---- survival: priorities + preemption + brownout --------------------
-    srv = ContinuousLMServer(cfg, params, slots=slots, kv="paged",
+    srv = ContinuousLMServer(cfg, params, slots=slots,
                              page_size=ps, pages=pool_pages,
                              prefill_chunk=4, preempt=True,
                              brownout=True)
@@ -2043,7 +1826,7 @@ def bench_tenants() -> dict:
     rounds = 2
 
     def make_server():
-        return ContinuousLMServer(cfg, params, slots=slots, kv="paged",
+        return ContinuousLMServer(cfg, params, slots=slots,
                                   page_size=ps, pages=pool_pages,
                                   prefill_chunk=4, tenants=tenants)
 
@@ -2145,8 +1928,8 @@ def bench_tenants() -> dict:
 
 
 def bench_speculative() -> dict:
-    """Speculative-decode row (ISSUE-13 acceptance): the bench_paged_kv
-    shared-prefix greedy storm served by the PR-7 paged pool
+    """Speculative-decode row (ISSUE-13 acceptance): a shared-prefix
+    greedy storm served by the PR-7 paged pool
     (speculate off — the baseline) vs the same pool with the FREE
     n-gram drafter (`speculate="ngram"`): each greedy lane proposes up
     to draft_len continuation tokens per round from its own history,
@@ -2202,7 +1985,7 @@ def bench_speculative() -> dict:
 
     def run_leg(speculate):
         srv = ContinuousLMServer(
-            cfg, params, slots=slots, kv="paged", page_size=ps,
+            cfg, params, slots=slots, page_size=ps,
             prefill_chunk=chunk,
             **({"speculate": speculate, "draft_len": dlen}
                if speculate else {}))
@@ -2309,7 +2092,7 @@ def bench_disagg() -> dict:
         n_long, n_short, new_long, new_short = 12, 24, 16, 16
         slots, ps, chunk = 8, 16, 16
     else:
-        # the paged row's model scale: wide dispatches cost real
+        # a model scale at which wide dispatches cost real
         # milliseconds, so prefill interference is measurable — the
         # regime the role split exists for
         cfg = dataclasses.replace(
@@ -2329,7 +2112,7 @@ def bench_disagg() -> dict:
     # spreads them over every worker and every worker's decode loop
     # interleaves wide prefill chunks — exactly the interference tail
     # the role split removes (a shared system prompt would concentrate
-    # on one worker and radix-cache away; that shape is the paged row)
+    # on one worker and radix-cache away)
     long_prompts = [rng.integers(
         0, cfg.vocab_size, (sys_len + tail,)).tolist()
         for _ in range(n_long)]
@@ -2528,7 +2311,7 @@ def bench_disagg() -> dict:
         serialize_export,
     )
 
-    ship_srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+    ship_srv = ContinuousLMServer(cfg, params, slots=2,
                                   page_size=ps, prefill_chunk=chunk,
                                   ship=True)
     try:
@@ -2689,7 +2472,7 @@ def bench_hibernate() -> dict:
     host_cap = int(2.5 * blob_est)
 
     state_dir = tempfile.mkdtemp(prefix="bench-hibernate-")
-    srv = ContinuousLMServer(cfg, params, slots=slots, kv="paged",
+    srv = ContinuousLMServer(cfg, params, slots=slots,
                              page_size=ps, pages=pages,
                              hibernate_idle_s=0.2, state_dir=state_dir,
                              swap_bytes=host_cap)
@@ -2916,7 +2699,6 @@ BENCHES = {
     "hibernate": bench_hibernate,
     "elastic": bench_elastic,
     "obs": bench_obs,
-    "paged": bench_paged_kv,
     "speculative": bench_speculative,
     "pressure": bench_pressure,
     "tenants": bench_tenants,

@@ -541,22 +541,6 @@ def cmd_serve(args) -> int:
                   f"{type(first).__name__}); the first request per "
                   "bucket compiles instead")
     if args.lm:
-        if args.lm_speculate != "off" and args.lm_kv != "paged":
-            raise SystemExit(
-                "serve: -lm-speculate requires -lm-kv paged "
-                "(speculative rollback rides the page tables)")
-        if args.lm_ship and args.lm_kv != "paged":
-            raise SystemExit(
-                "serve: -lm-ship requires -lm-kv paged (page shipping "
-                "moves block-table pages)")
-        if (args.lm_preempt or args.lm_brownout) and args.lm_kv != "paged":
-            raise SystemExit(
-                "serve: -lm-preempt/-lm-brownout require -lm-kv paged "
-                "(the overload-survival plane swaps block-table pages)")
-        if args.lm_hibernate_idle_s is not None and args.lm_kv != "paged":
-            raise SystemExit(
-                "serve: -lm-hibernate-idle-s requires -lm-kv paged "
-                "(hibernation parks block-table pages)")
         if (args.lm_disk_dir is not None and args.lm_hibernate_idle_s
                 is None and not args.lm_preempt):
             raise SystemExit(
@@ -567,7 +551,7 @@ def cmd_serve(args) -> int:
                      max_queue_depth=max_queue,
                      default_deadline_s=deadline_s,
                      breaker_threshold=breaker_n,
-                     kv=args.lm_kv, page_size=args.page_size,
+                     page_size=args.page_size,
                      pages=(args.lm_pages if args.lm_pages > 0 else None),
                      prefill_chunk=args.prefill_chunk,
                      speculate=args.lm_speculate,
@@ -595,7 +579,7 @@ def cmd_serve(args) -> int:
                                  f"{type(e).__name__}: {e}")
         warm_note = (f"{warmed} programs warm" if warmed
                      else "programs compile on first use")
-        if lm_srv is not None and args.lm_kv == "paged":
+        if lm_srv is not None:
             spec_note = (f", speculate {lm_srv.speculate} "
                          f"(draft_len {lm_srv.draft_len})"
                          if lm_srv.speculate != "off" else "")
@@ -618,10 +602,6 @@ def cmd_serve(args) -> int:
                   f"paged KV: {lm_srv.kv_pages} pages x "
                   f"{lm_srv.page_size} tokens, prefill chunk "
                   f"{lm_srv.prefill_chunk}{spec_note}, {warm_note})")
-        else:
-            print(f"serve: LM registered ({cfg.n_layers}L/d{cfg.d_model}, "
-                  f"max_len {cfg.max_len}, {args.lm_slots} decode slots, "
-                  f"dense KV, {warm_note})")
     srv.start()
     print(f"serve: resilience max_queue={max_queue or 'unbounded'} "
           f"deadline_ms={args.deadline_ms or 'none'} "
@@ -1443,12 +1423,6 @@ def build_parser() -> argparse.ArgumentParser:
     p_serve.add_argument("-lm-slots", "--lm-slots", dest="lm_slots",
                          type=int, default=4,
                          help="continuous-decode lanes for /lm/generate")
-    p_serve.add_argument("-lm-kv", "--lm-kv", dest="lm_kv",
-                         choices=("paged", "dense"), default="paged",
-                         help="KV cache mode for the continuous pool: "
-                              "block-table paged with radix prefix "
-                              "reuse (default) or the dense per-slot "
-                              "cache (docs/performance.md)")
     p_serve.add_argument("-lm-pages", "--lm-pages", dest="lm_pages",
                          type=int, default=0,
                          help="KV pages in the paged pool (0 = full "

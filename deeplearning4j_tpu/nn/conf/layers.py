@@ -159,9 +159,6 @@ class GravesLSTMConf(LayerConf):
     activation: str = "tanh"
     forget_gate_bias_init: float = 5.0
     return_sequences: bool = True
-    # None = env policy (DL4J_TPU_FUSED_LSTM); True/False pins the Pallas
-    # fused-scan kernel per layer (part of the conf -> no stale-jit risk).
-    fused: Optional[bool] = None
 
 
 @register_layer_conf
@@ -172,7 +169,6 @@ class LSTMConf(LayerConf):
     activation: str = "tanh"
     forget_gate_bias_init: float = 1.0
     return_sequences: bool = True
-    fused: Optional[bool] = None  # see GravesLSTMConf.fused
 
 
 @register_layer_conf

@@ -62,26 +62,6 @@ def _lstm_apply(conf, params, state, x, *, train=False, rng=None, mask=None,
     xz = jnp.einsum("bti,ij->btj", x, params["W"]) + params["b"]
     xz_t = jnp.swapaxes(xz, 0, 1)  # [time, batch, 4n]
 
-    # Fast path: the whole time loop as ONE Pallas kernel (weights + carry
-    # resident in VMEM across steps). Mask/non-tanh configs use the scan.
-    from deeplearning4j_tpu.nn.layers.lstm_kernel import (
-        fused_lstm_enabled,
-        fused_lstm_scan,
-    )
-
-    use_fused = (conf.fused if getattr(conf, "fused", None) is not None
-                 else fused_lstm_enabled())
-    if mask is None and conf.activation.lower() == "tanh" and use_fused:
-        zeros = jnp.zeros((n,), x.dtype)
-        hs = fused_lstm_scan(
-            xz_t, params["RW"],
-            params["pi"] if peephole else zeros,
-            params["pf"] if peephole else zeros,
-            params["po"] if peephole else zeros)
-        if conf.return_sequences:
-            return jnp.swapaxes(hs, 0, 1), state
-        return hs[-1], state
-
     if mask is not None:
         mask_t = jnp.swapaxes(mask.astype(x.dtype), 0, 1)[..., None]  # [T,B,1]
     else:

@@ -206,113 +206,13 @@ def generate(cfg: TransformerConfig, params: dict, prompt,
 
 
 # ---------------------------------------------------------------------------
-# Slot-based decode (continuous batching for the serving engine)
+# Paged slot decode (block-table paged KV for the continuous LM pool)
 #
 # `generate()` above runs ONE request (or one fixed batch) to completion:
 # every row shares a single scalar position.  A serving process wants the
-# opposite shape: a fixed pool of B_slots decode lanes over one
-# [L, B_slots, max_len, H, K] KV cache, where each slot sits at its OWN
-# position — finished sequences free their slot and queued prompts join
-# mid-flight (prefill rides the same per-token step, teacher-forced).
-# The step below is that primitive; serving/lm.py drives the loop.
-
-
-def _slot_attn(p, x, layer_k, layer_v, pos):
-    """Per-slot single-position attention: like `_cached_attn` but `pos`
-    is a [B] vector — each row writes its k/v at its own position
-    (vmapped `lax.dynamic_update_slice`) and masks its own history."""
-    q, k, v = qkv_proj(p, x)                              # [B, 1, H, K]
-
-    def write(buf, new, p_):                              # one slot's row
-        return lax.dynamic_update_slice(buf, new, (p_, 0, 0))
-
-    layer_k = jax.vmap(write)(layer_k, k, pos)
-    layer_v = jax.vmap(write)(layer_v, v, pos)
-    d = q.shape[-1]
-    s = jnp.einsum("bqhk,bshk->bqhs", q, layer_k) / jnp.sqrt(
-        jnp.asarray(d, q.dtype))
-    valid = jnp.arange(layer_k.shape[1])[None, :] <= pos[:, None]  # [B, S]
-    s = jnp.where(valid[:, None, None, :], s, mask_value(s.dtype))
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bqhs,bshk->bqhk", w, layer_v)
-    return out_proj(p, o), layer_k, layer_v
-
-
-def init_slot_cache(cfg: TransformerConfig, slots: int) -> dict:
-    """Slot KV cache: `init_cache` with a [B] per-slot position vector."""
-    require_classic(cfg, "the dense slot cache (kv='dense')")
-    dt = jnp.dtype(cfg.dtype)
-    shape = (slots, cfg.max_len, cfg.n_heads, cfg.head_dim)
-    return {"k": jnp.zeros((cfg.n_layers,) + shape, dt),
-            "v": jnp.zeros((cfg.n_layers,) + shape, dt),
-            "pos": jnp.zeros((slots,), jnp.int32)}
-
-
-def slot_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
-                     token: jax.Array) -> Tuple[jax.Array, dict]:
-    """token: [B] int32, row b at position cache['pos'][b] (a [B] vector)
-    -> (logits [B, V], cache with every pos advanced).
-
-    Identical math to `decode_step` per row — a slot decoding alone
-    produces the same logits as a batch-1 `generate()` at the same
-    position — but rows no longer share a position, which is what lets
-    requests at different depths share one dispatch."""
-    pos = cache["pos"]
-    x = (params["embed"][token][:, None, :]
-         + jnp.take(params["pos"], pos, axis=0)[:, None, :])
-    ks, vs = [], []
-    for i, layer in enumerate(params["layers"]):
-        def attend(p, h, i=i):
-            a, nk, nv = _slot_attn(p, h, cache["k"][i], cache["v"][i], pos)
-            ks.append(nk)
-            vs.append(nv)
-            return a
-
-        x = block(cfg, layer, x, attend)
-    x = norm(cfg, params["ln_f"], x)
-    logits = jnp.einsum("bsd,dv->bsv", x, lm_head(params))[:, 0]
-    new_cache = {"k": jnp.stack(ks), "v": jnp.stack(vs), "pos": pos + 1}
-    return logits, new_cache
-
-
-@functools.lru_cache(maxsize=8)
-def _compiled_slot_step(cfg: TransformerConfig):
-    """ONE jitted program per config for the whole serving lifetime: the
-    slot count is baked into the cache shapes, `pos` is a traced vector,
-    and the KV buffers are donated.  (Donated, not yet updated in place:
-    `slot_decode_step` still slices a layer and restacks with
-    `jnp.stack`, the idiom the paged step lost in PR 25; ROADMAP S4.)
-
-    Per-slot sampling happens on device: `temperature[b] == 0` rows take
-    the argmax, sampled rows draw from `fold_in(PRNGKey(seed[b]),
-    count[b])` — deterministic per request regardless of how requests
-    interleave across dispatches."""
-
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def step(params, cache_k, cache_v, pos, token, temperature, seeds,
-             counts):
-        cache = {"k": cache_k, "v": cache_v, "pos": pos}
-        logits, cache = slot_decode_step(cfg, params, cache, token)
-        nxt = _sample(logits.astype(jnp.float32), temperature, seeds,
-                      counts)
-        return nxt, cache["k"], cache["v"]
-
-    return step
-
-
-def make_slot_step(cfg: TransformerConfig):
-    """Compiled slot-step entry point for `serving.lm.ContinuousLMServer`:
-    fn(params, k, v, pos [B], token [B], temperature [B], seeds [B],
-    counts [B]) -> (next_token [B], k, v)."""
-    return _compiled_slot_step(cfg)
-
-
-# ---------------------------------------------------------------------------
-# Paged slot decode (block-table paged KV for the continuous LM pool)
-#
-# The dense slot cache above provisions `slots * max_len` KV positions
-# whether or not any lane ever fills them — the serving-state memory
-# ceiling.  The paged variant replaces it with ONE fixed pool of
+# opposite shape: a fixed pool of decode lanes, each at its OWN position —
+# finished sequences free their lane and queued prompts join mid-flight
+# (serving/lm.py drives the loop).  Its KV state is ONE fixed pool of
 # `[layers, pages, page_size, H*K]` rows plus a per-slot page list
 # (`[slots, max_pages]` int32 block table) carried through the jitted
 # step: a lane's logical position `t` of layer `i` lives at
@@ -428,9 +328,9 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
 
     - ``paged_kernel=False`` — the gather ORACLE: materialize the full
       ``[B, MP*ps, H, K]`` history through the block table and run
-      exactly the dense `_slot_attn` math over it; masked positions
-      contribute exact zeros, so outputs are byte-identical to the
-      dense pool.  Kept as the parity reference (and guarded against
+      `_cached_attn`'s masked softmax over it, each lane at its own
+      position; masked positions contribute exact zeros.  Kept as the
+      parity reference (and guarded against
       re-growth by dl4jlint PGD301 — this is the baselined occurrence).
     - ``paged_kernel=True`` — `paged_flash_attention` walks the block
       table INSIDE the kernel, reading `[ps, H*K]` blocks of the
@@ -532,7 +432,7 @@ def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
     Every family's layer is `transformer.block`; what differs is the
     attention it is handed (`_paged_attn` over the k and v pools,
     `_latent_paged_attn` over the one latent pool).  Identical math to
-    `slot_decode_step` per position — the chunk's own writes land in the
+    `decode_step` per position — the chunk's own writes land in the
     pool before the gather, so intra-chunk causal attention rides the
     same masked-softmax path as the history.  The stacked pool
     `[L, P, ps, row]` is carried from layer to layer, each writing its
@@ -657,12 +557,12 @@ def _compiled_paged_step(cfg: TransformerConfig, pages: int,
     `[L, P, ps, row]` are donated and come back as the SAME buffers with
     `B*C` rows a layer written (`memory_analysis().alias_size_in_bytes`
     is the pool's bytes; tests/test_paged_inplace.py holds it there),
-    and sampling is the SAME device-side per-slot automaton
-    as `_compiled_slot_step` (greedy/temperature, fold_in(seed, count))
-    so paged and dense lanes sample byte-identically.  `paged_kernel`
-    arrives pre-resolved to a bool (see `resolve_paged_kernel`) so the
-    auto-detected default and an explicit matching flag share ONE cache
-    entry — the compile ladder keeps its size either way.  A
+    and sampling is the device-side per-slot automaton `_sample`
+    (greedy/temperature, fold_in(seed, count)): deterministic per
+    request regardless of how requests interleave across dispatches.
+    `paged_kernel` arrives pre-resolved to a bool (see
+    `resolve_paged_kernel`) so the platform's choice and an explicit
+    matching flag share ONE cache entry.  A
     `RoutedExperts` configuration's program returns `[B + 3]` int32: the
     sampled tokens and then `expert_load`, in the one array the host
     already waits for."""
@@ -689,8 +589,9 @@ def make_paged_step(cfg: TransformerConfig, pages: int, page_size: int,
     fn(params, k, v, table [B, MP], pos [B], n_feed [B], tokens [B, C],
     temperature [B], seeds [B], counts [B]) -> (next_token [B], k, v).
 
-    `paged_kernel=None` auto-resolves (fused block-table kernel on TPU,
-    gather oracle elsewhere; DL4J_TPU_PAGED_KERNEL overrides)."""
+    `paged_kernel=None` takes the platform's rule (fused block-table
+    kernel on TPU, gather oracle elsewhere); a bool is the oracle seam
+    of tests and tools."""
     return _compiled_paged_step(cfg, int(pages), int(page_size),
                                 int(chunk),
                                 resolve_paged_kernel(paged_kernel))

@@ -41,8 +41,8 @@ Like ``kernels.flash_attention``, ``interpret=None`` auto-detects:
 compiled on TPU, Pallas interpret mode elsewhere — so the tier-1 parity
 sweep (tests/test_kernels.py, ``paged_kernel`` marker) exercises the
 real kernel everywhere the suite runs.  Whether the *serving* paths use
-the kernel at all is the separate ``paged_kernel_enabled()`` policy
-below, mirroring ``flash_enabled()``.
+the kernel at all is the separate ``paged_kernel_enabled()`` rule
+below: the platform decides.
 """
 
 from __future__ import annotations
@@ -62,23 +62,17 @@ from deeplearning4j_tpu.parallel.kernels import (
 
 
 def paged_kernel_enabled() -> bool:
-    """Policy for the paged decode/prefill/verify dispatches: the fused
-    block-table kernel on TPU by default, the gather oracle elsewhere;
-    opt in/out anywhere with DL4J_TPU_PAGED_KERNEL=1/0.  (Parity tests
-    opt IN on CPU — the kernel then runs in interpret mode.)"""
-    import os
-
-    flag = os.environ.get("DL4J_TPU_PAGED_KERNEL")
-    if flag is not None:
-        return flag.lower() in ("1", "true", "yes")
+    """The rule for the paged decode/prefill/verify dispatches: the
+    fused block-table kernel on a TPU, the gather oracle elsewhere (on
+    a CPU the kernel would run in the Pallas interpreter)."""
     return jax.default_backend() == "tpu"
 
 
 def resolve_paged_kernel(paged_kernel) -> bool:
-    """Normalize the ``paged_kernel=`` switch BEFORE it reaches any
-    compile-ladder cache key: ``None`` resolves through the policy
-    above, anything else coerces to bool — so auto-detect and an
-    explicit matching flag hit the SAME cached program."""
+    """``generation``'s ``paged_kernel=`` keyword as a bool BEFORE it
+    reaches a compile cache key: ``None`` takes the rule above, anything
+    else coerces — so the platform's choice and an explicit matching
+    flag hit the SAME cached program."""
     if paged_kernel is None:
         return paged_kernel_enabled()
     return bool(paged_kernel)
@@ -324,20 +318,6 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, pos, n_feed, qf, k_pages, v_pages)
-
-
-def paged_hbm_bytes(n_layers: int, lanes: int, live_pages: int,
-                    max_pages: int, page_size: int, n_heads: int,
-                    head_dim: int, itemsize: int,
-                    kernel: bool) -> int:
-    """Modeled K/V HBM bytes one decode dispatch reads (the cost model
-    in docs/performance.md): the gather path touches every block-table
-    row — ``MP * ps`` pool rows per lane per layer — while the kernel
-    reads only the lane's live pages.  Both read k AND v (the factor
-    2); q/output/params traffic is identical across the paths and
-    excluded."""
-    rows = (live_pages if kernel else max_pages) * page_size
-    return 2 * n_layers * lanes * rows * n_heads * head_dim * itemsize
 
 
 # ---------------------------------------------------------------------------

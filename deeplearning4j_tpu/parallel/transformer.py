@@ -173,7 +173,7 @@ class TransformerConfig:
     @property
     def classic(self) -> bool:
         """GPT-2's layer and nothing else: what the trainers, the mesh
-        runtimes and the dense slot cache compute."""
+        runtimes and the whole-sequence KV cache compute."""
         return (self.norm == "layer" and self.rope is None
                 and self.mlp == "gelu" and self.latent is None
                 and self.experts is None)

@@ -13,13 +13,12 @@ coalescing K concurrent *requests* per device dispatch.
   explicit `warmup()` and a compile-count guard (`engine.py`);
 - `ContinuousLMServer` — slot-based continuous LM decode: finished
   sequences free their slot and queued prompts join mid-flight
-  (`lm.py`).  KV state is block-table PAGED by default (ISSUE-7):
+  (`lm.py`).  KV state is block-table PAGED (ISSUE-7):
   a fixed pool of `[pages, page_size]` KV pages addressed through
   per-slot page lists, pages allocated on admission and refcount-freed
   on completion (`PagePool`), shared prompt prefixes prefilled once and
   radix-cached (`RadixPrefixCache`, copy-on-write at the divergence
-  page), long prompts fed up to `prefill_chunk` tokens per dispatch;
-  `kv="dense"` keeps the original `[slots, max_len]` cache; with
+  page), long prompts fed up to `prefill_chunk` tokens per dispatch; with
   `speculate="ngram"`/`"model"` (ISSUE-13) a cheap drafter
   (`draft.py`: prompt-lookup `NgramDrafter`, small-model
   `ModelDrafter`) proposes up to `draft_len` tokens per greedy lane

@@ -18,8 +18,9 @@ Two stdlib-cheap implementations:
   strong on exactly the traffic continuous batching concentrates
   (shared system prompts, template continuations, greedy decode loops).
 - `ModelDrafter` — a small zoo model (a tiny transformer config, or the
-  target model itself for self-speculation tests) decoding greedily in
-  its OWN dense slot cache via the existing `make_slot_step` program.
+  target model itself for self-speculation tests) decoding greedily
+  over a page pool of its OWN through the `make_paged_step` program the
+  target serves from, each lane on a fixed run of pages.
   Costs ~(catch_up + budget) 1-wide draft-model dispatches per round —
   worth it only when the draft model is much smaller than the target
   (docs/performance.md "The speculative decode cost model").
@@ -32,6 +33,10 @@ is called from the worker's lock-free dispatch path only.
 from __future__ import annotations
 
 from typing import List, Optional, Protocol, Sequence, runtime_checkable
+
+
+# tokens a page of the draft model's own pool (the serving pool's default)
+_PAGE_SIZE = 16
 
 
 @runtime_checkable
@@ -122,14 +127,16 @@ class NgramDrafter:
 
 class ModelDrafter:
     """Small-model drafting: a draft LM greedily rolls out `budget`
-    tokens per lane in its OWN dense slot cache (one
-    `make_slot_step` program, 1-wide dispatches).
+    tokens per lane over a page pool of its OWN (one `make_paged_step`
+    program, 1-wide dispatches).  The block table is the identity: lane
+    `i` owns pages `1 + i*mp .. (i+1)*mp` for good (page 0 is the null
+    page), so there is no allocator and no radix tree here.
 
     Lane state self-heals from the histories handed to `propose`: each
     call rewinds a lane to the longest common prefix of what was fed
     and the new committed history (rejected drafts and freed/reused
-    slots fall out naturally — the dense cache's position mask hides
-    everything past `pos`, so rewinding is a host-side counter move),
+    slots fall out naturally — the causal mask hides everything past
+    `pos`, so rewinding is a host-side counter move),
     teacher-forces the missing suffix, then rolls out proposals.  Lanes
     mid-teacher-forcing idle by RE-FEEDING their last token at its own
     position — k/v at a position are a pure function of (token,
@@ -156,7 +163,8 @@ class ModelDrafter:
         self.params = params
         self.n_slots = int(slots)
         self._step = None
-        self._cache = None          # (k, v) donated device buffers
+        self._cache = None          # the donated pool buffers
+        self._table = None          # [slots, mp] identity block table
         self._fed: List[List[int]] = [[] for _ in range(self.n_slots)]
 
     # ---- device plumbing --------------------------------------------------
@@ -164,14 +172,21 @@ class ModelDrafter:
     def _ensure_started(self) -> None:
         if self._step is not None:
             return
+        import numpy as np
+
         from deeplearning4j_tpu.parallel.generation import (
-            init_slot_cache,
-            make_slot_step,
+            init_paged_cache,
+            make_paged_step,
+            pages_per_seq,
         )
 
-        self._step = make_slot_step(self.cfg)
-        cache = init_slot_cache(self.cfg, self.n_slots)
-        self._cache = (cache["k"], cache["v"])
+        mp = pages_per_seq(self.cfg, _PAGE_SIZE)
+        total = self.n_slots * mp + 1
+        self._step = make_paged_step(self.cfg, total, _PAGE_SIZE, 1)
+        self._cache = tuple(
+            init_paged_cache(self.cfg, total, _PAGE_SIZE).values())
+        self._table = 1 + np.arange(self.n_slots * mp, dtype=np.int32
+                                    ).reshape(self.n_slots, mp)
 
     def warmup(self) -> None:
         """Compile the draft-model program before traffic (the LM
@@ -181,12 +196,13 @@ class ModelDrafter:
 
         self._ensure_started()
         zi = np.zeros((self.n_slots,), np.int32)
-        self._dispatch(zi, zi)
-        self.reset()                # the warm write clobbered pos 0
+        # nothing fed: only the null page is written, live lanes keep theirs
+        self._dispatch(zi, zi, zi)
 
-    def _dispatch(self, tokens, pos):
+    def _dispatch(self, tokens, pos, n_feed):
         """One 1-wide draft-model step; returns [B] greedy next tokens.
-        Sampling inputs are all-zero: temperature 0 = argmax rows."""
+        Lanes with `n_feed` 0 write the null page.  Sampling inputs are
+        all-zero: temperature 0 = argmax rows."""
         import numpy as np
 
         from deeplearning4j_tpu.obs.compilewatch import compile_scope
@@ -194,10 +210,11 @@ class ModelDrafter:
         zi = np.zeros((self.n_slots,), np.int32)
         zf = np.zeros((self.n_slots,), np.float32)
         with compile_scope("lm:draft"):
-            nxt, k, v = self._step(self.params, *self._cache, pos, tokens,
-                                   zf, zi, zi)
-        self._cache = (k, v)
-        return np.asarray(nxt)
+            nxt, *pools = self._step(
+                self.params, *self._cache, self._table, pos, n_feed,
+                tokens[:, None], zf, zi, zi)
+        self._cache = tuple(pools)
+        return np.asarray(nxt)[:self.n_slots]
 
     # ---- drafting ---------------------------------------------------------
 
@@ -255,7 +272,7 @@ class ModelDrafter:
                 elif self._fed[i]:             # idle: byte-idempotent re-feed
                     tokens[i] = self._fed[i][-1]
                     pos[i] = len(self._fed[i]) - 1
-            pred = self._dispatch(tokens, pos)
+            pred = self._dispatch(tokens, pos, self._feeding())
         # greedy rollout: feed each round's prediction back in
         out: List[List[int]] = [[] for _ in range(self.n_slots)]
         k_max = max(budgets)
@@ -278,8 +295,15 @@ class ModelDrafter:
                 elif self._fed[i]:
                     tokens[i] = self._fed[i][-1]
                     pos[i] = len(self._fed[i]) - 1
-            pred = self._dispatch(tokens, pos)
+            pred = self._dispatch(tokens, pos, self._feeding())
         return [p[:b] for p, b in zip(out, budgets)]
+
+    def _feeding(self):
+        """n_feed of a dispatch: every lane with a history feeds one
+        token (its next, or its last again), the rest none."""
+        import numpy as np
+
+        return np.asarray([1 if f else 0 for f in self._fed], np.int32)
 
     def reset(self) -> None:
         self._fed = [[] for _ in range(self.n_slots)]

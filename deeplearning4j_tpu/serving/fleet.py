@@ -279,7 +279,7 @@ def spawn_local_replica(name: str, net=None, *, lm=None, lm_slots: int = 4,
                         breaker_threshold: Optional[int] = 5,
                         breaker_cooldown_s: float = 1.0,
                         quantize: Optional[str] = None,
-                        lm_kv: str = "paged", lm_page_size: int = 16,
+                        lm_page_size: int = 16,
                         lm_pages: Optional[int] = None,
                         lm_prefill_chunk: int = 8,
                         lm_speculate: str = "off",
@@ -328,7 +328,7 @@ def spawn_local_replica(name: str, net=None, *, lm=None, lm_slots: int = 4,
                      default_deadline_s=default_deadline_s,
                      breaker_threshold=breaker_threshold,
                      breaker_cooldown_s=breaker_cooldown_s,
-                     kv=lm_kv, page_size=lm_page_size, pages=lm_pages,
+                     page_size=lm_page_size, pages=lm_pages,
                      prefill_chunk=lm_prefill_chunk,
                      speculate=lm_speculate, draft_len=lm_draft_len,
                      ship=ship, preempt=lm_preempt,

@@ -12,35 +12,32 @@ and advances every active lane each device step:
   rides as masked padding), so the WHOLE serving lifetime runs a fixed,
   pre-compilable program set per config.
 
-KV state comes in two modes (ISSUE-7):
+KV state is block-table paged (ISSUE-7): one fixed pool
+`[L, pages, page_size, H*K]` (lane-dense rows, updated in place by the
+donating step), per-slot page lists carried as a `[slots, max_pages]`
+int32 block table inside the jitted step
+(`parallel.generation.make_paged_step`).  Pages are allocated on
+admission and refcount-freed on completion (`serving/paged.py`), so
+device capacity is sum-of-actual-lengths instead of slots * max_len.
+On top of it:
 
-- `kv="dense"` — the original one `[L, slots, max_len, H, K]` cache:
-  every lane provisions max_len positions whether it uses them or not
-  (`parallel.generation.make_slot_step`).
-- `kv="paged"` (default) — block-table paged KV: one fixed pool
-  `[L, pages, page_size, H*K]` (lane-dense rows, updated in place by the
-  donating step), per-slot page lists
-  carried as a `[slots, max_pages]` int32 block table inside the jitted
-  step (`parallel.generation.make_paged_step`).  Pages are allocated on
-  admission and refcount-freed on completion (`serving/paged.py`), so
-  device capacity is sum-of-actual-lengths instead of slots * max_len.
-  On top of it:
+- **radix prefix reuse** — a host-side radix tree over prompt token
+  prefixes maps to refcounted page runs; a request whose prompt
+  shares a cached prefix skips prefill for those tokens entirely
+  (copy-on-write at the divergence page), which is what the fleet's
+  prefix-affinity router (ISSUE-6) was set up to feed;
+- **chunked prefill** — a long prompt feeds up to `prefill_chunk`
+  tokens per dispatch instead of one, so admission latency shrinks
+  by ~chunk× while active decode lanes keep advancing every step.
 
-  * **radix prefix reuse** — a host-side radix tree over prompt token
-    prefixes maps to refcounted page runs; a request whose prompt
-    shares a cached prefix skips prefill for those tokens entirely
-    (copy-on-write at the divergence page), which is what the fleet's
-    prefix-affinity router (ISSUE-6) was set up to feed;
-  * **chunked prefill** — a long prompt feeds up to `prefill_chunk`
-    tokens per dispatch instead of one, so admission latency shrinks
-    by ~chunk× while active decode lanes keep advancing every step.
-
-  The compile-count discipline holds: one program per
-  (config, pages, page_size, chunk) — a decode-step (chunk 1), one
-  prefill-chunk step when `prefill_chunk > 1`, and the copy-on-write
-  page copy; `warmup()` compiles all of them before traffic (after it,
-  no request can trigger an XLA compile), otherwise each compiles on
-  its first dispatch like every other serving program.
+The compile-count discipline holds: one program per
+(config, pages, page_size, chunk) — a decode-step (chunk 1), one
+prefill-chunk step when `prefill_chunk > 1`, and the copy-on-write
+page copy; `warmup()` compiles all of them before traffic (after it,
+no request can trigger an XLA compile), otherwise each compiles on
+its first dispatch like every other serving program.  On a TPU the
+steps attend through the fused block-table kernel, elsewhere through
+the gather oracle (`parallel.paged_kernel.paged_kernel_enabled`).
 
 Greedy and plain-temperature sampling run in the slot pool (sampling is
 seeded per request: `fold_in(PRNGKey(seed), tokens_generated)`, so a
@@ -50,7 +47,7 @@ top-k/top-p/beam requests take the legacy whole-sequence path in
 switches.
 
 **Speculative multi-token decode** (ISSUE-13, `speculate="ngram"` or
-`"model"`, paged KV only): a cheap drafter (`serving/draft.py`)
+`"model"`): a cheap drafter (`serving/draft.py`)
 proposes up to `draft_len` continuation tokens per greedy decode lane
 per round; the target model scores `[last_committed, d_1..d_k]` in ONE
 wide dispatch through the SAME chunked-feed program ladder chunked
@@ -66,13 +63,12 @@ for the whole request and flow back through the normal `PagePool`
 refcount discipline at completion, never per round.  SAMPLING lanes
 (temperature > 0) are never drafted for — verifying a sampled draft
 greedily would mis-sample — and fall back to 1-token decode per round
-while riding the same dispatches; `speculate` with `kv="dense"` is a
-typed ValueError at construction (the rollback story needs pages).
+while riding the same dispatches.
 Accounting: accept-rate / tokens-per-round counters in
 `ServingMetrics`, a `speculate` section in `stats()`, and
 drafted/accepted attrs on each request's decode trace span.
 
-**Disaggregated serving hooks** (ISSUE-14, `ship=True`, paged KV only):
+**Disaggregated serving hooks** (ISSUE-14, `ship=True`):
 the pool speaks the KV page-shipping wire plane (`serving/transfer.py`)
 so a fleet can split worker roles — prefill workers chew long prompts
 and ship the finished pages to decode workers:
@@ -315,7 +311,7 @@ class _Slot:
         self.pos = 0          # next cache position to write
         self.fed = 0          # prompt tokens already fed (prefill cursor)
         self.generated: List[int] = []
-        # paged-KV bookkeeping (kv="paged" only)
+        # paged-KV bookkeeping
         self.table: Optional[np.ndarray] = None   # [max_pages] int32 row
         self.owned: List[int] = []    # pages this lane allocated
         self.shared: List[int] = []   # prefix pages reused from the tree
@@ -331,10 +327,8 @@ class ContinuousLMServer:
 
     `generate(prompt_ids, max_new_tokens)` is thread-safe and blocks
     until the request's sequence is complete; any number of requests
-    share the device via the slot pool.  `kv="paged"` (default) serves
-    from the block-table paged pool with radix prefix reuse and chunked
-    prefill; `kv="dense"` keeps the original per-slot dense cache (the
-    bench baseline).
+    share the device via the slot pool, served from the block-table
+    paged pool with radix prefix reuse and chunked prefill.
     """
 
     def __init__(self, cfg, params, slots: int = 4,
@@ -342,13 +336,12 @@ class ContinuousLMServer:
                  max_queue_depth: Optional[int] = None,
                  default_deadline_s: Optional[float] = None,
                  breaker: Optional[CircuitBreaker] = None,
-                 kv: str = "paged", page_size: int = 16,
+                 page_size: int = 16,
                  pages: Optional[int] = None, prefill_chunk: int = 8,
                  speculate: str = "off", draft_len: int = 4,
                  drafter=None, draft_model=None, ship: bool = False,
                  preempt: bool = False, swap_bytes: int = 64 << 20,
                  brownout=None, tenants=None,
-                 paged_kernel: Optional[bool] = None,
                  hibernate_idle_s: Optional[float] = None,
                  state_dir: Optional[str] = None,
                  state_disk_bytes: int = 1 << 30,
@@ -360,16 +353,6 @@ class ContinuousLMServer:
         if max_queue_depth is not None and max_queue_depth < 1:
             raise ValueError(f"max_queue_depth must be >= 1 or None, got "
                              f"{max_queue_depth}")
-        if kv not in ("paged", "dense"):
-            raise ValueError(f"kv must be 'paged' or 'dense', got {kv!r}")
-        if kv == "dense":
-            from deeplearning4j_tpu.parallel.transformer import (
-                require_classic,
-            )
-
-            # typed, here: the slot cache keeps `[H, K]` rows of full
-            # heads and its step computes GPT-2's layer only
-            require_classic(cfg, "ContinuousLMServer(kv='dense')")
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if prefill_chunk < 1:
@@ -380,44 +363,11 @@ class ContinuousLMServer:
                              f"'model', got {speculate!r}")
         if drafter is not None and speculate == "off":
             speculate = "custom"           # injected Drafter instance
-        if speculate != "off" and kv != "paged":
-            # typed at ADMISSION of the config, not a crash at dispatch:
-            # speculative rollback is a pointer move ONLY on the paged
-            # pool (docs/performance.md "The speculative decode cost
-            # model"); the dense cache has no cheap rewind story
-            raise ValueError(
-                f"speculate={speculate!r} requires kv='paged' "
-                f"(got kv={kv!r}): rollback rides the page tables")
         if speculate != "off" and draft_len < 1:
             raise ValueError(f"draft_len must be >= 1, got {draft_len}")
-        if ship and kv != "paged":
-            # same typed-at-construction rule as speculate: shipping is
-            # page lists over the wire — the dense cache has none
+        if hibernate_idle_s is not None and float(hibernate_idle_s) < 0:
             raise ValueError(
-                f"ship=True requires kv='paged' (got kv={kv!r}): page "
-                f"shipping moves block-table pages")
-        if preempt and kv != "paged":
-            raise ValueError(
-                f"preempt=True requires kv='paged' (got kv={kv!r}): "
-                f"preemption swaps block-table pages out to the host")
-        if brownout and kv != "paged":
-            raise ValueError(
-                f"brownout requires kv='paged' (got kv={kv!r}): the "
-                f"ladder's signals are the paged pool's pressure")
-        if paged_kernel and kv != "paged":
-            raise ValueError(
-                f"paged_kernel=True requires kv='paged' (got kv={kv!r}):"
-                f" the fused kernel walks the block tables")
-        if hibernate_idle_s is not None:
-            if kv != "paged":
-                raise ValueError(
-                    f"hibernate_idle_s requires kv='paged' (got "
-                    f"kv={kv!r}): hibernation parks block-table pages "
-                    f"on the tiered state store")
-            if float(hibernate_idle_s) < 0:
-                raise ValueError(
-                    f"hibernate_idle_s must be >= 0, got "
-                    f"{hibernate_idle_s}")
+                f"hibernate_idle_s must be >= 0, got {hibernate_idle_s}")
         if state_dir is not None and not (preempt
                                           or hibernate_idle_s is not None):
             raise ValueError(
@@ -430,7 +380,6 @@ class ContinuousLMServer:
         self.max_queue_depth = max_queue_depth
         self.default_deadline_s = default_deadline_s
         self.breaker = breaker
-        self.kv = kv
         self.page_size = int(page_size)
         from deeplearning4j_tpu.parallel.generation import pages_per_seq
 
@@ -444,15 +393,11 @@ class ContinuousLMServer:
         if self.kv_pages < 1:
             raise ValueError(f"pages must be >= 1, got {self.kv_pages}")
         self.prefill_chunk = int(prefill_chunk)
-        # None = auto (fused block-table kernel on TPU, gather oracle
-        # elsewhere); resolved ONCE here so the ladder keys, stats and
-        # every make_*_step call agree for the server's lifetime
-        from deeplearning4j_tpu.parallel.paged_kernel import (
-            resolve_paged_kernel,
-        )
+        # what the platform rule gives the step programs (reported in
+        # `stats()`; the makers apply the same rule themselves)
+        from deeplearning4j_tpu.parallel import paged_kernel
 
-        self.paged_kernel = (resolve_paged_kernel(paged_kernel)
-                             if kv == "paged" else False)
+        self.paged_kernel = paged_kernel.paged_kernel_enabled()
         self.speculate = speculate
         self.draft_len = int(draft_len)
         self._drafter = drafter            # built in _start_locked if None
@@ -570,14 +515,13 @@ class ContinuousLMServer:
         paged pool's hard capacity: a request that could never fit the
         whole pool is the client's error, not an overload."""
         ids = validate_request(self.cfg, prompt_ids, max_new_tokens)
-        if self.kv == "paged":
-            need = self._required_pages(len(ids), int(max_new_tokens))
-            if need > self.kv_pages:
-                raise ValueError(
-                    f"request needs {need} KV pages "
-                    f"({len(ids)} prompt + {int(max_new_tokens)} new, "
-                    f"page_size {self.page_size}) but the pool holds "
-                    f"{self.kv_pages}; raise -lm-pages or shorten it")
+        need = self._required_pages(len(ids), int(max_new_tokens))
+        if need > self.kv_pages:
+            raise ValueError(
+                f"request needs {need} KV pages "
+                f"({len(ids)} prompt + {int(max_new_tokens)} new, "
+                f"page_size {self.page_size}) but the pool holds "
+                f"{self.kv_pages}; raise -lm-pages or shorten it")
         return ids
 
     def _retry_after_locked(self) -> float:
@@ -614,8 +558,7 @@ class ContinuousLMServer:
         via the ONE shared `validate()` contract."""
         if export:
             ids = validate_request(self.cfg, prompt_ids, max_new_tokens)
-            if (self.kv == "paged"
-                    and -(-len(ids) // self.page_size) > self.kv_pages):
+            if -(-len(ids) // self.page_size) > self.kv_pages:
                 raise ValueError(
                     f"prompt needs {-(-len(ids) // self.page_size)} "
                     f"prefill pages (page_size {self.page_size}) but "
@@ -938,10 +881,6 @@ class ContinuousLMServer:
     # ---- disaggregation: KV page export / import (ISSUE-14) ---------------
 
     def _require_ship(self, what: str) -> None:
-        if self.kv != "paged":
-            raise ValueError(
-                f"page {what} requires kv='paged': shipping moves "
-                f"block-table pages (got kv={self.kv!r})")
         if not self.ship:
             raise ValueError(
                 f"page {what} requested but the pool was started with "
@@ -1091,9 +1030,8 @@ class ContinuousLMServer:
                 ev = self._warm_req = threading.Event()
             self._cond.notify_all()
         if not ev.wait(timeout):
-            # the warm never ran (dense mode never went idle, or the
-            # device is wedged): report 0, not a count the zero-compile
-            # contract would falsely promise
+            # the warm never ran (the device is wedged): report 0, not
+            # a count the zero-compile contract would falsely promise
             return 0
         with self._cond:
             err, self._warm_error = self._warm_error, None
@@ -1103,11 +1041,9 @@ class ContinuousLMServer:
 
     def _warm_programs(self) -> None:
         """Worker-side warm: one dispatch per program against the live
-        cache.  Only called while every lane is idle — the paged step
-        with n_feed=0 writes nothing but the null page, and the idle
-        dense step's pos-0 write lands in lanes that restart at pos 0
-        on admission anyway — so cache contents stay serviceable and no
-        second pool is ever allocated."""
+        cache.  The paged step with n_feed=0 writes nothing but the null
+        page, so cache contents stay serviceable beside live lanes and
+        no second pool is ever allocated."""
         import jax
 
         if self._cache is None:
@@ -1128,11 +1064,6 @@ class ContinuousLMServer:
         try:
             zi = np.zeros((self.n_slots,), np.int32)
             zf = np.zeros((self.n_slots,), np.float32)
-            if self.kv == "dense":
-                out = warm("lm:dense", lambda: self._step(
-                    self.params, *self._cache, zi, zi, zf, zi, zi))
-                self._cache = tuple(out[1:])
-                return
             table = np.zeros((self.n_slots, self.max_pages), np.int32)
             if self.speculate != "off":
                 widths = [1, self.spec_width]
@@ -1174,8 +1105,6 @@ class ContinuousLMServer:
                 "total_s": time.perf_counter() - t_start}
 
     def compiled_programs(self) -> int:
-        if self.kv == "dense":
-            return 1
         # page gather + batched install serve the shipping wire plane,
         # preemption swap-out/restore AND hibernate/resume — one
         # compiled pair for all three
@@ -1253,21 +1182,15 @@ class ContinuousLMServer:
         return drained
 
     def _kv_bytes(self) -> Dict:
-        """Actual vs provisioned KV bytes — the honest memory column for
-        the bench (a dense pool's provisioned bytes are paid whether or
-        not any lane fills them; the paged pool's actual bytes follow
-        the refcounted pages, radix-shared prefixes counted once)."""
+        """Actual vs provisioned KV bytes: the pool's bytes are paid
+        whether or not any lane fills them; the active bytes follow the
+        refcounted pages, radix-shared prefixes counted once."""
         from deeplearning4j_tpu.parallel.generation import pool_token_bytes
 
-        cfg = self.cfg
-        per_tok = pool_token_bytes(cfg)
-        if self.kv == "dense":
-            provisioned = self.n_slots * cfg.max_len * per_tok
-            active = per_tok * sum(s.pos for s in self._slots if s.active)
-        else:
-            provisioned = self.kv_pages * self.page_size * per_tok
-            in_use = self._pool.in_use if self._pool is not None else 0
-            active = in_use * self.page_size * per_tok
+        per_tok = pool_token_bytes(self.cfg)
+        provisioned = self.kv_pages * self.page_size * per_tok
+        in_use = self._pool.in_use if self._pool is not None else 0
+        active = in_use * self.page_size * per_tok
         return {"provisioned": int(provisioned), "active": int(active),
                 "per_token": int(per_tok)}
 
@@ -1280,25 +1203,23 @@ class ContinuousLMServer:
             out["decode_steps"] = self._steps
             out["accepting"] = self._accepting
             out["kv_bytes"] = self._kv_bytes()
-            kv = {"mode": self.kv}
-            if self.kv == "paged":
-                kv.update({
-                    "page_size": self.page_size,
-                    "pages": self.kv_pages,
-                    "max_pages_per_seq": self.max_pages,
-                    "prefill_chunk": self.prefill_chunk,
-                    "pages_in_use": (self._pool.in_use
-                                     if self._pool is not None else 0),
-                    "pages_free": (self._pool.free
-                                   if self._pool is not None
-                                   else self.kv_pages),
-                    "radix_nodes": (self._tree.nodes
-                                    if self._tree is not None else 0),
-                    "ship": self.ship,
-                    "paged_kernel": self.paged_kernel})
+            out["kv"] = {
+                "mode": "paged",
+                "page_size": self.page_size,
+                "pages": self.kv_pages,
+                "max_pages_per_seq": self.max_pages,
+                "prefill_chunk": self.prefill_chunk,
+                "pages_in_use": (self._pool.in_use
+                                 if self._pool is not None else 0),
+                "pages_free": (self._pool.free
+                               if self._pool is not None
+                               else self.kv_pages),
+                "radix_nodes": (self._tree.nodes
+                                if self._tree is not None else 0),
+                "ship": self.ship,
+                "paged_kernel": self.paged_kernel}
             if self._sessions:
                 out["sessions_tracked"] = len(self._sessions)
-            out["kv"] = kv
             if self.preempt or self._pressure is not None:
                 pres: Dict = {"preempt": self.preempt}
                 if self._swap is not None:
@@ -1350,21 +1271,11 @@ class ContinuousLMServer:
         request.  Host-side page state is reset separately
         (`_reset_pool_locked`) because it must happen BEFORE the next admit
         round, while the device rebuild may be deferred to dispatch."""
-        if self.kv == "dense":
-            from deeplearning4j_tpu.parallel.generation import (
-                init_slot_cache,
-            )
+        from deeplearning4j_tpu.parallel.generation import init_paged_cache
 
-            cache = init_slot_cache(self.cfg, self.n_slots)
-        else:
-            from deeplearning4j_tpu.parallel.generation import (
-                init_paged_cache,
-            )
-
-            cache = init_paged_cache(self.cfg, self.kv_pages + 1,
-                                     self.page_size)
         # k and v, or the one latent pool (`generation.pool_layout`)
-        self._cache = tuple(v for k, v in cache.items() if k != "pos")
+        self._cache = tuple(init_paged_cache(
+            self.cfg, self.kv_pages + 1, self.page_size).values())
 
     def _reset_pool_locked(self) -> None:
         """Fresh allocator + radix tree + slot page bookkeeping.  Called
@@ -1373,8 +1284,6 @@ class ContinuousLMServer:
         pool would serve zeros as a cached prefix.  Caller holds
         ``self._cond`` (the ``*_locked`` contract — admission reads the
         pool/tree/CoW list under the same lock)."""
-        if self.kv != "paged":
-            return
         self._pool = PagePool(self.kv_pages + 1, self.page_size)
         self._tree = RadixPrefixCache(self._pool)
         self._pending_cow = []
@@ -1409,90 +1318,76 @@ class ContinuousLMServer:
 
     def _start_locked(self) -> None:
         if self._step is None:
-            if self.kv == "dense":
-                from deeplearning4j_tpu.parallel.generation import (
-                    make_slot_step,
-                )
+            from deeplearning4j_tpu.parallel.generation import (
+                make_page_copy,
+                make_paged_step,
+                make_spec_step,
+                pool_layout,
+            )
 
-                self._step = make_slot_step(self.cfg)
+            total = self.kv_pages + 1
+            self._decode_step = make_paged_step(
+                self.cfg, total, self.page_size, 1)
+            if self.speculate != "off":
+                # ONE wide program serves chunked prefill AND the
+                # speculative verify — the same chunked-feed ladder,
+                # widened to fit [last, d_1..d_draft_len]
+                self._chunk_step = make_spec_step(
+                    self.cfg, total, self.page_size, self.spec_width)
             else:
+                self._chunk_step = (make_paged_step(
+                    self.cfg, total, self.page_size, self.prefill_chunk)
+                    if self.prefill_chunk > 1 else None)
+            self._copy = make_page_copy(self.cfg, total, self.page_size)
+            if self.ship or self.preempt or self.hibernate:
                 from deeplearning4j_tpu.parallel.generation import (
-                    make_page_copy,
-                    make_paged_step,
-                    make_spec_step,
-                    pool_layout,
+                    make_page_gather,
+                    make_page_install,
                 )
 
-                total = self.kv_pages + 1
-                self._decode_step = make_paged_step(
-                    self.cfg, total, self.page_size, 1,
-                    paged_kernel=self.paged_kernel)
-                if self.speculate != "off":
-                    # ONE wide program serves chunked prefill AND the
-                    # speculative verify — the same chunked-feed ladder,
-                    # widened to fit [last, d_1..d_draft_len]
-                    self._chunk_step = make_spec_step(
-                        self.cfg, total, self.page_size, self.spec_width,
-                        paged_kernel=self.paged_kernel)
-                else:
-                    self._chunk_step = (make_paged_step(
-                        self.cfg, total, self.page_size,
-                        self.prefill_chunk,
-                        paged_kernel=self.paged_kernel)
-                        if self.prefill_chunk > 1 else None)
-                self._copy = make_page_copy(self.cfg, total,
-                                            self.page_size)
-                if self.ship or self.preempt or self.hibernate:
-                    from deeplearning4j_tpu.parallel.generation import (
-                        make_page_gather,
-                        make_page_install,
-                    )
+                self._gather = make_page_gather(self.cfg, total,
+                                                self.page_size)
+                self._install = make_page_install(self.cfg, total,
+                                                  self.page_size)
+            if self.speculate != "off" and self._drafter is None:
+                from deeplearning4j_tpu.serving.draft import make_drafter
 
-                    self._gather = make_page_gather(self.cfg, total,
-                                                    self.page_size)
-                    self._install = make_page_install(self.cfg, total,
-                                                      self.page_size)
-                if self.speculate != "off" and self._drafter is None:
-                    from deeplearning4j_tpu.serving.draft import (
-                        make_drafter,
-                    )
+                self._drafter = make_drafter(
+                    self.speculate, self.cfg, self.params,
+                    self.n_slots, draft_model=self._draft_model)
 
-                    self._drafter = make_drafter(
-                        self.speculate, self.cfg, self.params,
-                        self.n_slots, draft_model=self._draft_model)
-
-                # the pool arrays lead every call (k and v, or the one
-                # latent pool: `generation.pool_layout`)
-                n_pools = len(pool_layout(self.cfg).names)
-                if self.speculate != "off":
-                    def dispatch(params, *args):
-                        # speculative signature: every dispatch carries
-                        # n_draft and returns per-lane accepted counts
-                        # (zeros on the 1-wide plain-decode program)
-                        pools = args[:n_pools]
-                        (table, pos, n_feed, n_draft, tokens, temperature,
-                         seeds, counts) = args[n_pools:]
-                        if tokens.shape[1] == 1:
-                            out = self._decode_step(
-                                params, *pools, table, pos, n_feed,
-                                tokens, temperature, seeds, counts)
-                            return (out[0], np.zeros(
-                                (self.n_slots,), np.int32)) + tuple(out[1:])
-                        return self._chunk_step(
-                            params, *pools, table, pos, n_feed, n_draft,
+            # the pool arrays lead every call (k and v, or the one
+            # latent pool: `generation.pool_layout`)
+            n_pools = len(pool_layout(self.cfg).names)
+            if self.speculate != "off":
+                def dispatch(params, *args):
+                    # speculative signature: every dispatch carries
+                    # n_draft and returns per-lane accepted counts
+                    # (zeros on the 1-wide plain-decode program)
+                    pools = args[:n_pools]
+                    (table, pos, n_feed, n_draft, tokens, temperature,
+                     seeds, counts) = args[n_pools:]
+                    if tokens.shape[1] == 1:
+                        out = self._decode_step(
+                            params, *pools, table, pos, n_feed,
                             tokens, temperature, seeds, counts)
-                else:
-                    def dispatch(params, *args):
-                        # ONE entry point for every paged dispatch
-                        # (decode and prefill-chunk widths) so
-                        # fault-injection tests that stub `self._step`
-                        # intercept them all
-                        tokens = args[n_pools + 3]
-                        fn = (self._decode_step if tokens.shape[1] == 1
-                              else self._chunk_step)
-                        return fn(params, *args)
+                        return (out[0], np.zeros(
+                            (self.n_slots,), np.int32)) + tuple(out[1:])
+                    return self._chunk_step(
+                        params, *pools, table, pos, n_feed, n_draft,
+                        tokens, temperature, seeds, counts)
+            else:
+                def dispatch(params, *args):
+                    # ONE entry point for every paged dispatch
+                    # (decode and prefill-chunk widths) so
+                    # fault-injection tests that stub `self._step`
+                    # intercept them all
+                    tokens = args[n_pools + 3]
+                    fn = (self._decode_step if tokens.shape[1] == 1
+                          else self._chunk_step)
+                    return fn(params, *args)
 
-                self._step = dispatch
+            self._step = dispatch
             self._reset_pool_locked()
             self._reset_cache()
         self._running = True
@@ -1506,7 +1401,7 @@ class ContinuousLMServer:
         """Refcount-release everything a lane held: its own pages drop
         to 0 and return to the free list unless the radix tree kept
         them; shared prefix pages drop back to their other holders."""
-        if self.kv != "paged" or self._pool is None:
+        if self._pool is None:
             return
         if slot.owned:
             self._pool.release(slot.owned)
@@ -1848,27 +1743,18 @@ class ContinuousLMServer:
                 break
             if slot.active:
                 continue
-            if self.kv == "paged":
-                head = self._queue[0]
+            head = self._queue[0]
+            plan = self._plan_admission_paged(head)
+            while plan is None and self._preempt_one_locked(head):
                 plan = self._plan_admission_paged(head)
-                while plan is None and self._preempt_one_locked(head):
-                    plan = self._plan_admission_paged(head)
-                if plan is None:
-                    break              # head-of-line waits for pages
-                req = self._queue.popleft()
-                if self.tenants is not None:
-                    self.tenants.wfq.advance(req.vft)
-                self._install_paged_locked(slot, req, plan)
-            else:
-                slot.req = self._queue.popleft()
-                if self.tenants is not None:
-                    self.tenants.wfq.advance(slot.req.vft)
-                slot.req.t_installed = time.perf_counter()
-                slot.pos = 0
-                slot.fed = 0
-                slot.generated = []
+            if plan is None:
+                break              # head-of-line waits for pages
+            req = self._queue.popleft()
+            if self.tenants is not None:
+                self.tenants.wfq.advance(req.vft)
+            self._install_paged_locked(slot, req, plan)
         self.metrics.set_queue_depth(len(self._queue))
-        if self.kv == "paged" and self._pool is not None:
+        if self._pool is not None:
             self.metrics.set_pages(self._pool.in_use, self._pool.free,
                                    self.kv_pages)
 
@@ -2155,7 +2041,7 @@ class ContinuousLMServer:
         """Prefill just completed: register this prompt's FULL pages in
         the radix tree so the next shared-prefix request skips them.
         Page-granular — a prompt shorter than one page caches nothing."""
-        if self.kv != "paged" or slot.inserted:
+        if slot.inserted:
             return
         slot.inserted = True
         plen = len(slot.req.prompt)
@@ -2175,19 +2061,15 @@ class ContinuousLMServer:
             # this protected loop (a failing warm dispatch rides the
             # same fault path as a failing decode).  The paged step
             # with n_feed=0 touches only the null page, so it is safe
-            # even alongside live lanes; the dense warm waits for idle
-            # (its unconditional pos-0 write would clobber active rows)
+            # even alongside live lanes
             warm = self._warm_req
-            idle = not any(s.active for s in self._slots)
-            if warm is not None and (idle or self.kv == "paged"):
+            if warm is not None:
                 self._warm_req = None
                 # a warm dispatch that raises lands in the worker's
                 # fault arm (`_run`), which rebuilds the donated pool
                 # and hands the exception to the waiting warmup()
                 # through `_warming`
                 self._warming = warm
-            else:
-                warm = None
         if warm is not None:
             # the warm-up is `yield` time, but no round's: counted at
             # once, so the next round's host time does not carry it
@@ -2243,65 +2125,7 @@ class ContinuousLMServer:
             # worker thread (page/radix state was already reset by the
             # fault handler — slots restart at pos 0, nothing to keep)
             self._reset_cache()
-        if self.kv == "paged":
-            return self._dispatch_paged(active, cow, installs, level)
-        return self._dispatch_dense(active)
-
-    def _dispatch_dense(self, active) -> bool:
-        clock = self._clock
-        clock.to("marshal")
-        fed = dict.fromkeys(("prefill", "decode"), 0)
-        token = np.zeros((self.n_slots,), np.int32)
-        pos = np.zeros((self.n_slots,), np.int32)
-        temp = np.zeros((self.n_slots,), np.float32)
-        seeds = np.zeros((self.n_slots,), np.int32)
-        counts = np.zeros((self.n_slots,), np.int32)
-        for i, slot in enumerate(self._slots):
-            if not slot.active:
-                continue
-            req = slot.req
-            if slot.fed < len(req.prompt):     # prefill: teacher-force
-                token[i] = req.prompt[slot.fed]
-                fed["prefill"] += 1
-                req.prefill_rounds += 1
-            else:                              # decode: feed last sample
-                token[i] = slot.generated[-1]
-                fed["decode"] += 1
-            pos[i] = slot.pos
-            temp[i] = req.temperature
-            seeds[i] = req.seed
-            counts[i] = len(slot.generated)
-        clock.to("dispatch")
-        with compile_scope("lm:dense"):
-            nxt, k, v = self._step(self.params, *self._cache, pos, token,
-                                   temp, seeds, counts)
-        if self.breaker is not None:
-            self.breaker.record_success()
-        self._cache = (k, v)
-        clock.to("sync")
-        nxt = np.asarray(nxt)
-        clock.to("fold")
-        self._steps += 1
-        emitted = 0
-        for i, slot in enumerate(self._slots):
-            if not slot.active:
-                continue
-            slot.pos += 1
-            if slot.fed < len(slot.req.prompt):
-                slot.fed += 1
-                # the LAST prompt token's logits yield the first sample
-                if slot.fed < len(slot.req.prompt):
-                    continue
-            self._commit_tokens(slot, [int(nxt[i])])
-            emitted += 1
-            if len(slot.generated) >= slot.req.max_new:
-                self._finish_slot(slot)
-        self.metrics.record_dispatch(len(active), self.n_slots)
-        if emitted:
-            self.metrics.record_tokens(emitted)
-        clock.to("yield")
-        self.metrics.record_round(clock.take(), 1, self.n_slots, fed, 0)
-        return True
+        return self._dispatch_paged(active, cow, installs, level)
 
     def _draft_proposals(self) -> Dict[int, List[int]]:
         """One drafting round: collect per-lane proposals for GREEDY

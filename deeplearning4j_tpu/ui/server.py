@@ -536,7 +536,7 @@ class _Handler(ServingHTTPMixin, BaseHTTPRequestHandler):
                 raise ValueError(f"top_p must be in (0, 1], got {top_p}")
             # unsupported-combo validation at ADMISSION (ISSUE-13): a
             # client explicitly asking for speculative decode on a pool
-            # that cannot provide it (dense KV, speculation off, or no
+            # that cannot provide it (speculation off, or no
             # continuous pool at all) gets a typed 400 naming why, not
             # a silently different execution plan.  Sampling lanes on a
             # speculating pool are NOT an error: they ride the same
@@ -546,11 +546,6 @@ class _Handler(ServingHTTPMixin, BaseHTTPRequestHandler):
                     raise ValueError(
                         "speculate requested but no continuous LM pool "
                         "is registered (continuous=False)")
-                if lm_server.kv != "paged":
-                    raise ValueError(
-                        "speculate requested but the pool serves "
-                        "kv='dense': speculative rollback requires the "
-                        "paged KV plane (serve with -lm-kv paged)")
                 if lm_server.speculate == "off":
                     raise ValueError(
                         "speculate requested but the pool was started "
@@ -710,7 +705,7 @@ class _Handler(ServingHTTPMixin, BaseHTTPRequestHandler):
         if not prompt:
             self._json(400, {"error": "prompt_ids required"})
             return
-        if lm_server.kv != "paged" or not lm_server.ship:
+        if not lm_server.ship:
             # typed on the WIRE (the same kind the admit leg's 422
             # carries): "this worker cannot ship" must be machine-
             # distinguishable from "this request is bad everywhere" —
@@ -813,9 +808,7 @@ class UiServer:
                  default_deadline_s: Optional[float] = None,
                  breaker_threshold: Optional[int] = 5,
                  breaker_cooldown_s: float = 1.0,
-                 kv: str = "paged", page_size: int = 16,
-                 pages: Optional[int] = None,
-                 paged_kernel: Optional[bool] = None,
+                 page_size: int = 16, pages: Optional[int] = None,
                  prefill_chunk: int = 8, speculate: str = "off",
                  draft_len: int = 4, ship: bool = False,
                  preempt: bool = False, swap_bytes: int = 64 << 20,
@@ -829,16 +822,15 @@ class UiServer:
         `slots`-lane continuous batching pool; `continuous=False` keeps
         every request on the whole-sequence path.  `max_queue_depth`,
         `default_deadline_s` and the breaker knobs configure the
-        serving-plane resilience layer (docs/robustness.md).  `kv`,
+        serving-plane resilience layer (docs/robustness.md).
         `page_size`, `pages` and `prefill_chunk` configure the paged KV
         pool with radix prefix reuse (docs/performance.md "The KV
-        memory cost model"); `kv="dense"` keeps the original per-slot
-        dense cache.  `paged_kernel` forces the fused paged-attention
-        decode kernel on/off (None: on when the backend is TPU —
-        docs/performance.md "The paged-attention kernel cost model").  `speculate` ("ngram"/"model") turns on
-        speculative multi-token decode for greedy lanes with up to
-        `draft_len` drafts per round (paged KV only; sampling lanes
-        fall back to 1-token decode — docs/performance.md "The
+        memory cost model"); on a TPU its steps attend through the fused
+        paged-attention kernel (docs/performance.md "The
+        paged-attention kernel cost model").  `speculate`
+        ("ngram"/"model") turns on speculative multi-token decode for
+        greedy lanes with up to `draft_len` drafts per round (sampling
+        lanes fall back to 1-token decode — docs/performance.md "The
         speculative decode cost model").  `preempt`/`swap_bytes` turn
         on priority preemption with host KV swap-out and `brownout`
         (True or a `PressureConfig`) the degradation ladder — the
@@ -868,8 +860,7 @@ class UiServer:
             lm_server = ContinuousLMServer(
                 cfg, params, slots=slots, max_queue_depth=max_queue_depth,
                 default_deadline_s=default_deadline_s, breaker=breaker,
-                kv=kv, page_size=page_size, pages=pages,
-                paged_kernel=paged_kernel,
+                page_size=page_size, pages=pages,
                 prefill_chunk=prefill_chunk, speculate=speculate,
                 draft_len=draft_len, ship=ship, preempt=preempt,
                 swap_bytes=swap_bytes, brownout=brownout,

@@ -1,6 +1,8 @@
 """Config serde round-trips — parity with reference
 MultiLayerNeuralNetConfigurationTest / NeuralNetConfigurationTest (SURVEY §4)."""
 
+import pytest
+
 from deeplearning4j_tpu.nn.conf import (
     ConvolutionLayerConf,
     DenseLayerConf,
@@ -59,6 +61,16 @@ class TestJsonRoundTrip:
         r = layer_conf_from_dict(d)
         assert isinstance(r, GravesLSTMConf)
         assert r.forget_gate_bias_init == 5.0
+
+
+    @pytest.mark.parametrize("tag", ["graveslstm", "lstm"])
+    def test_saved_conf_with_the_retired_fused_key_still_loads(self, tag):
+        """Confs saved while the LSTM layers had a `fused` field carry
+        the key: it is ignored on read."""
+        r = layer_conf_from_dict({"type": tag, "n_in": 4, "n_out": 8,
+                                  "fused": True})
+        assert (r.n_in, r.n_out) == (4, 8)
+        assert "fused" not in r.to_dict()
 
 
 class TestOverridesAndBuilder:

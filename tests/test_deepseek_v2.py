@@ -249,8 +249,6 @@ def test_other_paths_refuse_the_new_kinds_with_a_typed_error(model):
 
     cfg, params = model
     with pytest.raises(tfm.UnsupportedLayerKind):
-        ContinuousLMServer(cfg, params, kv="dense")
-    with pytest.raises(tfm.UnsupportedLayerKind):
         hybrid.make_accum_train_step(cfg)
     with pytest.raises(tfm.UnsupportedLayerKind):
         hybrid.HybridParallelTrainer(cfg, mesh=None)

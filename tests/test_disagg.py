@@ -288,8 +288,6 @@ class TestShipParity:
 
     def test_ship_requires_paged_and_flag(self, lm):
         cfg, params = lm
-        with pytest.raises(ValueError, match="paged"):
-            ContinuousLMServer(cfg, params, kv="dense", ship=True)
         srv = _srv(cfg, params, ship=False)
         try:
             with pytest.raises(ValueError, match="ship"):
@@ -907,10 +905,12 @@ class TestCLISurface:
         with pytest.raises(SystemExit, match="-model and/or -lm"):
             cmd_serve_fleet(args)
 
-    def test_serve_ship_requires_paged(self):
-        from deeplearning4j_tpu.cli import build_parser, cmd_serve
+    def test_serve_has_one_kv_mode_and_no_flag_for_it(self, capsys):
+        from deeplearning4j_tpu.cli import build_parser
 
-        args = build_parser().parse_args(
-            ["serve", "-lm", "x", "-lm-kv", "dense", "-lm-ship"])
-        with pytest.raises(SystemExit, match="paged"):
-            cmd_serve(args)
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(
+                ["serve", "-lm", "x", "-lm-kv", "dense", "-lm-ship"])
+        assert "-lm-kv" in capsys.readouterr().err
+        args = build_parser().parse_args(["serve", "-lm", "x", "-lm-ship"])
+        assert args.lm_ship and not hasattr(args, "lm_kv")

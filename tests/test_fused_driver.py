@@ -305,13 +305,16 @@ class TestChunkedSupervision:
 
         x, y = _data(64)
         batches = _batches(x, y, 8)[:8] * 4
-        net = MultiLayerNetwork(
-            iris_mlp(updater="sgd", learning_rate=50.0)).init()
+        net = MultiLayerNetwork(iris_mlp(updater="sgd")).init()
+        # a divergence by construction: a NaN batch let through into
+        # the middle of a chunk (nothing checks batches at assembly)
         sup = TrainingSupervisor(net, self._cfg(
-            tmp_path, lr_backoff=0.01, max_rollbacks=4))
-        report = sup.run(ChaosDataSource(batches, ChaosConfig()))
-        assert report.rollbacks >= 1
-        assert report.lr_scale < 1.0
+            tmp_path, check_batches=False, lr_backoff=0.5,
+            max_rollbacks=4))
+        report = sup.run(ChaosDataSource(batches,
+                                         ChaosConfig(nan_steps=(5,))))
+        assert report.rollbacks == 1
+        assert report.lr_scale == 0.5
         assert np.isfinite(report.final_loss)
         assert any(f.action == "replay" for f in report.faults)
 

@@ -72,7 +72,6 @@ def _want(cfg, params, prompt, new):
 
 def _srv(cfg, params, tmp=None, **kw):
     kw.setdefault("slots", 2)
-    kw.setdefault("kv", "paged")
     kw.setdefault("page_size", PS)
     kw.setdefault("pages", 32)
     if tmp is not None:
@@ -510,7 +509,7 @@ class TestDiskChaos:
 class TestPreemptionOnTiers:
     def test_preempted_victim_resumes_through_the_store(self, tmp_path):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=PS, pages=8, prefill_chunk=4,
                                  preempt=True, state_dir=str(tmp_path))
         res = {}

@@ -194,7 +194,7 @@ class TestPagedParity:
         page sizes that do and do not divide max_len and both prefill
         widths (ISSUE-7 acceptance: byte-identical)."""
         cfg, params = _lm(max_len=30)
-        srv = ContinuousLMServer(cfg, params, slots=3, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=3,
                                  page_size=page_size, prefill_chunk=chunk)
         prompts = [[1, 2, 3], [5, 6], [7, 8, 9, 10, 11, 12, 13],
                    [4], [11, 12, 13, 14, 15, 16, 17, 18, 19]]
@@ -221,7 +221,7 @@ class TestPagedParity:
         change the running request's output — now with page allocation
         happening at the join."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=8, prefill_chunk=4)
         long_p, short_p = [1, 2, 3, 4], [9, 8]
         want_long = _want(cfg, params, long_p, 20)
@@ -246,17 +246,18 @@ class TestPagedParity:
 
     def test_sampling_is_seeded_per_request(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=8)
         a = srv.generate([1, 2], 5, temperature=0.9, seed=7, timeout=120)
         b = srv.generate([1, 2], 5, temperature=0.9, seed=7, timeout=120)
         srv.stop()
-        dense = ContinuousLMServer(cfg, params, slots=2, kv="dense")
-        c = dense.generate([1, 2], 5, temperature=0.9, seed=7, timeout=120)
-        dense.stop()
+        other = ContinuousLMServer(cfg, params, slots=3, page_size=4,
+                                   prefill_chunk=1)
+        c = other.generate([1, 2], 5, temperature=0.9, seed=7, timeout=120)
+        other.stop()
         assert a == b
-        # the paged pool samples through the SAME device automaton as
-        # the dense pool: same seed, same draw
+        # the draw is the request's (seed, tokens generated), whatever
+        # the pool's lanes, pages and programs: same seed, same draw
         assert a == c
 
 
@@ -268,7 +269,7 @@ class TestPrefixReuse:
         IS the KV B would have written."""
         cfg, params = _lm(max_len=32)
         ps = 8
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=ps, prefill_chunk=4)
         system = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3]  # 2 pages
         a_p, b_p = system + [10, 11], system + [12, 13, 14]
@@ -294,7 +295,7 @@ class TestPrefixReuse:
         split point — byte-identical to a cold decode, and the copy's
         source page survives for the next hit."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=8, prefill_chunk=4)
         a_p = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]     # caches page 1-8
         b_p = [1, 2, 3, 4, 5, 6, 40, 41, 42]   # diverges INSIDE the page
@@ -319,7 +320,7 @@ class TestPrefixReuse:
         cfg, params = _lm(max_len=32)
         p = [1, 2, 3, 4, 5, 6, 7, 8, 9]                   # 9 tokens, ps 8
         want = _want(cfg, params, p, 5)
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=8, prefill_chunk=4)
         assert srv.generate(p, 5, timeout=120) == want
         assert srv.generate(p, 5, timeout=120) == want
@@ -334,16 +335,15 @@ class TestPrefixReuse:
 
 
 class TestFreedSlotHygiene:
-    @pytest.mark.parametrize("kv", ["dense", "paged"])
-    def test_slot_reuse_with_shorter_prompt_matches_fresh_pool(self, kv):
+    @pytest.mark.parametrize("chunk", [1, 4])
+    def test_slot_reuse_with_shorter_prompt_matches_fresh_pool(self, chunk):
         """A slot freed by a LONG request and reoccupied by a SHORTER
         one must produce output byte-identical to a fresh pool: the
         previous occupant's KV beyond the new request's positions is
-        unreachable (masked in dense mode; unreferenced pages in paged
-        mode)."""
+        unreachable (unreferenced pages), with the chunk program
+        (`chunk` 4) and on the server that has none (`chunk` 1)."""
         cfg, params = _lm(max_len=32)
-        kw = dict(kv=kv) if kv == "dense" else dict(
-            kv=kv, page_size=8, prefill_chunk=4)
+        kw = dict(page_size=8, prefill_chunk=chunk)
         long_p = [7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17, 18]
         short_p = [5, 6]
         srv = ContinuousLMServer(cfg, params, slots=1, **kw)
@@ -361,7 +361,7 @@ class TestFreedSlotHygiene:
         position was written by B or by B's matched prefix)."""
         cfg, params = _lm(max_len=32)
         # exactly one lane's worth of pages: B always recycles A's
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=8, pages=4, prefill_chunk=4)
         a_p = [9, 8, 7, 6, 5, 4, 3, 2, 1]
         b_p = [1, 2, 3]
@@ -379,7 +379,7 @@ class TestFreedSlotHygiene:
 class TestPoolCapacity:
     def test_request_larger_than_pool_is_a_client_error(self):
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=8, pages=2)
         with pytest.raises(ValueError, match="KV pages"):
             srv.generate([1, 2, 3], 20)                   # needs 3 pages
@@ -390,7 +390,7 @@ class TestPoolCapacity:
         second waits for the first's pages, then completes correctly —
         admission control by capacity, not failure."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=8, pages=3, prefill_chunk=4)
         p1, p2 = [1, 2, 3, 4, 5], [6, 7, 8, 9]
         want = [_want(cfg, params, p1, 18), _want(cfg, params, p2, 18)]
@@ -417,7 +417,7 @@ class TestPoolCapacity:
         needs them, LRU cached prefixes are evicted and the request
         still serves (correctly) instead of waiting forever."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=8, pages=4, prefill_chunk=4)
         outs, wants = [], []
         for base in (0, 10, 20, 30):                      # distinct pages
@@ -447,7 +447,7 @@ class TestPageLedgerChaos:
         prefix pages.  A leaked page would show up as in_use nobody
         owns; a double-free raises PageLeakError inside the worker."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=3, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=3,
                                  page_size=8, pages=12, prefill_chunk=4)
         srv.warmup()
         real_step = srv._step
@@ -512,7 +512,7 @@ class TestPageLedgerChaos:
         contents: the tree must not survive the pool, or the next
         prefix hit would serve zeros."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=8, prefill_chunk=4)
         p = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10]
         want = _want(cfg, params, p, 6)
@@ -545,7 +545,7 @@ class TestPagedCompileGuard:
         import jax.monitoring
 
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=3, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=3,
                                  page_size=8, prefill_chunk=4)
         assert srv.warmup() == 3                   # decode + chunk + copy
         compiles = []
@@ -579,50 +579,30 @@ class TestPagedCompileGuard:
         assert stats["compiled_programs"] == 3
         assert stats["requests"] == 24
 
-    def test_dense_warmup_compiles_before_traffic_too(self):
-        """warmup() honors the same contract in dense mode: after it,
-        the first request triggers no XLA compile (a fleet replica is
-        warmed BEFORE it enters rotation, whichever kv mode it serves)."""
-        import jax.monitoring
-
-        cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="dense")
-        assert srv.warmup() == 1
-        compiles = []
-
-        def listener(event, duration, **kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                compiles.append(event)
-
-        jax.monitoring.register_event_duration_secs_listener(listener)
-        try:
-            out = srv.generate([1, 2, 3], 4, timeout=120)
-        finally:
-            jax.monitoring.clear_event_listeners()
-            srv.stop()
-        assert len(out) == 7
-        assert compiles == []
-
 
 # ---------------------------------------------------------------------------
 # Stats honesty (satellite: actual vs provisioned KV bytes)
 
 
 class TestKVBytesAccounting:
-    def test_dense_provisioned_is_worst_case_and_active_follows_lanes(self):
+    @pytest.mark.parametrize("slots", [2, 4])
+    def test_provisioned_is_the_pool_whatever_the_lane_count(self, slots):
+        """`kv_bytes.provisioned` is pages * page_size * per_token: the
+        pool's bytes, not a lane count times max_len."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=4, kv="dense")
-        per_tok = (2 * cfg.n_layers * cfg.n_heads * cfg.head_dim
-                   * np.dtype(cfg.dtype).itemsize)
+        srv = ContinuousLMServer(cfg, params, slots=slots,
+                                 page_size=8, pages=6)
         kvb = srv.stats()["kv_bytes"]
-        assert kvb["provisioned"] == 4 * 32 * per_tok
-        assert kvb["active"] == 0                  # nothing resident
-        srv.generate([1, 2, 3], 4, timeout=120)
         srv.stop()
+        assert kvb["per_token"] == (2 * cfg.n_layers * cfg.n_heads
+                                    * cfg.head_dim
+                                    * np.dtype(cfg.dtype).itemsize)
+        assert kvb["provisioned"] == 6 * 8 * kvb["per_token"]
+        assert kvb["active"] == 0                  # nothing resident
 
     def test_paged_active_bytes_follow_the_refcounted_pages(self):
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=4, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=4,
                                  page_size=8, pages=8)
         per_tok = (2 * cfg.n_layers * cfg.n_heads * cfg.head_dim
                    * np.dtype(cfg.dtype).itemsize)
@@ -633,21 +613,6 @@ class TestKVBytesAccounting:
         assert kvb["provisioned"] == 8 * 8 * per_tok   # pages, not slots
         # idle: only the radix-cached prompt page is resident
         assert kvb["active"] == 1 * 8 * per_tok
-
-    def test_paged_provisions_less_than_dense_at_equal_traffic(self):
-        """The headline: a half-size paged pool serves the same lanes a
-        dense pool provisions worst-case for."""
-        cfg, params = _lm(max_len=32)
-        dense = ContinuousLMServer(cfg, params, slots=4, kv="dense")
-        paged = ContinuousLMServer(cfg, params, slots=4, kv="paged",
-                                   page_size=8, pages=8)   # half capacity
-        try:
-            d = dense.stats()["kv_bytes"]["provisioned"]
-            p = paged.stats()["kv_bytes"]["provisioned"]
-            assert d / p == 2.0
-        finally:
-            dense.stop()
-            paged.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -701,14 +666,22 @@ class TestFleetPrefixStats:
 
 @pytest.mark.paged_kernel
 class TestPagedKernelServing:
+    @pytest.fixture(autouse=True)
+    def _kernel_on(self, monkeypatch):
+        """The platform rule gives the gather oracle on a CPU: stand in
+        for it, so the whole server runs the kernel (interpret mode)."""
+        from deeplearning4j_tpu.parallel import paged_kernel
+
+        monkeypatch.setattr(paged_kernel, "paged_kernel_enabled",
+                            lambda: True)
+
     def test_kernel_pool_greedy_parity_with_generate(self):
         """Greedy byte-parity of the kernel-backed pool against
         `generate()` across ragged prompt lengths — including prompts
         that straddle page boundaries mid-prefill."""
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=3, kv="paged",
-                                 page_size=8, prefill_chunk=4,
-                                 paged_kernel=True)
+        srv = ContinuousLMServer(cfg, params, slots=3,
+                                 page_size=8, prefill_chunk=4)
         try:
             for plen in (1, 3, 7, 9, 13):
                 prompt = [(5 * i + 1) % 49 + 1 for i in range(plen)]
@@ -718,7 +691,7 @@ class TestPagedKernelServing:
             srv.stop()
 
     def test_kernel_ladder_zero_new_compiles(self):
-        """The paged_kernel switch changes WHAT each ladder program
+        """The kernel changes WHAT each ladder program
         computes, never how many there are: warmup still compiles the
         same 3 programs (decode + chunk + CoW) and a mixed-length
         storm after warmup triggers ZERO XLA compiles — the
@@ -726,9 +699,8 @@ class TestPagedKernelServing:
         import jax.monitoring
 
         cfg, params = _lm(max_len=32)
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
-                                 page_size=8, prefill_chunk=4,
-                                 paged_kernel=True)
+        srv = ContinuousLMServer(cfg, params, slots=2,
+                                 page_size=8, prefill_chunk=4)
         assert srv.warmup() == 3                   # the existing ladder
         compiles = []
 
@@ -754,18 +726,61 @@ class TestPagedKernelServing:
         output stays byte-identical to 1-token decode."""
         cfg, params = _lm(max_len=48)
         prompt = [1, 2, 3, 1, 2, 3, 1]
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=8, prefill_chunk=4,
-                                 speculate="ngram", draft_len=3,
-                                 paged_kernel=True)
+                                 speculate="ngram", draft_len=3)
         try:
             assert srv.generate(prompt, 10, timeout=300) == \
                 _want(cfg, params, prompt, 10)
         finally:
             srv.stop()
 
-    def test_kernel_requires_paged_pool(self):
-        cfg, params = _lm()
-        with pytest.raises(ValueError, match="paged_kernel"):
-            ContinuousLMServer(cfg, params, kv="dense",
-                               paged_kernel=True)
+
+# ---------------------------------------------------------------------------
+# One KV path, one rule for the kernel: nothing above `generation.py`
+# offers a choice
+
+
+def _serving_entry(name):
+    if name == "ContinuousLMServer":
+        return ContinuousLMServer.__init__
+    if name == "UiServer.serve_lm":
+        from deeplearning4j_tpu.ui.server import UiServer
+
+        return UiServer.serve_lm
+    from deeplearning4j_tpu.serving.fleet import spawn_local_replica
+
+    return spawn_local_replica
+
+
+class TestOneKVPath:
+    @pytest.mark.parametrize("entry", ["ContinuousLMServer",
+                                       "UiServer.serve_lm",
+                                       "spawn_local_replica"])
+    def test_serving_entry_points_offer_no_kv_mode_or_kernel_switch(
+            self, entry):
+        import inspect
+
+        params = set(inspect.signature(_serving_entry(entry)).parameters)
+        assert not params & {"kv", "lm_kv", "paged_kernel",
+                             "lm_paged_kernel"}
+
+    def test_the_oracle_seam_stays_in_generation(self):
+        import inspect
+
+        from deeplearning4j_tpu.parallel import generation
+
+        for fn in (generation.paged_forward, generation.make_paged_step,
+                   generation.make_spec_step):
+            assert "paged_kernel" in inspect.signature(fn).parameters
+
+    def test_no_module_reads_the_retired_env_names(self):
+        import pathlib
+
+        import deeplearning4j_tpu
+
+        root = pathlib.Path(deeplearning4j_tpu.__file__).parent
+        for path in root.rglob("*.py"):
+            text = path.read_text()
+            for name in ("DL4J_TPU_PAGED_KERNEL", "DL4J_TPU_FUSED_LSTM"):
+                assert name not in text, (path, name)

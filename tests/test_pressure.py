@@ -225,7 +225,7 @@ class TestBrownoutLadder:
 class TestPriorityAdmission:
     def test_queue_is_priority_then_fifo_ordered(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4)
         try:
             reqs = []
@@ -248,7 +248,7 @@ class TestPriorityAdmission:
 
     def test_interactive_overtakes_queued_best_effort(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4)
         srv.warmup()
         done = []
@@ -289,7 +289,7 @@ class TestPriorityAdmission:
         interactive: the prefill worker's export stamps the class and
         the decode pool admits under it."""
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, ship=True)
         try:
             ex = srv.prefill_export([1, 2, 3, 4, 5], 4,
@@ -322,7 +322,7 @@ class TestPreemptionParity:
         import jax.monitoring
 
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, pages=8, prefill_chunk=4,
                                  preempt=True, swap_bytes=swap_bytes,
                                  speculate=speculate)
@@ -380,7 +380,7 @@ class TestPreemptionParity:
         victim, _, stats, _ = self._preempt_run(
             victim_kw={"seed": 7, "temperature": 0.7})
         assert stats.get("preemptions", 0) >= 1
-        ref_srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        ref_srv = ContinuousLMServer(cfg, params, slots=1,
                                      page_size=4)
         try:
             ref = ref_srv.generate([1, 2, 3], 28, seed=7,
@@ -428,7 +428,7 @@ class TestPreemptionParity:
         the early tokens are regenerated (byte-identically) and must
         not be re-pushed."""
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, pages=8, prefill_chunk=4,
                                  preempt=True)
         try:
@@ -456,20 +456,13 @@ class TestPreemptionParity:
 
     def test_compiled_programs_counts_the_swap_pair(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, preempt=True)
         try:
             # decode + chunk + copy + gather + install
             assert srv.warmup() == srv.compiled_programs() == 5
         finally:
             srv.stop()
-
-    def test_preempt_requires_paged(self):
-        cfg, params = _lm()
-        with pytest.raises(ValueError, match="preempt"):
-            ContinuousLMServer(cfg, params, kv="dense", preempt=True)
-        with pytest.raises(ValueError, match="brownout"):
-            ContinuousLMServer(cfg, params, kv="dense", brownout=True)
 
 
 # ---------------------------------------------------------------------------
@@ -483,7 +476,7 @@ class TestExhaustionRegression:
         FIFO waits, never a deadlock) and the page ledger balances.
         This pins the behavior preemption composes on top of."""
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=4, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=4,
                                  page_size=4, pages=10, prefill_chunk=4)
         try:
             srv.warmup()
@@ -523,7 +516,7 @@ class TestExhaustionRegression:
         the head request for a round, never wedge it or unbalance the
         ledger."""
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, prefill_chunk=4)
         try:
             srv.warmup()
@@ -546,7 +539,7 @@ class TestExhaustionRegression:
 class TestBrownoutWiring:
     def test_level4_sheds_best_effort_only(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, brownout=True)
         try:
             srv.warmup()
@@ -573,7 +566,7 @@ class TestBrownoutWiring:
         dwell) — every move counted, level visible in stats()."""
         cfg, params = _lm()
         srv = ContinuousLMServer(
-            cfg, params, slots=4, kv="paged", page_size=4, pages=10,
+            cfg, params, slots=4, page_size=4, pages=10,
             prefill_chunk=4, preempt=True,
             brownout=PressureConfig(
                 enter_free_frac=(0.8, 0.5, 0.3, 0.1),

@@ -39,7 +39,7 @@ def served():
     its stats read before and after them."""
     cfg, params = _lm()
     tracer = TraceRecorder()
-    srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+    srv = ContinuousLMServer(cfg, params, slots=2,
                              page_size=PAGE, prefill_chunk=CHUNK,
                              tracer=tracer)
     srv.warmup()
@@ -217,7 +217,7 @@ def test_prefill_lies_inside_decode_and_ends_at_the_first_token(served):
 def test_queue_wait_plus_prefill_is_the_time_to_the_first_token():
     cfg, params = _lm()
     tracer = TraceRecorder()
-    srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+    srv = ContinuousLMServer(cfg, params, slots=1,
                              page_size=PAGE, prefill_chunk=CHUNK,
                              tracer=tracer)
     try:
@@ -251,7 +251,7 @@ def test_a_profiler_capture_holds_the_phases_and_the_program_key(tmp_path):
     from jax.profiler import ProfileData
 
     cfg, params = _lm()
-    srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+    srv = ContinuousLMServer(cfg, params, slots=2,
                              page_size=PAGE, prefill_chunk=CHUNK)
     try:
         srv.warmup()

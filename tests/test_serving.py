@@ -122,9 +122,13 @@ class TestMicroBatcher:
         assert calls == [(2, 4)]
 
     def test_concurrent_requests_coalesce_and_match_sequential(self):
-        """ISSUE-3 satellite: N client threads against the batcher give
-        BYTE-identical outputs to sequential single-request calls, and
-        at least one dispatch carries more than one request."""
+        """ISSUE-3 satellite: N client threads against the batcher get
+        the outputs sequential single-request calls give, and at least
+        one dispatch carries more than one request.  The claim is
+        "batching does not change the answer", held to 2 ULP: a request
+        alone and the same rows inside a padded bucket are different
+        batch shapes, so different XLA programs, and XLA on a CPU does
+        not promise them the same last bit."""
         net = _mlp()
         reqs = _requests(48)
         sequential = [np.asarray(net.output(x)) for x in reqs]
@@ -149,7 +153,7 @@ class TestMicroBatcher:
         stats = engine.stats()
         engine.stop()
         for want, got in zip(sequential, results):
-            assert got.tobytes() == want.tobytes()  # byte-identical
+            np.testing.assert_array_max_ulp(got, want, maxulp=2)
         assert stats["max_batch_occupancy"] > 1
         assert stats["dispatches"] < len(reqs)  # actually coalesced
 

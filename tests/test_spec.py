@@ -10,9 +10,9 @@ drafter modes, mid-flight joins and ADVERSARIAL drafters (all-wrong,
 oversized, out-of-vocab proposals) — the accept/rollback rule, not
 draft quality, is what guarantees output; mixed speculative/sampling
 lanes (sampling falls back to 1-token decode and stays seeded-parity
-with a non-speculating pool); unsupported-combo admission (speculate
-with dense KV is a typed error at construction and a typed 400 over
-HTTP); the page-refcount ledger after a rollback-heavy chaos storm;
+with a non-speculating pool); unsupported-combo admission (typed errors
+at construction and over HTTP); the page-refcount ledger after a
+rollback-heavy chaos storm;
 zero XLA compiles after warmup; and the accept-rate / tokens-per-round
 accounting in stats(), /metrics and trace spans.
 """
@@ -160,6 +160,45 @@ class TestModelDrafter:
         assert p2 == _want(cfg, params, [1, 2, 3, 9], 3)[4:]
         assert p1 == _want(cfg, params, [1, 2, 3], 3)[3:]
 
+    def test_lanes_at_different_depths_draft_their_own_continuation(self):
+        """The drafter runs the paged step over a pool of its own with
+        an identity block table: lanes one token and two pages deep, an
+        idle lane between them, each proposing the draft model's greedy
+        `generate()` continuation for its own budget."""
+        cfg, _ = _lm(max_len=48, n_layers=1)
+        from deeplearning4j_tpu.parallel import transformer as tfm
+
+        params = tfm.init_params(cfg, jax.random.PRNGKey(7))
+        deep = [(3 * i) % 47 + 1 for i in range(21)]   # into page 2 of 3
+        drafter = ModelDrafter(cfg, params, slots=4)
+        props = drafter.propose([[5], None, deep, [2, 4, 6]], [4, 3, 2, 3])
+        assert props[0] == _want(cfg, params, [5], 4)[1:]
+        assert props[1] == []
+        assert props[2] == _want(cfg, params, deep, 2)[21:]
+        assert props[3] == _want(cfg, params, [2, 4, 6], 3)[3:]
+        mp = drafter._table.shape[1]
+        assert mp == 3 and drafter._table.tolist() == [
+            list(range(1 + i * mp, 1 + (i + 1) * mp)) for i in range(4)]
+        assert drafter.compiled_programs() == 1
+
+    @pytest.mark.parametrize("plen", [3, 15])
+    def test_rejected_draft_rewinds_to_what_a_fresh_drafter_proposes(
+            self, plen):
+        """Rejected drafts wrote the lane's future positions (`plen` 15:
+        over a page boundary).  After the rewind (a host counter move;
+        the causal mask hides what lies past `pos`) the next proposal is
+        byte-identical to that of a drafter that never saw them."""
+        cfg, params = _lm(max_len=48, n_layers=1)
+        hist = [(5 * i) % 47 + 1 for i in range(plen)]
+        used = ModelDrafter(cfg, params, slots=2)
+        (drafted, _) = used.propose([hist, None], [4, 0])
+        other = (drafted[0] + 1) % 49 + 1               # the target's pick
+        fresh = ModelDrafter(cfg, params, slots=2)
+        (p_used, _) = used.propose([hist + [other], None], [4, 0])
+        (p_fresh, _) = fresh.propose([hist + [other], None], [4, 0])
+        assert p_used == p_fresh == _want(
+            cfg, params, hist + [other], 4)[plen + 1:]
+
     def test_slot_reuse_resets_cleanly(self):
         cfg, params = _lm(max_len=32, n_layers=1)
         drafter = ModelDrafter(cfg, params, slots=1)
@@ -207,7 +246,7 @@ class TestSpeculativeParity:
     def test_greedy_matches_generate(self, mode, page_size, chunk,
                                      draft_len):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=4, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=4,
                                  page_size=page_size, prefill_chunk=chunk,
                                  speculate=mode, draft_len=draft_len)
         try:
@@ -237,7 +276,7 @@ class TestSpeculativeParity:
         target, so every greedy draft must be accepted and decode must
         finish in ~max_new/(draft_len+1) rounds instead of max_new."""
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4, prefill_chunk=4,
                                  speculate="model", draft_len=3)
         try:
@@ -256,7 +295,7 @@ class TestSpeculativeParity:
 
     def test_midflight_join_keeps_parity(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, prefill_chunk=4,
                                  speculate="ngram", draft_len=3)
         try:
@@ -311,7 +350,7 @@ class TestSpeculativeParity:
                         for h, b in zip(histories, budgets)]
 
         for drafter in (WrongDrafter(), RudeDrafter()):
-            srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+            srv = ContinuousLMServer(cfg, params, slots=2,
                                      page_size=4, prefill_chunk=4,
                                      draft_len=3, drafter=drafter)
             try:
@@ -341,7 +380,7 @@ class TestSpeculativeParity:
             def reset(self):
                 pass
 
-        srv = ContinuousLMServer(cfg, params, slots=3, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=3,
                                  page_size=4, pages=24, prefill_chunk=4,
                                  draft_len=3, drafter=WrongDrafter())
         try:
@@ -388,10 +427,10 @@ class TestSamplingFallback:
         is byte-identical to the same request on a non-speculating
         pool — the documented fallback, not silent mis-sampling."""
         cfg, params = _lm()
-        spec = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        spec = ContinuousLMServer(cfg, params, slots=2,
                                   page_size=4, prefill_chunk=4,
                                   speculate="ngram", draft_len=3)
-        base = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        base = ContinuousLMServer(cfg, params, slots=2,
                                   page_size=4, prefill_chunk=4)
         try:
             spec.warmup()
@@ -424,12 +463,6 @@ class TestSamplingFallback:
 
 
 class TestAdmissionValidation:
-    def test_speculate_with_dense_kv_is_typed_at_construction(self):
-        cfg, params = _lm()
-        with pytest.raises(ValueError, match="paged"):
-            ContinuousLMServer(cfg, params, kv="dense",
-                               speculate="ngram")
-
     def test_bad_speculate_mode_is_typed(self):
         cfg, params = _lm()
         with pytest.raises(ValueError, match="speculate"):
@@ -440,30 +473,6 @@ class TestAdmissionValidation:
         with pytest.raises(ValueError, match="draft_len"):
             ContinuousLMServer(cfg, params, speculate="ngram",
                                draft_len=0)
-
-    def test_http_speculate_on_dense_pool_is_a_400(self):
-        import json
-        import urllib.request
-
-        from deeplearning4j_tpu.ui.server import UiServer
-
-        cfg, params = _lm(max_len=32, n_layers=1)
-        srv = UiServer(port=0)
-        srv.serve_lm(cfg, params, slots=1, kv="dense").start()
-        try:
-            body = json.dumps({"prompt_ids": [1, 2, 3],
-                               "max_new_tokens": 4,
-                               "speculate": True}).encode()
-            req = urllib.request.Request(
-                srv.url + "/lm/generate", data=body,
-                headers={"Content-Type": "application/json"})
-            with pytest.raises(urllib.error.HTTPError) as e:
-                urllib.request.urlopen(req, timeout=30)
-            assert e.value.code == 400
-            payload = json.loads(e.value.read().decode())
-            assert "dense" in payload["error"]
-        finally:
-            srv.stop()
 
     def test_http_speculate_on_speculating_pool_serves(self):
         import json
@@ -499,7 +508,7 @@ class TestSpecCompileGuard:
         import jax.monitoring
 
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=3, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=3,
                                  page_size=4, prefill_chunk=4,
                                  speculate="ngram", draft_len=3)
         try:
@@ -535,11 +544,63 @@ class TestSpecCompileGuard:
 
     def test_model_drafter_program_is_counted_and_warmed(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, prefill_chunk=4,
                                  speculate="model", draft_len=2)
         try:
             assert srv.warmup() == srv.compiled_programs() == 4
+        finally:
+            srv.stop()
+
+    def test_model_speculation_compiles_nothing_after_warmup(self):
+        """`warmup()` covers the draft model's paged step too (its own
+        pool geometry, so its own program, key `lm:draft`): a storm of
+        ragged prompts after it compiles nothing, and stays
+        byte-identical to `generate()`."""
+        import jax.monitoring
+
+        from deeplearning4j_tpu.parallel import transformer as tfm
+
+        cfg, params = _lm()
+        d_cfg, _ = _lm(n_layers=1)
+        draft = (d_cfg, tfm.init_params(d_cfg, jax.random.PRNGKey(3)))
+        srv = ContinuousLMServer(cfg, params, slots=2,
+                                 page_size=4, prefill_chunk=4,
+                                 speculate="model", draft_len=3,
+                                 draft_model=draft)
+        prompts = [[(7 * i + j) % 49 + 1 for j in range(n)]
+                   for i, n in enumerate((2, 9, 5, 13))]
+        want = [_want(cfg, params, p, 8) for p in prompts]
+        try:
+            assert srv.warmup() == 4
+            warm = srv.stats()
+            assert "lm:drafter" in warm["warmup"]["programs"]
+            compiles = []
+
+            def listener(event, duration, **kw):
+                if event == ("/jax/core/compile/"
+                             "backend_compile_duration"):
+                    compiles.append(event)
+
+            got = [None] * len(prompts)
+
+            def client(i):
+                got[i] = srv.generate(prompts[i], 8, timeout=120)
+
+            jax.monitoring.register_event_duration_secs_listener(
+                listener)
+            try:
+                threads = [threading.Thread(target=client, args=(i,))
+                           for i in range(len(prompts))]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(timeout=150)
+            finally:
+                jax.monitoring.clear_event_listeners()
+            assert got == want
+            assert not compiles
+            assert srv.stats()["compiles_total"] == warm["compiles_total"]
         finally:
             srv.stop()
 
@@ -552,7 +613,7 @@ class TestSpecAccounting:
         cfg, params = _lm()
         registry = MetricsRegistry()
         tracer = TraceRecorder()
-        srv = ContinuousLMServer(cfg, params, slots=2, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=2,
                                  page_size=4, prefill_chunk=4,
                                  speculate="model", draft_len=3,
                                  tracer=tracer, registry=registry)
@@ -579,7 +640,7 @@ class TestSpecAccounting:
 
     def test_fallback_server_without_speculation_reports_no_section(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4)
         try:
             srv.generate([1, 2, 3], 4, timeout=120)
@@ -601,7 +662,10 @@ class TestFleetSpeculate:
         """`spawn_local_replica(lm_speculate=...)` boots speculating
         replicas; routed greedy traffic stays byte-identical to
         `generate()` and /fleet/stats grows an `lm_speculate` aggregate
-        with the fleet-wide accept rate."""
+        with the fleet-wide accept rate.  The replicas draft with their
+        own weights, so every draft is accepted whatever these random
+        weights continue a prompt with (an n-gram drafter proposes only
+        once the continuation repeats the history: these never do)."""
         from deeplearning4j_tpu.serving import FleetRouter
         from deeplearning4j_tpu.serving.fleet import spawn_local_replica
 
@@ -610,7 +674,7 @@ class TestFleetSpeculate:
         def factory(name):
             return spawn_local_replica(
                 name, lm=(cfg, params), lm_slots=2, lm_page_size=8,
-                lm_prefill_chunk=4, lm_speculate="ngram",
+                lm_prefill_chunk=4, lm_speculate="model",
                 lm_draft_len=3)
 
         router = FleetRouter(factory, replicas=2, request_timeout_s=60.0)
@@ -625,8 +689,8 @@ class TestFleetSpeculate:
             router.stop()
         spec = stats["fleet"].get("lm_speculate")
         assert spec is not None
-        assert spec["drafted"] >= spec["accepted"] > 0
-        assert 0 < spec["accept_rate"] <= 1.0
+        assert spec["drafted"] == spec["accepted"] > 0
+        assert spec["accept_rate"] == 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -666,7 +730,7 @@ class TestLintCoverage:
 class TestSpecFaultRecovery:
     def test_failed_dispatch_resets_drafter_with_the_pool(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4, prefill_chunk=4,
                                  speculate="model", draft_len=3)
         try:

@@ -241,7 +241,7 @@ class TestSLOTracker:
 class TestQueueComposition:
     def _server(self, tenants):
         cfg, params = _lm()
-        return ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        return ContinuousLMServer(cfg, params, slots=1,
                                   page_size=4, tenants=tenants)
 
     def test_one_tenant_is_the_historic_fifo(self):
@@ -334,7 +334,7 @@ class TestQuotaOnThePool:
     def test_over_quota_is_typed_with_derived_retry(self):
         cfg, params = _lm()
         srv = ContinuousLMServer(
-            cfg, params, slots=2, kv="paged", page_size=4,
+            cfg, params, slots=2, page_size=4,
             tenants={"b": {"rate": 10.0, "burst": 10.0}})
         try:
             srv.warmup()
@@ -353,7 +353,7 @@ class TestQuotaOnThePool:
 
     def test_ladder_retry_after_tracks_observed_cadence(self):
         cfg, params = _lm()
-        srv = ContinuousLMServer(cfg, params, slots=1, kv="paged",
+        srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4, preempt=True,
                                  brownout=True)
         try:
@@ -616,7 +616,7 @@ class TestTenantChaos:
 
         cfg, params = _lm()
         srv = ContinuousLMServer(
-            cfg, params, slots=2, kv="paged", page_size=4,
+            cfg, params, slots=2, page_size=4,
             tenants={"flood": {"rate": 20.0, "burst": 8.0}})
         try:
             srv.warmup()
@@ -651,25 +651,30 @@ class TestCompositionRegression:
     def test_compliant_interactive_overtakes_flooding_best_effort(self):
         """Tenant A's interactive request must win the slot over tenant
         B's ALREADY-QUEUED best_effort work — priority composes over
-        WFQ exactly as it did pre-tenancy."""
-        cfg, params = _lm()
+        WFQ exactly as it did pre-tenancy.  The request that holds the
+        slot decodes 100 tokens, so both others are queued long before
+        it ends (at 6 it could end first on a loaded host, and the
+        best_effort request then had the slot before the interactive
+        one existed)."""
+        cfg, params = _lm(max_len=128)
         srv = ContinuousLMServer(
-            cfg, params, slots=1, kv="paged", page_size=4,
+            cfg, params, slots=1, page_size=4,
             tenants={"team-a": {"weight": 4.0, "slo_ms": 500.0},
                      "team-b": {"weight": 1.0}})
         srv.warmup()
         done = []
         lock = threading.Lock()
 
-        def run(name, prompt, prio, tenant):
-            srv.generate(prompt, 6, priority=prio, tenant=tenant,
+        def run(name, prompt, prio, tenant, new=6):
+            srv.generate(prompt, new, priority=prio, tenant=tenant,
                          timeout=600)
             with lock:
                 done.append(name)
 
         try:
             t0 = threading.Thread(target=run, args=("first", [1, 2],
-                                                    "batch", "team-b"))
+                                                    "batch", "team-b",
+                                                    100))
             t0.start()
             _wait_mid_decode(srv, committed=1)
             t1 = threading.Thread(target=run, args=("be", [3, 4],
@@ -701,7 +706,7 @@ class TestCompositionRegression:
 
         cfg, params = _lm()
         srv = ContinuousLMServer(
-            cfg, params, slots=2, kv="paged", page_size=4, pages=8,
+            cfg, params, slots=2, page_size=4, pages=8,
             prefill_chunk=4, preempt=True,
             tenants={"team-a": {"weight": 4.0},
                      "team-b": {"weight": 1.0}})
@@ -761,7 +766,7 @@ class TestCompositionRegression:
 
         cfg, params = _lm()
         srv = ContinuousLMServer(
-            cfg, params, slots=2, kv="paged", page_size=4,
+            cfg, params, slots=2, page_size=4,
             preempt=True, brownout=True,
             tenants={"good": {"weight": 1.0},
                      "bad": {"slo_ms": 1.0, "slo_budget": 0.01}})
@@ -796,7 +801,7 @@ class TestCompositionRegression:
         the 429's Retry-After is max(bucket refill, ladder dwell)."""
         cfg, params = _lm()
         srv = ContinuousLMServer(
-            cfg, params, slots=2, kv="paged", page_size=4,
+            cfg, params, slots=2, page_size=4,
             preempt=True, brownout=True,
             tenants={"b": {"rate": 1000.0, "burst": 6.0}})
         try:

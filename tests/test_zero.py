@@ -230,6 +230,13 @@ class TestMeshTrainersDPAxis:
         assert per == {m_leaf.size // 2}
 
     def test_pipeline_flat_zero_bitwise(self):
+        """The flat sharded update against the replicated one.  The two
+        layouts are different XLA programs whose gradients agree to an
+        ULP, not to the bit (XLA on a CPU never promised that).  The
+        first Adam update is `lr * g / (|g| + eps)` and does not see an
+        ULP of `g`: bitwise there.  The moments carry it forward, so the
+        third step is held to 1e-6 absolute, as the hybrid trainer's
+        test above holds its own."""
         from deeplearning4j_tpu.parallel import transformer as tfm
         from deeplearning4j_tpu.parallel.hybrid import (
             PipelineParallelTrainer,
@@ -247,13 +254,19 @@ class TestMeshTrainersDPAxis:
             tr = PipelineParallelTrainer(cfg, mesh, n_microbatches=2,
                                          lr=0.01, seed=4, updater="adam",
                                          shard_update=shard)
-            for _ in range(3):
+            tr.fit_batch(tok, tgt)
+            first = (_flat(tr.stage_params), _flat(tr.io_params))
+            for _ in range(2):
                 tr.fit_batch(tok, tgt)
-            return tr
+            return tr, first
 
-        a, b = run(True), run(False)
-        assert np.array_equal(_flat(a.stage_params), _flat(b.stage_params))
-        assert np.array_equal(_flat(a.io_params), _flat(b.io_params))
+        (a, a1), (b, b1) = run(True), run(False)
+        assert np.array_equal(a1[0], b1[0])
+        assert np.array_equal(a1[1], b1[1])
+        np.testing.assert_allclose(_flat(a.stage_params),
+                                   _flat(b.stage_params), rtol=0, atol=1e-6)
+        np.testing.assert_allclose(_flat(a.io_params), _flat(b.io_params),
+                                   rtol=0, atol=1e-6)
         from jax.sharding import PartitionSpec as P
 
         m = jax.tree_util.tree_leaves(a.stage_opt["m"])[0]
@@ -299,8 +312,9 @@ class TestElasticResume:
 
 class TestSupervisorComposition:
     def test_divergence_rollback_repartitions_shards(self, tmp_path):
-        """An exploding run under the sharded default: the supervisor
-        rolls back by restoring the checkpoint INTO the shard layout
+        """A run that diverges (a NaN batch let through to the step:
+        non-finite by construction) under the sharded default: the
+        supervisor rolls back by restoring the checkpoint INTO the shard layout
         (restore_train_state repartitions, it does not install
         replicated moments), and training then completes finite."""
         from deeplearning4j_tpu.models import iris_mlp
@@ -313,15 +327,16 @@ class TestSupervisorComposition:
 
         x, y = _data()
         batches = [(x[i:i + 8], y[i:i + 8]) for i in range(0, 64, 8)] * 4
-        net = MultiLayerNetwork(
-            iris_mlp(updater="sgd", learning_rate=50.0)).init()
+        net = MultiLayerNetwork(iris_mlp(updater="sgd")).init()
         tr = DataParallelTrainer(net)
         assert tr.shard_update
         sup = TrainingSupervisor(tr, ResilienceConfig(
             checkpoint_dir=tmp_path / "ckpts", checkpoint_every=10,
-            min_history=3, lr_backoff=0.01, max_rollbacks=4))
-        report = sup.run(ChaosDataSource(batches, ChaosConfig()))
-        assert report.rollbacks >= 1
+            min_history=3, check_batches=False, lr_backoff=0.5,
+            max_rollbacks=4))
+        report = sup.run(ChaosDataSource(batches,
+                                         ChaosConfig(nan_steps=(3,))))
+        assert report.rollbacks == 1
         assert np.isfinite(report.final_loss)
         # the trainer still owns a SHARDED opt state after the rollback
         assert getattr(tr, "_opt_shard", None) is not None

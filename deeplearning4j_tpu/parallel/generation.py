@@ -333,12 +333,14 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
       parity reference (and guarded against
       re-growth by dl4jlint PGD301 — this is the baselined occurrence).
     - ``paged_kernel=True`` — `paged_flash_attention` walks the block
-      table INSIDE the kernel, reading `[ps, H*K]` blocks of the
-      stacked pool through the table offset to this layer's pages: no
-      contiguous history buffer, K/V streamed page-by-page,
-      beyond-``pos`` pages skipped, so HBM traffic scales with live
-      pages instead of ``MP*ps``.  Identical math at every fed column
-      (padding columns are never consumed).
+      table INSIDE the kernel's body, one grid step a lane: the
+      stacked pool stays in HBM and each live page `[ps, H*K]` is
+      fetched by its own DMA through the table offset to this layer's
+      pages, several pages a block: no contiguous history buffer,
+      beyond-``pos`` pages never visited, so HBM traffic and time
+      scale with live pages instead of ``MP*ps``.  Identical math at
+      every fed column (padding columns are never consumed; a lane
+      with ``n_feed == 0`` reads nothing and comes back as zeros).
     """
     q, k, v = qkv_proj(p, x)                              # [B, C, H, K]
     b, c, h, kd = q.shape

@@ -9,17 +9,21 @@ rows of HBM traffic per lane whether the lane holds 3 live pages or 30.
 ``paged_flash_attention`` removes the buffer entirely: the kernel takes
 the serving pool as it lies on the device, ``[L, P, ps, H*K]``, a
 ``layer``, the per-lane block table ``[B, MP]``, ``pos`` and
-``n_feed`` directly, prefetches the page ids as scalars
-(``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index maps can
-resolve *physical* page addresses ``layer*P + table[b, lp]`` before each
-grid step's DMA, and streams K/V one ``[ps, H*K]`` page at a time
-through a FlashAttention-style online softmax accumulator (PAPERS.md
-2205.14135; fused-epilogue discipline per 1808.05567).  Pages past a
-lane's frontier — beyond-``pos`` pages, which is where every
-null/unallocated block-table entry lives — are skipped: their grid
-steps clamp the index map onto the lane's last live page (no new DMA)
-and ``pl.when`` guards out the compute, so both bandwidth and FLOPs
-scale with *live* pages, not ``MP*ps``.
+``n_feed`` directly.  The table and the two vectors are prefetched as
+scalars (``pltpu.PrefetchScalarGridSpec``); the pool stays in HBM
+(``memory_space=pl.ANY``).  The grid is one step a LANE, and the lane's
+block table is walked inside the body: a ``fori_loop`` over the lane's
+live pages, several pages a block, each page ``layer*P + table[b, i]``
+fetched by its own DMA into a two-slot VMEM buffer (the next block in
+flight under this block's matmuls) and streamed through a
+FlashAttention-style online softmax accumulator (PAPERS.md 2205.14135;
+fused-epilogue discipline per 1808.05567).  Pages past a lane's
+frontier, beyond-``pos`` pages, which is where every null/unallocated
+block-table entry lives, are never visited, and a lane that feeds
+nothing takes no trip at all: bandwidth, FLOPs AND time scale with
+*live* pages, not ``MP*ps`` (a grid over ``(lanes, max_pages)`` paid
+0.22 us for every dead step: 7.9 of a chat round's 11.4 ms, PERF.md
+section 6, PR 29).
 
 The pool's rows are lane-dense (all heads of a position side by side:
 16 rows by 1,280 lanes is an exact bf16 tile for GPT-2-large), which is
@@ -55,9 +59,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.parallel.kernels import (
+    _DEFAULT_SCOPED_VMEM,
     REP,
+    _buf,
     _resolve_interpret,
-    mask_value,
 )
 
 
@@ -109,106 +114,162 @@ def _dot_f32(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _paged_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
-                       o_ref, qt, m_acc, l_acc, acc, *, scale, ps, c, cp,
-                       kd, tw, mp, neg):
-    """Grid program: one (lane, logical_page) pair, the page dimension
-    sequential (online-softmax accumulation in VMEM scratch).
+# Keys a block of the page walk holds: one 128-lane tile of scores.  A
+# page of 16 keys alone leaves the MXU's latches and the DMA's issue and
+# wait as all of a visit: on a v5e at GPT-2-large's row a page costs
+# 1.67 us walked one a block, 0.85 at two, 0.24 at four, 0.17 at eight
+# and 0.15 at sixteen, where a lane's tail block already fetches more
+# spare slots than pages (PERF.md section 6, PR 29).
+_KEYS = 128
 
-    table_ref/pos_ref/nf_ref are the scalar-prefetch operands — already
-    resident when the body runs, and consumed by the K/V index maps to
-    turn logical page ``lp`` into a physical pool address.  q_ref
-    ``[CP, H*K]`` (the C fed columns padded to whole sublane tiles) is
-    revisited across the page steps; k_ref/v_ref ``[ps, H*K]`` is this
-    lane's page ``lp`` of the layer the table is offset to (or a clamped
-    repeat of its last live page on dead steps — same block index, so
-    the pipeline issues no new DMA).  Rows are lane-dense: all heads of
-    a position side by side, ``ps`` rows of full 128-lane tiles, the
-    pool's own layout in HBM (no relayout on either side of the call).
+
+def _pages_per_block(ps: int, hkd: int, itemsize: int, mp: int,
+                     interpret: bool) -> int:
+    """Pages `G` one block of the walk fetches and attends over at once:
+    as many as make `_KEYS` keys, within the table's width and with the
+    K and V buffers (two slots each) inside a quarter of the scoped VMEM
+    `kernels._plan` sizes its blocks to.  Mosaic tiles a VMEM buffer's
+    rows in eights, so a compiled call whose page is not whole tiles
+    walks a page a block: the page then fills a buffer slot whole and
+    no DMA lands inside a tile."""
+    if not interpret and ps % 8:
+        return 1
+    fit = (_DEFAULT_SCOPED_VMEM // 4) // (4 * _buf(ps, hkd, itemsize))
+    return max(1, min(_KEYS // ps, mp, fit))
+
+
+def _paged_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
+                       o_ref, kbuf, vbuf, sem, qt, m_acc, l_acc, acc, *,
+                       scale, ps, c, cp, kd, tw, gp, neg):
+    """Grid program: one lane.  The lane's live pages are walked INSIDE
+    the body, `gp` pages a block, so a dispatch pays for the pages it
+    holds and nothing for the table's width or an idle lane.
+
+    table_ref/pos_ref/nf_ref are the scalar-prefetch operands, resident
+    when the body runs.  q_ref ``[CP, H*K]`` is the lane's C fed columns
+    padded to whole sublane tiles.  k_ref/v_ref are the WHOLE pool
+    ``[L*P, ps, H*K]`` left in HBM (``memory_space=pl.ANY``): page
+    ``table[b, i]`` (the table is already offset to the layer) is
+    fetched by its own DMA into rows ``[j*ps, (j+1)*ps)`` of a buffer
+    slot ``[gp*ps, H*K]``, two slots deep, the next block's DMAs in
+    flight under this block's matmuls.  Rows are lane-dense: all heads
+    of a position side by side, the pool's own layout in HBM (no
+    relayout on either side of the call).
+
+    The lane holds ``n = (pos + n_feed - 1) // ps + 1`` live pages
+    (every null block-table entry of a live lane lies past them) and
+    takes ``ceil(n / gp)`` trips.  The tail block's spare slots fetch
+    the lane's LAST live page again: every row a matmul reads was
+    written by a DMA from a live page, so a masked key's ``p`` of
+    exactly 0 never meets what VMEM happened to hold (0 x NaN is NaN on
+    the MXU), and no dead table entry is ever dereferenced.  A lane
+    with ``n_feed == 0`` reads nothing and writes zeros: no column of
+    it is consumed.
 
     The per-head reduction runs on the MXU, one ``tw``-lane tile (``g``
-    whole heads; a head pair at K=64) at a time.  At the lane's first
-    step the queries of a tile are laid out block-diagonally in ``qt``:
-    row ``gi*CP + ci`` holds column ``ci``'s query with every lane
-    outside head ``gi`` zeroed, so ``qt[j] @ k_tile.T`` is the
-    ``[g*CP, ps]`` score block of those heads (the zeros drop the other
-    heads' lanes from the contraction), the softmax statistics are
-    plain row statistics, and ``p @ v_tile`` gives each row its head's
-    value mix in that head's own lanes (the other lanes hold a mix that
-    is masked away at the flush).  Every slice is a whole tile: static
-    multiples of ``tw`` lanes and of ``CP`` sublanes.
+    whole heads; a head pair at K=64) at a time.  The queries of a tile
+    are laid out block-diagonally in ``qt``: row ``gi*CP + ci`` holds
+    column ``ci``'s query with every lane outside head ``gi`` zeroed, so
+    ``qt[j] @ k_tile.T`` is the ``[g*CP, gp*ps]`` score block of those
+    heads (the zeros drop the other heads' lanes from the contraction),
+    the softmax statistics are plain row statistics, and ``p @ v_tile``
+    gives each row its head's value mix in that head's own lanes (the
+    other lanes hold a mix that is masked away at the flush).  Every
+    slice is a whole tile: static multiples of ``tw`` lanes and of
+    ``CP`` sublanes.
     Row stats live lane-replicated ``[., g*CP, REP]`` (see kernels.REP).
     """
-    b, lp = pl.program_id(0), pl.program_id(1)
+    b = pl.program_id(0)
     nt = qt.shape[0]
     g = tw // kd
     rows = g * cp
+    keys = gp * ps
+    pos, nf = pos_ref[b], nf_ref[b]
+    # the lane's live pages: through its last written position
+    n = jnp.where(nf > 0, (pos + nf - 1) // ps + 1, 0)
 
-    def own():
-        # lanes of tile-local head gi, as [CP, tw] masks (built where
-        # they are used: the dead grid steps pay for nothing)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (cp, tw), 1)
-        return [(lane >= gi * kd) & (lane < (gi + 1) * kd)
-                for gi in range(g)]
+    def copies(blk, slot):
+        out = []
+        for j in range(gp):
+            page = table_ref[b, jnp.minimum(blk * gp + j, n - 1)]
+            dst = pl.ds(j * ps, ps)
+            out += [pltpu.make_async_copy(k_ref.at[page],
+                                          kbuf.at[slot, dst], sem.at[0, slot]),
+                    pltpu.make_async_copy(v_ref.at[page],
+                                          vbuf.at[slot, dst], sem.at[1, slot])]
+        return out
 
-    @pl.when(lp == 0)
-    def _init():
+    @pl.when(n == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n > 0)
+    def _busy():
+        for dma in copies(0, 0):
+            dma.start()
         m_acc[...] = jnp.full_like(m_acc, neg)
         l_acc[...] = jnp.zeros_like(l_acc)
         acc[...] = jnp.zeros_like(acc)
-        masks = own()
+        # lanes of tile-local head gi, as [CP, tw] masks
+        lane = jax.lax.broadcasted_iota(jnp.int32, (cp, tw), 1)
+        masks = [(lane >= gi * kd) & (lane < (gi + 1) * kd)
+                 for gi in range(g)]
         for j in range(nt):
             qj = q_ref[:, j * tw:(j + 1) * tw].astype(jnp.float32)
             qt[j] = jnp.concatenate(
                 [jnp.where(o, qj, 0.0) for o in masks], axis=0
             ).astype(qt.dtype)
-
-    # The lane's frontier: its last written position this dispatch.
-    # Pages strictly past it are fully masked — skip them (this is also
-    # where every null block-table entry of a live lane lives).
-    wmax = pos_ref[b] + jnp.maximum(nf_ref[b], 1) - 1
-
-    @pl.when(lp * ps <= wmax)
-    def _page():
-        # key t = lp*ps + column is visible to the query column ci of
-        # row gi*CP + ci iff t <= pos + ci — the oracle's causal mask,
+        # key t = blk*keys + column is visible to the query column ci of
+        # row gi*CP + ci iff t <= pos + ci: the oracle's causal mask,
         # intra-chunk included
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0)
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
         ci = r
         for gi in range(1, g):
             ci = jnp.where(r >= gi * cp, r - gi * cp, ci)
-        t = lp * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-        live = t <= pos_ref[b] + ci
-        for j in range(nt):
-            k_blk = k_ref[:, j * tw:(j + 1) * tw].astype(qt.dtype)
-            v_blk = v_ref[:, j * tw:(j + 1) * tw].astype(qt.dtype)
-            s = jax.lax.dot_general(                        # [ps, tw] each
-                qt[j], k_blk, (((1,), (1,)), ((), ())),
-                precision=(None if qt.dtype == jnp.bfloat16
-                           else jax.lax.Precision.HIGHEST),
-                preferred_element_type=jnp.float32) * scale  # [rows, ps]
-            s = jnp.where(live, s, neg)
-            m = m_acc[j][:, :1]                             # [rows, 1]
-            new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(live, jnp.exp(s - new_m), 0.0)
-            scale_old = jnp.exp(m - new_m)
-            new_l = (l_acc[j][:, :1] * scale_old
-                     + jnp.sum(p, axis=1, keepdims=True))
-            acc[j] = acc[j] * scale_old + _dot_f32(p, v_blk, ((1,), (0,)))
-            m_acc[j] = jnp.broadcast_to(new_m, (rows, REP))
-            l_acc[j] = jnp.broadcast_to(new_l, (rows, REP))
+        horizon = pos + ci - jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
 
-    @pl.when(lp == mp - 1)
-    def _flush():
-        masks = own()
+        def block(blk, carry):
+            slot = jax.lax.rem(blk, 2)
+
+            @pl.when((blk + 1) * gp < n)
+            def _next():
+                for dma in copies(blk + 1, 1 - slot):
+                    dma.start()
+
+            for dma in copies(blk, slot):
+                dma.wait()
+            live = blk * keys <= horizon
+            for j in range(nt):
+                k_blk = kbuf[slot, :, j * tw:(j + 1) * tw].astype(qt.dtype)
+                v_blk = vbuf[slot, :, j * tw:(j + 1) * tw].astype(qt.dtype)
+                s = jax.lax.dot_general(
+                    qt[j], k_blk, (((1,), (1,)), ((), ())),
+                    precision=(None if qt.dtype == jnp.bfloat16
+                               else jax.lax.Precision.HIGHEST),
+                    preferred_element_type=jnp.float32) * scale  # [rows, keys]
+                s = jnp.where(live, s, neg)
+                m = m_acc[j][:, :1]                             # [rows, 1]
+                new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.where(live, jnp.exp(s - new_m), 0.0)
+                scale_old = jnp.exp(m - new_m)
+                new_l = (l_acc[j][:, :1] * scale_old
+                         + jnp.sum(p, axis=1, keepdims=True))
+                acc[j] = (acc[j] * scale_old
+                          + _dot_f32(p, v_blk, ((1,), (0,))))
+                m_acc[j] = jnp.broadcast_to(new_m, (rows, REP))
+                l_acc[j] = jnp.broadcast_to(new_l, (rows, REP))
+            return carry
+
+        jax.lax.fori_loop(0, (n + gp - 1) // gp, block, 0)
         for j in range(nt):
             o = acc[j] / jnp.maximum(l_acc[j][:, :1], 1e-30)  # [rows, tw]
             out = jnp.zeros((cp, tw), jnp.float32)
             for gi in range(g):
                 out = jnp.where(masks[gi], o[gi * cp:(gi + 1) * cp], out)
-            for ci in range(c):
-                o_ref[ci, :, j * tw:(j + 1) * tw] = (
-                    out[ci:ci + 1].astype(o_ref.dtype))
+            for col in range(c):
+                o_ref[col, :, j * tw:(j + 1) * tw] = (
+                    out[col:col + 1].astype(o_ref.dtype))
 
 
 def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
@@ -239,7 +300,8 @@ def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
     padding columns (never consumed — `paged_decode_step` indexes
     column ``n_feed - 1``, the verify step at most that) attend only
     through the lane's frontier page rather than the oracle's full
-    ``pos + c`` horizon.
+    ``pos + c`` horizon, and a lane with ``n_feed == 0`` comes back as
+    zeros.
     """
     b, c, h, kd = q.shape
     hkd = h * kd
@@ -275,30 +337,27 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
     # the MXU's operand dtype: the pool's own if that is bf16, else f32
     md = jnp.bfloat16 if k_pages.dtype == jnp.bfloat16 else jnp.float32
 
-    def _lane_map(bi, lp, tbl, pos_, nf):
+    gp = _pages_per_block(ps, hkd, k_pages.dtype.itemsize, mp, interpret)
+
+    def _lane_map(bi, tbl, pos_, nf):
         return (bi, 0, 0)
 
-    def _out_map(bi, lp, tbl, pos_, nf):
+    def _out_map(bi, tbl, pos_, nf):
         return (bi, 0, 0, 0)
-
-    def _page_map(bi, lp, tbl, pos_, nf):
-        # Clamp dead grid steps onto the lane's last live logical page:
-        # the repeated block index means the pipeline re-uses the
-        # already-resident page instead of DMAing a dead one.
-        wmax = pos_[bi] + jnp.maximum(nf[bi], 1) - 1
-        live_lp = jnp.minimum(lp, wmax // ps)
-        return (tbl[bi, live_lp], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, mp),
+        grid=(b,),
         in_specs=[
             pl.BlockSpec((None, cp, hkd), _lane_map),
-            pl.BlockSpec((None, ps, hkd), _page_map),
-            pl.BlockSpec((None, ps, hkd), _page_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((None, c, 1, hkd), _out_map),
         scratch_shapes=[
+            pltpu.VMEM((2, gp * ps, hkd), k_pages.dtype),  # K blocks
+            pltpu.VMEM((2, gp * ps, hkd), v_pages.dtype),  # V blocks
+            pltpu.SemaphoreType.DMA((2, 2)),               # [K|V, slot]
             pltpu.VMEM((nt, g * cp, tw), md),           # block-diagonal q
             pltpu.VMEM((nt, g * cp, REP), jnp.float32),  # running max
             pltpu.VMEM((nt, g * cp, REP), jnp.float32),  # running denom
@@ -306,7 +365,7 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
         ],
     )
     kernel = functools.partial(_paged_attn_kernel, scale=scale, ps=ps,
-                               c=c, cp=cp, kd=kd, tw=tw, mp=mp, neg=_NEG)
+                               c=c, cp=cp, kd=kd, tw=tw, gp=gp, neg=_NEG)
     # The result stays 4-D with the feed width second, [B, C, 1, H*K],
     # and the block table the call's first operand: the benchmark's
     # trace readers find the kernel, and the width, by that signature.
@@ -315,7 +374,7 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, 1, hkd), qf.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(table, pos, n_feed, qf, k_pages, v_pages)
 
@@ -328,11 +387,13 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
 # `v_width` lanes).  Every query head of a lane reads the same page, so
 # the kernel's work per cached byte is `2 * H` matmul rows and not one:
 # 128 heads put it at the v5e's ridge where `_paged_attn_kernel` is
-# bound by bytes alone.  The block table is walked INSIDE the body, a
-# `fori_loop` over the lane's live pages with the next page's DMA in
-# flight under the current page's matmuls, so a dispatch costs its live
-# pages and nothing for the table's width (a grid over `max_pages` pays
-# for every dead step; at 16k positions that is most of them).
+# bound by bytes alone.  The block table is walked INSIDE the body as in
+# `_paged_attn_kernel`, a `fori_loop` over the lane's live pages with
+# the next page's DMA in flight under the current page's matmuls, so a
+# dispatch costs its live pages and nothing for the table's width.  The
+# two walks differ in their block: a latent page of 128 positions is a
+# block by itself and all heads read it, a `[ps, H*K]` page of 16 is
+# one of several and each 128-lane tile of it belongs to a head pair.
 
 
 def _latent_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, pool_ref, o_ref,

@@ -323,6 +323,7 @@ from deeplearning4j_tpu.parallel.generation import (  # noqa: E402
 )
 from deeplearning4j_tpu.parallel.kernels import mask_value  # noqa: E402
 from deeplearning4j_tpu.parallel.paged_kernel import (  # noqa: E402
+    _pages_per_block,
     paged_flash_attention,
     resolve_paged_kernel,
 )
@@ -416,8 +417,10 @@ class TestPagedFlashAttention:
 
     def test_null_page_lane(self):
         """An inactive lane (all-null table, pos=0, n_feed=0) rides the
-        dispatch like the oracle's masked lanes: finite output, and the
-        live lanes around it are untouched by its presence."""
+        dispatch without a page visit: zeros out (no column of it is
+        consumed; `paged_decode_step` samples its column 0 for a lane
+        the scheduler ignores), and the live lanes around it are
+        untouched by its presence."""
         ps, mp, c = 4, 4, 2
         pos = np.array([0, 6], np.int32)
         q, kp, vp, table, posj = _paged_state(2, c, 2, 8, ps, mp, pos,
@@ -426,11 +429,7 @@ class TestPagedFlashAttention:
         nf = jnp.asarray([0, 2], jnp.int32)
         got = paged_flash_attention(q, kp, vp, table, posj, nf)
         want = _gather_oracle(q, kp, vp, table, posj)
-        assert np.isfinite(np.asarray(got)).all()
-        # lane 0 column 0 is what paged_decode_step would read
-        # (max(n_feed-1, 0) = 0) — it must match the oracle too
-        np.testing.assert_allclose(np.asarray(got)[0, 0],
-                                   np.asarray(want)[0, 0], atol=1e-5)
+        assert not np.asarray(got)[0].any()
         _assert_fed_columns_match(got, want, nf)
 
     def test_property_random_shapes(self):
@@ -470,6 +469,118 @@ class TestPagedFlashAttention:
         want = _gather_oracle(q, kp, vp, table, posj)
         _assert_fed_columns_match(got.astype(jnp.float32), want, nf,
                                   atol=2e-2)
+
+
+# The page walk inside the kernel's body (ISSUE 29): every case runs on a
+# stacked pool whose dead table entries are out of range and whose pages
+# that no live entry of the layer names hold NaN (the other layers' pages
+# among them), so a dead page read, a wrong layer, or a buffer row that no
+# DMA wrote but a matmul multiplied, shows as a NaN or a fault.  Lanes are
+# (pos, n_feed); `_WALK_G` is the kernel's own pages per block at the
+# cases' shapes.
+_WALK_PS, _WALK_MP = 16, 12
+_WALK_G = _pages_per_block(_WALK_PS, 16, 4, _WALK_MP, True)
+
+
+def _walk_history(pages):
+    """One lane whose history is `pages` pages long, ending mid-page."""
+    return [(pages * _WALK_PS - 7, 1)]
+
+
+_WALK_CASES = {
+    # name: (lanes [(pos, n_feed)], width, layers, layer, dtype)
+    "dead_entries_and_nan_pool": (
+        [(0, 1), (37, 1), (5, 1), (150, 1)], 1, 2, 1, "float32"),
+    "dead_entries_and_nan_pool_bf16": (
+        [(0, 8), (37, 3), (150, 8)], 8, 2, 0, "bfloat16"),
+    "idle_lanes_between_busy": (
+        [(0, 0), (21, 4), (0, 0), (0, 0), (130, 2), (0, 0)],
+        4, 1, 0, "float32"),
+    "history_1_page": (_walk_history(1), 1, 1, 0, "float32"),
+    "history_g_minus_1_pages": (
+        _walk_history(_WALK_G - 1), 1, 1, 0, "float32"),
+    "history_g_pages": (_walk_history(_WALK_G), 1, 1, 0, "float32"),
+    "history_g_plus_1_pages": (
+        _walk_history(_WALK_G + 1), 1, 1, 0, "float32"),
+    "history_max_pages": (_walk_history(_WALK_MP), 1, 1, 0, "float32"),
+    "pos_mod_ps_0": ([(3 * _WALK_PS, 1), (0, 1)], 1, 1, 0, "float32"),
+    "pos_mod_ps_1": ([(3 * _WALK_PS + 1, 1), (1, 1)], 1, 1, 0, "float32"),
+    "pos_mod_ps_last": ([(4 * _WALK_PS - 1, 1), (_WALK_PS - 1, 1)],
+                        1, 1, 0, "float32"),
+    "width_1": ([(9, 1), (140, 1)], 1, 1, 0, "float32"),
+    "width_5_verify_feed": (
+        [(12, 5), (139, 5), (30, 1)], 5, 1, 0, "float32"),
+    "width_8": ([(12, 8), (120, 8)], 8, 1, 0, "float32"),
+    "ragged_n_feed_wide_round": (
+        [(10, 8), (15, 1), (126, 3), (0, 0), (64, 7)], 8, 1, 0, "float32"),
+    "layer_first": ([(9, 2), (140, 3)], 3, 3, 0, "float32"),
+    "layer_last": ([(9, 2), (140, 3)], 3, 3, 2, "float32"),
+}
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_page_walk_reads_live_pages_only(case):
+    lanes, c, n_layers, layer, dtype = _WALK_CASES[case]
+    assert 1 < _WALK_G - 1 and _WALK_G + 1 < _WALK_MP  # five histories
+    ps, mp, h, kd = _WALK_PS, _WALK_MP, 2, 8
+    b = len(lanes)
+    pos = np.array([p for p, _ in lanes], np.int32)
+    nf = np.array([f for _, f in lanes], np.int32)
+    rng = np.random.default_rng(len(case))
+    pages = 1 + b * mp
+    shape = (n_layers, pages, ps, h * kd)
+    q = jnp.asarray(rng.standard_normal((b, c, h, kd)), dtype)
+    clean_k = rng.standard_normal(shape).astype(np.float32)
+    clean_v = rng.standard_normal(shape).astype(np.float32)
+    if dtype == "bfloat16":     # the oracle sees what the pool holds
+        clean_k = np.asarray(jnp.asarray(clean_k, dtype), np.float32)
+        clean_v = np.asarray(jnp.asarray(clean_v, dtype), np.float32)
+    clean_table = np.zeros((b, mp), np.int32)
+    dirty_table = np.full((b, mp), 1 << 20, np.int32)
+    live = np.zeros((pages,), bool)
+    for i in range(b):
+        if nf[i]:
+            n = (pos[i] + nf[i] - 1) // ps + 1
+            assert n <= mp
+            ids = 1 + i * mp + rng.permutation(mp)[:n]
+            clean_table[i, :n] = dirty_table[i, :n] = ids
+            live[ids] = True
+    dirty_k = np.full(shape, np.nan, np.float32)
+    dirty_v = np.full(shape, np.nan, np.float32)
+    dirty_k[layer, live] = clean_k[layer, live]
+    dirty_v[layer, live] = clean_v[layer, live]
+
+    got = paged_flash_attention(
+        q, jnp.asarray(dirty_k, dtype), jnp.asarray(dirty_v, dtype),
+        jnp.asarray(dirty_table), jnp.asarray(pos), jnp.asarray(nf),
+        layer=layer)
+    want = _gather_oracle(
+        q.astype(jnp.float32),
+        jnp.asarray(clean_k[layer].reshape(pages, ps, h, kd)),
+        jnp.asarray(clean_v[layer].reshape(pages, ps, h, kd)),
+        jnp.asarray(clean_table), jnp.asarray(pos))
+    got = np.asarray(got.astype(jnp.float32))
+    assert np.isfinite(got).all()
+    assert not got[nf == 0].any()               # an idle lane: zeros
+    _assert_fed_columns_match(got, want, nf,
+                              atol=2e-2 if dtype == "bfloat16" else 1e-5)
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("shape, want", [
+    # (ps, H*K, itemsize, max_pages, interpret) -> pages a block
+    ((16, 1280, 2, 64, False), 8),      # the serve cells: 128 keys
+    ((32, 1280, 2, 32, False), 4),
+    ((128, 1280, 2, 8, False), 1),      # a page is a block by itself
+    ((16, 1280, 2, 3, False), 3),       # never wider than the table
+    ((8, 1280, 4, 128, False), 16),
+    ((4, 16, 4, 6, False), 1),          # compiled: no DMA inside a tile
+    ((4, 16, 4, 6, True), 6),           # the interpreter takes any page
+    ((16, 16384, 2, 64, False), 2),     # the buffers stay inside VMEM
+])
+def test_pages_per_block_comes_from_the_shapes(shape, want):
+    assert _pages_per_block(*shape) == want
 
 
 @pytest.mark.paged_kernel

@@ -93,7 +93,7 @@ def test_every_block_of_a_blocked_mix_holds_the_same_traffic():
         blocks = [[s for s in window
                    if b * 10.0 <= s.arrival_s < (b + 1) * 10.0]
                   for b in range(5)]
-        assert [len(b) for b in blocks] == [14] * 5
+        assert [len(b) for b in blocks] == [round(m["rate_per_s"] * 10)] * 5
         longest = [max(len(s.turns[0].user) for s in b) for b in blocks]
         # each block got one of the five longest prompts, and one of the
         # five shortest
@@ -101,6 +101,29 @@ def test_every_block_of_a_blocked_mix_holds_the_same_traffic():
                                       for s in window)[-5]
         tokens = [sum(len(s.turns[0].user) for s in b) for b in blocks]
         assert max(tokens) < 1.25 * min(tokens)
+
+
+BLOCKED = sorted(p.stem for p in (spec.HERE / "traffic").glob("*.json")
+                 if "block_s" in json.loads(p.read_text()))
+
+
+@pytest.mark.parametrize("name", BLOCKED)
+def test_a_blocked_mix_gives_every_block_a_whole_number_of_arrivals(name):
+    """`rate_per_s` times `block_s` is a whole number, and the window the
+    cells run is a whole number of blocks: otherwise the blocks' counts
+    differ and the load is no longer even."""
+    m = mix(name)
+    per_block = m["rate_per_s"] * m["block_s"]
+    assert per_block == pytest.approx(round(per_block), abs=1e-9)
+    assert round(per_block) >= 1
+    seconds = json.loads((spec.ROOT / "BENCHMARK.json").read_text())[
+        "run_seconds"]
+    assert seconds % m["block_s"] == 0
+    sched = generators.build(m, 7, float(seconds), 50304, 1024)
+    at = np.array([s.arrival_s for s in sched.sessions])
+    counts = np.histogram(at[at >= 0], bins=np.arange(
+        0, seconds + m["block_s"], m["block_s"]))[0]
+    assert set(counts) == {round(per_block)}
 
 
 def test_session_arrivals_are_the_rate_times_the_span():

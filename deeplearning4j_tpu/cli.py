@@ -376,7 +376,8 @@ def _lm_mesh_layout(runtime: str, n: int, S: int, n_heads: int,
     degrades to 1, so the same command works from one real chip up to a
     full slice — on n=1 both runtimes become plain local training."""
     if runtime == "hybrid":
-        sp = 2 if n % 2 == 0 and S % 2 == 0 else 1
+        # a causal ring over sp chips deals the sequence in 2 * sp chunks
+        sp = 2 if n % 2 == 0 and S % 4 == 0 else 1
         tp = 2 if (n // sp) % 2 == 0 and n_heads % 2 == 0 else 1
         dp = max(1, n // (sp * tp))
         if B % dp:

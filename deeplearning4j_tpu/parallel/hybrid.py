@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -213,7 +214,8 @@ class HybridParallelTrainer:
             def loss_fn(p):
                 pc = (p if compute_dtype == jnp.float32
                       else _cast_floating(p, compute_dtype))
-                return tfm.lm_loss(cfg_, pc, tokens, targets, mesh_, axes_)
+                return tfm.lm_loss(cfg_, pc, tokens, targets, mesh_, axes_,
+                                   dealt=True)
 
             loss, grads = jax.value_and_grad(loss_fn)(params)
             updates, opt_state = transform.update(grads, opt_state, params)
@@ -254,8 +256,6 @@ class HybridParallelTrainer:
         replicate.  A moment with no divisible free dim keeps its
         param placement — correct, just not sharded (the remainder
         rule here is whole-leaf, unlike the flat plane's padding)."""
-        import numpy as np
-
         from deeplearning4j_tpu.parallel import partition as part_lib
 
         n_data = dict(zip(self.mesh.axis_names,
@@ -294,8 +294,19 @@ class HybridParallelTrainer:
         synchronizing (JIT107 discipline: back-to-back steps pipeline
         on the chips — sync only where a report is due)."""
         dsh = NamedSharding(self.mesh, P(self.axes.data, self.axes.seq))
-        tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), dsh)
-        targets = jax.device_put(jnp.asarray(targets, jnp.int32), dsh)
+        # a causal ring wants each row dealt zigzag over the `seq` chips:
+        # tokens and targets alike, here and nowhere else (the loss is a
+        # mean over positions, and the step gathers the learned positions
+        # in the same order)
+        order = tfm.seq_order(self.mesh, self.axes, np.shape(tokens)[1])
+
+        def place(a):
+            a = a if isinstance(a, jax.Array) else np.asarray(a)
+            if order is not None:
+                a = a[:, order]
+            return jax.device_put(a.astype(jnp.int32), dsh)
+
+        tokens, targets = place(tokens), place(targets)
         # named on the profiler's host plane; compiles counted by key
         with compile_scope("train:hybrid"):
             self.params, self.opt_state, loss = self._step(
@@ -309,8 +320,6 @@ class HybridParallelTrainer:
     def export_params(self) -> dict:
         """Gathered host copy of the params in the standard
         `transformer.init_params` layout (for checkpointing/generation)."""
-        import numpy as np
-
         return jax.tree_util.tree_map(np.asarray, self.params)
 
 
@@ -377,8 +386,6 @@ class PipelineParallelTrainer:
             # worker persists its stage's 1/n_data slice; io moments are
             # [padded_extent_io] placed P(data).
             from deeplearning4j_tpu.parallel.partition import padded_extent
-            import numpy as np
-
             self._k0_stage = sum(
                 int(np.prod(np.shape(a)))
                 for a in jax.tree_util.tree_leaves(self.stage_params)
@@ -581,8 +588,6 @@ class PipelineParallelTrainer:
         layout: the [n_stages, layers_per_stage, ...] stacked leaves
         unstack back into the list-of-layer-dicts tree (for
         checkpointing/generation)."""
-        import numpy as np
-
         stacked = jax.tree_util.tree_map(np.asarray, self.stage_params)
         n_layers = self.cfg.n_layers
         flat = jax.tree_util.tree_map(

@@ -5,12 +5,40 @@ reference loops an LSTM over time on one device, `GravesLSTM.java:108`).
 For the TPU framework long context is first-class: the sequence dimension
 is sharded over a mesh axis, each device holds a Q/K/V block, and K/V
 blocks rotate around the ring via `lax.ppermute` while a running
-flash-attention-style (m, l, o) accumulator keeps the softmax exact —
-O(S/P) memory per device, compute overlapping communication on ICI.
+flash-attention-style accumulator keeps the softmax exact — O(S/P) memory
+per device, compute overlapping communication on ICI.
+
+The schedule is static.  The axis size is known when the ring is traced,
+so its steps are a Python loop: at step `s` a chip holds the block of chip
+`(its index - s) mod n` and works that out itself.  Nothing but K/V (and,
+in the backward, dK/dV) travels, and the permute that feeds step `s + 1`
+does not wait for step `s`'s kernel.
+
+A CAUSAL ring over n > 1 chips wants the sequence dealt zigzag
+(`zigzag_order`: 2n chunks, chip i holds chunks i and 2n-1-i; Megatron-LM
+context parallelism, zhuzilin/ring-flash-attention), so that every chip
+does the same work.  A contiguous deal gives chip 0 one block of live
+scores and chip n-1 n of them.  Dealt zigzag, with lo/hi a block's early
+and late chunk:
+
+- step 0, a chip's own K/V: one causal call on its whole block (lo < hi,
+  so the block's own mask is the causal one);
+- step s >= 1, holding chip j's block: q hi sees k lo whole, whoever j is;
+  and if j came before (j < i), q lo sees k lo whole, else q hi sees k hi
+  whole.  Every other pair of chunks is masked whole and is not computed.
+  So a remote step is two chunk-by-chunk calls without a mask, run as ONE
+  kernel call with the two stacked on the batch axis (`_half` names the
+  chunk that varies), and a chip does 1 + (n-1)/2 blocks of work whatever
+  its index.
+
+Whoever calls a causal ring deals the sequence (the trainer does, where it
+places the batch: `transformer.seq_order`).  A non-causal ring has no
+uneven work and no cases: its layout stays contiguous.
 
 Two inner-block engines:
 - `ring_attention` — plain-jnp blockwise softmax (reference formulation,
-  autodiff backward; materializes [S/P, S/P] scores per block).
+  autodiff backward; materializes [S/P, S/P] scores per block, masked by
+  the positions the layout gives).
 - `ring_flash_attention` — the Pallas flash kernels per block with a
   custom distributed VJP: the backward is a SECOND ring pass that rotates
   (K, V, dK, dV) while each device folds in its local Q/dO contribution
@@ -28,9 +56,51 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 NEG_INF = -1e30
+
+
+def zigzag_order(n: int, seq_len: int) -> np.ndarray:
+    """order[c] = the position in the sequence of column c of a row dealt
+    for a causal ring over n chips: the sequence cut into 2n chunks, chip i
+    (columns [i, i+1) * seq_len/n) holding chunks i and 2n-1-i, in that
+    order.  Deal with `x[:, order]`, undo with `x[:, np.argsort(order)]`."""
+    if seq_len % (2 * n):
+        raise ValueError(
+            f"a causal ring over {n} chips deals the sequence in {2 * n} "
+            f"chunks: sequence length {seq_len} is not a multiple of "
+            f"{2 * n}")
+    chunks = np.arange(seq_len, dtype=np.int32).reshape(2 * n, -1)
+    return np.concatenate([chunks[[i, 2 * n - 1 - i]].reshape(-1)
+                           for i in range(n)])
+
+
+def _half(idx, step):
+    """Remote step `step` of a zigzag ring on chip `idx` (an int, or the
+    traced `axis_index`): the chunk h of which the chip's own q half h sees
+    the held block's k half h whole.  0 (early) where the held block comes
+    from a chip before this one, 1 (late) where from one after."""
+    return (idx < step) * 1
+
+
+def zigzag_schedule(n: int, idx: int) -> list:
+    """What chip `idx` of a zigzag ring over n computes, step by step, as
+    (q chunk, k chunk, causal) in the 2n chunks' own numbers: the schedule
+    the traced ring follows, in plain integers for the tests."""
+    lo, hi = idx, 2 * n - 1 - idx
+    steps = [[(lo, lo, True), (hi, lo, False), (hi, hi, True)]]
+    for s in range(1, n):
+        j = (idx - s) % n
+        mine, held = (lo, hi), (j, 2 * n - 1 - j)
+        h = _half(idx, s)
+        steps.append([(mine[h], held[h], False), (hi, held[0], False)])
+    return steps
+
+
+def _ring_perm(axis_size):
+    return [(i, (i + 1) % axis_size) for i in range(axis_size)]
 
 
 def _block_attn(q, k, v, mask):
@@ -67,52 +137,52 @@ def attention(q, k, v, causal: bool = True):
 def ring_attention(q, k, v, axis_name: Optional[str], causal: bool = True):
     """Attention with the S dimension sharded over `axis_name`.
 
-    Call inside shard_map: q/k/v are the LOCAL blocks [B, S_local, H, D].
-    Requires equal S_local per device. axis_name=None falls back to the
-    dense single-device path.
+    Call inside shard_map: q/k/v are the LOCAL blocks [B, S_local, H, D],
+    dealt zigzag (`zigzag_order`) where causal.  Requires equal S_local per
+    device. axis_name=None falls back to the dense single-device path.
     """
     if axis_name is None:
         return attention(q, k, v, causal)
 
     axis_size = lax.psum(1, axis_name)
-    my_idx = lax.axis_index(axis_name)
-    s_local = q.shape[1]
-    b, _, h, dh = q.shape
+    b, s_local, h, dh = q.shape
+    perm = _ring_perm(axis_size)
 
-    # positions are global: block i covers [i*s_local, (i+1)*s_local)
-    q_pos = my_idx * s_local + jnp.arange(s_local)
+    if causal:
+        my_idx = lax.axis_index(axis_name)
+        within = jnp.arange(s_local) % (s_local // 2)
+        late = jnp.arange(s_local) >= s_local // 2
 
-    perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
+        def positions(idx):
+            """Of the rows of chip idx's block: chunks idx and 2n-1-idx."""
+            if axis_size == 1:
+                return jnp.arange(s_local)
+            chunk = jnp.where(late, 2 * axis_size - 1 - idx, idx)
+            return chunk * (s_local // 2) + within
 
-    def body(carry, _):
-        kv, kv_idx, m, l, o = carry
-        k_blk, v_blk = kv
-        k_pos = kv_idx * s_local + jnp.arange(s_local)
-        if causal:
-            mask = q_pos[:, None] >= k_pos[None, :]
-        else:
-            mask = jnp.ones((s_local, s_local), bool)
-        bm, bl, bo = _block_attn(q, k_blk, v_blk, mask)
-        new_m = jnp.maximum(m, bm)
-        # rescale both accumulators onto the new max
-        scale_old = jnp.exp(m - new_m)
-        scale_new = jnp.exp(bm - new_m)
-        l = l * scale_old + bl * scale_new
-        o = o * scale_old[..., None] + bo * scale_new[..., None]
-        # rotate KV around the ring (overlaps with next block's compute)
-        k_nxt = lax.ppermute(k_blk, axis_name, perm)
-        v_nxt = lax.ppermute(v_blk, axis_name, perm)
-        kv_idx = lax.ppermute(kv_idx, axis_name, perm)
-        return ((k_nxt, v_nxt), kv_idx, new_m, l, o), None
-
-    init = (
-        (k, v),
-        my_idx,
-        jnp.full((b, s_local, h), NEG_INF, q.dtype),
-        jnp.zeros((b, s_local, h), q.dtype),
-        jnp.zeros((b, s_local, h, dh), q.dtype),
-    )
-    (_, _, _, l, o), _ = lax.scan(body, init, None, length=axis_size)
+        q_pos = positions(my_idx)
+    m = jnp.full((b, s_local, h), NEG_INF, q.dtype)
+    l = jnp.zeros((b, s_local, h), q.dtype)
+    o = jnp.zeros((b, s_local, h, dh), q.dtype)
+    for step in range(axis_size):
+        with jax.named_scope("ring:remote" if step else "ring:local"):
+            if step:
+                # rotate KV around the ring (no kernel stands before it)
+                k = lax.ppermute(k, axis_name, perm)
+                v = lax.ppermute(v, axis_name, perm)
+            if causal:
+                k_pos = positions((my_idx - step) % axis_size)
+                mask = q_pos[:, None] >= k_pos[None, :]
+            else:
+                mask = jnp.ones((s_local, s_local), bool)
+            bm, bl, bo = _block_attn(q, k, v, mask)
+            new_m = jnp.maximum(m, bm)
+            # rescale both accumulators onto the new max
+            scale_old = jnp.exp(m - new_m)
+            scale_new = jnp.exp(bm - new_m)
+            l = l * scale_old + bl * scale_new
+            o = o * scale_old[..., None] + bo * scale_new[..., None]
+            m = new_m
     return o / jnp.maximum(l, 1e-30)[..., None]
 
 
@@ -144,56 +214,67 @@ def _flash_block_bwd(q, k, v, g, lse, delta, causal, interpret):
                          causal, interpret)
 
 
-def _ring_cases(causal, my_idx, kv_idx):
-    """0 = fully masked (skip), 1 = diagonal (causal mask), 2 = full."""
-    if not causal:
-        return jnp.int32(2)
-    return jnp.sign(my_idx - kv_idx).astype(jnp.int32) + 1
+def _halves(x):
+    """[B, S_local, ...] -> [B, 2, S_local/2, ...]: a block's two chunks."""
+    return x.reshape(x.shape[0], 2, x.shape[1] // 2, *x.shape[2:])
+
+
+def _pair(x, h, fixed):
+    """The operand of a remote step's one kernel call: the block x itself
+    where the ring has no mask (h None); else [2B, S_local/2, ...], chunk
+    h of x (h traced) stacked on chunk `fixed` of it."""
+    if h is None:
+        return x
+    xh = _halves(x)
+    return jnp.concatenate(
+        [lax.dynamic_index_in_dim(xh, h, 1, keepdims=False), xh[:, fixed]])
+
+
+def _unpair(y, h, fixed, fill=0.0):
+    """What such a call gives, as blocks [B, S_local, ...]: y itself (h
+    None); else its two results, each on its own chunk of a block that
+    holds `fill` elsewhere."""
+    if h is None:
+        return [y]
+    b = y.shape[0] // 2
+    chunk = jnp.arange(2).reshape((1, 2) + (1,) * (y.ndim - 1))
+    parts = [jnp.where(chunk == at, part[:, None], fill)
+             for at, part in ((h, y[:b]), (fixed, y[b:]))]
+    return [p.reshape(b, -1, *y.shape[2:]) for p in parts]
+
+
+def _merge(o, lse, bo, blse):
+    """lse-weighted combine of normalized outputs (numerically stable:
+    weights are exp of non-positive numbers); o is the f32 carry.  Rows a
+    block did not compute come with blse = NEG_INF and weigh nothing."""
+    new_lse = jnp.logaddexp(lse, blse)
+    w_old = jnp.exp(lse - new_lse)
+    w_new = jnp.exp(blse - new_lse)
+    return o * w_old[..., None] + bo * w_new[..., None], new_lse
 
 
 def _ring_flash_fwd_pass(q, k, v, axis_name, causal, interpret):
     axis_size = lax.psum(1, axis_name)
+    perm = _ring_perm(axis_size)
+    with jax.named_scope("ring:local"):
+        o, lse = _flash_block_fwd(q, k, v, causal, interpret)
+        # the combine weights are f32, so the running output is too
+        o = o.astype(jnp.float32)
     # Non-causal rings never branch on block position, so don't emit
     # axis_index at all: the partition-id HLO it lowers to is rejected by
     # the SPMD partitioner when XLA keeps the shard_map body outlined
-    # (observed on CPU meshes), and an unused carry doesn't DCE it.
-    my_idx = lax.axis_index(axis_name) if causal else jnp.int32(0)
-    b, s_local, h, _ = q.shape
-    perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
-
-    def body(carry, _):
-        k_blk, v_blk, kv_idx, o, lse = carry
-
-        def skip(_):
-            return jnp.zeros_like(q), jnp.full_like(lse, NEG_INF)
-
-        def diag(_):
-            return _flash_block_fwd(q, k_blk, v_blk, True, interpret)
-
-        def full(_):
-            return _flash_block_fwd(q, k_blk, v_blk, False, interpret)
-
-        if causal:
-            bo, blse = lax.switch(_ring_cases(causal, my_idx, kv_idx),
-                                  [skip, diag, full], None)
-        else:
-            bo, blse = full(None)
-        # lse-weighted combine of normalized outputs (numerically stable:
-        # weights are exp of non-positive numbers).
-        new_lse = jnp.logaddexp(lse, blse)
-        w_old = jnp.exp(lse - new_lse)
-        w_new = jnp.exp(blse - new_lse)
-        o = o * w_old[..., None] + bo * w_new[..., None]   # f32 carry
-        k_n = lax.ppermute(k_blk, axis_name, perm)
-        v_n = lax.ppermute(v_blk, axis_name, perm)
-        i_n = lax.ppermute(kv_idx, axis_name, perm)
-        return (k_n, v_n, i_n, o, new_lse), None
-
-    # the combine weights are f32, so the running output is too: a
-    # q.dtype carry would change type across the scan for bf16 inputs
-    init = (k, v, my_idx, jnp.zeros(q.shape, jnp.float32),
-            jnp.full((b, s_local, h), NEG_INF, jnp.float32))
-    (_, _, _, o, lse), _ = lax.scan(body, init, None, length=axis_size)
+    # (observed on CPU meshes), and an unused value doesn't DCE it.
+    my_idx = lax.axis_index(axis_name) if causal and axis_size > 1 else None
+    for step in range(1, axis_size):
+        with jax.named_scope("ring:remote"):
+            k = lax.ppermute(k, axis_name, perm)
+            v = lax.ppermute(v, axis_name, perm)
+            h = _half(my_idx, step) if causal else None
+            bo, blse = _flash_block_fwd(_pair(q, h, 1), _pair(k, h, 0),
+                                        _pair(v, h, 0), False, interpret)
+            for part, plse in zip(_unpair(bo, h, 1),
+                                  _unpair(blse, h, 1, NEG_INF)):
+                o, lse = _merge(o, lse, part, plse)
     return o.astype(q.dtype), lse
 
 
@@ -204,8 +285,8 @@ def ring_flash_attention(q, k, v, axis_name: Optional[str],
     """Ring attention with the Pallas flash kernels as the inner block.
 
     Call inside shard_map with q/k/v the LOCAL sequence blocks
-    [B, S_local, H, D]. axis_name=None falls back to the single-device
-    flash kernel.
+    [B, S_local, H, D], dealt zigzag (`zigzag_order`) where causal.
+    axis_name=None falls back to the single-device flash kernel.
     """
     from deeplearning4j_tpu.parallel import kernels as _k
 
@@ -241,42 +322,31 @@ def _rfa_bwd(axis_name, causal, interpret, residuals, g):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     axis_size = lax.psum(1, axis_name)
-    my_idx = lax.axis_index(axis_name)
-    perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
+    perm = _ring_perm(axis_size)
+    rot = lambda x: lax.ppermute(x, axis_name, perm)  # noqa: E731
     # Global softmax-jacobian row correction, once per backward.
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
-    def body(carry, _):
-        k_blk, v_blk, dk_blk, dv_blk, kv_idx, dq = carry
-        zeros = (jnp.zeros_like(q), jnp.zeros_like(k_blk),
-                 jnp.zeros_like(v_blk))
-
-        def skip(_):
-            return zeros
-
-        def diag(_):
-            return _flash_block_bwd(q, k_blk, v_blk, g, lse, delta, True,
-                                    interpret)
-
-        def full(_):
-            return _flash_block_bwd(q, k_blk, v_blk, g, lse, delta, False,
-                                    interpret)
-
-        dqc, dkc, dvc = lax.switch(_ring_cases(causal, my_idx, kv_idx),
-                                   [skip, diag, full], None)
-        # dq accumulates locally; dK/dV accumulate ON the rotating block,
-        # so after a full circle each block carries every device's
-        # contribution and is back home.
-        dq = dq + dqc
-        dk_blk = dk_blk + dkc
-        dv_blk = dv_blk + dvc
-        rot = lambda x: lax.ppermute(x, axis_name, perm)  # noqa: E731
-        return (rot(k_blk), rot(v_blk), rot(dk_blk), rot(dv_blk),
-                rot(kv_idx), dq), None
-
-    init = (k, v, jnp.zeros_like(k), jnp.zeros_like(v), my_idx,
-            jnp.zeros_like(q))
-    (_, _, dk, dv, _, dq), _ = lax.scan(body, init, None, length=axis_size)
+    # dq accumulates locally; dK/dV accumulate ON the rotating block, so
+    # after a full circle each block carries every device's contribution
+    # and is back home.  K and V themselves stop one hop short.
+    with jax.named_scope("ring:local"):
+        dq, dk, dv = _flash_block_bwd(q, k, v, g, lse, delta, causal,
+                                      interpret)
+    my_idx = lax.axis_index(axis_name) if causal and axis_size > 1 else None
+    for step in range(1, axis_size):
+        with jax.named_scope("ring:remote"):
+            k, v, dk, dv = rot(k), rot(v), rot(dk), rot(dv)
+            h = _half(my_idx, step) if causal else None
+            dqc, dkc, dvc = _flash_block_bwd(
+                _pair(q, h, 1), _pair(k, h, 0), _pair(v, h, 0),
+                _pair(g, h, 1), _pair(lse, h, 1), _pair(delta, h, 1),
+                False, interpret)
+            dq = sum(_unpair(dqc, h, 1), dq)
+            dk = sum(_unpair(dkc, h, 0), dk)
+            dv = sum(_unpair(dvc, h, 0), dv)
+    if axis_size > 1:
+        with jax.named_scope("ring:remote"):
+            dk, dv = rot(dk), rot(dv)
     return dq, dk, dv
 
 

@@ -16,7 +16,9 @@ split between the two JAX mechanisms:
   algorithm: exact long-context attention needs the ring schedule. That
   inner function — and only it — runs under `shard_map`
   (`ring_attention.py`), whose ppermute transpose is exact, so `jax.grad`
-  taken OUTSIDE the shard_map stays correct.
+  taken OUTSIDE the shard_map stays correct.  A causal ring wants a row's
+  columns dealt zigzag over the chips (`seq_order`): `apply` and `lm_loss`
+  deal them, or take them dealt from a trainer that placed them so.
 
 `apply(cfg, params, tokens)` with mesh=None is the identical single-chip
 model; tests assert step-for-step equivalence between the two.
@@ -36,7 +38,11 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import shard_map
-from deeplearning4j_tpu.parallel.ring_attention import attention, ring_attention
+from deeplearning4j_tpu.parallel.ring_attention import (
+    attention,
+    ring_attention,
+    zigzag_order,
+)
 
 
 class UnsupportedLayerKind(ValueError):
@@ -555,9 +561,21 @@ def out_proj(p, o):
     return out
 
 
+def seq_order(mesh: Optional[Mesh], axes: MeshAxes, seq_len: int,
+              causal: bool = True) -> Optional[np.ndarray]:
+    """The order a mesh's attention wants a row's columns in: order[c] is
+    the position in the sequence that column c holds.  None is the natural
+    order: one device, a `seq` axis of one chip, or a ring without a mask.
+    A causal ring over more chips is dealt zigzag, so that each does the
+    same work (`ring_attention.zigzag_order`)."""
+    n = 1 if mesh is None else mesh.shape.get(axes.seq, 1)
+    return zigzag_order(n, seq_len) if causal and n > 1 else None
+
+
 def _attn(p, x, mesh: Optional[Mesh], axes: MeshAxes, causal: bool):
     """x:[B,S,d] full arrays. Ring attention under shard_map when a mesh is
-    given (seq axis shards S); plain attention otherwise."""
+    given (seq axis shards S, its columns in `seq_order`); plain attention
+    otherwise."""
     q, k, v = qkv_proj(p, x)
     if mesh is None:
         from deeplearning4j_tpu.parallel import kernels
@@ -623,7 +641,8 @@ def _moe_dense(p, x, top_k: int = 1):
 
 def _moe_dispatch(p, x, capacity_factor: float,
                   mesh: Optional[Mesh] = None,
-                  axes: MeshAxes = MeshAxes(), top_k: int = 1):
+                  axes: MeshAxes = MeshAxes(), top_k: int = 1,
+                  order: Optional[np.ndarray] = None):
     """Capacity-based top-k dispatch (Switch routing at k=1, GShard-style
     top-2 at k=2; Switch Transformer, Fedus et al. 2021 / GShard, Lepikhin
     et al. 2020 — public formulations): the N*k (token, expert)
@@ -634,7 +653,10 @@ def _moe_dispatch(p, x, capacity_factor: float,
     capacity factor, NOT with n_experts.  Assignments past an expert's
     capacity (token-major priority: a token's second choice ranks after
     its first) contribute nothing — identity via the surrounding
-    residual, the standard drop rule.
+    residual, the standard drop rule.  Tokens rank by their position in
+    the sequence: where a row's columns are dealt (`order`, as
+    `seq_order` gives it), the ranks are counted in the natural order, so
+    that a mesh drops what one device drops.
 
     Static shapes throughout (scatter/gather via `.at[]` / advanced
     indexing), so the routing is jit/GSPMD-clean; with a mesh the buffer
@@ -654,7 +676,12 @@ def _moe_dispatch(p, x, capacity_factor: float,
     onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)
     # 0-based slot of each assignment within its expert's buffer
     # (token-major priority), C and above = overflow.
+    if order is not None:
+        onehot = onehot.reshape(B, S, top_k, E)[:, np.argsort(order)]
+        onehot = onehot.reshape(A, E)
     slot = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1
+    if order is not None:
+        slot = slot.reshape(B, S, top_k)[:, order].reshape(A)
     keep = (slot < C).astype(x.dtype)                          # [A]
     slot = jnp.clip(slot, 0, C - 1)
     x_rep = jnp.repeat(xf, top_k, axis=0)                      # [A, d]
@@ -780,7 +807,7 @@ def _routed_experts(ex: RoutedExperts, p, x, valid=None):
 
 def _moe(p, x, capacity_factor: float = 0.0,
          mesh: Optional[Mesh] = None, axes: MeshAxes = MeshAxes(),
-         top_k: int = 1):
+         top_k: int = 1, order: Optional[np.ndarray] = None):
     """The Switch / GShard expert block.  Three paths compute an expert
     layer in this module, and which one runs is decided here and in
     `feed_forward`:
@@ -795,7 +822,8 @@ def _moe(p, x, capacity_factor: float = 0.0,
     - `_moe_dense`, every expert on every token: the ORACLE the two are
       tested against; nothing serves through it."""
     if capacity_factor > 0:
-        return _moe_dispatch(p, x, capacity_factor, mesh, axes, top_k)
+        return _moe_dispatch(p, x, capacity_factor, mesh, axes, top_k,
+                             order)
     if mesh is None:
         return _moe_dropless(p, x, top_k)
     return _moe_dense(p, x, top_k)
@@ -850,6 +878,12 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
           return_aux: bool = False):
     """tokens:[B,S] int32 -> logits [B,S,V]. Pass mesh to parallelize.
 
+    Tokens and logits are in the natural order whatever the mesh: where
+    its attention wants the columns dealt (`seq_order`) they are dealt
+    here and the logits put back, which moves [B,S,V] between the chips.
+    A training step deals its batch itself and never undoes it
+    (`lm_loss`).
+
     MoE routing: `train=True` (the lm_loss path) uses capacity-based
     dispatch — FLOP-saving but drops overflow tokens, so logits can
     depend on batch composition.  At inference on one device the
@@ -860,6 +894,20 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     `RoutedExperts` run here whole-sequence on one device, at inference
     (the path the tests hold the paged one against); they do not train
     and do not shard."""
+    order = seq_order(mesh, axes, tokens.shape[1], causal)
+    if order is None:
+        return _apply_dealt(cfg, params, tokens, None, mesh, axes, causal,
+                            train, return_aux)
+    logits, aux = _apply_dealt(cfg, params, tokens[:, order], order, mesh,
+                               axes, causal, train, True)
+    logits = logits[:, np.argsort(order)]
+    return (logits, aux) if return_aux else logits
+
+
+def _apply_dealt(cfg: TransformerConfig, params: dict, tokens, order, mesh,
+                 axes, causal: bool, train: bool, return_aux: bool):
+    """`apply` on a row whose column c holds position `order[c]` (None:
+    the natural order); the logits stay in the columns' order."""
     if not cfg.classic and (mesh is not None or train):
         require_classic(cfg, "apply(train=True) / apply(mesh=...)")
 
@@ -883,7 +931,8 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
             if "moe" not in layer:
                 return feed_forward(cfg, layer, h)
             aux[0] = _moe_aux_loss(layer["moe"], h)
-            return _moe(layer["moe"], h, cf, mesh, axes, cfg.moe_top_k)
+            return _moe(layer["moe"], h, cf, mesh, axes, cfg.moe_top_k,
+                        order)
 
         return block(cfg, layer, x, attend, ffn, constrain), aux[0]
 
@@ -891,7 +940,8 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         one = jax.checkpoint(one)
     x = params["embed"][tokens]
     if cfg.rope is None:
-        x = x + params["pos"][None, :tokens.shape[1], :]
+        pos = params["pos"]
+        x = x + (pos[:tokens.shape[1]] if order is None else pos[order])
     x = constrain(x)
     auxs = []
     for layer in params["layers"]:
@@ -906,13 +956,21 @@ def apply(cfg: TransformerConfig, params: dict, tokens: jax.Array,
 
 def lm_loss(cfg: TransformerConfig, params: dict, tokens: jax.Array,
             targets: jax.Array, mesh: Optional[Mesh] = None,
-            axes: MeshAxes = MeshAxes()) -> jax.Array:
+            axes: MeshAxes = MeshAxes(), dealt: bool = False) -> jax.Array:
     """Mean next-token cross-entropy over the full batch (training mode:
     MoE layers route with capacity-based dispatch + the Switch
-    load-balancing auxiliary loss weighted by cfg.moe_aux_weight)."""
+    load-balancing auxiliary loss weighted by cfg.moe_aux_weight).
+
+    The loss is a mean over positions, so where the mesh wants a row's
+    columns dealt (`seq_order`) tokens and targets are dealt alike and
+    nothing is put back.  `dealt`: the caller has done so already (the
+    trainer, on the host, where it places the batch)."""
     use_aux = bool(cfg.n_experts) and cfg.moe_aux_weight > 0
-    out = apply(cfg, params, tokens, mesh, axes, train=True,
-                return_aux=use_aux)
+    order = seq_order(mesh, axes, tokens.shape[1])
+    if order is not None and not dealt:
+        tokens, targets = tokens[:, order], targets[:, order]
+    out = _apply_dealt(cfg, params, tokens, order, mesh, axes, True, True,
+                       use_aux)
     logits, aux = out if use_aux else (out, None)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

@@ -309,12 +309,14 @@ def test_lm_mesh_layout_factorization():
                                       n_layers=4, B=8)
         dp, sp, tp = shape
         assert dp * sp * tp <= n and B % dp == 0
-        assert 16 % sp == 0 and 4 % tp == 0
+        assert 16 % (2 * sp) == 0 and 4 % tp == 0
         shape, B, mb = _lm_mesh_layout("pipeline", n, S=16, n_heads=4,
                                        n_layers=4, B=8)
         dp, stages = shape
         assert dp * stages <= n and 4 % stages == 0
         assert B % dp == 0 and (B // dp) % mb == 0
+    # a length the zigzag ring cannot deal (2 * sp chunks) keeps sp at 1
+    assert _lm_mesh_layout("hybrid", 4, 18, 4, 4, 8)[0] == (2, 1, 2)
     # n=1 degrades to the trivial mesh for both
     assert _lm_mesh_layout("hybrid", 1, 16, 4, 4, 8)[0] == (1, 1, 1)
     assert _lm_mesh_layout("pipeline", 1, 16, 4, 4, 8)[0] == (1, 1)

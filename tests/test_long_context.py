@@ -22,6 +22,7 @@ from deeplearning4j_tpu.parallel.ring_attention import (
     attention,
     ring_attention,
     ring_flash_attention,
+    zigzag_order,
 )
 from jax.sharding import PartitionSpec as P
 
@@ -42,10 +43,17 @@ def mesh():
     return make_mesh((N_DEV,), ("seq",), devices=jax.devices()[:N_DEV])
 
 
-def _ring(fn, mesh_, **kw):
-    return shard_map(
-        lambda q, k, v: fn(q, k, v, "seq", **kw), mesh=mesh_,
+def _ring(fn, mesh_, causal):
+    """Natural order in and out: a causal ring's rows are dealt zigzag
+    over the chips on the way in (`zigzag_order`) and put back after."""
+    ring = shard_map(
+        lambda q, k, v: fn(q, k, v, "seq", causal=causal), mesh=mesh_,
         in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"))
+    if not causal:
+        return ring
+    order = zigzag_order(N_DEV, S)
+    return lambda q, k, v: ring(q[:, order], k[:, order], v[:, order])[
+        :, np.argsort(order)]
 
 
 class TestRingAtScale:

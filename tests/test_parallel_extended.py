@@ -21,6 +21,8 @@ from deeplearning4j_tpu.parallel.ring_attention import (
     attention,
     ring_attention,
     ring_flash_attention,
+    zigzag_order,
+    zigzag_schedule,
 )
 from deeplearning4j_tpu.parallel.mesh import shard_map
 from jax.sharding import PartitionSpec as P
@@ -30,47 +32,53 @@ def _all_devices(n):
     return jax.devices()[:n]
 
 
+def _ring_on_mesh(fn, n, causal):
+    """`fn` as a ring over `n` host devices, natural order in and out: a
+    causal ring's rows are dealt zigzag on the way in and put back on the
+    way out, as the trainer deals a batch."""
+    mesh = make_mesh((n,), ("seq",), devices=_all_devices(n))
+    ring = shard_map(
+        lambda q, k, v: fn(q, k, v, "seq", causal=causal), mesh=mesh,
+        in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"))
+    if not causal:
+        return ring
+
+    def dealt(q, k, v):
+        order = zigzag_order(n, q.shape[1])
+        return ring(q[:, order], k[:, order], v[:, order])[
+            :, np.argsort(order)]
+
+    return dealt
+
+
+def _qkv(seed, b, s, h, d):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+                 for _ in range(3))
+
+
+def _weighted(out):
+    # a different weight a position, so that a row put back in the wrong
+    # place shows in the loss and in every gradient
+    return jnp.sum(out ** 2 * (1 + jnp.arange(out.shape[1]))[:, None, None])
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_dense_attention(self, causal):
-        mesh = make_mesh((4,), ("seq",), devices=_all_devices(4))
-        rng = np.random.default_rng(0)
-        b, s, h, d = 2, 16, 2, 8
-        q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
-                               jnp.float32) for _ in range(3))
-
+        q, k, v = _qkv(0, 2, 16, 2, 8)
         expected = attention(q, k, v, causal=causal)
-
-        ring = shard_map(
-            lambda q, k, v: ring_attention(q, k, v, "seq", causal=causal),
-            mesh=mesh,
-            in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq")),
-            out_specs=P(None, "seq"))
-        got = jax.jit(ring)(q, k, v)
+        got = jax.jit(_ring_on_mesh(ring_attention, 4, causal))(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                    atol=2e-5)
 
     def test_grads_match_dense(self):
-        mesh = make_mesh((4,), ("seq",), devices=_all_devices(4))
-        rng = np.random.default_rng(1)
-        b, s, h, d = 1, 8, 2, 4
-        q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
-                               jnp.float32) for _ in range(3))
-
-        def dense_loss(q, k, v):
-            return jnp.sum(attention(q, k, v, causal=True) ** 2)
-
-        ring = shard_map(
-            lambda q, k, v: ring_attention(q, k, v, "seq", causal=True),
-            mesh=mesh,
-            in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"))
-
-        def ring_loss(q, k, v):
-            return jnp.sum(ring(q, k, v) ** 2)
-
-        ge = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+        q, k, v = _qkv(1, 1, 8, 2, 4)
+        ring = _ring_on_mesh(ring_attention, 4, True)
+        ge = jax.grad(lambda q, k, v: jnp.sum(
+            attention(q, k, v, causal=True) ** 2), (0, 1, 2))(q, k, v)
+        gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            ring(q, k, v) ** 2), (0, 1, 2)))(q, k, v)
         for a, b_ in zip(gr, ge):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        atol=2e-4)
@@ -82,37 +90,17 @@ class TestRingFlashAttention:
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_dense_attention(self, causal):
-        mesh = make_mesh((4,), ("seq",), devices=_all_devices(4))
-        rng = np.random.default_rng(2)
-        b, s, h, d = 2, 16, 2, 8
-        q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
-                               jnp.float32) for _ in range(3))
+        q, k, v = _qkv(2, 2, 16, 2, 8)
         expected = attention(q, k, v, causal=causal)
-        ring = shard_map(
-            lambda q, k, v: ring_flash_attention(q, k, v, "seq",
-                                                 causal=causal),
-            mesh=mesh,
-            in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"))
-        got = jax.jit(ring)(q, k, v)
+        got = jax.jit(_ring_on_mesh(ring_flash_attention, 4, causal))(
+            q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                    atol=2e-5)
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_ring_backward_matches_dense(self, causal):
-        mesh = make_mesh((4,), ("seq",), devices=_all_devices(4))
-        rng = np.random.default_rng(3)
-        b, s, h, d = 1, 16, 2, 4
-        q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
-                               jnp.float32) for _ in range(3))
-
-        ring = shard_map(
-            lambda q, k, v: ring_flash_attention(q, k, v, "seq",
-                                                 causal=causal),
-            mesh=mesh,
-            in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"))
-
+        q, k, v = _qkv(3, 1, 16, 2, 4)
+        ring = _ring_on_mesh(ring_flash_attention, 4, causal)
         ge = jax.grad(lambda q, k, v: jnp.sum(
             attention(q, k, v, causal=causal) ** 2), (0, 1, 2))(q, k, v)
         gr = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
@@ -122,13 +110,108 @@ class TestRingFlashAttention:
                                        atol=2e-4)
 
     def test_axis_none_is_single_device_flash(self):
-        rng = np.random.default_rng(4)
-        q, k, v = (jnp.asarray(rng.standard_normal((2, 16, 2, 8)),
-                               jnp.float32) for _ in range(3))
+        q, k, v = _qkv(4, 2, 16, 2, 8)
         got = ring_flash_attention(q, k, v, None, causal=True)
         want = attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-6)
+
+
+# the ring's layout follows from `causal` and the axis size alone (ISSUE
+# 36): causal over 2, 4 and 8 chips is dealt zigzag, without a mask the
+# layout stays contiguous
+RING_LAYOUTS = [(True, 2), (True, 4), (True, 8), (False, 2)]
+RING_ENGINES = {"plain": ring_attention, "flash": ring_flash_attention}
+
+
+@pytest.mark.parametrize("causal,n", RING_LAYOUTS)
+@pytest.mark.parametrize("engine", sorted(RING_ENGINES))
+class TestRingSchedule:
+    """Both engines against dense attention on one device, at every
+    layout the code derives."""
+
+    def test_forward(self, engine, causal, n):
+        q, k, v = _qkv(10 + n, 2, 32, 2, 8)
+        got = jax.jit(_ring_on_mesh(RING_ENGINES[engine], n, causal))(
+            q, k, v)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(attention(q, k, v, causal=causal)),
+            atol=2e-5)
+
+    def test_gradients(self, engine, causal, n):
+        q, k, v = _qkv(20 + n, 1, 32, 2, 4)
+        ring = _ring_on_mesh(RING_ENGINES[engine], n, causal)
+        want = jax.grad(lambda q, k, v: _weighted(
+            attention(q, k, v, causal=causal)), (0, 1, 2))(q, k, v)
+        got = jax.jit(jax.grad(lambda q, k, v: _weighted(ring(q, k, v)),
+                               (0, 1, 2)))(q, k, v)
+        for a, b_ in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=2e-3, rtol=1e-4)
+
+
+class TestZigzagOrder:
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    def test_is_a_permutation_with_chunks_i_and_2n_1_i_on_chip_i(self, n):
+        s = 8 * n
+        order = zigzag_order(n, s)
+        assert sorted(order.tolist()) == list(range(s))
+        c = s // (2 * n)
+        for i in range(n):
+            chip = order[i * 2 * c:(i + 1) * 2 * c]
+            assert chip[:c].tolist() == list(range(i * c, (i + 1) * c))
+            late = 2 * n - 1 - i
+            assert chip[c:].tolist() == list(range(late * c, (late + 1) * c))
+
+    def test_indivisible_length_raises_with_the_numbers(self):
+        with pytest.raises(ValueError, match=r"4 chips.*8.*chunks.*20"):
+            zigzag_order(4, 20)
+
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    def test_every_chip_does_the_same_work_and_all_of_it(self, n):
+        """Each chip: one step on its own block, then n-1 remote steps of
+        two unmasked chunk pairs; together exactly the pairs of chunks
+        that the causal mask leaves live, each once."""
+        seen = set()
+        for i in range(n):
+            steps = zigzag_schedule(n, i)
+            assert len(steps) == n
+            assert [c for _, _, c in steps[0]] == [True, False, True]
+            assert all(len(st) == 2 and not any(c for _, _, c in st)
+                       for st in steps[1:])
+            pairs = [(qc, kc) for st in steps for qc, kc, _ in st]
+            assert {qc for qc, _ in pairs} <= {i, 2 * n - 1 - i}
+            assert all((qc == kc) == c for st in steps for qc, kc, c in st)
+            assert not seen & set(pairs) and len(set(pairs)) == len(pairs)
+            seen |= set(pairs)
+        assert seen == {(qc, kc) for qc in range(2 * n)
+                        for kc in range(qc + 1)}
+
+
+@pytest.mark.parametrize("engine", sorted(RING_ENGINES))
+def test_causal_ring_permutes_no_scalar(engine):
+    """The schedule is computed, not received: forward and backward of a
+    causal ring over 2 chips send K/V (and dK/dV) round and nothing else,
+    no block index among them."""
+    import re
+
+    q = jnp.zeros((1, 16, 2, 8), jnp.float32)
+    mesh = make_mesh((2,), ("seq",), devices=_all_devices(2))
+    ring = shard_map(
+        lambda q, k, v: RING_ENGINES[engine](q, k, v, "seq", causal=True),
+        mesh=mesh, in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"))
+    lowered = jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(ring(q, k, v) ** 2), (0, 1, 2))).lower(
+            q, q, q)
+    sent = [line for line in lowered.as_text().splitlines()
+            if "collective_permute" in line or "ppermute" in line]
+    assert sent
+    operands = [re.findall(r"tensor<([^>]*)>", line) for line in sent]
+    assert all(types and all("x" in t for t in types)
+               for types in operands), operands
+    hlo = lowered.compile().as_text()
+    moved = re.findall(r"= (\S+) collective-permute(?:-start)?\(", hlo)
+    assert moved and not [t for t in moved if "[]" in t], moved
 
 
 class TestMoEDispatch:
@@ -456,6 +539,74 @@ class TestHybridParallelTrainer:
         flat_w = jax.tree_util.tree_leaves(want)
         for a, b_ in zip(flat_g, flat_w):
             np.testing.assert_allclose(a, b_, atol=5e-4)
+
+    def test_seq_2_first_step_is_one_device_lm_loss(self):
+        """The trainer deals each row zigzag over the two `seq` chips and
+        gathers the learned positions in the same order: loss and first
+        gradients (read off one SGD step) are the one-device `lm_loss`'s
+        on the batch as given, `pos` among them and in natural order."""
+        cfg = tfm.TransformerConfig(vocab_size=53, d_model=16, n_heads=2,
+                                    n_layers=2, d_ff=32, max_len=24)
+        mesh = make_mesh((1, 2, 1), ("data", "seq", "model"),
+                         devices=_all_devices(2))
+        rng = np.random.default_rng(12)
+        tokens = rng.integers(0, cfg.vocab_size, (2, 16))
+        targets = rng.integers(0, cfg.vocab_size, (2, 16))
+        params = tfm.init_params(cfg, jax.random.PRNGKey(5))
+        lr = 0.5
+        trainer = HybridParallelTrainer(cfg, mesh, lr=lr, params=params)
+        loss = trainer.fit_batch(tokens, targets)
+        want_loss, want = jax.value_and_grad(lambda p: tfm.lm_loss(
+            cfg, p, jnp.asarray(tokens), jnp.asarray(targets)))(params)
+        np.testing.assert_allclose(loss, float(want_loss), atol=1e-5)
+        after = trainer.export_params()
+        got = jax.tree_util.tree_map(
+            lambda a, b: (np.asarray(a) - b) / lr, params, after)
+        for (path, g), w in zip(
+                jax.tree_util.tree_flatten_with_path(got)[0],
+                jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(g, np.asarray(w), atol=2e-5,
+                                       err_msg=str(path))
+        # a row of `pos` moves by its own position's gradient, and the
+        # rows past the batch's length not at all
+        assert np.abs(np.asarray(want["pos"])[:16]).min(axis=1).max() > 0
+        np.testing.assert_allclose(
+            after["pos"], np.asarray(params["pos"] - lr * want["pos"]),
+            atol=1e-5)
+        np.testing.assert_array_equal(after["pos"][16:],
+                                      np.asarray(params["pos"])[16:])
+
+    def test_apply_and_lm_loss_deal_for_themselves_on_a_seq_mesh(self):
+        """Off the trainer the batch arrives in natural order: `lm_loss`
+        deals it, `apply` deals it and puts the logits back."""
+        cfg = tfm.TransformerConfig(vocab_size=53, d_model=16, n_heads=2,
+                                    n_layers=1, d_ff=32, max_len=16)
+        mesh = make_mesh((1, 4, 1), ("data", "seq", "model"),
+                         devices=_all_devices(4))
+        rng = np.random.default_rng(13)
+        tokens = jnp.asarray(rng.integers(0, 53, (2, 16)), jnp.int32)
+        targets = jnp.asarray(rng.integers(0, 53, (2, 16)), jnp.int32)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(6))
+        np.testing.assert_allclose(
+            np.asarray(jax.jit(lambda p, t: tfm.apply(cfg, p, t, mesh))(
+                params, tokens)),
+            np.asarray(tfm.apply(cfg, params, tokens)), atol=2e-5)
+        np.testing.assert_allclose(
+            float(jax.jit(lambda p: tfm.lm_loss(
+                cfg, p, tokens, targets, mesh))(params)),
+            float(tfm.lm_loss(cfg, params, tokens, targets)), atol=1e-5)
+        assert tfm.seq_order(mesh, tfm.MeshAxes(), 16, causal=False) is None
+        assert tfm.seq_order(None, tfm.MeshAxes(), 16) is None
+
+    def test_length_the_ring_cannot_deal_raises(self):
+        cfg = tfm.TransformerConfig(vocab_size=31, d_model=16, n_heads=2,
+                                    n_layers=1, d_ff=32, max_len=16)
+        mesh = make_mesh((1, 2, 1), ("data", "seq", "model"),
+                         devices=_all_devices(2))
+        trainer = HybridParallelTrainer(cfg, mesh)
+        with pytest.raises(ValueError, match=r"2 chips.*4 chunks.*6"):
+            trainer.fit_batch(np.zeros((2, 6), np.int32),
+                              np.zeros((2, 6), np.int32))
 
     @pytest.mark.slow  # ~6s; the single-device A/B above is the
     # stronger hybrid-trainer gate and stays in tier-1

@@ -1,5 +1,8 @@
 """Ring-flash long-context evidence: S=4096 sharded 8 ways on the
-virtual CPU mesh, fwd+bwd vs the dense oracle, with wall timings.
+virtual CPU mesh, fwd+bwd vs the dense oracle, with wall timings.  The
+ring is causal, so each row is dealt zigzag over the 8 devices on the way
+in (`zigzag_order`: device i holds chunks i and 15-i of 16) and put back
+on the way out; the oracle sees the natural order.
 
 Proves the SURVEY §5 long-context extension at a length where blocking
 and ring scheduling actually engage (the 2015 reference's long-sequence
@@ -21,7 +24,15 @@ from deeplearning4j_tpu.parallel.mesh import shard_map  # noqa: E402
 from deeplearning4j_tpu.parallel.ring_attention import (  # noqa: E402
     attention,
     ring_flash_attention,
+    zigzag_order,
 )
+
+
+def dealt(ring, order):
+    """`ring` on rows in the natural order: dealt in, put back out."""
+    back = np.argsort(order)
+    return lambda q, k, v: ring(q[:, order], k[:, order], v[:, order])[
+        :, back]
 
 
 def main() -> None:
@@ -33,10 +44,10 @@ def main() -> None:
     q, k, v = (jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
                for _ in range(3))
     mesh = make_mesh((N,), ("seq",), devices=jax.devices()[:N])
-    ring = shard_map(
+    ring = dealt(shard_map(
         lambda q, k, v: ring_flash_attention(q, k, v, "seq", causal=True),
         mesh=mesh, in_specs=(P(None, "seq"),) * 3,
-        out_specs=P(None, "seq"))
+        out_specs=P(None, "seq")), zigzag_order(N, S))
 
     def loss_ring(q, k, v):
         return jnp.sum(ring(q, k, v) ** 2)
@@ -68,7 +79,9 @@ def main() -> None:
     # Leg 2: S=16384 — the bench_longctx length.  A global dense oracle
     # would materialize [16384, 16384] scores, so the reference here is
     # the ring schedule with the DENSE per-hop inner (exact blockwise
-    # softmax-merge), which the flash inner must match.
+    # softmax-merge), which the flash inner must match.  Both engines
+    # take the same dealt rows, so nothing is dealt or put back here:
+    # the random rows ARE the dealt ones.
     from deeplearning4j_tpu.parallel.ring_attention import ring_attention
 
     S2 = 16384

@@ -16,12 +16,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.parallel import kda
 from deeplearning4j_tpu.parallel.kernels import mask_value
 from deeplearning4j_tpu.parallel.paged_kernel import (
     latent_paged_attention,
@@ -30,6 +32,8 @@ from deeplearning4j_tpu.parallel.paged_kernel import (
 )
 from deeplearning4j_tpu.parallel.transformer import (
     TransformerConfig,
+    UnsupportedLayerKind,
+    attn_gated,
     block,
     embed_tokens,
     feed_forward,
@@ -259,23 +263,98 @@ class PoolLayout:
 
 
 def pool_layout(cfg: TransformerConfig) -> PoolLayout:
-    """Full heads: a key pool and a value pool, a row `[H, K]`.  Latent
+    """Full heads: a key pool and a value pool, a row `[Hkv, K]` (the
+    K/V heads: all of them unless the queries are grouped).  Latent
     attention: ONE pool, a row `[c_kv | k_rope]` for all heads, key and
     value at once (576 values for DeepSeek-V2), held in whole 128-lane
     tiles (640 lanes: the device pads the minor dim to them whatever is
     declared, and the kernel's DMA takes whole tiles), the tail zero."""
     if cfg.latent is None:
-        return PoolLayout(("k", "v"), cfg.n_heads, cfg.head_dim)
+        return PoolLayout(("k", "v"), cfg.n_kv_heads, cfg.head_dim)
     values = cfg.latent.row_values
     return PoolLayout(("kv",), 1,
                       -(-values // 128) * 128 if values > 128 else values)
 
 
+def pool_layers(cfg: TransformerConfig) -> Tuple[Optional[int], ...]:
+    """Layer -> its index in the paged pool: the pool is as deep as the
+    model has FULL layers, and a recurrent layer has no pages (None)."""
+    out, n = [], 0
+    for kind in cfg.mixer_kinds():
+        out.append(n if kind == "full" else None)
+        n += kind == "full"
+    return tuple(out)
+
+
+def pool_depth(cfg: TransformerConfig) -> int:
+    """How many layers have pages: the FULL ones."""
+    return cfg.mixer_kinds().count("full")
+
+
 def pool_token_bytes(cfg: TransformerConfig) -> int:
-    """Bytes the pool holds a cached token, all layers."""
+    """Bytes the pool holds a cached token, all layers that have pages."""
     lay = pool_layout(cfg)
-    return (len(lay.names) * cfg.n_layers * lay.row
+    return (len(lay.names) * pool_depth(cfg) * lay.row
             * jnp.dtype(cfg.dtype).itemsize)
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLayout:
+    """What a recurrent configuration keeps a SEQUENCE (not a token):
+    `state [n, rows, H, K, V]` float32 and `tail [n, rows, (taps - 1) *
+    H * (2K + V)]` (the convolutions' last inputs, one lane-dense vector
+    a row: with `[taps - 1, channels]` as the minor dims the TPU tiles 3
+    rows as 4 or 8 and converts the whole pool between the two around
+    every scatter, 2.9 ms a round at 257 rows; my chip run, PR 38), `n`
+    the recurrent layers, addressed by a row id a lane; row 0 is the null
+    row idle lanes carry.  Live lanes and snapshots are rows of the same
+    arrays."""
+
+    names: Tuple[str, ...]
+    depth: int
+    state: Tuple[int, ...]      # a row of `state`, one layer
+    tail: Tuple[int, ...]       # a row of `tail`, one layer
+
+
+def state_layout(cfg: TransformerConfig) -> Optional[StateLayout]:
+    """None where no layer is recurrent."""
+    if not cfg.recurrent:
+        return None
+    la = cfg.linear
+    return StateLayout(
+        ("state", "tail"), cfg.mixer_kinds().count("kda"),
+        (la.heads, la.k_dim, la.v_dim),
+        ((la.conv_taps - 1) * la.heads * (2 * la.k_dim + la.v_dim),))
+
+
+def state_row_bytes(cfg: TransformerConfig) -> int:
+    """Bytes one state row holds, all recurrent layers: a live lane's or
+    a snapshot's (0 where no layer is recurrent)."""
+    lay = state_layout(cfg)
+    if lay is None:
+        return 0
+    return lay.depth * (4 * math.prod(lay.state)
+                        + jnp.dtype(cfg.dtype).itemsize
+                        * math.prod(lay.tail))
+
+
+def pool_names(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """Every array the step programs carry, donated, in call order: the
+    paged pools, then a recurrent configuration's state and tail."""
+    lay = state_layout(cfg)
+    return pool_layout(cfg).names + (lay.names if lay is not None else ())
+
+
+def init_state_pool(cfg: TransformerConfig, rows: int) -> dict:
+    """`{"state", "tail"}` of `rows` rows (row 0 the null row), zero.  The
+    rows are rounded up to whole 16-row tiles: `tail [n, rows, W]` is then
+    the same bytes as `[n * rows, W]`, the form its rows are gathered and
+    written in, and no copy of the pool stands between the two."""
+    lay = state_layout(cfg)
+    rows = -(-int(rows) // 16) * 16
+    return {"state": jnp.zeros((lay.depth, rows) + lay.state, jnp.float32),
+            "tail": jnp.zeros((lay.depth, rows) + lay.tail,
+                              jnp.dtype(cfg.dtype))}
 
 
 def init_paged_cache(cfg: TransformerConfig, pages: int,
@@ -286,7 +365,7 @@ def init_paged_cache(cfg: TransformerConfig, pages: int,
     pool `kv`."""
     dt = jnp.dtype(cfg.dtype)
     lay = pool_layout(cfg)
-    shape = (cfg.n_layers, int(pages), int(page_size), lay.row)
+    shape = (pool_depth(cfg), int(pages), int(page_size), lay.row)
     return {name: jnp.zeros(shape, dt) for name in lay.names}
 
 
@@ -306,6 +385,16 @@ def _fed_rows(table, pos, n_feed, c: int, pages: int, ps: int, layer: int):
     off = jnp.where(real, wpos % ps, 0)
     base = layer * pages                                  # this layer's pages
     return ((base + page) * ps + off).reshape(-1), wpos
+
+
+def _write_fed_rows(cache_k, cache_v, k, v, idx):
+    """The fed tokens' k/v rows written at flat rows `idx` (`_fed_rows`) of
+    the stacked pools `[L, P, ps, row]`.  -> (cache_k, cache_v, and the two
+    as flat `[L*P*ps, row]` views, which the gather oracles read)."""
+    row = cache_k.shape[-1]
+    fk = cache_k.reshape(-1, row).at[idx].set(k.reshape(-1, row))
+    fv = cache_v.reshape(-1, row).at[idx].set(v.reshape(-1, row))
+    return fk.reshape(cache_k.shape), fv.reshape(cache_v.shape), fk, fv
 
 
 def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
@@ -344,14 +433,11 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
     """
     q, k, v = qkv_proj(p, x)                              # [B, C, H, K]
     b, c, h, kd = q.shape
-    _, pages, ps, hkd = cache_k.shape
+    _, pages, ps, _ = cache_k.shape
     mp = table.shape[1]
     base = layer * pages                                  # this layer's pages
     idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
-    fk = cache_k.reshape(-1, hkd).at[idx].set(k.reshape(b * c, hkd))
-    fv = cache_v.reshape(-1, hkd).at[idx].set(v.reshape(b * c, hkd))
-    cache_k = fk.reshape(cache_k.shape)
-    cache_v = fv.reshape(cache_v.shape)
+    cache_k, cache_v, fk, fv = _write_fed_rows(cache_k, cache_v, k, v, idx)
     if paged_kernel:
         o = paged_flash_attention(q, cache_k, cache_v, table, pos, n_feed,
                                   layer=layer)
@@ -369,6 +455,72 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bqhs,bshk->bqhk", w, hist_v)
     return out_proj(p, o), cache_k, cache_v
+
+
+def _grouped_paged_attn(p, x, cache_k, cache_v, layer: int, table, pos,
+                        n_feed, paged_kernel: bool = False):
+    """`_paged_attn` where the K/V heads are fewer than the query heads
+    (query head j reads K/V head `j // G`) and the output may be gated:
+    the pool's row is `[Hkv * K]`, the scatter is the same, the kernel is
+    `paged_flash_attention`'s grouped form and the oracle gathers the
+    history as `[B, S, Hkv, K]`.  Scores in float32."""
+    with jax.named_scope("attn:gqa"):
+        q, k, v = qkv_proj(p, x)              # [B,C,H,K], [B,C,Hkv,K]
+        b, c, h, kd = q.shape
+        hkv = k.shape[2]
+        _, pages, ps, _ = cache_k.shape
+        mp = table.shape[1]
+        idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
+        cache_k, cache_v, fk, fv = _write_fed_rows(cache_k, cache_v, k, v,
+                                                   idx)
+        if paged_kernel:
+            o = paged_flash_attention(q, cache_k, cache_v, table, pos,
+                                      n_feed, layer=layer)
+        else:
+            gidx = ((layer * pages + table)[:, :, None] * ps
+                    + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
+            # the gather ORACLE of the grouped path (parity reference)
+            hist_k = fk[gidx].reshape(b, mp * ps, hkv, kd)  # noqa: PGD301 — oracle
+            hist_v = fv[gidx].reshape(b, mp * ps, hkv, kd)  # noqa: PGD301 — oracle
+            qg = q.reshape(b, c, hkv, h // hkv, kd)
+            sc = jnp.einsum("bcngk,bsnk->bcngs", qg, hist_k).astype(
+                jnp.float32) * kd ** -0.5
+            seen = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]
+            sc = jnp.where(seen[:, :, None, None, :], sc,
+                           mask_value(sc.dtype))
+            w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
+            o = jnp.einsum("bcngs,bsnk->bcngk", w, hist_v
+                           ).reshape(b, c, h, kd)
+        return out_proj(p, attn_gated(p, x, o)), cache_k, cache_v
+
+
+def _kda_paged(cfg: TransformerConfig, p, x, state, tail, layer: int,
+               rows, n_feed, kernel: bool):
+    """A KDA layer in a step program: lane b's state and tail are row
+    `rows[b]` of recurrent layer `layer` of the pools `[n, R, ...]`, read
+    and written where they lie (the pools are donated).  An idle lane
+    carries the null row 0 and writes it back as it was.
+    -> (out [B, C, d], state, tail)."""
+    n, r = state.shape[:2]
+    at = layer * r + rows
+    flat_s = state.reshape((n * r,) + state.shape[2:])
+    flat_t = tail.reshape((n * r,) + tail.shape[2:])
+    taps, lanes = cfg.linear.conv_taps - 1, len(rows)
+    # A tail row is one 147 KB vector.  It is read and written a lane at a
+    # time by dynamic slices, which the compiler updates in place; as a
+    # gather and a scatter of `[lanes, W]` it split the pool by taps and
+    # passed over all of it several times a round (2.2 ms of a 6.4 ms
+    # width-1 round at 771 rows; my chip run, PR 38).
+    old_t = jnp.stack([lax.dynamic_index_in_dim(flat_t, at[b], keepdims=False)
+                       for b in range(lanes)])
+    out, new_s, new_t = kda.attend(
+        cfg, p, x, flat_s[at], old_t.reshape(lanes, taps, -1), n_feed,
+        kernel)
+    new_t = new_t.reshape(lanes, -1)
+    for b in range(lanes):
+        flat_t = lax.dynamic_update_index_in_dim(flat_t, new_t[b], at[b], 0)
+    return (out, flat_s.at[at].set(new_s).reshape(state.shape),
+            flat_t.reshape(tail.shape))
 
 
 def _latent_paged_attn(cfg: TransformerConfig, p, x, pool, layer: int,
@@ -425,7 +577,9 @@ def _latent_paged_attn(cfg: TransformerConfig, p, x, pool, layer: int,
 def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
                   table: jax.Array, pos: jax.Array, n_feed: jax.Array,
                   tokens: jax.Array, paged_kernel: bool = False,
-                  loads: Optional[list] = None) -> Tuple[jax.Array, dict]:
+                  loads: Optional[list] = None,
+                  rows: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, dict]:
     """tokens: [B, C] int32, lane b feeding its first n_feed[b] columns
     at positions pos[b].. -> (the last layer's output [B, C, d] at EVERY
     fed column, before the final norm and the head, cache with the fed
@@ -440,7 +594,9 @@ def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
     `[L, P, ps, row]` is carried from layer to layer, each writing its
     own rows into it; what comes back is that buffer, not a stack of
     per-layer copies.  `loads` collects each `RoutedExperts` layer's
-    load counts."""
+    load counts.  A recurrent configuration's `cache` also holds `state`
+    and `tail` (`state_layout`), lane b's in row `rows[b]`; its full
+    layers have the pool's layers `pool_layers(cfg)`."""
     c = tokens.shape[1]
     wpos = pos[:, None] + jnp.arange(c)[None, :]
     pidx = jnp.minimum(wpos, cfg.max_len - 1)             # clip padding
@@ -453,16 +609,26 @@ def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
         def ffn(layer, h):
             return feed_forward(cfg, layer, h, fed, loads)
 
+    mixers, paged_at = cfg.mixer_kinds(), pool_layers(cfg)
+    grouped = cfg.kv_heads is not None or cfg.attn_gate
     for i, layer in enumerate(params["layers"]):
         def attend(p, h, i=i):
-            if cfg.latent is not None:
+            if mixers[i] == "kda":
+                a, pools["state"], pools["tail"] = _kda_paged(
+                    cfg, p, h, pools["state"], pools["tail"],
+                    mixers[:i].count("kda"), rows, n_feed, paged_kernel)
+            elif cfg.latent is not None:
                 a, pools["kv"] = _latent_paged_attn(
-                    cfg, p, h, pools["kv"], i, table, pos, n_feed,
+                    cfg, p, h, pools["kv"], paged_at[i], table, pos, n_feed,
                     paged_kernel=paged_kernel)
+            elif grouped:
+                a, pools["k"], pools["v"] = _grouped_paged_attn(
+                    p, h, pools["k"], pools["v"], paged_at[i], table, pos,
+                    n_feed, paged_kernel=paged_kernel)
             else:
                 a, pools["k"], pools["v"] = _paged_attn(
-                    p, h, pools["k"], pools["v"], i, table, pos, n_feed,
-                    paged_kernel=paged_kernel)
+                    p, h, pools["k"], pools["v"], paged_at[i], table, pos,
+                    n_feed, paged_kernel=paged_kernel)
             return a
 
         x = block(cfg, layer, x, attend, ffn)
@@ -484,13 +650,16 @@ def _last_fed(a: jax.Array, n_feed: jax.Array) -> jax.Array:
 def paged_forward(cfg: TransformerConfig, params: dict, cache: dict,
                   table: jax.Array, pos: jax.Array, n_feed: jax.Array,
                   tokens: jax.Array, paged_kernel: bool = False,
-                  loads: Optional[list] = None) -> Tuple[jax.Array, dict]:
+                  loads: Optional[list] = None,
+                  rows: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, dict]:
     """`_paged_hidden` under the head: logits [B, C, V] at EVERY fed
     column, what the speculative verify step consumes
     (`make_spec_step`): column j scores the token that should FOLLOW fed
     token j."""
     x, pools = _paged_hidden(cfg, params, cache, table, pos, n_feed,
-                             tokens, paged_kernel=paged_kernel, loads=loads)
+                             tokens, paged_kernel=paged_kernel, loads=loads,
+                             rows=rows)
     return _head(cfg, params, x), pools
 
 
@@ -511,7 +680,8 @@ def expert_load(cfg: TransformerConfig, loads: list) -> jax.Array:
 def paged_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
                       table: jax.Array, pos: jax.Array, n_feed: jax.Array,
                       tokens: jax.Array, paged_kernel: bool = False,
-                      loads: Optional[list] = None
+                      loads: Optional[list] = None,
+                      rows: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, dict]:
     """Logits at each lane's LAST fed column (-> [B, V]) — the
     chunked-prefill/decode entry point.  The new families take the
@@ -520,7 +690,8 @@ def paged_decode_step(cfg: TransformerConfig, params: dict, cache: dict,
     HLO they had, and taking the column first there is a `perf_opt`'s,
     with its pairs (PERF.md section 7)."""
     x, cache = _paged_hidden(cfg, params, cache, table, pos, n_feed,
-                             tokens, paged_kernel=paged_kernel, loads=loads)
+                             tokens, paged_kernel=paged_kernel, loads=loads,
+                             rows=rows)
     if cfg.classic:
         return _last_fed(_head(cfg, params, x), n_feed), cache
     return _head(cfg, params, _last_fed(x, n_feed)[:, None])[:, 0], cache
@@ -530,8 +701,9 @@ def _pooled(cfg: TransformerConfig, run):
     """`jax.jit` of `run(params, pools: tuple, *rest)` as
     `step(params, *pools, *rest)`, the pools donated: two for full
     heads (`k`, `v`: the call the serving plane has always made), one
-    for latent rows."""
-    n = len(pool_layout(cfg).names)
+    for latent rows; a recurrent configuration's state and tail after
+    them (`pool_names`)."""
+    n = len(pool_names(cfg))
 
     def step(params, *args):
         return run(params, args[:n], *args[n:])
@@ -567,15 +739,17 @@ def _compiled_paged_step(cfg: TransformerConfig, pages: int,
     matching flag share ONE cache entry.  A
     `RoutedExperts` configuration's program returns `[B + 3]` int32: the
     sampled tokens and then `expert_load`, in the one array the host
-    already waits for."""
-    names = pool_layout(cfg).names
+    already waits for.  A recurrent configuration's program carries
+    `state` and `tail` after the paged pools and takes the lanes' state
+    rows `[B]` as its last argument."""
+    names = pool_names(cfg)
 
     def run(params, pools, table, pos, n_feed, tokens, temperature, seeds,
-            counts):
+            counts, rows=None):
         loads = [] if cfg.experts is not None else None
         logits, cache = paged_decode_step(
             cfg, params, dict(zip(names, pools)), table, pos, n_feed,
-            tokens, paged_kernel=paged_kernel, loads=loads)
+            tokens, paged_kernel=paged_kernel, loads=loads, rows=rows)
         nxt = _sample(logits.astype(jnp.float32), temperature, seeds,
                       counts)
         if loads:
@@ -692,13 +866,27 @@ def _compiled_spec_step(cfg: TransformerConfig, pages: int,
     return _pooled(cfg, run)
 
 
+def require_stateless(cfg: TransformerConfig, who: str) -> None:
+    """Raise `UnsupportedLayerKind` where `cfg` has a recurrent layer:
+    the gate of every path that moves or rewinds a lane by its pages
+    alone (a lane of such a model is its pages AND its state row)."""
+    if cfg.recurrent:
+        raise UnsupportedLayerKind(
+            f"{who} handles a lane as its K/V pages; this configuration "
+            f"has recurrent layers ({cfg.mixer_kinds().count('kda')} "
+            f"\"kda\") whose state it would neither move nor roll back")
+
+
 def make_spec_step(cfg: TransformerConfig, pages: int, page_size: int,
                    width: int, paged_kernel: bool | None = None):
     """Compiled speculative-verify entry for the LM pool:
     fn(params, k, v, table [B, MP], pos [B], n_feed [B], n_draft [B],
     tokens [B, W], temperature [B], seeds [B], counts [B])
     -> (bonus_token [B], accepted [B], k, v).  `paged_kernel=None`
-    auto-resolves exactly as in `make_paged_step`."""
+    auto-resolves exactly as in `make_paged_step`.  Refused for a
+    recurrent configuration: a rejected draft has already moved the
+    state."""
+    require_stateless(cfg, "speculative decoding (make_spec_step)")
     return _compiled_spec_step(cfg, int(pages), int(page_size),
                                int(width),
                                resolve_paged_kernel(paged_kernel))
@@ -760,6 +948,7 @@ def _compiled_page_gather(cfg: TransformerConfig, pages: int,
 def make_page_gather(cfg: TransformerConfig, pages: int, page_size: int):
     """Compiled page-gather entry: fn(*pools, table_row [MP]) ->
     one page stack [L, MP, ps, heads, width] a pool."""
+    require_stateless(cfg, "page export (make_page_gather)")
     return _compiled_page_gather(cfg, int(pages), int(page_size))
 
 
@@ -792,7 +981,37 @@ def _compiled_page_install(cfg: TransformerConfig, pages: int,
 def make_page_install(cfg: TransformerConfig, pages: int, page_size: int):
     """Compiled page-install entry: fn(*pools, *stacks, table_row [MP],
     n) -> pools."""
+    require_stateless(cfg, "page import (make_page_install)")
     return _compiled_page_install(cfg, int(pages), int(page_size))
+
+
+@functools.lru_cache(maxsize=16)
+def _compiled_state_copy(cfg: TransformerConfig, n: int):
+    """Saving and restoring a recurrent lane are ONE program, as
+    `make_page_copy` is for pages: row `dst[i]` of every recurrent layer
+    of `state` and `tail` becomes row `src[i]` (or zero where `src[i] < 0`:
+    a lane that starts from nothing), `n` copies a dispatch, the pools
+    donated.  A spare entry is `(0, 0)`, the null row onto itself."""
+
+    def state_copy(state, tail, src, dst):
+        with jax.named_scope("state:copy"):
+            def move(buf):
+                got = buf[:, jnp.maximum(src, 0)]
+                keep = (src >= 0).reshape((1, n) + (1,) * (buf.ndim - 2))
+                return buf.at[:, dst].set(jnp.where(keep, got, 0))
+
+            return move(state), move(tail)
+
+    # the benchmark's reader finds the program by this function's name
+    return jax.jit(state_copy, donate_argnums=(0, 1))
+
+
+def make_state_copy(cfg: TransformerConfig, n: int):
+    """Compiled state-row copy entry: fn(state, tail, src [n], dst [n])
+    -> (state, tail)."""
+    if state_layout(cfg) is None:
+        raise ValueError("this configuration keeps no recurrent state")
+    return _compiled_state_copy(cfg, int(n))
 
 
 # ---------------------------------------------------------------------------

@@ -306,6 +306,11 @@ def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
     b, c, h, kd = q.shape
     hkd = h * kd
     table = jnp.asarray(table, jnp.int32)
+    if (k_pages.shape[2] != h if layer is None
+            else k_pages.shape[3] != hkd):
+        # fewer K/V heads than query heads: the grouped kernel below
+        return _grouped_paged_attention(q, k_pages, v_pages, table, pos,
+                                        n_feed, interpret, layer)
     if layer is None:
         ps = k_pages.shape[1]
     else:
@@ -377,6 +382,183 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(table, pos, n_feed, qf, k_pages, v_pages)
+
+
+# ---------------------------------------------------------------------------
+# Grouped queries (fewer K/V heads than query heads)
+#
+# A pool row is `[Hkv * K]`: with K = 128 a K/V head is one 128-lane tile
+# of the page, and the `G = H / Hkv` query heads that read it (times the
+# fed columns of a query block) are the ROWS of one query block against
+# that tile, so a page fetched once serves `G` heads: a plain matmul a
+# K/V head and no block-diagonal layout.  The walk is the latent kernel's:
+# the lane's live pages inside the body, a page a block (a page of 128
+# positions is one tile of scores), the next page's DMA under this page's
+# matmuls.
+
+
+def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
+                         o_ref, kbuf, vbuf, sem, m_acc, l_acc, acc, out, *,
+                         scale, ps, cq, g, hkv, kd, neg):
+    """Grid program (lane b, query block j).  q_ref `[Hkv, cq*G, K]`: for
+    a K/V head its `G` query heads of `cq` fed columns, row `ci*G + gi`;
+    k_ref/v_ref the whole pool `[L*P, ps, Hkv*K]` in HBM; o_ref
+    `[cq', H, K]` (the block's real columns).  Key `t` is visible to
+    column `ci` iff `t <= pos + ci`.  A block past the lane's fed columns
+    reads nothing and writes zeros."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    rows = cq * g
+    nf = nf_ref[b]
+    first = j * cq
+    last = pos_ref[b] + jnp.minimum(first + cq, nf) - 1
+    n = jnp.where(first < nf, last // ps + 1, 0)
+
+    def copies(i, slot):
+        page = table_ref[b, i]
+        return [pltpu.make_async_copy(k_ref.at[page], kbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_ref.at[page], vbuf.at[slot],
+                                      sem.at[1, slot])]
+
+    @pl.when(n == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n > 0)
+    def _busy():
+        for dma in copies(0, 0):
+            dma.start()
+        m_acc[...] = jnp.full_like(m_acc, neg)
+        l_acc[...] = jnp.zeros_like(l_acc)
+        acc[...] = jnp.zeros_like(acc)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) // g
+        horizon = (pos_ref[b] + first + ci
+                   - jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1))
+        exact = (None if q_ref.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+
+        def page_step(i, carry):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n)
+            def _next():
+                for dma in copies(i + 1, 1 - slot):
+                    dma.start()
+
+            for dma in copies(i, slot):
+                dma.wait()
+            live = i * ps <= horizon
+            for nh in range(hkv):
+                k_blk = kbuf[slot, :, nh * kd:(nh + 1) * kd]
+                v_blk = vbuf[slot, :, nh * kd:(nh + 1) * kd]
+                s = jax.lax.dot_general(
+                    q_ref[nh], k_blk.astype(q_ref.dtype),
+                    (((1,), (1,)), ((), ())), precision=exact,
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(live, s, neg)
+                m = m_acc[nh][:, :1]
+                new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.where(live, jnp.exp(s - new_m), 0.0)
+                scale_old = jnp.exp(m - new_m)
+                new_l = (l_acc[nh][:, :1] * scale_old
+                         + jnp.sum(p, axis=1, keepdims=True))
+                acc[nh] = (acc[nh] * scale_old
+                           + _dot_f32(p, v_blk, ((1,), (0,))))
+                m_acc[nh] = jnp.broadcast_to(new_m, (rows, REP))
+                l_acc[nh] = jnp.broadcast_to(new_l, (rows, REP))
+            return carry
+
+        jax.lax.fori_loop(0, n, page_step, 0)
+        for nh in range(hkv):
+            o = acc[nh] / jnp.maximum(l_acc[nh][:, :1], 1e-30)
+            out[:, nh * g:(nh + 1) * g, :] = o.reshape(cq, g, kd)
+        o_ref[...] = out[:o_ref.shape[0]].astype(o_ref.dtype)
+
+
+def _grouped_query_block(c: int) -> int:
+    """Fed columns a query block of the grouped kernel: the width in
+    whole sublane tiles, at most 32 (256 query rows a K/V head at G = 8,
+    and the block's buffers about 7 MB of VMEM)."""
+    cp = -(-c // 8) * 8
+    cq = min(cp, 32)
+    while cp % cq:
+        cq -= 8
+    return cq
+
+
+def _grouped_paged_attention(q, k_pages, v_pages, table, pos, n_feed,
+                             interpret, layer):
+    """`paged_flash_attention` for `H` query heads over `Hkv < H` K/V
+    heads: same operands, same result `[B, C, H, K]`."""
+    b, c, h, kd = q.shape
+    if layer is None:
+        ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    else:
+        ps, hkv = k_pages.shape[2], k_pages.shape[3] // kd
+        table = table + layer * k_pages.shape[1]
+    g = h // hkv
+    k_pages = k_pages.reshape(-1, ps, hkv * kd)
+    v_pages = v_pages.reshape(-1, ps, hkv * kd)
+    n_feed = (jnp.full((b,), c, jnp.int32) if n_feed is None
+              else jnp.asarray(n_feed, jnp.int32))
+    cq = _grouped_query_block(c)
+    cp = -(-c // cq) * cq
+    if cp != c and cp != cq:
+        raise ValueError(f"a feed of {c} columns is neither one query "
+                         f"block nor whole blocks of {cq}")
+    # rows of a K/V head's query block: column-major, its G heads inside
+    qg = jnp.pad(q, ((0, 0), (0, cp - c), (0, 0), (0, 0)))
+    qg = qg.reshape(b, cp, hkv, g, kd).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(b, hkv, cp * g, kd)
+    return _grouped_call(table, jnp.asarray(pos, jnp.int32), n_feed, qg,
+                         k_pages, v_pages, c=c, cq=cq, g=g,
+                         interpret=_resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("c", "cq", "g", "interpret"))
+def _grouped_call(table, pos, n_feed, qg, k_pages, v_pages, *, c, cq, g,
+                  interpret):
+    b, hkv, _, kd = qg.shape
+    ps = k_pages.shape[1]
+    rows = cq * g
+
+    def _q_map(bi, ji, tbl, pos_, nf):
+        return (bi, 0, ji, 0)
+
+    def _o_map(bi, ji, tbl, pos_, nf):
+        return (bi, ji, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, -(-c // cq)),
+        in_specs=[pl.BlockSpec((None, hkv, rows, kd), _q_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, min(cq, c), hkv * g, kd), _o_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, ps, hkv * kd), k_pages.dtype),
+            pltpu.VMEM((2, ps, hkv * kd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hkv, rows, REP), jnp.float32),   # running max
+            pltpu.VMEM((hkv, rows, REP), jnp.float32),   # running denom
+            pltpu.VMEM((hkv, rows, kd), jnp.float32),    # accumulator
+            pltpu.VMEM((cq, hkv * g, kd), jnp.float32),  # heads in order
+        ],
+    )
+    kernel = functools.partial(
+        _grouped_attn_kernel, scale=1.0 / (kd ** 0.5), ps=ps, cq=cq, g=g,
+        hkv=hkv, kd=kd, neg=_NEG)
+    # as for `_paged_call`: the block table first, the result 4-D with the
+    # feed width second; the trace's readers find the kernel by that
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, c, hkv * g, kd), qg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="grouped_paged_attention",
+    )(table, pos, n_feed, qg, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -85,6 +85,24 @@ class LatentAttention:
 
 
 @dataclass(frozen=True)
+class LinearAttention:
+    """A gated delta-rule layer (Kimi Delta Attention, arXiv:2510.26692):
+    `heads` heads whose state is a `k_dim x v_dim` matrix each, a causal
+    depthwise convolution of `conv_taps` taps before q, k and v, the
+    per-channel decay and the output gate as projections through
+    `gate_rank`, and write strengths in (0, 2) where `neg_eigval` (else
+    (0, 1)).  What the cache keeps a sequence and layer is the state
+    (float32) and the convolution's last `conv_taps - 1` inputs."""
+
+    heads: int
+    k_dim: int
+    v_dim: int
+    conv_taps: int = 4
+    gate_rank: int = 128
+    neg_eigval: bool = True
+
+
+@dataclass(frozen=True)
 class RoutedExperts:
     """A SwiGLU expert layer routed over `published` experts of which
     the slice `held = (lo, hi)` has its weights HERE (one chip's share
@@ -98,6 +116,8 @@ class RoutedExperts:
     width: int                  # one routed expert's hidden width
     groups: int = 1             # group-limited routing: experts in
     groups_kept: int = 1        # `groups` groups, the best `groups_kept`
+    # "softmax" | "sigmoid" (a sigmoid router chooses by score + a
+    # per-expert bias and weighs by the score alone)
     score: str = "softmax"
     scale: float = 1.0          # routed_scaling_factor
     renormalize: bool = False   # norm_topk_prob
@@ -112,8 +132,9 @@ class RoutedExperts:
                 1 <= self.groups_kept <= self.groups):
             raise ValueError(f"{self.groups_kept} of {self.groups} groups "
                              f"over {self.published} experts")
-        if self.score != "softmax":
-            raise ValueError(f"router score {self.score!r}: softmax only")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router score {self.score!r}: \"softmax\" "
+                             f"or \"sigmoid\"")
 
     @property
     def n_held(self) -> int:
@@ -163,6 +184,19 @@ class TransformerConfig:
     # (first_k_dense_replace); the dense MLP's width is `d_ff`
     experts: Optional[RoutedExperts] = None
     dense_layers: int = 0
+    # a head's width where it is not d_model / n_heads, and the K/V
+    # heads where they are fewer than the query heads (query head j
+    # reads K/V head j // (n_heads / kv_heads))
+    head_width: Optional[int] = None
+    kv_heads: Optional[int] = None
+    # "auto": learned positions unless `rope`; "none": no positions
+    positions: str = "auto"
+    # each layer's MIXER kind, "full" (softmax attention, or latent
+    # where `latent` is set) | "kda" (`linear`); None = every layer full
+    mixers: Optional[Tuple[str, ...]] = None
+    linear: Optional[LinearAttention] = None
+    # full layers' output gate: (a * sigmoid(h W_gate)) W_o, elementwise
+    attn_gate: bool = False
 
     def __post_init__(self):
         if self.n_experts and not (1 <= self.moe_top_k <= self.n_experts):
@@ -175,6 +209,19 @@ class TransformerConfig:
         if self.experts is not None and self.n_experts:
             raise ValueError("`experts` and `n_experts` are two expert "
                              "layers; a configuration has one")
+        if self.positions not in ("auto", "none"):
+            raise ValueError(f"positions {self.positions!r}")
+        if self.kv_heads is not None and (
+                self.kv_heads < 1 or self.n_heads % self.kv_heads):
+            raise ValueError(f"{self.kv_heads} K/V heads under "
+                             f"{self.n_heads} query heads")
+        if self.mixers is not None:
+            if (len(self.mixers) != self.n_layers
+                    or set(self.mixers) - {"full", "kda"}):
+                raise ValueError(f"mixers {self.mixers} for "
+                                 f"{self.n_layers} layers")
+            if "kda" in self.mixers and self.linear is None:
+                raise ValueError("a \"kda\" layer needs `linear`")
 
     @property
     def classic(self) -> bool:
@@ -182,7 +229,23 @@ class TransformerConfig:
         runtimes and the whole-sequence KV cache compute."""
         return (self.norm == "layer" and self.rope is None
                 and self.mlp == "gelu" and self.latent is None
-                and self.experts is None)
+                and self.experts is None and self.head_width is None
+                and self.kv_heads is None and self.positions == "auto"
+                and self.mixers is None and self.linear is None
+                and not self.attn_gate)
+
+    @property
+    def learned_positions(self) -> bool:
+        return self.rope is None and self.positions == "auto"
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a state a sequence instead of a K/V history."""
+        return "kda" in self.mixer_kinds()
+
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's mixer kind, "full" | "kda"."""
+        return self.mixers or ("full",) * self.n_layers
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each layer's feed-forward kind: "dense", "moe" (Switch /
@@ -194,8 +257,14 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_width is not None:
+            return self.head_width
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.kv_heads if self.kv_heads is not None else self.n_heads
 
 
 @dataclass(frozen=True)
@@ -211,8 +280,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
     """Full (unsharded) parameter tree; place with `param_specs`."""
     dt = jnp.dtype(cfg.dtype)
     d, h, dh, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
-    keys = iter(jax.random.split(
-        key, 4 + (8 if cfg.classic else 16) * cfg.n_layers))
+    per_layer = (8 if cfg.classic
+                 else 32 if cfg.mixers is not None or cfg.attn_gate else 16)
+    keys = iter(jax.random.split(key, 4 + per_layer * cfg.n_layers))
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, dt) / jnp.sqrt(
@@ -228,10 +298,40 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
                 "wu": dense(next(keys), lead + (d, width), d),
                 "wd": dense(next(keys), lead + (width, d), width)}
 
+    def kda_layer():
+        la = cfg.linear
+        hk = (la.heads, la.k_dim)
+        taps = (la.conv_taps,) + hk
+        return {
+            "wq": dense(next(keys), (d,) + hk, d),
+            "wk": dense(next(keys), (d,) + hk, d),
+            "wv": dense(next(keys), (d, la.heads, la.v_dim), d),
+            "conv_q": dense(next(keys), taps, la.conv_taps),
+            "conv_k": dense(next(keys), taps, la.conv_taps),
+            "conv_v": dense(next(keys),
+                            (la.conv_taps, la.heads, la.v_dim),
+                            la.conv_taps),
+            "wf_down": dense(next(keys), (d, la.gate_rank), d),
+            "wf_up": dense(next(keys), (la.gate_rank,) + hk, la.gate_rank),
+            # decays of about 0.9 to 0.999 a token
+            "a_log": jnp.log(jax.random.uniform(
+                next(keys), (la.heads,), dt, 0.02, 0.2)),
+            "dt_bias": jnp.zeros(hk, dt),
+            "wb": dense(next(keys), (d, la.heads), d),
+            "wg_down": dense(next(keys), (d, la.gate_rank), d),
+            "wg_up": dense(next(keys), (la.gate_rank, la.heads, la.v_dim),
+                           la.gate_rank),
+            "o_norm": {"scale": jnp.ones((la.v_dim,), dt)},
+            "wo": dense(next(keys), (la.heads, la.v_dim, d),
+                        la.heads * la.v_dim),
+        }
+
     layers = []
-    for kind in cfg.layer_kinds():
+    for kind, mixer in zip(cfg.layer_kinds(), cfg.mixer_kinds()):
         layer = {"ln1": gain(d), "ln2": gain(d)}
-        if cfg.latent is not None:
+        if mixer == "kda":
+            layer["attn"] = kda_layer()
+        elif cfg.latent is not None:
             la = cfg.latent
             layer["attn"] = {
                 "wdq": dense(next(keys), (d, la.q_rank), d),
@@ -247,12 +347,15 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
                 "wo": dense(next(keys), (h, la.v_dim, d), h * la.v_dim),
             }
         else:
+            hkv = cfg.n_kv_heads
             layer["attn"] = {
                 "wq": dense(next(keys), (d, h, dh), d),
-                "wk": dense(next(keys), (d, h, dh), d),
-                "wv": dense(next(keys), (d, h, dh), d),
+                "wk": dense(next(keys), (d, hkv, dh), d),
+                "wv": dense(next(keys), (d, hkv, dh), d),
                 "wo": dense(next(keys), (h, dh, d), d),
             }
+            if cfg.attn_gate:
+                layer["attn"]["wgate"] = dense(next(keys), (d, h, dh), d)
         if cfg.attn_bias:
             layer["attn"].update(
                 bq=jnp.zeros((h, dh), dt), bk=jnp.zeros((h, dh), dt),
@@ -262,6 +365,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
             layer["experts"] = {
                 "gate": dense(next(keys), (d, ex.published), d),
                 **swiglu(ex.width, (ex.n_held,))}
+            if ex.score == "sigmoid":
+                layer["experts"]["bias"] = jnp.zeros((ex.published,),
+                                                     jnp.float32)
             if ex.shared_width:
                 layer["experts"]["shared"] = swiglu(ex.shared_width)
         elif kind == "moe":
@@ -294,7 +400,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
         "ln_f": gain(d),
         "layers": layers,
     }
-    if cfg.rope is None:
+    if cfg.learned_positions:
         out["pos"] = dense(next(keys), (cfg.max_len, d), 1) * 0.02
     if not cfg.tie_embeddings:
         out["head"] = dense(next(keys), (d, cfg.vocab_size), d)
@@ -310,8 +416,10 @@ def require_classic(cfg: TransformerConfig, who: str) -> None:
             f"heads only; this configuration has norm={cfg.norm!r} "
             f"rope={cfg.rope is not None} mlp={cfg.mlp!r} "
             f"latent={cfg.latent is not None} "
-            f"experts={cfg.experts is not None} (serve it through the "
-            f"paged pool)")
+            f"experts={cfg.experts is not None} "
+            f"mixers={sorted(set(cfg.mixer_kinds()))} "
+            f"kv_heads={cfg.kv_heads} positions={cfg.positions!r} (serve "
+            f"it through the paged pool)")
 
 
 def param_specs(cfg: TransformerConfig, model_axis: Optional[str]) -> dict:
@@ -417,6 +525,34 @@ def deepseek_v2(layers: int = 60, experts_held: Tuple[int, int] = (0, 160),
         dense_layers=1)
 
 
+def solar_open2(layers: int = 48, experts_held: Tuple[int, int] = (0, 320),
+                vocab: int = 196608, max_len: int = 1048576,
+                dtype: str = "bfloat16") -> TransformerConfig:
+    """Solar-Open2-250B at its published widths (`solar_open2`): d 4096,
+    no positions, RMSNorm 1e-5; layer i is grouped-query softmax attention
+    (64 query heads over 8 K/V heads of 128, an elementwise sigmoid output
+    gate) where i % 4 == 0 and a KDA layer (64 heads, state 128 x 128 a
+    head, 4-tap convolutions, gates of rank 128, write strengths in
+    (0, 2)) otherwise; every layer's feed-forward 320 routed experts of
+    1280 (8 a token by sigmoid score + bias, renormalised, scale 1)
+    beside one shared expert of 1280; untied head.  The arguments give
+    one chip's share, as for `deepseek_v2`.  Served through the paged
+    pool only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=4096, n_heads=64, n_layers=layers,
+        d_ff=10240, max_len=max_len, dtype=dtype, norm="rms",
+        norm_eps=1e-5, mlp="swiglu", head_width=128, kv_heads=8,
+        positions="none", attn_gate=True,
+        mixers=tuple("full" if i % 4 == 0 else "kda"
+                     for i in range(layers)),
+        linear=LinearAttention(heads=64, k_dim=128, v_dim=128, conv_taps=4,
+                               gate_rank=128, neg_eigval=True),
+        experts=RoutedExperts(published=320, held=tuple(experts_held),
+                              per_token=8, width=1280, score="sigmoid",
+                              scale=1.0, renormalize=True,
+                              shared_width=1280))
+
+
 def _layer_norm(p, x, eps=1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
@@ -441,7 +577,7 @@ def embed_tokens(cfg: TransformerConfig, params: dict, tokens, positions):
     """tokens [B, S] at `positions` [B, S] (or [S]) -> [B, S, d]: learned
     positions are added here, rotary ones are applied inside attention."""
     x = params["embed"][tokens]
-    if cfg.rope is None:
+    if cfg.learned_positions:
         x = x + params["pos"][positions]
     return x
 
@@ -602,6 +738,34 @@ def _attn(p, x, mesh: Optional[Mesh], axes: MeshAxes, causal: bool):
     return out_proj(p, o)
 
 
+def attn_gated(p, h, o):
+    """A full layer's output gate, where its parameters have one:
+    o [B,S,H,K] * sigmoid(h W_gate), elementwise."""
+    if "wgate" not in p:
+        return o
+    gate = jnp.einsum("bsd,dhk->bshk", h, p["wgate"])
+    return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+
+
+def _grouped_attn(p, x, causal: bool):
+    """Whole-sequence softmax attention with fewer K/V heads than query
+    heads and the output gate, one device, no kernel: the path the paged
+    one is tested against."""
+    with jax.named_scope("attn:gqa"):
+        q, k, v = qkv_proj(p, x)
+        b, s, h, kd = q.shape
+        g = h // k.shape[2]
+        qg = q.reshape(b, s, h // g, g, kd)
+        sc = jnp.einsum("bsngk,btnk->bngst", qg, k).astype(
+            jnp.float32) * kd ** -0.5
+        if causal:
+            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc,
+                           jnp.finfo(jnp.float32).min / 2)
+        w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
+        o = jnp.einsum("bngst,btnk->bsngk", w, v).reshape(b, s, h, kd)
+        return out_proj(p, attn_gated(p, x, o))
+
+
 def _mlp(p, x):
     h = jax.nn.gelu(jnp.einsum("bsd,df->bsf", x, p["w1"]) + p["b1"])
     return jnp.einsum("bsf,fd->bsd", h, p["w2"]) + p["b2"]
@@ -751,13 +915,25 @@ def _moe_dropless(p, x, top_k: int = 1):
     return y.reshape(b, s, d)
 
 
-def group_limited_top_k(scores, ex: RoutedExperts):
+def group_limited_top_k(scores, ex: RoutedExperts, bias=None):
     """scores [N, E] -> (idx [N, k], weights [N, k]): a group's score is
     its largest expert score; only experts of the `groups_kept` best
     groups stand; the `per_token` largest of those, weighted by their
     own score times `scale` (renormalised first only where the
-    configuration says so)."""
+    configuration says so).  With `bias` [E] (a sigmoid router's
+    correction) the CHOICE is by `scores + bias` and the weights are
+    the scores alone."""
     n, e = scores.shape
+    if bias is not None:
+        choice = scores + bias
+        if ex.groups > 1:
+            raise UnsupportedLayerKind(
+                "a bias-corrected choice over more than one routing group")
+        _, idx = lax.top_k(choice, ex.per_token)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if ex.renormalize and ex.per_token > 1:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx, w * ex.scale
     if ex.groups > 1:
         per = e // ex.groups
         best = jnp.max(scores.reshape(n, ex.groups, per), axis=-1)
@@ -784,7 +960,12 @@ def _routed_experts(ex: RoutedExperts, p, x, valid=None):
         logits = jnp.dot(xf.astype(jnp.float32),
                          p["gate"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        idx, w = group_limited_top_k(jax.nn.softmax(logits, axis=-1), ex)
+        if ex.score == "sigmoid":
+            idx, w = group_limited_top_k(jax.nn.sigmoid(logits), ex,
+                                         p["bias"].astype(jnp.float32))
+        else:
+            idx, w = group_limited_top_k(jax.nn.softmax(logits, axis=-1),
+                                         ex)
         here = (idx >= lo) & (idx < hi)
         real = (jnp.ones_like(here) if valid is None
                 else jnp.broadcast_to(valid.reshape(-1, 1), here.shape))
@@ -922,30 +1103,41 @@ def _apply_dealt(cfg: TransformerConfig, params: dict, tokens, order, mesh,
     def attend(p, h):
         if cfg.latent is not None:
             return _latent_attn(cfg, p, h, causal)
+        if cfg.kv_heads is not None or cfg.attn_gate:
+            return _grouped_attn(p, h, causal)
         return _attn(p, h, mesh, axes, causal)
 
-    def one(layer, x):
-        aux = [jnp.zeros((), x.dtype)]
+    def recur(p, h):
+        from deeplearning4j_tpu.parallel import kda
 
-        def ffn(layer, h):
-            if "moe" not in layer:
-                return feed_forward(cfg, layer, h)
-            aux[0] = _moe_aux_loss(layer["moe"], h)
-            return _moe(layer["moe"], h, cf, mesh, axes, cfg.moe_top_k,
-                        order)
+        return kda.whole_sequence(cfg, p, h)
 
-        return block(cfg, layer, x, attend, ffn, constrain), aux[0]
+    def layer_of(mixer):
+        def one(layer, x):
+            aux = [jnp.zeros((), x.dtype)]
 
-    if cfg.remat:
-        one = jax.checkpoint(one)
+            def ffn(layer, h):
+                if "moe" not in layer:
+                    return feed_forward(cfg, layer, h)
+                aux[0] = _moe_aux_loss(layer["moe"], h)
+                return _moe(layer["moe"], h, cf, mesh, axes, cfg.moe_top_k,
+                            order)
+
+            return block(cfg, layer, x, mixer, ffn, constrain), aux[0]
+
+        return jax.checkpoint(one) if cfg.remat else one
+
+    # a layer's mixer is its kind's (`mixer_kinds`), one function a kind
+    ones = {"full": layer_of(attend), "kda": layer_of(recur)}
     x = params["embed"][tokens]
-    if cfg.rope is None:
+    if cfg.learned_positions:
         pos = params["pos"]
         x = x + (pos[:tokens.shape[1]] if order is None else pos[order])
     x = constrain(x)
     auxs = []
-    for layer in params["layers"]:
-        x, aux = one(layer, x)
+    kinds = cfg.mixer_kinds()
+    for i, layer in enumerate(params["layers"]):
+        x, aux = ones[kinds[i]](layer, x)
         auxs.append(aux)
     x = norm(cfg, params["ln_f"], x)
     logits = jnp.einsum("bsd,dv->bsv", x, lm_head(params))

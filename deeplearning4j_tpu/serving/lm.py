@@ -189,7 +189,11 @@ from deeplearning4j_tpu.serving.hibernate import (
     prefix_key,
 )
 from deeplearning4j_tpu.serving.metrics import ServingMetrics
-from deeplearning4j_tpu.serving.paged import PagePool, RadixPrefixCache
+from deeplearning4j_tpu.serving.paged import (
+    PagePool,
+    RadixPrefixCache,
+    StatePool,
+)
 from deeplearning4j_tpu.serving.pressure import (
     BrownoutLadder,
     PRIORITY_RANK,
@@ -256,7 +260,7 @@ class _LMRequest:
                  "priority", "rank", "swap_key", "swap_restore",
                  "swap_error", "stream_pushed", "preempted",
                  "tenant", "vft", "cost", "prefill_rounds",
-                 "prefill_wide_rounds")
+                 "prefill_wide_rounds", "snapshot_matched")
 
     def __init__(self, prompt: List[int], max_new: int, temperature: float,
                  seed: int, deadline: Optional[float] = None,
@@ -300,11 +304,14 @@ class _LMRequest:
         # of them dispatched the wide program (the `prefill` span's attrs)
         self.prefill_rounds = 0
         self.prefill_wide_rounds = 0
+        # prompt tokens whose state came from a snapshot (recurrent models)
+        self.snapshot_matched = 0
 
 
 class _Slot:
     __slots__ = ("req", "pos", "fed", "generated",
-                 "table", "owned", "shared", "inserted")
+                 "table", "owned", "shared", "inserted",
+                 "row", "trail", "trail_pos")
 
     def __init__(self):
         self.req: Optional[_LMRequest] = None
@@ -316,6 +323,12 @@ class _Slot:
         self.owned: List[int] = []    # pages this lane allocated
         self.shared: List[int] = []   # prefix pages reused from the tree
         self.inserted = False         # prompt pages registered in the tree
+        # a recurrent model's lane: its live state row, the trailing row
+        # (the state at the last page boundary it landed on) and that
+        # boundary's position (None: the trailing row holds nothing yet)
+        self.row = 0
+        self.trail = 0
+        self.trail_pos: Optional[int] = None
 
     @property
     def active(self) -> bool:
@@ -346,6 +359,8 @@ class ContinuousLMServer:
                  state_dir: Optional[str] = None,
                  state_disk_bytes: int = 1 << 30,
                  swap_quantize: bool = True,
+                 state_rows: Optional[int] = None,
+                 snapshot_every: Optional[int] = None,
                  tracer: Optional[TraceRecorder] = None,
                  registry: Optional[MetricsRegistry] = None):
         if slots < 1:
@@ -374,6 +389,46 @@ class ContinuousLMServer:
                 "state_dir names a disk tier nothing would write: it "
                 "requires preempt=True or hibernate_idle_s (serve with "
                 "-lm-preempt or -lm-hibernate-idle-s)")
+        # a recurrent model (state rows beside the pages, snapshots under
+        # the radix tree): what of the serving plane it is refused
+        self.recurrent = bool(getattr(cfg, "recurrent", False))
+        if not self.recurrent and (state_rows is not None
+                                   or snapshot_every is not None):
+            raise ValueError(
+                "state_rows / snapshot_every size the state pool of a "
+                "model with recurrent layers; this one has none")
+        if self.recurrent:
+            from deeplearning4j_tpu.parallel.transformer import (
+                UnsupportedLayerKind,
+            )
+
+            for on, what in ((speculate != "off", "speculative decoding"),
+                             (ship, "page shipping (ship=True)"),
+                             (preempt, "preemption with host swap-out"),
+                             (hibernate_idle_s is not None,
+                              "session hibernation")):
+                if on:
+                    raise UnsupportedLayerKind(
+                        f"{what} moves or rewinds a lane by its K/V pages; "
+                        f"this model's recurrent layers keep a state row "
+                        f"a lane that it would leave behind")
+            state_rows = (4 * int(slots) if state_rows is None
+                          else int(state_rows))
+            if state_rows < 2 * int(slots):
+                raise ValueError(
+                    f"state_rows must be at least 2 a lane "
+                    f"({2 * int(slots)}), got {state_rows}")
+            if snapshot_every is None:
+                snapshot_every = 16 * max(int(page_size),
+                                          int(prefill_chunk))
+            if (snapshot_every % int(page_size)
+                    or snapshot_every % int(prefill_chunk)):
+                raise ValueError(
+                    f"snapshot_every ({snapshot_every}) must be a multiple "
+                    f"of page_size ({page_size}) and prefill_chunk "
+                    f"({prefill_chunk})")
+        self.state_rows = state_rows
+        self.snapshot_every = snapshot_every
         self.cfg = cfg
         self.params = params
         self.n_slots = int(slots)
@@ -432,6 +487,17 @@ class ContinuousLMServer:
         self._pool: Optional[PagePool] = None
         self._tree: Optional[RadixPrefixCache] = None
         self._pending_cow: List[Dict] = []
+        # recurrent models: the state rows' allocator, the row-copy
+        # program, restores awaiting their copy, and what the tree had
+        # evicted at the last count
+        self._states: Optional[StatePool] = None
+        self._state_copy = None
+        # copies a dispatch of the row-copy program: a round seldom has
+        # more than a lane or two at a boundary, and every entry, spare or
+        # not, moves a whole row (12.6 MB for Solar-Open2's three layers)
+        self._copy_batch = max(2, self.n_slots // 2)
+        self._pending_state: List[Dict] = []
+        self._snap_evicted_seen = 0
         # disaggregation plane (ISSUE-14): page export/import programs,
         # shipments awaiting their device install, and the sticky-session
         # LRU (session_id -> last-seen tick) behind session_affinity_hits
@@ -978,6 +1044,7 @@ class ContinuousLMServer:
                 generated=(len(req.result) - len(req.prompt)
                            if req.result else 0),
                 prefix_matched=req.prefix_matched or None,
+                snapshot_matched=req.snapshot_matched or None,
                 drafted=req.drafted or None,
                 accepted=(req.accepted if req.drafted else None),
                 preempted=req.preempted or None,
@@ -1078,14 +1145,23 @@ class ContinuousLMServer:
             else:
                 widths = [1] + ([self.prefill_chunk]
                                 if self.prefill_chunk > 1 else [])
+                # a recurrent model's lanes name their state rows last
+                # (all the null row here)
+                rows = (zi,) if self.recurrent else ()
                 for w in widths:
                     tok = np.zeros((self.n_slots, w), np.int32)
                     out = warm(f"lm:paged[w{w}]", lambda: self._step(
                         self.params, *self._cache, table, zi, zi, tok, zf,
-                        zi, zi))
+                        zi, zi, *rows))
                     self._cache = tuple(out[1:])
-            self._cache = tuple(warm("lm:page_copy", lambda: self._copy(
-                *self._cache, np.int32(0), np.int32(0))))
+            if self.recurrent:
+                # the null row onto itself; no match ends mid-page, so
+                # there is no copy-on-write page copy to warm
+                warm("lm:state_copy", lambda: self._copy_state_rows([]))
+            else:
+                self._cache = tuple(warm(
+                    "lm:page_copy", lambda: self._copy(
+                        *self._cache, np.int32(0), np.int32(0))))
             if self.ship or self.preempt or self.hibernate:
                 # the shipping/swap/hibernate pair: a gather out of the live
                 # pool (not donated — the row of nulls reads only the null
@@ -1218,6 +1294,20 @@ class ContinuousLMServer:
                                 if self._tree is not None else 0),
                 "ship": self.ship,
                 "paged_kernel": self.paged_kernel}
+            if self.recurrent:
+                from deeplearning4j_tpu.parallel.generation import (
+                    state_row_bytes,
+                )
+
+                st = out.setdefault("state", {})
+                st.update({
+                    "rows": self.state_rows,
+                    "rows_in_use": (self._states.in_use
+                                    if self._states is not None else 0),
+                    "snapshots_held": (self._tree.snapshots
+                                       if self._tree is not None else 0),
+                    "snapshot_every": self.snapshot_every,
+                    "row_bytes": state_row_bytes(self.cfg)})
             if self._sessions:
                 out["sessions_tracked"] = len(self._sessions)
             if self.preempt or self._pressure is not None:
@@ -1273,9 +1363,18 @@ class ContinuousLMServer:
         round, while the device rebuild may be deferred to dispatch."""
         from deeplearning4j_tpu.parallel.generation import init_paged_cache
 
-        # k and v, or the one latent pool (`generation.pool_layout`)
-        self._cache = tuple(init_paged_cache(
+        # k and v, or the one latent pool (`generation.pool_layout`), and
+        # after them a recurrent model's state and tail rows
+        pools = tuple(init_paged_cache(
             self.cfg, self.kv_pages + 1, self.page_size).values())
+        if self.recurrent:
+            from deeplearning4j_tpu.parallel.generation import (
+                init_state_pool,
+            )
+
+            pools += tuple(init_state_pool(
+                self.cfg, self.state_rows + 1).values())
+        self._cache = pools
 
     def _reset_pool_locked(self) -> None:
         """Fresh allocator + radix tree + slot page bookkeeping.  Called
@@ -1285,8 +1384,12 @@ class ContinuousLMServer:
         ``self._cond`` (the ``*_locked`` contract — admission reads the
         pool/tree/CoW list under the same lock)."""
         self._pool = PagePool(self.kv_pages + 1, self.page_size)
-        self._tree = RadixPrefixCache(self._pool)
+        self._states = (StatePool(self.state_rows + 1)
+                        if self.recurrent else None)
+        self._tree = RadixPrefixCache(self._pool, self._states)
         self._pending_cow = []
+        self._pending_state = []
+        self._snap_evicted_seen = 0
         # shipments awaiting device install referenced pages (and
         # content) that died with the pool — their lanes restart or fail
         # with it, so the pending plane resets wholesale too
@@ -1296,6 +1399,8 @@ class ContinuousLMServer:
             s.owned = []
             s.shared = []
             s.inserted = False
+            s.row = s.trail = 0
+            s.trail_pos = None
         if self._drafter is not None:
             # the drafter's lane state tracked lanes that no longer
             # exist; its own cache self-heals via the common-prefix
@@ -1322,7 +1427,7 @@ class ContinuousLMServer:
                 make_page_copy,
                 make_paged_step,
                 make_spec_step,
-                pool_layout,
+                pool_names,
             )
 
             total = self.kv_pages + 1
@@ -1338,7 +1443,16 @@ class ContinuousLMServer:
                 self._chunk_step = (make_paged_step(
                     self.cfg, total, self.page_size, self.prefill_chunk)
                     if self.prefill_chunk > 1 else None)
-            self._copy = make_page_copy(self.cfg, total, self.page_size)
+            if self.recurrent:
+                from deeplearning4j_tpu.parallel.generation import (
+                    make_state_copy,
+                )
+
+                self._state_copy = make_state_copy(self.cfg,
+                                                   self._copy_batch)
+            else:
+                self._copy = make_page_copy(self.cfg, total,
+                                            self.page_size)
             if self.ship or self.preempt or self.hibernate:
                 from deeplearning4j_tpu.parallel.generation import (
                     make_page_gather,
@@ -1358,7 +1472,7 @@ class ContinuousLMServer:
 
             # the pool arrays lead every call (k and v, or the one
             # latent pool: `generation.pool_layout`)
-            n_pools = len(pool_layout(self.cfg).names)
+            n_pools = len(pool_names(self.cfg))
             if self.speculate != "off":
                 def dispatch(params, *args):
                     # speculative signature: every dispatch carries
@@ -1411,6 +1525,10 @@ class ContinuousLMServer:
         slot.shared = []
         slot.table = None
         slot.inserted = False
+        if self._states is not None:
+            self._states.release([r for r in (slot.row, slot.trail) if r])
+            slot.row = slot.trail = 0
+            slot.trail_pos = None
 
     def _resolve_swap_locked(self, req: _LMRequest) -> None:
         """Turn a requeued victim's swap key into an installable
@@ -1450,6 +1568,8 @@ class ContinuousLMServer:
         supply the fresh pages — the request stays queued, FIFO.  Every
         page the plan references is already retained."""
         plen = len(req.prompt)
+        if self.recurrent:
+            return self._plan_admission_state(req)
         if req.swap_key is not None and self._swap is not None:
             # a preempted lane coming back: resolve its host swap into
             # the same install plane a shipped lane uses (or fall back
@@ -1531,6 +1651,36 @@ class ContinuousLMServer:
         return {"full": full, "partial": partial, "fresh": fresh,
                 "matched": matched, "total_pages": total_pages}
 
+    def _plan_admission_state(self, req: _LMRequest):
+        """`_plan_admission_paged` for a recurrent model: the match ends
+        at the deepest SNAPSHOT on the prompt's path (a page boundary, so
+        no copy-on-write), and the lane needs two state rows, its live
+        one and its trailing one, beside its pages.  Eviction may drop
+        pages (with their snapshots) and then snapshots alone; it is run
+        only where it can cover the shortfall."""
+        plen = len(req.prompt)
+        total_pages = self._required_pages(plen, req.max_new)
+        full, snap = self._tree.match_snapshot(req.prompt[:plen - 1])
+        need = total_pages - len(full)
+        if (self._pool.free < need
+                and self._pool.free + self._tree.evictable() >= need):
+            self._tree.evict(need)
+        if (self._states.free < 2 and self._states.free
+                + self._tree.snapshots_evictable() >= 2):
+            self._tree.evict_snapshots(2)
+        fresh = self._pool.alloc(need)
+        rows = self._states.alloc(2) if fresh is not None else None
+        if rows is None:
+            if fresh:
+                self._pool.release(fresh)
+            if full:
+                self._pool.release(full)
+                self._states.release([snap])
+            return None
+        return {"full": full, "partial": None, "fresh": fresh,
+                "matched": len(full) * self.page_size,
+                "total_pages": total_pages, "rows": rows, "snap": snap}
+
     def _probe_hibernated_locked(self, req: _LMRequest,
                                  have: int) -> Optional[Dict]:
         """Longest hibernated whole-page prompt prefix beyond the
@@ -1601,6 +1751,17 @@ class ContinuousLMServer:
         row[:n_full] = plan["full"]
         row[n_full:plan["total_pages"]] = plan["fresh"]
         slot.table = row
+        if "rows" in plan:
+            # a recurrent lane starts from the matched snapshot's state,
+            # or from nothing: one row copy ahead of its first feed.  The
+            # snapshot stays retained until that copy is dispatched.
+            slot.row, slot.trail = plan["rows"]
+            slot.trail_pos = None
+            snap = plan["snap"]
+            req.snapshot_matched = plan["matched"] if snap else 0
+            self._pending_state.append(
+                {"src": -1 if snap is None else int(snap),
+                 "dst": int(slot.row), "held": snap})
         if req.import_pages is not None:
             # shipped-in lane: arrive mid-flight exactly where the
             # prefill worker left it — prompt fully fed, first token(s)
@@ -1757,6 +1918,50 @@ class ContinuousLMServer:
         if self._pool is not None:
             self.metrics.set_pages(self._pool.in_use, self._pool.free,
                                    self.kv_pages)
+        self._record_state()
+
+    def _record_state(self, taken: int = 0, hit: int = 0,
+                      copied: int = 0) -> None:
+        """The state rows held now, and what the tree evicted since the
+        last count (eviction happens inside admission and snapshotting)."""
+        if self._states is None:
+            return
+        evicted = self._tree.snapshots_evicted - self._snap_evicted_seen
+        self._snap_evicted_seen = self._tree.snapshots_evicted
+        self.metrics.record_state(self._states.in_use, taken=taken, hit=hit,
+                                  evicted=evicted, copied=copied)
+
+    def _copy_state_rows(self, copies) -> tuple:
+        """Dispatch `(src, dst)` state-row copies through the ONE row-copy
+        program, `_copy_batch` a dispatch (spare entries copy the null row
+        onto itself); `src < 0` zeroes `dst`.  With nothing to copy one
+        dispatch still runs: the warm-up's, which waits on the `(state,
+        tail)` returned."""
+        n = self._copy_batch
+        # the worker thread owns `_cache` between dispatches (as in
+        # `_dispatch_paged`); every caller is the worker
+        pools = self._cache  # noqa: LCK101
+        paged, rows = pools[:-2], pools[-2:]
+        for i in range(0, max(len(copies), 1), n):
+            src = np.zeros((n,), np.int32)
+            dst = np.zeros((n,), np.int32)
+            for j, (a, b) in enumerate(copies[i:i + n]):
+                src[j], dst[j] = a, b
+            with compile_scope("lm:state_copy"):
+                rows = tuple(self._state_copy(*rows, src, dst))
+        self._cache = paged + rows  # noqa: LCK101
+        return rows
+
+    def _give_snapshot(self, slot: _Slot, tokens) -> bool:
+        """Hand the lane's trailing row, the state after `tokens` (whole
+        pages, all written), to the radix tree with the pages that lead to
+        it.  False where the tree has that snapshot already."""
+        n = len(tokens) // self.page_size
+        self._tree.insert(tokens, [int(p) for p in slot.table[:n]])
+        if not self._tree.attach(tokens, slot.trail):
+            return False
+        slot.trail, slot.trail_pos = 0, None
+        return True
 
     def _hibernate_idle_locked(self, now: float) -> None:
         """Park idle sticky sessions' cached pages on the tiered state
@@ -2034,6 +2239,14 @@ class ContinuousLMServer:
                 while len(self._hib_sessions) > self._session_capacity:
                     self._hib_sessions.popitem(last=False)
             slot.req.event.set()
+        if (self.recurrent and slot.trail_pos is not None
+                and slot.trail_pos > slot.req.prefix_matched):
+            # the request's end: its trailing row, the state at the last
+            # page boundary it landed on, goes to the tree, so that the
+            # session's next turn prefills at most a page and its new text
+            seq = slot.req.prompt + slot.generated
+            if self._give_snapshot(slot, seq[:slot.trail_pos]):
+                self._record_state(taken=1)
         self._free_slot_pages(slot)
         slot.req = None
 
@@ -2041,7 +2254,9 @@ class ContinuousLMServer:
         """Prefill just completed: register this prompt's FULL pages in
         the radix tree so the next shared-prefix request skips them.
         Page-granular — a prompt shorter than one page caches nothing."""
-        if slot.inserted:
+        if slot.inserted or self.recurrent:
+            # a recurrent model's pages serve a later prompt only up to a
+            # snapshot: they enter the tree with one (`_give_snapshot`)
             return
         slot.inserted = True
         plen = len(slot.req.prompt)
@@ -2088,6 +2303,7 @@ class ContinuousLMServer:
                 return False
             cow, self._pending_cow = self._pending_cow, []
             installs, self._pending_install = self._pending_install, []
+            restores, self._pending_state = self._pending_state, []
             # the brownout level this round dispatches under — read
             # once with the lock held; the ladder only moves inside
             # _admit_locked, so the level cannot change mid-dispatch
@@ -2104,6 +2320,9 @@ class ContinuousLMServer:
                     # un-executed CoW copies hold a retention on their
                     # source page; the lane that wanted them is failing
                     self._pool.release([item["src"]])
+                for item in restores:
+                    if item["held"] is not None:
+                        self._states.release([item["held"]])
                 for s in self._slots:
                     if s.active:
                         self.metrics.record_shed()
@@ -2125,7 +2344,7 @@ class ContinuousLMServer:
             # worker thread (page/radix state was already reset by the
             # fault handler — slots restart at pos 0, nothing to keep)
             self._reset_cache()
-        return self._dispatch_paged(active, cow, installs, level)
+        return self._dispatch_paged(active, cow, installs, level, restores)
 
     def _draft_proposals(self) -> Dict[int, List[int]]:
         """One drafting round: collect per-lane proposals for GREEDY
@@ -2205,10 +2424,13 @@ class ContinuousLMServer:
         """An export's page stacks (every pool's) padded to the install
         program's fixed `[L, max_pages, ps, heads, width]`; `ex=None`
         gives the all-zero stacks the warm-up installs."""
-        from deeplearning4j_tpu.parallel.generation import pool_layout
+        from deeplearning4j_tpu.parallel.generation import (
+            pool_depth,
+            pool_layout,
+        )
 
         lay = pool_layout(self.cfg)
-        shape = (self.cfg.n_layers, self.max_pages, self.page_size,
+        shape = (pool_depth(self.cfg), self.max_pages, self.page_size,
                  lay.heads, lay.width)
         out = []
         for i in range(len(lay.names)):
@@ -2243,7 +2465,7 @@ class ContinuousLMServer:
         self._finish_slot(slot)
 
     def _dispatch_paged(self, active, cow, installs,
-                        level: int = 0) -> bool:
+                        level: int = 0, restores=()) -> bool:
         # land shipped-in pages first (their lane's committed state is
         # already live — its next feed reads them), then pending
         # copy-on-write pages: a CoW admitted in the same round may
@@ -2276,6 +2498,13 @@ class ContinuousLMServer:
                     *self._cache, np.int32(item["src"]),
                     np.int32(item["dst"])))
             self._pool.release([item["src"]])
+        if restores:
+            # a recurrent lane's state: the matched snapshot's row into
+            # its live row (or zeros), ahead of its first feed
+            self._copy_state_rows([(r["src"], r["dst"]) for r in restores])
+            held = [r["held"] for r in restores if r["held"] is not None]
+            self._states.release(held)
+            self._record_state(hit=len(held), copied=len(restores))
         # brownout ladder effects (ISSUE-15, docs/robustness.md "The
         # degradation ladder"): level 1 turns speculation off (drafts
         # buy throughput with wide-dispatch compute — under pressure
@@ -2315,6 +2544,7 @@ class ContinuousLMServer:
         seeds = np.zeros((self.n_slots,), np.int32)
         counts = np.zeros((self.n_slots,), np.int32)
         table = np.zeros((self.n_slots, self.max_pages), np.int32)
+        rows = np.zeros((self.n_slots,), np.int32)
         for i, slot in enumerate(self._slots):
             if not slot.active:
                 continue
@@ -2322,6 +2552,13 @@ class ContinuousLMServer:
             remaining = len(req.prompt) - slot.fed
             if remaining > 0:                  # chunked prefill
                 f = min(remaining, width, chunk_eff)
+                if self.recurrent:
+                    # a feed that crosses a page boundary ends on the last
+                    # one it crosses: the state is known where a round
+                    # ends, and boundaries are where it is kept
+                    end = (slot.pos + f) // self.page_size * self.page_size
+                    if end > slot.pos:
+                        f = end - slot.pos
                 tokens[i, :f] = req.prompt[slot.fed:slot.fed + f]
                 n_feed[i] = f
                 fed["prefill"] += f
@@ -2350,6 +2587,7 @@ class ContinuousLMServer:
             seeds[i] = req.seed
             counts[i] = len(slot.generated)
             table[i] = slot.table
+            rows[i] = slot.row
         clock.to("dispatch")
         with compile_scope(f"lm:paged[w{width}]"):
             if self.speculate != "off":
@@ -2359,7 +2597,8 @@ class ContinuousLMServer:
             else:
                 nxt, *pools = self._step(
                     self.params, *self._cache, table, pos, n_feed, tokens,
-                    temp, seeds, counts)
+                    temp, seeds, counts, *((rows,) if self.recurrent
+                                           else ()))
                 acc = None
         if self.breaker is not None:
             self.breaker.record_success()
@@ -2376,12 +2615,15 @@ class ContinuousLMServer:
         clock.to("fold")
         self._steps += 1
         emitted = 0
+        saves, taken = [], 0
         for i, slot in enumerate(self._slots):
             if not slot.active or n_feed[i] == 0:
                 continue
             if slot.fed < len(slot.req.prompt):
                 slot.pos += int(n_feed[i])
                 slot.fed += int(n_feed[i])
+                if self.recurrent:
+                    taken += self._keep_state(slot, saves, prompt=True)
                 if slot.fed < len(slot.req.prompt):
                     continue
                 # prefill complete: its full pages become reusable, and
@@ -2406,6 +2648,8 @@ class ContinuousLMServer:
                 a = int(acc[i]) if acc is not None else 0
                 k_drafted = int(n_draft[i])
                 slot.pos += 1 + a
+                if self.recurrent:
+                    self._keep_state(slot, saves, prompt=False)
                 if k_drafted:
                     slot.req.drafted += k_drafted
                     slot.req.accepted += a
@@ -2419,6 +2663,13 @@ class ContinuousLMServer:
                     1 + a, drafted=k_drafted, accepted=a)
             if len(slot.generated) >= slot.req.max_new:
                 self._finish_slot(slot)
+        if self.recurrent:
+            if saves:
+                # after the step that made the states, before any later
+                # dispatch that could write the rows: device order
+                self._copy_state_rows(saves)
+            self._record_state(taken=taken, copied=len(saves))
+            self.metrics.record_kda_rows(width, len(active))
         self.metrics.record_dispatch(len(active), self.n_slots)
         if emitted:
             self.metrics.record_tokens(emitted)
@@ -2428,6 +2679,30 @@ class ContinuousLMServer:
         self.metrics.record_round(clock.take(), width, self.n_slots, fed,
                                   live_pages, attn_rows, attn_pairs)
         return True
+
+    def _keep_state(self, slot: _Slot, saves: List, prompt: bool) -> int:
+        """A recurrent lane just advanced to `slot.pos`.  Where that is a
+        page boundary, its trailing row is refreshed (one row copy, queued
+        in `saves`); where it is a multiple of `snapshot_every` inside
+        the prompt, the refreshed row goes to the radix tree and the lane
+        takes a fresh trailing row, if the state pool can give one.
+        -> snapshots given to the tree (0 or 1)."""
+        if slot.pos % self.page_size or not slot.trail:
+            return 0
+        saves.append((slot.row, slot.trail))
+        slot.trail_pos = slot.pos
+        if not prompt or slot.pos % self.snapshot_every:
+            return 0
+        if self._states.free < 1:
+            self._tree.evict_snapshots(1)
+        fresh = self._states.alloc(1)
+        if fresh is None:
+            return 0
+        if self._give_snapshot(slot, slot.req.prompt[:slot.pos]):
+            slot.trail = fresh[0]
+            return 1
+        self._states.release(fresh)
+        return 0
 
     def _run(self) -> None:
         while True:

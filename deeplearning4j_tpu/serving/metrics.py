@@ -58,6 +58,8 @@ FEED_KINDS = ("prefill", "decode", "draft")
 # a round is width 1 or wide; where a routed pair's expert lies
 ROUND_KINDS = ("w1", "wide")
 EXPERT_PLACES = ("held", "absent")
+# what happened to a recurrent model's state snapshot under the radix tree
+SNAPSHOT_EVENTS = ("taken", "hit", "evicted")
 # host time of one round (everything but `sync`): milliseconds matter
 _ROUND_HOST_BUCKETS = (0.00025, 0.0005, 0.001, 0.002, 0.003, 0.004, 0.005,
                        0.0075, 0.01, 0.02, 0.05, 0.1, 0.25, 1.0)
@@ -293,6 +295,24 @@ class ServingMetrics:
         self.expert_rounds_total = Counter(
             "serving_lm_expert_rounds_total",
             "rounds that reported an expert load")
+        # a recurrent model's state rows beside the pages (ISSUE-38)
+        self.state_rows_gauge = Gauge(
+            "serving_lm_state_rows_in_use",
+            "state rows held by live lanes and by snapshots")
+        self.snapshots = {
+            event: Counter("serving_lm_snapshots_total",
+                           "state snapshots given to the radix tree, "
+                           "restored into a lane, dropped by eviction")
+            for event in SNAPSHOT_EVENTS}
+        self.state_copy_rows_total = Counter(
+            "serving_lm_state_copy_rows_total",
+            "state rows copied (saved, restored or zeroed) by the "
+            "row-copy program")
+        self.kda_rows = {
+            kind: Counter("serving_lm_kda_rows_total",
+                          "state rows the round's recurrent layers read "
+                          "and wrote: its active lanes")
+            for kind in ROUND_KINDS}
         self.round_host_hist = Histogram(
             "serving_lm_round_host_seconds",
             "host time of one round: every phase but sync",
@@ -366,11 +386,14 @@ class ServingMetrics:
                   self.compute_hist,
                   self.idle_seconds_total, self.feed_capacity_total,
                   self.live_pages_total, self.round_host_hist,
-                  self.expert_peak_total, self.expert_rounds_total):
+                  self.expert_peak_total, self.expert_rounds_total,
+                  self.state_rows_gauge, self.state_copy_rows_total):
             registry.register(m, **labels)
         for cells, label in ((self.attn_rows, "round"),
                              (self.attn_pairs, "round"),
-                             (self.expert_pairs, "place")):
+                             (self.expert_pairs, "place"),
+                             (self.snapshots, "event"),
+                             (self.kda_rows, "round")):
             for value, m in cells.items():
                 registry.register(m, **{label: value}, **labels)
         for (_event, cls), m in self.class_counters.items():
@@ -477,6 +500,22 @@ class ServingMetrics:
         kind = "w1" if int(width) == 1 else "wide"
         self.attn_rows[kind].inc(int(attn_rows))
         self.attn_pairs[kind].inc(int(attn_pairs))
+
+    def record_state(self, rows_in_use: int, taken: int = 0, hit: int = 0,
+                     evicted: int = 0, copied: int = 0) -> None:
+        """The state-row economy of a recurrent model: rows held now,
+        and snapshots taken / restored / evicted and rows copied since
+        the last call."""
+        self.state_rows_gauge.set(int(rows_in_use))
+        for event, n in (("taken", taken), ("hit", hit),
+                         ("evicted", evicted)):
+            if n:
+                self.snapshots[event].inc(int(n))
+        if copied:
+            self.state_copy_rows_total.inc(int(copied))
+
+    def record_kda_rows(self, width: int, lanes: int) -> None:
+        self.kda_rows["w1" if int(width) == 1 else "wide"].inc(int(lanes))
 
     def record_expert_load(self, held: int, absent: int,
                            peak_x1000: int) -> None:
@@ -861,6 +900,14 @@ class ServingMetrics:
                 "host_ms": {"mean": 1e3 * host["mean"],
                             "p50": 1e3 * host["p50"],
                             "p99": 1e3 * host["p99"]}}
+        if any(int(m.value) for m in self.kda_rows.values()):
+            out["state"] = {
+                "rows_in_use": int(self.state_rows_gauge.value),
+                "snapshots": {e: int(m.value)
+                              for e, m in self.snapshots.items()},
+                "copy_rows": int(self.state_copy_rows_total.value),
+                "kda_rows": {k: int(m.value)
+                             for k, m in self.kda_rows.items()}}
         if int(self.expert_rounds_total.value):
             out["experts"] = {
                 "rounds": int(self.expert_rounds_total.value),

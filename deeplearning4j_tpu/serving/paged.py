@@ -42,6 +42,10 @@ class PageLeakError(AssertionError):
     """The page ledger stopped balancing: allocated != in_use + free."""
 
 
+class StateLeakError(PageLeakError):
+    """The state-row ledger stopped balancing."""
+
+
 class PagePool:
     """Refcounted fixed pool of KV page ids.
 
@@ -51,6 +55,9 @@ class PagePool:
     refcount reaches 0 returns to the free list.  Page 0 (null) is
     outside the economy entirely.
     """
+
+    Leak = PageLeakError        # what a broken ledger raises
+    what = "page"
 
     def __init__(self, pages: int, page_size: int):
         if pages < 2:
@@ -97,16 +104,16 @@ class PagePool:
     def retain(self, page_ids: Sequence[int]) -> None:
         for p in page_ids:
             if not 0 < p < self.pages or self._ref[p] <= 0:
-                raise PageLeakError(
-                    f"retain of un-allocated page {p} (ref "
+                raise self.Leak(
+                    f"retain of un-allocated {self.what} {p} (ref "
                     f"{self._ref[p] if 0 <= p < self.pages else '?'})")
             self._ref[p] += 1
 
     def release(self, page_ids: Sequence[int]) -> None:
         for p in page_ids:
             if not 0 < p < self.pages or self._ref[p] <= 0:
-                raise PageLeakError(
-                    f"release of un-held page {p} (ref "
+                raise self.Leak(
+                    f"release of un-held {self.what} {p} (ref "
                     f"{self._ref[p] if 0 <= p < self.pages else '?'})")
             self._ref[p] -= 1
             if self._ref[p] == 0:
@@ -126,8 +133,23 @@ class PagePool:
         return out
 
 
+class StatePool(PagePool):
+    """The page pool's economy for the rows of a recurrent model's state
+    pool (`generation.state_layout`): a live lane holds rows, a radix
+    node may hold one as its snapshot, row 0 is the null row idle lanes
+    carry and is never handed out.  Same refcounts, same ledger check,
+    `StateLeakError` where it breaks."""
+
+    Leak = StateLeakError
+    what = "state row"
+
+    def __init__(self, rows: int):
+        super().__init__(rows, 1)
+
+
 class _RadixNode:
-    __slots__ = ("key", "page", "children", "last_used", "parent")
+    __slots__ = ("key", "page", "children", "last_used", "parent", "snap",
+                 "snap_used")
 
     def __init__(self, key: Optional[Tuple[int, ...]], page: Optional[int],
                  parent: Optional["_RadixNode"]):
@@ -136,6 +158,10 @@ class _RadixNode:
         self.children: Dict[Tuple[int, ...], "_RadixNode"] = {}
         self.last_used = 0
         self.parent = parent
+        # a recurrent model's state AFTER this page's last token, as a
+        # row of the state pool the tree holds one reference on
+        self.snap: Optional[int] = None
+        self.snap_used = 0
 
 
 def _common_prefix(a: Sequence[int], b: Sequence[int]) -> int:
@@ -156,14 +182,26 @@ class RadixPrefixCache:
     on every cached page; `evict()` drops LRU leaves whose page nobody
     else holds, returning capacity without ever invalidating a page an
     active lane still reads.
+
+    With `states` (a recurrent model: some layers keep a state a sequence
+    and no K/V history) a node may carry a SNAPSHOT, the state after its
+    page's last token.  Pages alone no longer make a prefix reusable: the
+    state at their end has to be known, so `match()` ends at the deepest
+    node on the path that has a snapshot and never mid-page (no
+    copy-on-write), eviction frees a node's snapshot with its page, and
+    `evict_snapshots` may drop a snapshot alone, which costs the prefix
+    its reuse past the next snapshot up the path and frees a row.
     """
 
-    def __init__(self, pool: PagePool):
+    def __init__(self, pool: PagePool, states: Optional[StatePool] = None):
         self.pool = pool
+        self.states = states
         self.ps = pool.page_size
         self.root = _RadixNode(None, None, None)
         self._clock = itertools.count(1)
         self.nodes = 0
+        self.snapshots = 0              # held now
+        self.snapshots_evicted = 0      # dropped since the tree was made
 
     # ---- lookup -----------------------------------------------------------
 
@@ -180,6 +218,11 @@ class RadixPrefixCache:
         when the lane completes.  Callers cap reuse by passing
         `tokens[:plen-1]`: the last prompt token must always be re-fed
         to produce the first sampled logits."""
+        if self.states is not None:
+            pages, row = self.match_snapshot(tokens)
+            if row is not None:
+                self.states.release([row])
+            return pages, None
         tick = next(self._clock)
         node, pages, i = self.root, [], 0
         partial: Optional[Tuple[int, int]] = None
@@ -207,6 +250,89 @@ class RadixPrefixCache:
         if partial is not None:
             self.pool.retain([partial[0]])
         return pages, partial
+
+    def match_snapshot(self, tokens: Sequence[int]
+                       ) -> Tuple[List[int], Optional[int]]:
+        """`match()` for a recurrent model: `(pages, row)`, the pages up
+        to the deepest node on the path of whole matched pages that
+        carries a snapshot, and that snapshot's state row; `([], None)`
+        where the path has none.  Pages past it are not reused: the
+        state at their end is not known.  The pages AND the row are
+        retained; the caller releases the row once it has copied it."""
+        tick = next(self._clock)
+        node, path, i, deepest = self.root, [], 0, 0
+        while True:
+            chunk = tuple(int(t) for t in tokens[i:i + self.ps])
+            child = (node.children.get(chunk)
+                     if len(chunk) == self.ps else None)
+            if child is None:
+                break
+            path.append(child)
+            node, i = child, i + self.ps
+            if child.snap is not None:
+                deepest = len(path)
+        path = path[:deepest]
+        if not path:
+            return [], None
+        for n in path:
+            n.last_used = tick
+        path[-1].snap_used = tick
+        pages = [n.page for n in path]
+        self.pool.retain(pages)
+        self.states.retain([path[-1].snap])
+        return pages, path[-1].snap
+
+    def attach(self, tokens: Sequence[int], row: int) -> bool:
+        """Give the tree `row` as the snapshot after `tokens` (whole
+        pages, all of them inserted already).  The tree takes over the
+        caller's reference; False, and the row stays the caller's, where
+        that node has a snapshot already."""
+        node = self.root
+        for i in range(0, len(tokens), self.ps):
+            node = node.children[tuple(int(t)
+                                       for t in tokens[i:i + self.ps])]
+        if node is self.root or node.snap is not None:
+            return False
+        node.snap = int(row)
+        node.snap_used = node.last_used = next(self._clock)
+        self.snapshots += 1
+        return True
+
+    def _drop_snapshot(self, node: _RadixNode) -> None:
+        self.states.release([node.snap])
+        node.snap = None
+        self.snapshots -= 1
+        self.snapshots_evicted += 1
+
+    def _snapshot_nodes(self) -> List[_RadixNode]:
+        out, stack = [], list(self.root.children.values())
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            if n.snap is not None:
+                out.append(n)
+        return out
+
+    def snapshots_evictable(self) -> int:
+        """Snapshots the tree alone holds (no lane is restoring them)."""
+        return sum(self.states.refcount(n.snap) == 1
+                   for n in self._snapshot_nodes())
+
+    def evict_snapshots(self, need_free: int) -> int:
+        """Drop snapshots alone, least recently restored first, until
+        the state pool has `need_free` rows free.  The pages stay: they
+        serve prefixes that end at a snapshot further up the path."""
+        dropped = 0
+        if self.states.free >= need_free:
+            return 0
+        for node in sorted(self._snapshot_nodes(),
+                           key=lambda n: n.snap_used):
+            if self.states.free >= need_free:
+                break
+            if self.states.refcount(node.snap) == 1:
+                self._drop_snapshot(node)
+                dropped += 1
+        return dropped
 
     # ---- insert -----------------------------------------------------------
 
@@ -285,14 +411,17 @@ class RadixPrefixCache:
         heap: List[Tuple[int, int, _RadixNode]] = []
 
         def push(node: _RadixNode) -> None:
+            # a leaf without a snapshot serves nobody (a recurrent model
+            # reuses pages only up to a snapshot): it goes first
             if not node.children and self.pool.refcount(node.page) == 1:
-                heapq.heappush(heap, (node.last_used, next(tie), node))
+                heapq.heappush(heap, (node.snap is not None, node.last_used,
+                                      next(tie), node))
 
         for leaf in self._leaves():
             push(leaf)
         evicted = 0
         while heap and self.pool.free < need_free:
-            _, _, victim = heapq.heappop(heap)
+            _, _, _, victim = heapq.heappop(heap)
             # a node may sit in the heap twice (pushed as a leaf, again
             # as an emptied parent) or have been pinned since: re-check
             if (victim.children
@@ -301,6 +430,8 @@ class RadixPrefixCache:
                 continue
             del victim.parent.children[victim.key]
             self.pool.release([victim.page])
+            if victim.snap is not None:
+                self._drop_snapshot(victim)
             self.nodes -= 1
             evicted += 1
             if victim.parent is not self.root:
@@ -333,6 +464,8 @@ class RadixPrefixCache:
                 break
             del victim.parent.children[victim.key]
             self.pool.release([victim.page])
+            if victim.snap is not None:
+                self._drop_snapshot(victim)
             self.nodes -= 1
             dropped += 1
         return dropped
@@ -350,10 +483,13 @@ class RadixPrefixCache:
             n = stack.pop()
             stack.extend(n.children.values())
             self.pool.release([n.page])
+            if n.snap is not None:
+                self._drop_snapshot(n)
             dropped += 1
         self.root = _RadixNode(None, None, None)
         self.nodes = 0
         return dropped
 
 
-__all__ = ["PageLeakError", "PagePool", "RadixPrefixCache"]
+__all__ = ["PageLeakError", "PagePool", "RadixPrefixCache",
+           "StateLeakError", "StatePool"]

@@ -80,10 +80,15 @@ def model_signature(cfg, page_size: int) -> Dict:
     layout function gives it (`generation.pool_layout`: full heads, or
     one latent row for all heads); a pool that is not the k and v pair
     says how many arrays it has under `pools`."""
-    from deeplearning4j_tpu.parallel.generation import pool_layout
+    from deeplearning4j_tpu.parallel.generation import (
+        pool_depth,
+        pool_layout,
+        require_stateless,
+    )
 
+    require_stateless(cfg, "page shipping (serving/transfer.py)")
     lay = pool_layout(cfg)
-    sig = {"n_layers": int(cfg.n_layers), "n_heads": int(lay.heads),
+    sig = {"n_layers": int(pool_depth(cfg)), "n_heads": int(lay.heads),
            "head_dim": int(lay.width), "dtype": str(cfg.dtype),
            "max_len": int(cfg.max_len),
            "vocab_size": int(cfg.vocab_size),

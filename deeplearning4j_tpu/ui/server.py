@@ -816,7 +816,9 @@ class UiServer:
                  hibernate_idle_s: Optional[float] = None,
                  state_dir: Optional[str] = None,
                  state_disk_bytes: int = 1 << 30,
-                 swap_quantize: bool = True) -> "UiServer":
+                 swap_quantize: bool = True,
+                 state_rows: Optional[int] = None,
+                 snapshot_every: Optional[int] = None) -> "UiServer":
         """Register a TransformerLM for POST /lm/generate.  With
         `continuous` (default) greedy/temperature requests decode in a
         `slots`-lane continuous batching pool; `continuous=False` keeps
@@ -846,7 +848,12 @@ class UiServer:
         byte-identically — even after a process restart over the same
         `state_dir`; `swap_quantize=False` keeps swap/hibernate frames
         exact instead of per-page int8 (docs/robustness.md "The state
-        hierarchy")."""
+        hierarchy").  `state_rows` and `snapshot_every` size the state
+        pool of a model with recurrent layers (live lanes and the radix
+        tree's snapshots share its rows; a prompt leaves a snapshot every
+        `snapshot_every` tokens) and are a `ValueError` for any other
+        model; such a model is refused `speculate`, `ship`, `preempt` and
+        hibernation (`UnsupportedLayerKind`)."""
         lm_server = None
         if continuous:
             from deeplearning4j_tpu.serving import (
@@ -868,6 +875,7 @@ class UiServer:
                 hibernate_idle_s=hibernate_idle_s, state_dir=state_dir,
                 state_disk_bytes=state_disk_bytes,
                 swap_quantize=swap_quantize,
+                state_rows=state_rows, snapshot_every=snapshot_every,
                 tracer=self.state.tracer,
                 registry=self.state.registry)
         with self.state.lock:

@@ -652,11 +652,16 @@ class TestCompositionRegression:
         """Tenant A's interactive request must win the slot over tenant
         B's ALREADY-QUEUED best_effort work — priority composes over
         WFQ exactly as it did pre-tenancy.  The request that holds the
-        slot decodes 100 tokens, so both others are queued long before
-        it ends (at 6 it could end first on a loaded host, and the
-        best_effort request then had the slot before the interactive
-        one existed)."""
-        cfg, params = _lm(max_len=128)
+        slot decodes 480 tokens, so both others are queued long before
+        it ends (at 6, and under six test workers at 100, it could end
+        first on a loaded host, and the best_effort request then had the
+        slot before the interactive one existed).  The best_effort
+        request decodes 200: `done` is the order in which the CLIENTS'
+        threads woke, and at 6 tokens each the loser of the slot ended
+        a few milliseconds after the winner, less than a thread's
+        wake-up on a loaded host, so the order could read reversed
+        (the driver's run of PR 38 did)."""
+        cfg, params = _lm(max_len=512)
         srv = ContinuousLMServer(
             cfg, params, slots=1, page_size=4,
             tenants={"team-a": {"weight": 4.0, "slo_ms": 500.0},
@@ -674,12 +679,12 @@ class TestCompositionRegression:
         try:
             t0 = threading.Thread(target=run, args=("first", [1, 2],
                                                     "batch", "team-b",
-                                                    100))
+                                                    480))
             t0.start()
             _wait_mid_decode(srv, committed=1)
             t1 = threading.Thread(target=run, args=("be", [3, 4],
                                                     "best_effort",
-                                                    "team-b"))
+                                                    "team-b", 200))
             t1.start()
             deadline = time.perf_counter() + 5
             while time.perf_counter() < deadline:

@@ -655,8 +655,25 @@ def flash_attention(q, k, v, causal: bool = True,
     return out
 
 
+# What a block's `jax.checkpoint` keeps of its attention (transformer.
+# `_apply_dealt`): the forward kernel's two outputs, so that the backward
+# goes to dK/dV and dQ without running the forward kernel again.
+SAVED_NAMES = ("attn_out", "attn_lse")
+
+
+def _saved(out, lse):
+    """`out` and `lse` under `SAVED_NAMES`; an identity off a checkpoint.
+    (Imported here and not at the top: a Mosaic body carries the line
+    numbers of everything above it, and those key the compile cache.)"""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return (checkpoint_name(out, SAVED_NAMES[0]),
+            checkpoint_name(lse, SAVED_NAMES[1]))
+
+
 def _fa_fwd(q, k, v, causal, interpret):
-    out, lse = _flash_forward(q, k, v, causal, _resolve_interpret(interpret))
+    out, lse = _saved(*_flash_forward(q, k, v, causal,
+                                      _resolve_interpret(interpret)))
     return out, (q, k, v, out, lse)
 
 

@@ -305,10 +305,14 @@ def _rfa_fwd(q, k, v, axis_name, causal, interpret):
         out, lse = _k._flash_forward(q, k, v, causal,
                                      _k._resolve_interpret(interpret))
         b, s, h, _ = q.shape
-        return out, (q, k, v, out, lse.reshape(b, h, s).transpose(0, 2, 1))
+        out, lse = _k._saved(out, lse.reshape(b, h, s).transpose(0, 2, 1))
+        return out, (q, k, v, out, lse)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    out, lse = _ring_flash_fwd_pass(q, k, v, axis_name, causal, interpret)
+    # the merged output and statistics: saved, none of the ring's forward
+    # kernels or hops is left in a checkpoint's recomputation
+    out, lse = _k._saved(
+        *_ring_flash_fwd_pass(q, k, v, axis_name, causal, interpret))
     return out, (q, k, v, out, lse)
 
 

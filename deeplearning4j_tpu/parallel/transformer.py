@@ -170,9 +170,9 @@ class TransformerConfig:
     # parameter) — at GPT-2-small scale this is the difference between
     # 124M and 163M params.
     tie_embeddings: bool = False
-    # Rematerialize each transformer block in the backward pass
-    # (jax.checkpoint): activation memory drops from O(L*B*S*d) to the
-    # block boundaries, the standard trade for long-context training.
+    # Recompute each block's activations in the backward pass (jax.
+    # checkpoint), all but the flash kernels' output and row statistics
+    # (kernels.SAVED_NAMES): O(L*B*S*d) falls to the block boundaries.
     remat: bool = False
     # --- the layer vocabulary past GPT-2's (all defaults are GPT-2) ---
     norm: str = "layer"         # "layer" (gain and bias) | "rms" (gain)
@@ -1125,7 +1125,14 @@ def _apply_dealt(cfg: TransformerConfig, params: dict, tokens, order, mesh,
 
             return block(cfg, layer, x, mixer, ffn, constrain), aux[0]
 
-        return jax.checkpoint(one) if cfg.remat else one
+        if not cfg.remat:
+            return one
+        from deeplearning4j_tpu.parallel import kernels
+
+        # a mixer that names nothing (no kernel, KDA, latent) saves nothing
+        return jax.checkpoint(
+            one, policy=jax.checkpoint_policies.save_only_these_names(
+                *kernels.SAVED_NAMES))
 
     # a layer's mixer is its kind's (`mixer_kinds`), one function a kind
     ones = {"full": layer_of(attend), "kda": layer_of(recur)}

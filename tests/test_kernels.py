@@ -175,6 +175,41 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4)
 
+    @pytest.mark.parametrize("seq_chips", [1, 2], ids=["one-device", "ring"])
+    def test_remat_does_not_run_the_forward_kernel_again(self, monkeypatch,
+                                                         seq_chips):
+        """A block's checkpoint saves the forward kernel's output and row
+        statistics (`kernels.SAVED_NAMES`), so the gradient of a rematted
+        model launches the kernels of a plain one: a forward, a dK/dV and
+        a dQ for every block pair of the ring, a layer."""
+        from deeplearning4j_tpu.parallel import make_mesh
+        from deeplearning4j_tpu.parallel import transformer as tfm
+
+        monkeypatch.setenv("DL4J_TPU_FLASH", "1")
+        mesh = None if seq_chips == 1 else make_mesh(
+            (1, seq_chips, 1), ("data", "seq", "model"),
+            devices=jax.devices()[:seq_chips])
+        tokens = jnp.asarray(
+            np.random.default_rng(0).integers(0, 17, (2, 8)), jnp.int32)
+
+        def launches(jaxpr):
+            return sum(
+                (e.primitive.name == "pallas_call")
+                + sum(launches(j) for j in jax.core.jaxprs_in_params(e.params))
+                for e in jaxpr.eqns)
+
+        def grad_launches(remat):
+            cfg = tfm.TransformerConfig(
+                vocab_size=17, d_model=16, n_heads=2, n_layers=2, d_ff=32,
+                max_len=16, remat=remat)
+            params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+            return launches(jax.make_jaxpr(jax.grad(
+                lambda p: tfm.lm_loss(cfg, p, tokens, tokens, mesh)))(
+                    params).jaxpr)
+
+        assert grad_launches(True) == grad_launches(False) \
+            == 2 * 3 * seq_chips
+
 
 # ---------------------------------------------------------------------------
 # One parity test over what the kernels adapt to (ISSUE 27): operand dtype,

@@ -664,22 +664,31 @@ class TestFlagshipTrainingPath:
         loss0 = float(tfm.lm_loss(cfg, params, tokens, targets))
         assert loss0 < 2.0 * np.log(cfg.vocab_size), loss0
 
-    @pytest.mark.slow  # ~13s; grad-accumulation equivalence keeps
-    # the flagship training path covered in tier-1
-    def test_remat_is_numerically_transparent(self):
+    # the plain-attention case is ~13s; grad-accumulation equivalence
+    # keeps the flagship training path covered in tier-1.  With the flash
+    # kernels (interpreted here) the block's checkpoint saves their output
+    # and row statistics and does not recompute them.
+    @pytest.mark.parametrize("flash,experts", [
+        pytest.param("0", 0, marks=pytest.mark.slow, id="plain-dense"),
+        pytest.param("1", 0, id="flash-dense"),
+        pytest.param("1", 4, id="flash-moe"),
+    ])
+    def test_remat_is_numerically_transparent(self, monkeypatch, flash,
+                                              experts):
+        monkeypatch.setenv("DL4J_TPU_FLASH", flash)
         tokens = jnp.asarray(
             np.random.default_rng(1).integers(0, 64, (2, 8)), jnp.int32)
         targets = jnp.roll(tokens, -1, axis=1)
-        p = tfm.init_params(self._cfg(), jax.random.PRNGKey(1))
+        plain = self._cfg(n_experts=experts)
+        rematted = self._cfg(n_experts=experts, remat=True)
+        p = tfm.init_params(plain, jax.random.PRNGKey(1))
         for train in (False, True):
-            base = tfm.apply(self._cfg(), p, tokens, train=train)
-            rem = tfm.apply(self._cfg(remat=True), p, tokens, train=train)
+            base = tfm.apply(plain, p, tokens, train=train)
+            rem = tfm.apply(rematted, p, tokens, train=train)
             np.testing.assert_allclose(np.asarray(rem), np.asarray(base),
                                        atol=1e-6)
-        g0 = jax.grad(lambda q: tfm.lm_loss(self._cfg(), q, tokens,
-                                            targets))(p)
-        g1 = jax.grad(lambda q: tfm.lm_loss(self._cfg(remat=True), q,
-                                            tokens, targets))(p)
+        g0, g1 = (jax.grad(lambda q, cfg=cfg: tfm.lm_loss(
+            cfg, q, tokens, targets))(p) for cfg in (plain, rematted))
         for a, b in zip(jax.tree_util.tree_leaves(g0),
                         jax.tree_util.tree_leaves(g1)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -11,10 +11,14 @@ doing right now, and where did this one slow request spend its time":
   export; request ids propagate across the fleet via ``X-Request-Id``;
   `PhaseClock` / `annotate` put a loop's phases on the profiler's host
   plane and in counters at once;
-- `compilewatch` — first-class ``compiles_total{program_key=...}``
-  fed by ``jax.monitoring`` compile events, plus the recent-event ring
-  the tracer uses to attach ``xla_compile`` spans to the request that
-  paid for an off-ladder recompile;
+- `compilewatch` — the build account, fed by ``jax.monitoring``: every
+  program the process builds by key (the active `compile_scope`, else
+  ``fn:<function>``) and by stage (Python tracing, lowering, compile or
+  cache load, with the persistent cache's hits and misses):
+  ``compiles_total{program_key}``, ``compile_seconds_total{program_key,
+  stage}``, ``compile_cache_total{program_key, result}``, plus the
+  recent-event ring the tracer uses to attach ``xla_compile`` spans to
+  the request that paid for an off-ladder recompile;
 - `telemetry` — `TrainingTelemetry`, the listener-slot feed for step
   time, examples/sec, grad norm, loss-scale grow/backoff events and
   supervisor interventions (``dl4j train -metrics-port``);

@@ -190,23 +190,25 @@ class HybridParallelTrainer:
         self.shard_update = bool(shard_update)
         self._pspecs = tfm.param_specs(cfg, axes.model)
         given = params is not None
-        if not given:
-            params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
-        self.params = place_params(mesh, _master_f32(params), self._pspecs)
-        if given:
-            # a placed shard can share its buffer with the array it came
-            # from (`device_put` onto the device that holds it), and the
-            # step donates its masters: copy, so that the first step does
-            # not delete the caller's weights
-            self.params = jax.tree_util.tree_map(jnp.copy, self.params)
-        transform = make_updater(UpdaterConfig(
-            updater=updater, learning_rate=lr, epsilon=1e-8))
-        self.opt_state = transform.init(self.params)
-        self._opt_specs = (self._zero1_opt_specs() if self.shard_update
-                           else None)
-        if self._opt_specs is not None:
-            self.opt_state = place_params(mesh, self.opt_state,
-                                          self._opt_specs)
+        # the masters' and the moments' placement: built once, by key
+        with compile_scope("train:place"):
+            if not given:
+                params = tfm.init_params(cfg, jax.random.PRNGKey(seed))
+            self.params = place_params(mesh, _master_f32(params), self._pspecs)
+            if given:
+                # a placed shard can share its buffer with the array it came
+                # from (`device_put` onto the device that holds it), and the
+                # step donates its masters: copy, so that the first step does
+                # not delete the caller's weights
+                self.params = jax.tree_util.tree_map(jnp.copy, self.params)
+            transform = make_updater(UpdaterConfig(
+                updater=updater, learning_rate=lr, epsilon=1e-8))
+            self.opt_state = transform.init(self.params)
+            self._opt_specs = (self._zero1_opt_specs() if self.shard_update
+                               else None)
+            if self._opt_specs is not None:
+                self.opt_state = place_params(mesh, self.opt_state,
+                                              self._opt_specs)
         cfg_, mesh_, axes_ = cfg, mesh, axes
         compute_dtype = jnp.dtype(cfg.dtype)
 

@@ -36,7 +36,12 @@ def device_line() -> str:
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compile cache and return its directory.
-    Call before the first compile of the process."""
+    Call before the first compile of the process: the build account
+    (`obs.compilewatch`) starts listening here too, so it hears every
+    program the process builds and what the cache saved of each."""
+    from deeplearning4j_tpu.obs.compilewatch import compile_watcher
+
+    compile_watcher()
     placed = os.environ.get(CACHE_ENV)
     if placed:
         return placed

@@ -173,8 +173,10 @@ from typing import Dict, Iterator, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu.obs.compilewatch import (
+    STAGES,
     compile_scope,
     compile_watcher,
+    over_keys,
 )
 from deeplearning4j_tpu.obs.registry import MetricsRegistry
 from deeplearning4j_tpu.obs.trace import (
@@ -1118,14 +1120,26 @@ class ContinuousLMServer:
         t_start = time.perf_counter()
         compiles = self._compile_watch.total()
         programs: Dict[str, float] = {}
+        stages: Dict[str, Dict[str, float]] = {}
 
         def warm(key, call):
             """One program under its key, waited for: what it costs to
-            compile or load and to run once."""
+            trace, lower, compile or load, and to run once."""
             t0 = time.perf_counter()
             with compile_scope(key):
                 out = jax.block_until_ready(call())
-            programs[key] = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            programs[key] = t1 - t0
+            # whatever was built in the key's interval (a drafter builds
+            # under a key of its own); cache_load lies inside backend
+            split = {**dict.fromkeys(STAGES, 0.0), **over_keys(
+                self._compile_watch.stage_seconds(t0, t1))}
+            cache = over_keys(self._compile_watch.cache_results(t0, t1))
+            stages[key] = {
+                **split, "hits": cache.get("hit", 0),
+                "misses": cache.get("miss", 0),
+                "run": t1 - t0 - sum(split[stage] for stage in
+                                     ("trace", "lower", "backend"))}
             return out
 
         try:
@@ -1177,6 +1191,7 @@ class ContinuousLMServer:
         finally:
             self._warmup_stats = {
                 "programs": programs,
+                "stages": stages,
                 "compiles": self._compile_watch.total() - compiles,
                 "total_s": time.perf_counter() - t_start}
 
@@ -1365,15 +1380,16 @@ class ContinuousLMServer:
 
         # k and v, or the one latent pool (`generation.pool_layout`), and
         # after them a recurrent model's state and tail rows
-        pools = tuple(init_paged_cache(
-            self.cfg, self.kv_pages + 1, self.page_size).values())
-        if self.recurrent:
-            from deeplearning4j_tpu.parallel.generation import (
-                init_state_pool,
-            )
+        with compile_scope("kv:pool"):
+            pools = tuple(init_paged_cache(
+                self.cfg, self.kv_pages + 1, self.page_size).values())
+            if self.recurrent:
+                from deeplearning4j_tpu.parallel.generation import (
+                    init_state_pool,
+                )
 
-            pools += tuple(init_state_pool(
-                self.cfg, self.state_rows + 1).values())
+                pools += tuple(init_state_pool(
+                    self.cfg, self.state_rows + 1).values())
         self._cache = pools
 
     def _reset_pool_locked(self) -> None:

@@ -247,21 +247,23 @@ class TestPriorityAdmission:
             srv.stop()
 
     def test_interactive_overtakes_queued_best_effort(self):
-        cfg, params = _lm()
+        cfg, params = _lm(max_len=128)
         srv = ContinuousLMServer(cfg, params, slots=1,
                                  page_size=4)
         srv.warmup()
         done = []
         lock = threading.Lock()
 
-        def run(name, prompt, prio):
-            srv.generate(prompt, 6, priority=prio, timeout=600)
+        def run(name, prompt, prio, new=6):
+            srv.generate(prompt, new, priority=prio, timeout=600)
             with lock:
                 done.append(name)
 
         try:
+            # the slot's holder decodes 100 tokens: two thread starts on
+            # a loaded host have outlasted 6 (a whole run of PR 40)
             t0 = threading.Thread(target=run,
-                                  args=("first", [1, 2], "batch"))
+                                  args=("first", [1, 2], "batch", 100))
             t0.start()
             _wait_mid_decode(srv, committed=1)
             # while the slot is busy: best_effort queues first,

@@ -29,6 +29,8 @@ from deeplearning4j_tpu.parallel.paged_kernel import (
     latent_paged_attention,
     paged_flash_attention,
     resolve_paged_kernel,
+    row_writer_takes,
+    write_kv_rows,
 )
 from deeplearning4j_tpu.parallel.transformer import (
     TransformerConfig,
@@ -231,12 +233,13 @@ def generate(cfg: TransformerConfig, params: dict, prompt,
 #
 # Heads and head size share the pool's LAST axis (`H*K`, 1280 lanes for
 # GPT-2-large): a page is `page_size` rows of full 128-lane tiles, so the
-# buffer as it rests on the device, the rows the step scatters into it
-# and the blocks the paged kernel reads have one physical layout.  That
-# is what lets the donated buffers be updated in place: with `(H, K)` as
-# the minor dims the TPU compiler pads `(20, 64)` to `(24, 128)` for the
-# kernel, lays the scatter's rows out a third way, and converts between
-# the three by copying the pool (PERF.md section 4).
+# buffer as it rests on the device, the rows the step writes into it
+# (`_write_fed_rows`: an XLA scatter, or the row writer's DMAs of whole
+# 8-row groups) and the blocks the paged kernel reads have one physical
+# layout.  That is what lets the donated buffers be updated in place:
+# with `(H, K)` as the minor dims the TPU compiler pads `(20, 64)` to
+# `(24, 128)` for the kernel, lays the scatter's rows out a third way,
+# and converts between the three by copying the pool (PERF.md section 4).
 
 
 def pages_per_seq(cfg: TransformerConfig, page_size: int) -> int:
@@ -370,11 +373,11 @@ def init_paged_cache(cfg: TransformerConfig, pages: int,
 
 
 def _fed_rows(table, pos, n_feed, c: int, pages: int, ps: int, layer: int):
-    """Where a dispatch writes: (flat pool rows [B*C] of the fed columns,
-    write positions [B, C]).  Lane b's column j lands at position
-    `pos[b] + j` of its own pages of layer `layer`, flat row
-    `(layer*P + page)*ps + off` of the stacked pool; padding columns and
-    inactive lanes write the layer's null page 0."""
+    """Where a dispatch's scatter writes: the flat pool rows [B*C] of the
+    fed columns.  Lane b's column j lands at position `pos[b] + j` of its
+    own pages of layer `layer`, flat row `(layer*P + page)*ps + off` of
+    the stacked pool; padding columns and inactive lanes write the
+    layer's null page 0."""
     mp = table.shape[1]
     j = jnp.arange(c)[None, :]                            # [1, C]
     wpos = pos[:, None] + j                               # [B, C] write pos
@@ -384,17 +387,50 @@ def _fed_rows(table, pos, n_feed, c: int, pages: int, ps: int, layer: int):
     page = jnp.where(real, page, 0)                       # padding -> null
     off = jnp.where(real, wpos % ps, 0)
     base = layer * pages                                  # this layer's pages
-    return ((base + page) * ps + off).reshape(-1), wpos
+    return ((base + page) * ps + off).reshape(-1)
 
 
-def _write_fed_rows(cache_k, cache_v, k, v, idx):
-    """The fed tokens' k/v rows written at flat rows `idx` (`_fed_rows`) of
-    the stacked pools `[L, P, ps, row]`.  -> (cache_k, cache_v, and the two
-    as flat `[L*P*ps, row]` views, which the gather oracles read)."""
-    row = cache_k.shape[-1]
-    fk = cache_k.reshape(-1, row).at[idx].set(k.reshape(-1, row))
-    fv = cache_v.reshape(-1, row).at[idx].set(v.reshape(-1, row))
-    return fk.reshape(cache_k.shape), fv.reshape(cache_v.shape), fk, fv
+def kv_rows_by_kernel(paged_kernel: bool, n_pools: int, ps: int,
+                      row: int) -> bool:
+    """Which form a step program's write of its fed rows takes
+    (`_write_fed_rows`), from what the program being built sees: the row
+    writer (`paged_kernel.write_kv_rows`) where the paged kernel runs (a
+    TPU, or `paged_kernel=True`), the pools are a K and a V pool, and
+    they are whole tiles; the `.at[].set` scatter elsewhere: the oracle,
+    and the one latent pool.  Every feed width alike: at width 1 too the
+    writer is the faster of the two (PERF.md section 5)."""
+    return bool(paged_kernel) and n_pools == 2 and row_writer_takes(ps, row)
+
+
+def kv_write_path(cfg: TransformerConfig, page_size: int,
+                  paged_kernel: bool | None = None) -> str:
+    """"kernel" or "scatter": `kv_rows_by_kernel` for `cfg`'s pools, what
+    `stats()["kv"]["write_path"]` reports of each step program."""
+    lay = pool_layout(cfg)
+    return ("kernel" if kv_rows_by_kernel(
+        resolve_paged_kernel(paged_kernel), len(lay.names), int(page_size),
+        lay.row) else "scatter")
+
+
+def _write_fed_rows(pools: tuple, rows: tuple, layer: int, table, pos,
+                    n_feed, paged_kernel: bool) -> tuple:
+    """The fed tokens' rows (`rows`, each `[B, C, ...]`) written into
+    layer `layer` of the stacked pools (`pools`, each `[L, P, ps, row]`:
+    k and v, or the one latent pool), in one of two forms
+    (`kv_rows_by_kernel`): the row writer, one call for both pools that
+    touches the real rows' 8-row groups and nothing else, or the scatter
+    at flat rows `_fed_rows`, whose padding goes to the null page.
+    -> the pools."""
+    _, pages, ps, row = pools[0].shape
+    c = rows[0].shape[1]
+    with jax.named_scope("kv:write"):
+        if kv_rows_by_kernel(paged_kernel, len(pools), ps, row):
+            return write_kv_rows(*pools, *rows, table, pos, n_feed, layer)
+        idx = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
+        return tuple(
+            pool.reshape(-1, row).at[idx].set(new.reshape(-1, row)
+                                              ).reshape(pool.shape)
+            for pool, new in zip(pools, rows))
 
 
 def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
@@ -407,13 +443,14 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
     this dispatch.  Returns (out [B, C, d], cache_k, cache_v): the same
     stacked buffers with this layer's fed rows written.
 
-    Each lane scatters its fed tokens' k/v rows into its OWN pages of
-    this layer (padding columns and inactive lanes write the layer's
-    null page 0) at flat row `(layer*P + page)*ps + off` of the stacked
-    buffer — no per-layer slice is taken and nothing is restacked, so
-    under `donate_argnums` the pool is updated where it lies.  Then the
-    lane attends over its logical history.  Two history paths share
-    that scatter:
+    Each lane's fed tokens' k/v rows are written into its OWN pages of
+    this layer (`_write_fed_rows`: by the row writer, which touches the
+    real rows alone, or by the scatter, whose padding columns and
+    inactive lanes write the layer's null page 0), row `off` of page
+    `layer*P + page` of the stacked buffer — no per-layer slice is taken
+    and nothing is restacked, so under `donate_argnums` the pool is
+    updated where it lies.  Then the lane attends over its logical
+    history.  Two history paths follow that write:
 
     - ``paged_kernel=False`` — the gather ORACLE: materialize the full
       ``[B, MP*ps, H, K]`` history through the block table and run
@@ -435,18 +472,19 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
     b, c, h, kd = q.shape
     _, pages, ps, _ = cache_k.shape
     mp = table.shape[1]
-    base = layer * pages                                  # this layer's pages
-    idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
-    cache_k, cache_v, fk, fv = _write_fed_rows(cache_k, cache_v, k, v, idx)
+    cache_k, cache_v = _write_fed_rows((cache_k, cache_v), (k, v), layer,
+                                       table, pos, n_feed, paged_kernel)
     if paged_kernel:
         o = paged_flash_attention(q, cache_k, cache_v, table, pos, n_feed,
                                   layer=layer)
         return out_proj(p, o), cache_k, cache_v
     # gather each lane's logical history: [B, S, H, K], S = MP * ps
-    gidx = ((base + table)[:, :, None] * ps
+    gidx = ((layer * pages + table)[:, :, None] * ps
             + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
+    fk, fv = cache_k.reshape(-1, h * kd), cache_v.reshape(-1, h * kd)
     hist_k = fk[gidx].reshape(b, mp * ps, h, kd)
     hist_v = fv[gidx].reshape(b, mp * ps, h, kd)
+    wpos = pos[:, None] + jnp.arange(c)[None, :]          # [B, C] write pos
     s = jnp.einsum("bqhk,bshk->bqhs", q, hist_k) / jnp.sqrt(
         jnp.asarray(kd, q.dtype))
     causal = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]
@@ -461,7 +499,7 @@ def _grouped_paged_attn(p, x, cache_k, cache_v, layer: int, table, pos,
                         n_feed, paged_kernel: bool = False):
     """`_paged_attn` where the K/V heads are fewer than the query heads
     (query head j reads K/V head `j // G`) and the output may be gated:
-    the pool's row is `[Hkv * K]`, the scatter is the same, the kernel is
+    the pool's row is `[Hkv * K]`, the write is the same, the kernel is
     `paged_flash_attention`'s grouped form and the oracle gathers the
     history as `[B, S, Hkv, K]`.  Scores in float32."""
     with jax.named_scope("attn:gqa"):
@@ -470,9 +508,8 @@ def _grouped_paged_attn(p, x, cache_k, cache_v, layer: int, table, pos,
         hkv = k.shape[2]
         _, pages, ps, _ = cache_k.shape
         mp = table.shape[1]
-        idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
-        cache_k, cache_v, fk, fv = _write_fed_rows(cache_k, cache_v, k, v,
-                                                   idx)
+        cache_k, cache_v = _write_fed_rows((cache_k, cache_v), (k, v), layer,
+                                           table, pos, n_feed, paged_kernel)
         if paged_kernel:
             o = paged_flash_attention(q, cache_k, cache_v, table, pos,
                                       n_feed, layer=layer)
@@ -480,8 +517,11 @@ def _grouped_paged_attn(p, x, cache_k, cache_v, layer: int, table, pos,
             gidx = ((layer * pages + table)[:, :, None] * ps
                     + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
             # the gather ORACLE of the grouped path (parity reference)
+            fk = cache_k.reshape(-1, hkv * kd)
+            fv = cache_v.reshape(-1, hkv * kd)
             hist_k = fk[gidx].reshape(b, mp * ps, hkv, kd)  # noqa: PGD301 — oracle
             hist_v = fv[gidx].reshape(b, mp * ps, hkv, kd)  # noqa: PGD301 — oracle
+            wpos = pos[:, None] + jnp.arange(c)[None, :]
             qg = q.reshape(b, c, hkv, h // hkv, kd)
             sc = jnp.einsum("bcngk,bsnk->bcngs", qg, hist_k).astype(
                 jnp.float32) * kd ** -0.5
@@ -540,15 +580,15 @@ def _latent_paged_attn(cfg: TransformerConfig, p, x, pool, layer: int,
     b, c, _ = x.shape
     _, pages, ps, r = pool.shape
     with jax.named_scope("attn:latent"):
-        idx, wpos = _fed_rows(table, pos, n_feed, c, pages, ps, layer)
+        wpos = pos[:, None] + jnp.arange(c)[None, :]
         q_nope, q_rope, c_kv, k_rope = latent_proj(cfg, p, x, wpos)
         def to_row(a):      # the tail of a row's 128-lane tiles, zero
             return jnp.pad(a, [(0, 0)] * (a.ndim - 1)
                            + [(0, r - la.row_values)])
 
         row = to_row(jnp.concatenate([c_kv, k_rope], axis=-1))
-        flat = pool.reshape(-1, r).at[idx].set(row.reshape(b * c, r))
-        pool = flat.reshape(pool.shape)
+        pool, = _write_fed_rows((pool,), (row,), layer, table, pos, n_feed,
+                                paged_kernel)
         w_uk = p["wukv"][:, :, :la.nope_dim]                  # [rank, H, nope]
         w_uv = p["wukv"][:, :, la.nope_dim:]                  # [rank, H, v]
         q_lat = jnp.einsum("bchk,rhk->bchr", q_nope, w_uk)
@@ -562,7 +602,7 @@ def _latent_paged_attn(cfg: TransformerConfig, p, x, pool, layer: int,
             mp = table.shape[1]
             gidx = ((layer * pages + table)[:, :, None] * ps
                     + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
-            hist = flat[gidx]                                 # [B, S, R]
+            hist = pool.reshape(-1, r)[gidx]                  # [B, S, R]
             sc = jnp.einsum("bchr,bsr->bchs", q_abs, hist
                             ).astype(jnp.float32) * scale
             seen = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]

@@ -27,9 +27,12 @@ section 6, PR 29).
 
 The pool's rows are lane-dense (all heads of a position side by side:
 16 rows by 1,280 lanes is an exact bf16 tile for GPT-2-large), which is
-the one layout the step's scatter, the resident buffer and this kernel
-agree on: the call neither slices the pool nor asks the compiler to
-relay it, so the step updates it in place (PERF.md section 4).  The
+the one layout the step's write (the `.at[].set` scatter, or the row
+writer at the end of this file: `write_kv_rows`, one call a layer that
+fetches, fills and sends back the 8-row groups the fed rows lie in), the
+resident buffer and this kernel agree on: no call slices the pool or
+asks the compiler to relay it, so the step updates it in place (PERF.md
+section 4).  The
 price is that a head is no longer a dim of the block: the per-head
 reduction is a block-diagonal matmul on the MXU, one 128-lane tile
 (``128 // K`` heads) at a time (see ``_paged_attn_kernel``).
@@ -39,7 +42,8 @@ dispatch) ride the same kernel: query column ``c`` sits at write
 position ``pos + c`` and the in-kernel mask admits keys at
 ``t <= pos + c`` — bitwise the same causal semantics as the oracle's
 masked softmax, including intra-chunk attention (the chunk's own k/v
-were scattered into the pool before the kernel runs).
+were written into the pool, by the scatter or by the row writer, before
+the kernel runs).
 
 Like ``kernels.flash_attention``, ``interpret=None`` auto-detects:
 compiled on TPU, Pallas interpret mode elsewhere — so the tier-1 parity
@@ -278,8 +282,9 @@ def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
     """Fused block-table paged attention.
 
     q: [B, C, H, K] queries (C = feed width; decode dispatches use 1);
-    k_pages/v_pages: the page pool AFTER this dispatch's scatter (the
-    chunk's own k/v are already in their pages), in one of two forms:
+    k_pages/v_pages: the page pool AFTER this dispatch's write, scatter
+    or row writer (the chunk's own k/v are already in their pages), in
+    one of two forms:
     with ``layer`` the serving pool as it lies on the device,
     ``[L, P, ps, H*K]``, of which the kernel reads layer ``layer``'s
     pages; with ``layer=None`` one layer's pages ``[P, ps, H, K]``
@@ -705,3 +710,218 @@ def _latent_call(table, pos, n_feed, q, pool, *, vw, scale, interpret):
         interpret=interpret,
         name="latent_paged_attention",
     )(table, pos, n_feed, q, pool)
+
+
+# ---------------------------------------------------------------------------
+# The row writer: a dispatch's fed K/V rows into the pool where it lies
+#
+# The step programs' other way to write is an XLA scatter of `B*C` rows a
+# pool and layer, which a TPU runs a row at a time (0.16 us a row of 2,560
+# bytes, padding rows sent to the null page included: 1.4 ms of a 4.6 ms
+# wide round of GPT-2-large; PERF.md section 5).  Here one call a layer
+# takes BOTH pools as they lie, `[L*P, ps, row]` left in HBM and aliased to
+# the call's outputs, and writes only the real rows: lane `b`'s positions
+# `pos[b] .. pos[b] + n_feed[b] - 1`.  Mosaic starts a DMA only on a tile of
+# 8 rows (a bf16 row is half a 32-bit sublane, and a slice it cannot prove
+# aligned is refused), so the unit is the 8-row GROUP of a page: every group
+# a lane's fed range touches is fetched, the new rows are placed in it, and
+# it is sent back: whole tiles both ways, every fetch in flight before the
+# first is waited for (each on a semaphore of its own: a wait answers for
+# that group and no other), and every send before the first is waited for.
+# Padding columns and lanes that feed nothing touch nothing (the null page
+# is never written), and a written page is never a shared one (the radix
+# tree shares full prompt pages only), so no two lanes of a call meet in a
+# group.
+
+_GROUP = 8          # rows of one tile of the pool: where a DMA may start
+_WRITER_COLUMNS = 128   # fed columns one grid step takes, at most
+_WRITER_VMEM = 8 << 20  # what a grid step's blocks and buffers may hold
+
+
+def row_writer_takes(ps: int, row: int) -> bool:
+    """Whether the pool's shapes are the writer's: compiled, whole tiles
+    (a page of whole 8-row groups, a row of whole 128-lane tiles); the
+    interpreter takes any."""
+    return _resolve_interpret(None) or (ps % _GROUP == 0 and row % 128 == 0)
+
+
+def _writer_blocks(b: int, c: int, ps: int, row: int, itemsize: int):
+    """(rows a group, fed columns a grid step, lanes a grid step, groups a
+    lane and step, staging rows) from the shapes: the whole width a step up
+    to `_WRITER_COLUMNS`, and as many lanes as fit `_WRITER_VMEM` (all 16
+    of a GPT-2-large wide round: their DMAs are then in flight together)."""
+    gr = _GROUP if ps % _GROUP == 0 else ps
+    cb = min(c, _WRITER_COLUMNS)
+    groups = (cb + gr - 2) // gr + 1
+    stage = (cb + gr - 1) // gr * gr + 2 * gr
+    lane = 2 * (2 * _buf(cb, row, itemsize) + groups * _buf(gr, row, itemsize)
+                + _buf(stage, row, 4))
+    lb = max(d for d in range(1, b + 1)
+             if b % d == 0 and (d == 1 or d * lane <= _WRITER_VMEM))
+    return gr, cb, lb, groups, stage
+
+
+def _row_writer_kernel(table_ref, pos_ref, nf_ref, knew, vnew, kin, vin,
+                       kout, vout, kbuf, vbuf, kstage, vstage, fetched, sent,
+                       *, ps, gr, cb, lb):
+    """Grid program (lane block jl, column block jc): lanes
+    `[jl*lb, (jl+1)*lb)`, their fed columns `[jc*cb, (jc+1)*cb)`.
+
+    knew/vnew `[lb, cb, row]` are the step's new rows in VMEM; kin/vin are
+    the pools, which kout/vout alias: the body reads and writes kout/vout.
+    A lane's columns of this step sit at positions `p .. p + n - 1`
+    (`p = pos + jc*cb`, `n` of them fed); group `i` of them holds the
+    positions `t0 .. t0 + gr - 1`, `t0 = (p // gr + i) * gr`, rows
+    `t0 % ps ..` of page `table[b, t0 // ps]` (the table is offset to the
+    layer), and is live while `n > 0` and `t0 < p + n`.  Its row `r` takes
+    column `t0 - p + r` where that is a fed column and keeps what it held
+    elsewhere.  The columns reach the rows' places through a float32
+    staging copy of the block (exact for a bf16 pool) read at the aligned
+    pair of groups that holds the window, and a sublane roll."""
+    del kin, vin
+    jl, jc = pl.program_id(0), pl.program_id(1)
+    groups, row = kbuf.shape[1], kbuf.shape[-1]
+    mp = table_ref.shape[1]
+    pools = ((knew, kout, kbuf, kstage, 0), (vnew, vout, vbuf, vstage, 1))
+
+    def each(fn):
+        """`fn(l, i, page, off, c0, n)` for every live group of the step."""
+        def lane(l, carry):
+            b = jl * lb + l
+            p = pos_ref[b] + jc * cb
+            n = jnp.clip(nf_ref[b] - jc * cb, 0, cb)
+
+            def group(i, carry):
+                t0 = (p // gr + i) * gr
+
+                @pl.when((n > 0) & (t0 < p + n))
+                def _():
+                    fn(l, i, table_ref[b, jnp.minimum(t0 // ps, mp - 1)],
+                       pl.multiple_of(t0 % ps, gr), t0 - p, n)
+                return carry
+
+            return jax.lax.fori_loop(0, groups, group, carry)
+
+        jax.lax.fori_loop(0, lb, lane, 0)
+
+    # A group's fetch has its own semaphore: `place` reads the group as
+    # soon as ITS copy has landed, whatever order the others complete in.
+    # The sends of a pool share one: nothing reads what they wrote before
+    # `wait_sends` has waited once for each, and they are all one size.
+    def fetch(out, buf, s, l, i, page, off):
+        return pltpu.make_async_copy(out.at[page, pl.ds(off, gr)],
+                                     buf.at[l, i], fetched.at[s, l, i])
+
+    def send(out, buf, s, l, i, page, off):
+        return pltpu.make_async_copy(buf.at[l, i],
+                                     out.at[page, pl.ds(off, gr)],
+                                     sent.at[s])
+
+    def start_fetches(l, i, page, off, c0, n):
+        for _, out, buf, _, s in pools:
+            fetch(out, buf, s, l, i, page, off).start()
+
+    each(start_fetches)
+    for new, _, _, stage, _ in pools:      # under the fetches in flight
+        stage[:, gr:gr + cb, :] = new[...].astype(stage.dtype)
+
+    def place(l, i, page, off, c0, n):
+        col = jax.lax.broadcasted_iota(jnp.int32, (gr, row), 0) + c0
+        fed = (col >= 0) & (col < n)
+        # the window's columns c0 .. c0 + gr - 1 are staging rows
+        # c0 + gr ..: inside the aligned pair of groups at `at`
+        at = pl.multiple_of((c0 + gr) // gr * gr, gr)
+        for _, out, buf, stage, s in pools:
+            fetch(out, buf, s, l, i, page, off).wait()
+            pair = stage[l, pl.ds(at, 2 * gr), :]
+            win = pltpu.roll(pair, (2 * gr - (c0 + gr - at)) % (2 * gr),
+                             0)[:gr]
+            buf[l, i] = jnp.where(fed, win, buf[l, i].astype(stage.dtype)
+                                  ).astype(buf.dtype)
+            send(out, buf, s, l, i, page, off).start()
+
+    each(place)
+
+    def wait_sends(l, i, page, off, c0, n):
+        for _, out, buf, _, s in pools:
+            send(out, buf, s, l, i, page, off).wait()
+
+    each(wait_sends)
+
+
+def write_kv_rows(k_pool, v_pool, k_new, v_new, table, pos, n_feed,
+                  layer: int):
+    """The fed rows of one dispatch and layer into both pools.
+
+    k_pool/v_pool: the serving pools `[L, P, ps, row]`; k_new/v_new
+    `[B, C, ...]` with `row` values a column; table `[B, MP]`, pos `[B]`,
+    n_feed `[B]` as for `paged_flash_attention`.  Lane b's column `j <
+    n_feed[b]` becomes row `(pos[b] + j) % ps` of page
+    `table[b, (pos[b] + j) // ps]` of layer `layer`; nothing else of the
+    pools changes (the scatter it stands in for also writes its padding
+    to the null page).  -> (k_pool, v_pool), the same buffers where the
+    caller donates them.  The layer reaches the kernel through the block
+    table, as in `paged_flash_attention`: one trace and one lowering a
+    step program."""
+    n_layers, pages, ps, row = k_pool.shape
+    b, c = k_new.shape[:2]
+    table = jnp.asarray(table, jnp.int32) + layer * pages
+    k_flat, v_flat = _row_writer_call(
+        table, jnp.asarray(pos, jnp.int32), jnp.asarray(n_feed, jnp.int32),
+        k_new.reshape(b, c, row), v_new.reshape(b, c, row),
+        k_pool.reshape(-1, ps, row), v_pool.reshape(-1, ps, row),
+        interpret=_resolve_interpret(None))
+    return k_flat.reshape(k_pool.shape), v_flat.reshape(v_pool.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _row_writer_call(table, pos, n_feed, k_new, v_new, k_pages, v_pages, *,
+                     interpret):
+    """The pallas_call: k_new/v_new [B, C, row], k_pages/v_pages
+    [pages, ps, row] -> the two pools, aliased."""
+    b, c, row = k_new.shape
+    ps = k_pages.shape[1]
+    gr, cb, lb, groups, stage = _writer_blocks(
+        b, c, ps, row, k_pages.dtype.itemsize)
+    cp = -(-c // cb) * cb
+    k_new = jnp.pad(k_new, ((0, 0), (0, cp - c), (0, 0)))
+    v_new = jnp.pad(v_new, ((0, 0), (0, cp - c), (0, 0)))
+
+    def _new_map(jl, jc, tbl, pos_, nf):
+        return (jl, jc, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b // lb, cp // cb),
+        in_specs=[pl.BlockSpec((lb, cb, row), _new_map),
+                  pl.BlockSpec((lb, cb, row), _new_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[
+            pltpu.VMEM((lb, groups, gr, row), k_pages.dtype),  # K groups
+            pltpu.VMEM((lb, groups, gr, row), v_pages.dtype),  # V groups
+            pltpu.VMEM((lb, stage, row), jnp.float32),         # new K rows
+            pltpu.VMEM((lb, stage, row), jnp.float32),         # new V rows
+            pltpu.SemaphoreType.DMA((2, lb, groups)),  # fetches: a group's
+            pltpu.SemaphoreType.DMA((2,)),             # sends: a pool's
+        ],
+    )
+    kernel = functools.partial(_row_writer_kernel, ps=ps, gr=gr, cb=cb,
+                               lb=lb)
+    # The result is the two pools, 3-D: nothing here answers to the
+    # signature the trace's readers find the attention kernels by.  A
+    # column block's groups are read after the block before it has
+    # written them, so the grid is sequential.
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kv_row_writer",
+    )(table, pos, n_feed, k_new, v_new, k_pages, v_pages)

@@ -483,6 +483,7 @@ class ContinuousLMServer:
         self._thread: Optional[threading.Thread] = None
         self._cache = None    # lazy: (k, v) device buffers
         self._step = None     # ONE dispatch entry point (tests stub it)
+        self._write_path: Dict[str, str] = {}   # set with the programs
         self._decode_step = None
         self._chunk_step = None
         self._copy = None
@@ -1308,7 +1309,8 @@ class ContinuousLMServer:
                 "radix_nodes": (self._tree.nodes
                                 if self._tree is not None else 0),
                 "ship": self.ship,
-                "paged_kernel": self.paged_kernel}
+                "paged_kernel": self.paged_kernel,
+                "write_path": dict(self._write_path)}
             if self.recurrent:
                 from deeplearning4j_tpu.parallel.generation import (
                     state_row_bytes,
@@ -1440,6 +1442,7 @@ class ContinuousLMServer:
     def _start_locked(self) -> None:
         if self._step is None:
             from deeplearning4j_tpu.parallel.generation import (
+                kv_write_path,
                 make_page_copy,
                 make_paged_step,
                 make_spec_step,
@@ -1447,6 +1450,12 @@ class ContinuousLMServer:
             )
 
             total = self.kv_pages + 1
+            # how each step program writes its fed K/V rows, by width
+            wide = (self.spec_width if self.speculate != "off"
+                    else self.prefill_chunk)
+            path = kv_write_path(self.cfg, self.page_size,
+                                 self.paged_kernel)
+            self._write_path = {f"w{w}": path for w in sorted({1, wide})}
             self._decode_step = make_paged_step(
                 self.cfg, total, self.page_size, 1)
             if self.speculate != "off":

@@ -352,15 +352,21 @@ def test_block_plan_refuses_a_length_with_no_sublane_tile(s):
 
 from deeplearning4j_tpu.parallel.generation import (  # noqa: E402
     _paged_attn,
+    _write_fed_rows,
     init_paged_cache,
+    kv_rows_by_kernel,
     paged_forward,
+    pool_layout,
     spec_verify_step,
 )
 from deeplearning4j_tpu.parallel.kernels import mask_value  # noqa: E402
+from deeplearning4j_tpu.parallel import paged_kernel as pk  # noqa: E402
 from deeplearning4j_tpu.parallel.paged_kernel import (  # noqa: E402
     _pages_per_block,
+    _writer_blocks,
     paged_flash_attention,
     resolve_paged_kernel,
+    write_kv_rows,
 )
 
 
@@ -667,12 +673,16 @@ class TestPagedKernelFullStack:
             lk, ck = paged_forward(cfg, params, dict(cache), table, pos,
                                    nf, toks, paged_kernel=True)
             _assert_fed_columns_match(lk, lo, np.asarray(nf), atol=1e-5)
-            # the scatter code is shared; deeper layers' writes inherit
-            # the previous layer's rounding, so tolerance not equality
-            np.testing.assert_allclose(np.asarray(ck["k"]),
-                                       np.asarray(co["k"]), atol=1e-5)
-            np.testing.assert_allclose(np.asarray(ck["v"]),
-                                       np.asarray(co["v"]), atol=1e-5)
+            # the kernel's program writes by the row writer at either
+            # width, the oracle by the scatter (`kv_rows_by_kernel`);
+            # deeper layers' writes inherit the previous layer's rounding,
+            # so tolerance not equality.  The null page is the scatter's
+            # alone to write
+            assert kv_rows_by_kernel(True, 2, 4, 16)
+            np.testing.assert_allclose(np.asarray(ck["k"])[:, 1:],
+                                       np.asarray(co["k"])[:, 1:], atol=1e-5)
+            np.testing.assert_allclose(np.asarray(ck["v"])[:, 1:],
+                                       np.asarray(co["v"])[:, 1:], atol=1e-5)
 
     def test_spec_verify_parity(self):
         """The speculative verify dispatch: bonus logits AND per-lane
@@ -696,8 +706,9 @@ class TestPagedKernelFullStack:
         np.testing.assert_array_equal(np.asarray(ak), np.asarray(ao))
 
     def test_layer_level_paged_attn_switch(self):
-        """`_paged_attn` itself: both switch positions share one
-        scatter and agree at fed columns (C=1 and C=3)."""
+        """`_paged_attn` itself: the two switch positions write the same
+        rows (the scatter, the row writer) and agree at fed columns (C=1
+        and C=3)."""
         cfg, params = self._cfg()
         layer = params["layers"][0]["attn"]
         for c, seed in [(1, 0), (3, 1)]:
@@ -726,6 +737,79 @@ class TestPagedKernelFullStack:
             np.testing.assert_array_equal(np.asarray(vk), np.asarray(vo))
 
 
+# The row writer (ISSUE 41): the fed K/V rows of a dispatch into the pools
+# where they lie, against the `.at[].set` scatter it stands in for,
+# bit-equal over BOTH pools of every layer except the written layer's null
+# page (the scatter's padding goes there; the writer never touches it).
+# Every case holds, in one batch: an idle lane (n_feed 0), a lane that
+# feeds one column, lanes that feed the whole width, one of them ending on
+# the table's last position, a run that straddles a page boundary, pages
+# handed out in a shuffled order, and a second idle lane whose table and
+# unaligned position point into the very group the one-column lane writes
+# (a lane that feeds nothing must not fetch that group and send it back
+# stale); the layer written is the middle one of three.
+_WRITER_CASES = {
+    # name: (C, ps, dtype, row, what the case adds)
+    **{f"c{c}_ps{ps}": (c, ps, "float32", 16, None)
+       for c in (1, 8, 16) for ps in (4, 8, 16)},
+    "bf16_rows": (8, 16, "bfloat16", 128, None),
+    "odd_width_5": (5, 8, "float32", 16, None),
+    "two_column_blocks": (24, 8, "float32", 16, "columns"),
+    "a_lane_a_grid_step": (8, 16, "float32", 32, "lanes"),
+}
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", list(_WRITER_CASES))
+def test_row_writer_matches_the_scatter(case, monkeypatch):
+    c, ps, dtype, row, twist = _WRITER_CASES[case]
+    # (a twisted case has shapes no other call has: the jitted call is
+    # cached by shapes, and its blocks are worked out when it is traced)
+    if twist == "columns":      # the width in blocks of 8 columns
+        monkeypatch.setattr(pk, "_WRITER_COLUMNS", 8)
+    if twist == "lanes":        # no room for two lanes in a grid step
+        monkeypatch.setattr(pk, "_WRITER_VMEM", 1)
+    n_layers, layer, b = 3, 1, 7
+    mp = max(4, -(-3 * c // ps) + 1)
+    pages = 1 + b * mp
+    gr, cb, lb, _, _ = _writer_blocks(b, c, ps, row, 4)
+    assert (cb, lb) == {"columns": (8, b), "lanes": (c, 1)}.get(
+        twist, (c, b))
+    rng = np.random.default_rng(c * 100 + ps)
+    table = np.stack([1 + i * mp + rng.permutation(mp) for i in range(b)])
+    table[0] = 0                                    # the idle lane
+    table[6] = table[1]                             # idle, on lane 1's pages
+    last = mp * ps - c                              # ends on the last page
+    straddle = max(ps - 1, 0)                       # crosses into page 1
+    pos = np.array([0, 3, last, straddle, ps, 2 * ps + 1, 2], np.int32)
+    nf = np.array([0, 1, c, c, c, max(c - 2, 1), 0], np.int32)
+    assert pos[3] // ps != (pos[3] + max(c, 2) - 1) // ps or c == 1
+    shape = (n_layers, pages, ps, row)
+    ck = jnp.asarray(rng.standard_normal(shape), dtype)
+    cv = jnp.asarray(rng.standard_normal(shape), dtype)
+    k = jnp.asarray(rng.standard_normal((b, c, 2, row // 2)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, c, 2, row // 2)), dtype)
+    args = (jnp.asarray(table, jnp.int32), jnp.asarray(pos),
+            jnp.asarray(nf))
+
+    got_k, got_v = write_kv_rows(ck, cv, k, v, *args, layer)
+    want_k, want_v = _write_fed_rows((ck, cv), (k, v), layer, *args,
+                                     paged_kernel=False)
+    for got, want, old, new in ((got_k, want_k, ck, k),
+                                (got_v, want_v, cv, v)):
+        got, want = np.asarray(got), np.array(want)
+        assert got.dtype == want.dtype and got.shape == shape
+        # the null page: the writer leaves it as it was
+        np.testing.assert_array_equal(got[layer, 0],
+                                      np.asarray(old)[layer, 0])
+        want[layer, 0] = got[layer, 0]
+        np.testing.assert_array_equal(got, want)
+        # and the rows did land: lane 2's last column at the last position
+        np.testing.assert_array_equal(
+            got[layer, table[2, mp - 1], ps - 1],
+            np.asarray(new).reshape(b, c, row)[2, c - 1])
+
+
 @pytest.mark.paged_kernel
 class TestMaskValueAndPolicy:
     """The dtype-aware mask constant (satellite: the hardcoded -1e30
@@ -750,6 +834,40 @@ class TestMaskValueAndPolicy:
         bad = jnp.where(jnp.zeros((2, 4), bool), s, jnp.float16(-1e30))
         assert np.isnan(np.asarray(
             jax.nn.softmax(bad, axis=-1), np.float32)).all()
+
+    @pytest.mark.parametrize("case, want", [
+        # (paged_kernel, pools, ps, row, compiled) -> the writer?
+        ((True, 2, 16, 1280, True), True),      # the serve cells' pools
+        ((True, 2, 128, 1024, True), True),     # Solar-Open2's
+        ((True, 1, 128, 640, True), False),     # the one latent pool
+        ((False, 2, 16, 1280, True), False),    # the oracle's program
+        ((True, 2, 4, 1280, True), False),      # a page under a tile
+        ((True, 2, 16, 96, True), False),       # a row under a lane tile
+        ((True, 2, 4, 16, False), True),        # the interpreter takes any
+    ])
+    def test_write_path_comes_from_the_program_s_shapes(self, case, want,
+                                                        monkeypatch):
+        """`kv_rows_by_kernel`: the row writer iff the paged kernel runs
+        and the pools are a K and a V pool of whole tiles, whatever the
+        feed width; `kv_write_path` is the same rule asked of a
+        configuration, for `stats()["kv"]["write_path"]`."""
+        from deeplearning4j_tpu.parallel import transformer as tfm
+        from deeplearning4j_tpu.parallel.generation import kv_write_path
+
+        on, pools, ps, row, compiled = case
+        monkeypatch.setattr(pk, "_resolve_interpret",
+                            lambda interpret: not compiled)
+        assert kv_rows_by_kernel(on, pools, ps, row) is want
+        if pools == 2:
+            cfg = tfm.TransformerConfig(vocab_size=50, d_model=row,
+                                        n_heads=2, n_layers=1, d_ff=32,
+                                        max_len=32)
+        else:
+            cfg = tfm.deepseek_v2(layers=2, experts_held=(0, 40), vocab=64,
+                                  max_len=256)
+            assert pool_layout(cfg).row == row
+        assert kv_write_path(cfg, ps, on) == (
+            "kernel" if want else "scatter")
 
     def test_resolve_paged_kernel(self, monkeypatch):
         """An explicit bool is the oracle seam; `None` is the platform

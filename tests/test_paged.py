@@ -720,6 +720,9 @@ class TestPagedKernelServing:
         assert compiles == []
         assert stats["compiled_programs"] == 3
         assert stats["kv"]["paged_kernel"] is True
+        # both programs write their fed rows by the row writer
+        # (`generation.kv_rows_by_kernel`)
+        assert stats["kv"]["write_path"] == {"w1": "kernel", "w4": "kernel"}
 
     def test_kernel_speculative_parity(self):
         """The verify dispatch on the kernel path: speculative greedy

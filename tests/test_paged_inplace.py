@@ -1,8 +1,11 @@
 """The serve step updates the KV pool in place (ISSUE 25).
 
 The pool rests on the device as ``[L, P, ps, H*K]``: one lane-dense
-layout that the step's scatter writes and the paged kernel reads, so the
-donated buffers come back as themselves.  These tests hold that:
+layout that the step's write fills (in either of its forms: the
+``.at[].set`` scatter of the oracle and of the latent pool, or the row
+writer's DMAs of whole 8-row groups in a step program on a TPU; ISSUE 41)
+and the paged kernel reads, so the donated buffers come back as
+themselves.  These tests hold that:
 
 - the new formulation (stacked pool carried layer to layer, rows written
   at ``(layer*P + page)*ps + off``) is bit-equal to the old one written
@@ -13,7 +16,10 @@ donated buffers come back as themselves.  These tests hold that:
 - page copy / gather / install keep their ``[L, MP, ps, H, K]`` contract;
 - the lowered ``step`` aliases both pool arguments to its outputs, on the
   CPU and, compile-only, at GPT-2-large's width for a TPU v5e, where the
-  step's temporaries must not hold a copy of the pool.
+  step's temporaries must not hold a copy of the pool and the
+  program's writes are the row writer's calls, which alias the pools too
+  and do not answer to the signature the trace's readers find the
+  attention kernel by.
 """
 
 import dataclasses
@@ -289,10 +295,30 @@ def test_v5e_step_holds_no_copy_of_the_pool(one_chip, width, monkeypatch):
     assert ma.temp_size_in_bytes < pool_bytes // 8
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == cfg.n_layers
-    for ln in calls:
-        assert re.search(r"= bf16\[%d,%d,\d+,\d+\]\S* custom-call\("
-                         % (lanes, width), ln), ln
+    # the attention kernel, a call a layer, by what the trace's readers
+    # find it by (`benchmark/readings.py:PAGED_KERNEL`): a 4-D bf16 result
+    # with the feed width second, the block table its first operand
+    reads = re.compile(r"= bf16\[\d+,(\d+),\d+,\d+\]\S* custom-call\(")
+    attention = [ln for ln in calls if reads.search(ln)]
+    assert len(attention) == cfg.n_layers
+    for ln in attention:
+        assert int(reads.search(ln).group(1)) == width, ln[:300]
+        assert "operand_layout_constraints={s32[%d," % lanes in ln
+    # the program's writes, at either width: the row writer, a call a
+    # layer for both pools, its result the two pools (a tuple of 3-D
+    # arrays, so the readers' pattern passes it by), each aliased to its
+    # operand
+    writers = [ln for ln in calls if "kv_row_writer" in ln]
+    assert gen.kv_rows_by_kernel(True, 2, ps, 1280)
+    assert len(writers) == cfg.n_layers
+    assert len(calls) == len(attention) + len(writers)
+    flat = r"bf16\[%d,%d,1280\]\S*" % (cfg.n_layers * pages, ps)
+    for ln in writers:
+        assert not reads.search(ln), ln[:300]
+        assert re.search(r"= \(%s, %s\) custom-call\(" % (flat, flat),
+                         ln), ln[:300]
+        assert ("output_to_operand_aliasing={{0}: (5, {}), {1}: (6, {})}"
+                in ln), ln[:300]
     # no copy and no restacking of a pool-sized buffer is left
     big = r"bf16\[%d,%d,%d,\d+(,\d+)?\]" % (cfg.n_layers, pages, ps)
     assert not [ln for ln in text.splitlines()
@@ -327,6 +353,55 @@ def test_v5e_paged_kernel_compiles_and_keeps_its_signature(one_chip, width):
                      (lanes, width, hkd), calls[0]), calls[0][:300]
     assert ("operand_layout_constraints={s32[%d,%d]" % (lanes, mp)
             in calls[0]), calls[0][:600]
+
+
+_WRITER_SHAPES = {
+    # lanes, chunk, page, row, max pages a lane, pages of the whole pool
+    "gpt2_large_serve": (16, 8, 16, 1280, 64, 36 * 1025),
+    "solar_open2_serve": (4, 512, 128, 1024, 160, 641),
+    # the width-1 programs of the same two
+    "gpt2_large_decode": (16, 1, 16, 1280, 64, 36 * 1025),
+    "solar_open2_decode": (4, 1, 128, 1024, 160, 641),
+}
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", list(_WRITER_SHAPES))
+def test_v5e_row_writer_compiles_and_aliases_its_pools(one_chip, case):
+    """`_row_writer_call` at the serve cells' shape (16 lanes, chunk 8,
+    page 16, row 1,280, the whole 36-layer pool) and at Solar-Open2's
+    (4 lanes, chunk 512, page 128, row 1,024), and at width 1 of both
+    (a group's fetch has a semaphore of its own: 16 x 2 to 4 x 17 a
+    pool), lowers through Mosaic: ONE
+    custom call whose result is the two pools, each aliased to its
+    operand (the alias bytes are both pools', exactly), no temporary the
+    size of a page run, and nothing of the attention kernels' signature:
+    the trace's readers must not take it for a step program's kernel."""
+    lanes, c, ps, row, mp, pages = _WRITER_SHAPES[case]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((pages, ps, row), jnp.bfloat16)
+    new = sds((lanes, c, row), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda *a: pk._row_writer_call(*a, interpret=False),
+        donate_argnums=(5, 6)).lower(
+        sds((lanes, mp), np.int32), sds((lanes,), np.int32),
+        sds((lanes,), np.int32), new, new, pool, pool).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == 2 * pages * ps * row * 2
+    assert ma.temp_size_in_bytes < 1 << 20
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "kv_row_writer" in calls[0]
+    flat = r"bf16\[%d,%d,%d\]\S*" % (pages, ps, row)
+    assert re.search(r"= \(%s, %s\) custom-call\(" % (flat, flat),
+                     calls[0]), calls[0][:300]
+    assert not re.search(r"= bf16\[\d+,\d+,\d+,\d+\]\S* custom-call\(",
+                         calls[0])
+    assert ("output_to_operand_aliasing={{0}: (5, {}), {1}: (6, {})}"
+            in calls[0])
 
 
 @pytest.mark.paged_kernel

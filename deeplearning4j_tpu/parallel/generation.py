@@ -43,6 +43,7 @@ from deeplearning4j_tpu.parallel.transformer import (
     latent_softmax_scale,
     lm_head,
     norm,
+    normed_rotated,
     out_proj,
     qkv_proj,
     require_classic,
@@ -496,23 +497,34 @@ def _paged_attn(p, x, cache_k, cache_v, layer: int, table, pos, n_feed,
 
 
 def _grouped_paged_attn(p, x, cache_k, cache_v, layer: int, table, pos,
-                        n_feed, paged_kernel: bool = False):
-    """`_paged_attn` where the K/V heads are fewer than the query heads
-    (query head j reads K/V head `j // G`) and the output may be gated:
-    the pool's row is `[Hkv * K]`, the write is the same, the kernel is
-    `paged_flash_attention`'s grouped form and the oracle gathers the
-    history as `[B, S, Hkv, K]`.  Scores in float32."""
+                        n_feed, paged_kernel: bool = False, *,
+                        cfg: TransformerConfig):
+    """`_paged_attn` on the grouped-query path (`cfg.grouped`): the K/V
+    heads may be fewer than the query heads (query head j reads K/V head
+    `j // G`) and the output gated: the pool's row is `[Hkv * K]`, the
+    write is the same, the kernel is `paged_flash_attention`'s grouped
+    form and the oracle gathers the history as `[B, S, Hkv, K]`.  Scores
+    in float32.  q and the fed k are normed and rotated at their
+    absolute positions where the configuration says so
+    (`normed_rotated`), and the pool keeps the ROTATED keys.  Under a
+    block mask (`cfg.block_length` B > 1) the fed column at absolute
+    position p sees the rows `< min(pos + n_feed, (p // B + 1) * B)`:
+    one rule for a prefill chunk of whole blocks, a denoise round and a
+    commit pass; B = 1 is the causal rule and the causal program."""
     with jax.named_scope("attn:gqa"):
         q, k, v = qkv_proj(p, x)              # [B,C,H,K], [B,C,Hkv,K]
         b, c, h, kd = q.shape
         hkv = k.shape[2]
         _, pages, ps, _ = cache_k.shape
         mp = table.shape[1]
+        q, k = normed_rotated(cfg, p, q, k,
+                              pos[:, None] + jnp.arange(c)[None, :])
         cache_k, cache_v = _write_fed_rows((cache_k, cache_v), (k, v), layer,
                                            table, pos, n_feed, paged_kernel)
+        blk = cfg.block_length
         if paged_kernel:
             o = paged_flash_attention(q, cache_k, cache_v, table, pos,
-                                      n_feed, layer=layer)
+                                      n_feed, layer=layer, block=blk)
         else:
             gidx = ((layer * pages + table)[:, :, None] * ps
                     + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
@@ -525,7 +537,11 @@ def _grouped_paged_attn(p, x, cache_k, cache_v, layer: int, table, pos,
             qg = q.reshape(b, c, hkv, h // hkv, kd)
             sc = jnp.einsum("bcngk,bsnk->bcngs", qg, hist_k).astype(
                 jnp.float32) * kd ** -0.5
-            seen = jnp.arange(mp * ps)[None, None, :] <= wpos[:, :, None]
+            sees = wpos                     # the last row a column sees
+            if blk > 1:
+                sees = jnp.minimum((wpos // blk + 1) * blk,
+                                   (pos + n_feed)[:, None]) - 1
+            seen = jnp.arange(mp * ps)[None, None, :] <= sees[:, :, None]
             sc = jnp.where(seen[:, :, None, None, :], sc,
                            mask_value(sc.dtype))
             w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
@@ -650,7 +666,7 @@ def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
             return feed_forward(cfg, layer, h, fed, loads)
 
     mixers, paged_at = cfg.mixer_kinds(), pool_layers(cfg)
-    grouped = cfg.kv_heads is not None or cfg.attn_gate
+    grouped = cfg.grouped
     for i, layer in enumerate(params["layers"]):
         def attend(p, h, i=i):
             if mixers[i] == "kda":
@@ -664,7 +680,7 @@ def _paged_hidden(cfg: TransformerConfig, params: dict, cache: dict,
             elif grouped:
                 a, pools["k"], pools["v"] = _grouped_paged_attn(
                     p, h, pools["k"], pools["v"], paged_at[i], table, pos,
-                    n_feed, paged_kernel=paged_kernel)
+                    n_feed, paged_kernel=paged_kernel, cfg=cfg)
             else:
                 a, pools["k"], pools["v"] = _paged_attn(
                     p, h, pools["k"], pools["v"], paged_at[i], table, pos,
@@ -813,6 +829,121 @@ def make_paged_step(cfg: TransformerConfig, pages: int, page_size: int,
                                 resolve_paged_kernel(paged_kernel))
 
 
+def require_causal(cfg: TransformerConfig, who: str) -> None:
+    """Raise `UnsupportedLayerKind` where `cfg` is a block model: the
+    gate of every path whose round is "one lane, one new token"."""
+    if cfg.block_length > 1:
+        raise UnsupportedLayerKind(
+            f"{who} commits a token a lane and round; this model "
+            f"generates blocks of {cfg.block_length} positions by "
+            f"unmasking (make_block_step)")
+
+
+# ---------------------------------------------------------------------------
+# The block round (generation by diffusion over blocks)
+#
+# A block model (`cfg.block_length` B > 1) generates a block of B positions
+# by unmasking it over a few forwards: a decode lane feeds its current block
+# at `pos .. pos + B - 1`, masked columns as `cfg.mask_token`, known ones as
+# their token, against its committed history; the logits at a column predict
+# THAT column's token.  `pos` does not move over these denoise rounds, so the
+# provisional K/V rows they write are overwritten by the next round and are
+# read by nothing else; when no column is masked one more feed of the same
+# columns (the commit pass) writes the K/V of the block's final tokens, and
+# `pos += B`.  The unmasking choice runs in the program, so a round has the
+# one host sync it always had.
+
+
+def block_unmask(logits, tokens, known, quota, tau, mask_token: int):
+    """One denoise step's choice.  logits [L, B, V] float32 at a lane's B
+    block columns, tokens [L, B] the block as fed, known [L, B] bool,
+    quota [L] int32, tau [L] float32.  At every masked column the best
+    token `t` (the mask id's logit left out) and its confidence
+    `c = softmax(logits)[t]` (over the whole vocabulary); unmasked are the
+    `quota` masked columns of highest `c` (ties to the lower position) and
+    every masked column with `c > tau`: the static schedule gives
+    `quota = B / steps` and a `tau` no confidence reaches, the dynamic one
+    `quota = 1` (its fallback) and its threshold.
+    -> (tokens [L, B] after the step, known [L, B] after it)."""
+    width = tokens.shape[1]
+    drop = jnp.arange(logits.shape[-1]) == mask_token
+    best = jnp.argmax(jnp.where(drop, -jnp.inf, logits), axis=-1)
+    top = jnp.take_along_axis(logits, best[..., None], axis=-1)[..., 0]
+    conf = jnp.exp(top - jax.nn.logsumexp(logits, axis=-1))    # [L, B]
+    conf = jnp.where(known, -1.0, conf)
+    # a column's rank among its lane's: how many stand before it
+    i, j = jnp.arange(width)[:, None], jnp.arange(width)[None, :]
+    before = ((conf[:, None, :] > conf[:, :, None])
+              | ((conf[:, None, :] == conf[:, :, None]) & (j < i)))
+    rank = jnp.sum(before, axis=-1)                            # [L, B]
+    take = ~known & ((rank < quota[:, None]) | (conf > tau[:, None]))
+    return (jnp.where(take, best.astype(tokens.dtype), tokens),
+            known | take)
+
+
+@functools.lru_cache(maxsize=16)
+def _compiled_block_step(cfg: TransformerConfig, pages: int,
+                         page_size: int, chunk: int,
+                         paged_kernel: bool = False):
+    """`_compiled_paged_step` for a block model, one program per width:
+    `chunk` is B (the narrow program, `[slots, B]`) or the prefill chunk
+    (whole blocks).  A decode lane feeds its block in the first B columns
+    (`n_feed = B`), a prefill lane whole blocks of its prompt; the head,
+    the float32 softmax and the unmasking choice run over the first B
+    columns of every lane and a prefill lane's are ignored by the host.
+    Returns ONE int32 array `[2 * slots * B (+ 3)]`: the blocks after this
+    round's unmasking, their known flags, and a `RoutedExperts`
+    configuration's `expert_load`.  `held` is such an array, the round
+    before's, and a lane with `carry` set feeds the block and the flags it
+    holds there in the place of `tokens[:, :B]` and `known`: the block of a
+    lane whose last round the host has not read yet never leaves the
+    device, so the next round is dispatched while that one runs."""
+    names = pool_names(cfg)
+    blk = cfg.block_length
+
+    def run(params, pools, table, pos, n_feed, tokens, known, quota, tau,
+            held, carry):
+        cells = tokens.shape[0] * blk
+        kept = (carry != 0)[:, None]
+        tokens = tokens.at[:, :blk].set(jnp.where(
+            kept, held[:cells].reshape(-1, blk), tokens[:, :blk]))
+        known = jnp.where(kept, held[cells:2 * cells].reshape(-1, blk),
+                          known)
+        loads = [] if cfg.experts is not None else None
+        x, cache = _paged_hidden(
+            cfg, params, dict(zip(names, pools)), table, pos, n_feed,
+            tokens, paged_kernel=paged_kernel, loads=loads)
+        with jax.named_scope("blocks:unmask"):
+            logits = _head(cfg, params, x[:, :blk]).astype(jnp.float32)
+            new, now = block_unmask(logits, tokens[:, :blk], known != 0,
+                                    quota, tau, cfg.mask_token)
+        out = jnp.concatenate([new.reshape(-1).astype(jnp.int32),
+                               now.reshape(-1).astype(jnp.int32)])
+        if loads:
+            out = jnp.concatenate([out, expert_load(cfg, loads)])
+        return (out,) + tuple(cache[n] for n in names)
+
+    return _pooled(cfg, run)
+
+
+def make_block_step(cfg: TransformerConfig, pages: int, page_size: int,
+                    chunk: int, paged_kernel: bool | None = None):
+    """Compiled block-round entry for `serving.lm.ContinuousLMServer`:
+    fn(params, k, v, table [S, MP], pos [S], n_feed [S], tokens [S, C],
+    known [S, B] int32, quota [S] int32, tau [S] float32,
+    held [2 * S * B (+ 3)] int32, carry [S] int32)
+    -> (blocks and flags [2 * S * B (+ 3)] int32, k, v)."""
+    if cfg.block_length < 2:
+        raise ValueError("a causal model's round is make_paged_step's")
+    if chunk % cfg.block_length or page_size % cfg.block_length:
+        raise ValueError(
+            f"a feed of {chunk} columns / a page of {page_size} rows does "
+            f"not hold whole blocks of {cfg.block_length}")
+    return _compiled_block_step(cfg, int(pages), int(page_size),
+                                int(chunk),
+                                resolve_paged_kernel(paged_kernel))
+
+
 # ---------------------------------------------------------------------------
 # Speculative verify (multi-token decode on the chunked-feed path)
 #
@@ -927,6 +1058,7 @@ def make_spec_step(cfg: TransformerConfig, pages: int, page_size: int,
     recurrent configuration: a rejected draft has already moved the
     state."""
     require_stateless(cfg, "speculative decoding (make_spec_step)")
+    require_causal(cfg, "speculative decoding (make_spec_step)")
     return _compiled_spec_step(cfg, int(pages), int(page_size),
                                int(width),
                                resolve_paged_kernel(paged_kernel))

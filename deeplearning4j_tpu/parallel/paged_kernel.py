@@ -278,7 +278,8 @@ def _paged_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
 
 def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
                           interpret: bool | None = None,
-                          layer: int | None = None) -> jax.Array:
+                          layer: int | None = None,
+                          block: int = 1) -> jax.Array:
     """Fused block-table paged attention.
 
     q: [B, C, H, K] queries (C = feed width; decode dispatches use 1);
@@ -311,11 +312,12 @@ def paged_flash_attention(q, k_pages, v_pages, table, pos, n_feed=None,
     b, c, h, kd = q.shape
     hkd = h * kd
     table = jnp.asarray(table, jnp.int32)
-    if (k_pages.shape[2] != h if layer is None
-            else k_pages.shape[3] != hkd):
-        # fewer K/V heads than query heads: the grouped kernel below
+    if block > 1 or (k_pages.shape[2] != h if layer is None
+                     else k_pages.shape[3] != hkd):
+        # fewer K/V heads than query heads, or the block mask (`block`,
+        # see `_grouped_attn_kernel`): the grouped kernel below
         return _grouped_paged_attention(q, k_pages, v_pages, table, pos,
-                                        n_feed, interpret, layer)
+                                        n_feed, interpret, layer, block)
     if layer is None:
         ps = k_pages.shape[1]
     else:
@@ -404,18 +406,26 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
 
 def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
                          o_ref, kbuf, vbuf, sem, m_acc, l_acc, acc, out, *,
-                         scale, ps, cq, g, hkv, kd, neg):
+                         scale, ps, cq, g, hkv, kd, neg, block=1):
     """Grid program (lane b, query block j).  q_ref `[Hkv, cq*G, K]`: for
     a K/V head its `G` query heads of `cq` fed columns, row `ci*G + gi`;
     k_ref/v_ref the whole pool `[L*P, ps, Hkv*K]` in HBM; o_ref
     `[cq', H, K]` (the block's real columns).  Key `t` is visible to
-    column `ci` iff `t <= pos + ci`.  A block past the lane's fed columns
-    reads nothing and writes zeros."""
+    column `ci` iff `t <= pos + ci`; under a block mask (`block` B > 1:
+    causal between blocks of B positions dealt by absolute position,
+    bidirectional inside one) the column at absolute position
+    `p = pos + ci` sees the rows `t < min(pos + n_feed, (p // B + 1) * B)`,
+    which for B = 1 is the causal rule of every fed column and the code
+    B = 1 traces is the causal one as it was.  A block past the lane's
+    fed columns reads nothing and writes zeros."""
     b, j = pl.program_id(0), pl.program_id(1)
     rows = cq * g
     nf = nf_ref[b]
     first = j * cq
     last = pos_ref[b] + jnp.minimum(first + cq, nf) - 1
+    if block > 1:       # through the end of the last column's block
+        last = jnp.minimum((last // block + 1) * block,
+                           pos_ref[b] + nf) - 1
     n = jnp.where(first < nf, last // ps + 1, 0)
 
     def copies(i, slot):
@@ -437,8 +447,11 @@ def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
         l_acc[...] = jnp.zeros_like(l_acc)
         acc[...] = jnp.zeros_like(acc)
         ci = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) // g
-        horizon = (pos_ref[b] + first + ci
-                   - jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1))
+        sees = pos_ref[b] + first + ci      # the last row a column sees
+        if block > 1:
+            sees = jnp.minimum((sees // block + 1) * block,
+                               pos_ref[b] + nf) - 1
+        horizon = sees - jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
         exact = (None if q_ref.dtype == jnp.bfloat16
                  else jax.lax.Precision.HIGHEST)
 
@@ -492,7 +505,7 @@ def _grouped_query_block(c: int) -> int:
 
 
 def _grouped_paged_attention(q, k_pages, v_pages, table, pos, n_feed,
-                             interpret, layer):
+                             interpret, layer, block: int = 1):
     """`paged_flash_attention` for `H` query heads over `Hkv < H` K/V
     heads: same operands, same result `[B, C, H, K]`."""
     b, c, h, kd = q.shape
@@ -517,12 +530,14 @@ def _grouped_paged_attention(q, k_pages, v_pages, table, pos, n_feed,
     qg = qg.reshape(b, hkv, cp * g, kd)
     return _grouped_call(table, jnp.asarray(pos, jnp.int32), n_feed, qg,
                          k_pages, v_pages, c=c, cq=cq, g=g,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=_resolve_interpret(interpret),
+                         block=int(block))
 
 
-@functools.partial(jax.jit, static_argnames=("c", "cq", "g", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("c", "cq", "g", "interpret", "block"))
 def _grouped_call(table, pos, n_feed, qg, k_pages, v_pages, *, c, cq, g,
-                  interpret):
+                  interpret, block=1):
     b, hkv, _, kd = qg.shape
     ps = k_pages.shape[1]
     rows = cq * g
@@ -552,7 +567,7 @@ def _grouped_call(table, pos, n_feed, qg, k_pages, v_pages, *, c, cq, g,
     )
     kernel = functools.partial(
         _grouped_attn_kernel, scale=1.0 / (kd ** 0.5), ps=ps, cq=cq, g=g,
-        hkv=hkv, kd=kd, neg=_NEG)
+        hkv=hkv, kd=kd, neg=_NEG, block=block)
     # as for `_paged_call`: the block table first, the result 4-D with the
     # feed width second; the trace's readers find the kernel by that
     return pl.pallas_call(

@@ -197,6 +197,17 @@ class TransformerConfig:
     linear: Optional[LinearAttention] = None
     # full layers' output gate: (a * sigmoid(h W_gate)) W_o, elementwise
     attn_gate: bool = False
+    # a head's RMSNorm of q and of k (one gain vector of `head_dim` for
+    # all query heads, one for all K/V heads) before the rotation
+    qk_norm: bool = False
+    # block diffusion: position i sees position j iff j // B <= i // B
+    # (causal between blocks of `block_length`, bidirectional inside
+    # one; 1 = causal), blocks dealt by ABSOLUTE position; a block is
+    # generated by unmasking it over a few forwards, a masked position
+    # fed as `mask_token`, and the logits at a position predict THAT
+    # position's token (`serving/lm.py`, the block round)
+    block_length: int = 1
+    mask_token: Optional[int] = None
 
     def __post_init__(self):
         if self.n_experts and not (1 <= self.moe_top_k <= self.n_experts):
@@ -222,6 +233,23 @@ class TransformerConfig:
                                  f"{self.n_layers} layers")
             if "kda" in self.mixers and self.linear is None:
                 raise ValueError("a \"kda\" layer needs `linear`")
+        if self.block_length < 1:
+            raise ValueError(f"block_length {self.block_length}")
+        if self.block_length > 1:
+            if self.latent is not None or self.mixers is not None:
+                raise UnsupportedLayerKind(
+                    "the block mask is the grouped-query path's: no "
+                    "latent and no recurrent layers under it")
+            if (self.mask_token is None
+                    or not 0 <= self.mask_token < self.vocab_size):
+                raise ValueError(
+                    f"a block model feeds its masked positions as "
+                    f"`mask_token` (an id of the vocabulary), got "
+                    f"{self.mask_token}")
+        if self.qk_norm and self.latent is not None:
+            raise UnsupportedLayerKind(
+                "q/k norms are the grouped-query path's; latent "
+                "attention norms its compressed ranks")
 
     @property
     def classic(self) -> bool:
@@ -232,7 +260,19 @@ class TransformerConfig:
                 and self.experts is None and self.head_width is None
                 and self.kv_heads is None and self.positions == "auto"
                 and self.mixers is None and self.linear is None
-                and not self.attn_gate)
+                and not self.attn_gate and not self.qk_norm
+                and self.block_length == 1)
+
+    @property
+    def grouped(self) -> bool:
+        """Full layers take the grouped-query path (`_grouped_attn`, and
+        `generation._grouped_paged_attn` over the K and V pools): fewer
+        K/V heads than query heads, the output gate, rotary positions on
+        full heads, q/k norms, the block mask."""
+        return self.latent is None and (
+            self.kv_heads is not None or self.attn_gate
+            or self.rope is not None or self.qk_norm
+            or self.block_length > 1)
 
     @property
     def learned_positions(self) -> bool:
@@ -356,6 +396,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
             }
             if cfg.attn_gate:
                 layer["attn"]["wgate"] = dense(next(keys), (d, h, dh), d)
+            if cfg.qk_norm:
+                layer["attn"]["q_norm"] = {"scale": jnp.ones((dh,), dt)}
+                layer["attn"]["k_norm"] = {"scale": jnp.ones((dh,), dt)}
         if cfg.attn_bias:
             layer["attn"].update(
                 bq=jnp.zeros((h, dh), dt), bk=jnp.zeros((h, dh), dt),
@@ -553,6 +596,28 @@ def solar_open2(layers: int = 48, experts_held: Tuple[int, int] = (0, 320),
                               shared_width=1280))
 
 
+def sdar_30b_a3b(layers: int = 48, vocab: int = 151936,
+                 max_len: int = 32768, block_length: int = 4,
+                 dtype: str = "bfloat16") -> TransformerConfig:
+    """SDAR-30B-A3B-Chat at its published widths (`sdar_moe`,
+    arXiv:2510.06303): d 2048, 32 query heads over 4 K/V heads of 128
+    with a head's RMSNorm of q and k, rotary theta 1e6 over the full
+    128, RMSNorm 1e-6, every layer 128 routed experts of 768 (8 a token
+    by softmax score, renormalised, no shared expert), untied head; a
+    block-diffusion model: blocks of `block_length` positions, causal
+    between and bidirectional inside, masked positions fed as id 151669.
+    Every expert is held.  Served through the paged pool only."""
+    return TransformerConfig(
+        vocab_size=vocab, d_model=2048, n_heads=32, n_layers=layers,
+        d_ff=6144, max_len=max_len, dtype=dtype, norm="rms",
+        norm_eps=1e-6, mlp="swiglu", head_width=128, kv_heads=4,
+        rope=YarnRope(theta=1e6), qk_norm=True,
+        experts=RoutedExperts(published=128, held=(0, 128), per_token=8,
+                              width=768, score="softmax",
+                              renormalize=True),
+        block_length=block_length, mask_token=151669)
+
+
 def _layer_norm(p, x, eps=1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
@@ -747,18 +812,47 @@ def attn_gated(p, h, o):
     return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
 
 
-def _grouped_attn(p, x, causal: bool):
+def normed_rotated(cfg: TransformerConfig, p, q, k, positions):
+    """q [B,S,H,K] and k [B,S,Hkv,K] of a grouped-query layer as its
+    scores take them: each head RMS-normed by the layer's `q_norm` /
+    `k_norm` gains where the configuration has them, then rotated over
+    the full head at `positions` [B, S] where it has rotary positions
+    (plain rotary is `YarnRope(factor=1)`; the half-split convention of
+    `apply_rope`).  Keys are rotated BEFORE they are kept, as the latent
+    rows are, so a reused or shipped page stays valid.  A configuration
+    with neither gets q and k back untouched, in the same program."""
+    if not (cfg.qk_norm or cfg.rope is not None):
+        return q, k
+    with jax.named_scope("attn:rope"):
+        if cfg.qk_norm:
+            q = _rms_norm(p["q_norm"], q, cfg.norm_eps)
+            k = _rms_norm(p["k_norm"], k, cfg.norm_eps)
+        if cfg.rope is not None:
+            cos, sin = rope_cos_sin(cfg.rope, q.shape[-1], positions)
+            cos, sin = cos[..., None, :], sin[..., None, :]
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        return q, k
+
+
+def _grouped_attn(p, x, causal: bool, cfg: TransformerConfig):
     """Whole-sequence softmax attention with fewer K/V heads than query
     heads and the output gate, one device, no kernel: the path the paged
-    one is tested against."""
+    one is tested against.  `cfg` gives its q/k norms, rotary positions
+    and block mask (position i sees j iff `j // B <= i // B`)."""
     with jax.named_scope("attn:gqa"):
         q, k, v = qkv_proj(p, x)
         b, s, h, kd = q.shape
+        q, k = normed_rotated(
+            cfg, p, q, k, jnp.broadcast_to(jnp.arange(s), (b, s)))
         g = h // k.shape[2]
         qg = q.reshape(b, s, h // g, g, kd)
         sc = jnp.einsum("bsngk,btnk->bngst", qg, k).astype(
             jnp.float32) * kd ** -0.5
-        if causal:
+        if causal and cfg.block_length > 1:
+            blk = jnp.arange(s) // cfg.block_length
+            sc = jnp.where(blk[None, :] <= blk[:, None], sc,
+                           jnp.finfo(jnp.float32).min / 2)
+        elif causal:
             sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc,
                            jnp.finfo(jnp.float32).min / 2)
         w = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
@@ -1103,8 +1197,8 @@ def _apply_dealt(cfg: TransformerConfig, params: dict, tokens, order, mesh,
     def attend(p, h):
         if cfg.latent is not None:
             return _latent_attn(cfg, p, h, causal)
-        if cfg.kv_heads is not None or cfg.attn_gate:
-            return _grouped_attn(p, h, causal)
+        if cfg.grouped:
+            return _grouped_attn(p, h, causal, cfg)
         return _attn(p, h, mesh, axes, causal)
 
     def recur(p, h):

@@ -149,6 +149,52 @@ Per-tenant ledgers (tokens in/out, throttles, SLO burn rate) ride
 ``serving_lm_tenant_*`` families (docs/robustness.md "Tenancy &
 SLOs").
 
+**The block round** (ISSUE-42): a block-diffusion model
+(`cfg.block_length` B > 1: causal between blocks of B positions dealt by
+absolute position, bidirectional inside one) does not decode "one lane,
+one new token".  A lane in its decode phase holds its CURRENT BLOCK: B
+token ids and a `known` flag a column (a flag, never a comparison with
+the mask id, which traffic may send as an ordinary token).  A **denoise
+round** feeds the block at `pos .. pos + B - 1` (masked columns as
+`cfg.mask_token`), writes its provisional K/V into the lane's own next
+rows (as a rejected draft's are: `pos` does not move, so nothing reads
+them later), and gets back from the step program
+(`parallel.generation.make_block_step`) the block after this round's
+unmasking: `denoise_steps` S gives the static schedule (the `B / S`
+masked columns of highest confidence a round, ties to the lower
+position), `unmask="dynamic"` every masked column whose confidence
+passes `tau` and the single best one if none does.  When no column is
+masked the lane's next round is a **commit pass**: the same feed with
+every column known, after which `pos += B`, the block's tokens are
+committed (`_commit_tokens`, B at once: a stream yields in bursts of at
+most B and never a token that could still change) and the next block
+starts all masked.  Prefill feeds whole blocks (`page_size` and
+`prefill_chunk` are multiples of B); the prompt's last `len % B` tokens
+ride the first denoise round as known columns; the last block of an
+answer is denoised whole and what lies past `max_new` is dropped.  The
+narrow program is `[slots, B]`.  THE RULE, written once: **a lane's
+durable state is its committed blocks.**  Preemption, swap-out,
+hibernation and every radix insert act at the last committed block
+(`pos`), and a block in flight is denoised again after a restore
+(`serving_lm_block_redone_total`).  **One round ahead of the host**:
+under the static schedule which lanes denoise, commit, begin a block or
+end follows from counts the host has (a step unmasks `B / S` columns),
+and only the token ids come from the device; so round N + 1 is
+dispatched BEFORE round N is read, a lane whose block round N is still
+unmasking feeds it from round N's result left on the device (the step
+program's `held` and `carry`), and the device does not wait while the
+host reads, folds, streams and marshals (`_block_round`; the dynamic
+schedule's counts come from the device, so its round is read before the
+next is built).  The host's account of a lane is then one round behind
+the device's: `pos` and `fed` move at dispatch, tokens at the read, a
+lane that ends is freed at the read of its last round and idles for the
+round between, and whoever moves a lane's durable state reads the round
+in flight first (`_settle_block_round`).  Refused where the server is
+built: speculation (a draft has no meaning inside a block) and page shipping
+(`prefill_export` resolves a lane at prefill completion, which for a
+block model is mid-block); sampling at a temperature is refused a
+request.
+
 Resilience contract (ISSUE-4, mirrors `batcher.MicroBatcher`): bounded
 admission (`max_queue_depth` -> `ServingOverloadError`), per-request
 deadlines shed at the admitter before a prompt ever occupies a slot
@@ -262,7 +308,9 @@ class _LMRequest:
                  "priority", "rank", "swap_key", "swap_restore",
                  "swap_error", "stream_pushed", "preempted",
                  "tenant", "vft", "cost", "prefill_rounds",
-                 "prefill_wide_rounds", "snapshot_matched")
+                 "prefill_wide_rounds", "snapshot_matched",
+                 "prefill_len", "unmask_steps", "surplus", "surplus_steps",
+                 "blocks", "denoise_rounds")
 
     def __init__(self, prompt: List[int], max_new: int, temperature: float,
                  seed: int, deadline: Optional[float] = None,
@@ -308,12 +356,41 @@ class _LMRequest:
         self.prefill_wide_rounds = 0
         # prompt tokens whose state came from a snapshot (recurrent models)
         self.snapshot_matched = 0
+        # prompt tokens a prefill feeds: all of them, or a block model's
+        # whole blocks (the tail rides the first denoise round)
+        self.prefill_len = len(self.prompt)
+        # the block round's account (block models): the denoise step,
+        # within its block, at which each committed token was unmasked;
+        # what the last block held past the answer's end, and its steps;
+        # blocks committed (a commit pass each) and denoise lane-rounds
+        self.unmask_steps: List[int] = []
+        self.surplus: List[int] = []
+        self.surplus_steps: List[int] = []
+        self.blocks = 0
+        self.denoise_rounds = 0
+
+
+class _Block:
+    """A block in flight at `first .. first + B - 1`: its ids (the mask id
+    where a column is masked), a column's known flag, the denoise step that
+    unmasked it (-1: a prompt token) and the denoise steps the block has
+    had, all as of the last round the host has read."""
+    __slots__ = ("first", "tokens", "known", "steps", "step")
+
+    def __init__(self, first: int, tail: List[int], width: int,
+                 mask_token: int):
+        rest = width - len(tail)
+        self.first = first
+        self.tokens = list(tail) + [mask_token] * rest
+        self.known = [True] * len(tail) + [False] * rest
+        self.steps = [-1] * width
+        self.step = 0
 
 
 class _Slot:
     __slots__ = ("req", "pos", "fed", "generated",
                  "table", "owned", "shared", "inserted",
-                 "row", "trail", "trail_pos")
+                 "row", "trail", "trail_pos", "block")
 
     def __init__(self):
         self.req: Optional[_LMRequest] = None
@@ -331,6 +408,10 @@ class _Slot:
         self.row = 0
         self.trail = 0
         self.trail_pos: Optional[int] = None
+        # a block model's lane in its decode phase: the block it denoises
+        # at `pos .. pos + B - 1` (None: not begun, or its commit pass is
+        # dispatched)
+        self.block: Optional[_Block] = None
 
     @property
     def active(self) -> bool:
@@ -363,6 +444,9 @@ class ContinuousLMServer:
                  swap_quantize: bool = True,
                  state_rows: Optional[int] = None,
                  snapshot_every: Optional[int] = None,
+                 denoise_steps: Optional[int] = None,
+                 unmask: Optional[str] = None,
+                 tau: Optional[float] = None,
                  tracer: Optional[TraceRecorder] = None,
                  registry: Optional[MetricsRegistry] = None):
         if slots < 1:
@@ -431,6 +515,54 @@ class ContinuousLMServer:
                     f"({prefill_chunk})")
         self.state_rows = state_rows
         self.snapshot_every = snapshot_every
+        # the block round (module docstring): B = 1 is every causal model
+        self.block = int(getattr(cfg, "block_length", 1))
+        if self.block == 1:
+            if not (denoise_steps is None and unmask is None
+                    and tau is None):
+                raise ValueError(
+                    "denoise_steps / unmask / tau set the unmasking "
+                    "schedule of a block-diffusion model "
+                    "(cfg.block_length > 1); this one is causal")
+        else:
+            from deeplearning4j_tpu.parallel.transformer import (
+                UnsupportedLayerKind,
+            )
+
+            for on, what in ((speculate != "off" or drafter is not None,
+                              "speculative decoding: a draft has no "
+                              "meaning inside a block"),
+                             (ship, "page shipping (ship=True): a lane "
+                              "would leave at prefill completion, which "
+                              "is mid-block")):
+                if on:
+                    raise UnsupportedLayerKind(
+                        f"a block-diffusion model (block_length "
+                        f"{self.block}) is refused {what}")
+            if int(page_size) % self.block or (
+                    int(prefill_chunk) % self.block):
+                raise ValueError(
+                    f"page_size ({page_size}) and prefill_chunk "
+                    f"({prefill_chunk}) must hold whole blocks of "
+                    f"{self.block}: a cached page is valid for any "
+                    f"request that shares its prefix only because its "
+                    f"K/V depend on tokens up to the page's end")
+            denoise_steps = (self.block if denoise_steps is None
+                             else int(denoise_steps))
+            if denoise_steps < 1 or self.block % denoise_steps:
+                raise ValueError(
+                    f"denoise_steps ({denoise_steps}) must divide the "
+                    f"block length ({self.block})")
+            unmask = "static" if unmask is None else unmask
+            if unmask not in ("static", "dynamic"):
+                raise ValueError(f"unmask must be 'static' or 'dynamic', "
+                                 f"got {unmask!r}")
+            tau = 0.9 if tau is None else float(tau)
+            if not 0.0 <= tau <= 1.0:
+                raise ValueError(f"tau must be in [0, 1], got {tau}")
+        self.denoise_steps = denoise_steps
+        self.unmask = unmask
+        self.tau = tau
         self.cfg = cfg
         self.params = params
         self.n_slots = int(slots)
@@ -568,6 +700,9 @@ class ContinuousLMServer:
         self._warm_error: Optional[BaseException] = None
         self._slots = [_Slot() for _ in range(self.n_slots)]
         self._steps = 0
+        # a block model's round that is dispatched and not read yet (the
+        # static schedule runs one round ahead of the host: `_block_round`)
+        self._ahead: Optional[Dict] = None
         # the worker's wall time by phase of the round (its thread only)
         self._clock = PhaseClock("lm:")
         self._warmup_stats: Optional[Dict] = None
@@ -576,8 +711,21 @@ class ContinuousLMServer:
 
     def _required_pages(self, plen: int, max_new: int) -> int:
         """Pages one lane needs: positions written = plen + max_new - 1
-        (the final sampled token is returned, never fed)."""
-        return -(-(plen + max_new - 1) // self.page_size)
+        (the final sampled token is returned, never fed); a block model
+        writes whole blocks through its answer's last one."""
+        rows = plen + max_new - 1
+        if self.block > 1:
+            rows = -(-(plen + max_new) // self.block) * self.block
+        return -(-rows // self.page_size)
+
+    def _kv_rows(self, n_tokens: int) -> int:
+        """How many leading positions of a finished sequence of
+        `n_tokens` hold K/V made from its own tokens alone: all but the
+        last sampled one, or a block model's whole blocks (the last
+        block's rows also saw what was dropped past the answer's end)."""
+        if self.block > 1:
+            return n_tokens // self.block * self.block
+        return n_tokens - 1
 
     def validate(self, prompt_ids, max_new_tokens: int) -> List[int]:
         """`validate_request` against this server's config, plus the
@@ -637,6 +785,11 @@ class ContinuousLMServer:
             ids = self.validate(prompt_ids, max_new_tokens)
         if temperature < 0:
             raise ValueError(f"temperature must be >= 0, got {temperature}")
+        if self.block > 1 and temperature > 0:
+            raise ValueError(
+                "a block-diffusion model is served greedily: the "
+                "unmasking schedule ranks positions by the confidence of "
+                "their best token (temperature must be 0)")
         # fold into int32 range (the device-side PRNGKey seed dtype) so a
         # huge client seed cannot overflow the worker's seed vector
         seed = int(seed) & 0x7FFFFFFF
@@ -646,6 +799,7 @@ class ContinuousLMServer:
             request_id = new_request_id()
         req = _LMRequest(ids, int(max_new_tokens), float(temperature),
                          seed, request_id=request_id)
+        req.prefill_len = len(ids) // self.block * self.block
         if deadline_s is not None:
             req.deadline = req.enqueued + float(deadline_s)
         req.session_id = (str(session_id) if session_id is not None
@@ -1051,14 +1205,21 @@ class ContinuousLMServer:
                 drafted=req.drafted or None,
                 accepted=(req.accepted if req.drafted else None),
                 preempted=req.preempted or None,
-                swap_error=req.swap_error))
+                swap_error=req.swap_error,
+                **({"blocks": req.blocks,
+                    "denoise_rounds": req.denoise_rounds,
+                    "commit_rounds": req.blocks,
+                    "unmask_steps": list(req.unmask_steps),
+                    "surplus": list(req.surplus),
+                    "surplus_steps": list(req.surplus_steps)}
+                   if self.block > 1 else {})))
             if (req.t_first is not None
                     and req.t_first >= req.t_installed):
                 # a preempted lane's first token predates its last
                 # install: its prefill is not this residency's
                 spans.append(span(
                     "prefill", req.t_installed, req.t_first,
-                    fed_tokens=len(req.prompt) - req.prefix_matched,
+                    fed_tokens=req.prefill_len - req.prefix_matched,
                     rounds=req.prefill_rounds,
                     wide_rounds=req.prefill_wide_rounds))
             if self._compile_watch.any_since(req.t_installed):
@@ -1158,16 +1319,22 @@ class ContinuousLMServer:
                 if hasattr(self._drafter, "warmup"):
                     warm("lm:drafter", self._drafter.warmup)
             else:
-                widths = [1] + ([self.prefill_chunk]
-                                if self.prefill_chunk > 1 else [])
+                widths = [self.block] + ([self.prefill_chunk]
+                                         if self.prefill_chunk > self.block
+                                         else [])
                 # a recurrent model's lanes name their state rows last
-                # (all the null row here)
-                rows = (zi,) if self.recurrent else ()
+                # (all the null row here); a block model's feed carries
+                # its columns' known flags, the quota and the threshold
+                if self.block > 1:
+                    tail = (np.ones((self.n_slots, self.block), np.int32),
+                            zi, zf, self._no_block_held(), zi)
+                else:
+                    tail = (zf, zi, zi) + ((zi,) if self.recurrent else ())
                 for w in widths:
                     tok = np.zeros((self.n_slots, w), np.int32)
                     out = warm(f"lm:paged[w{w}]", lambda: self._step(
-                        self.params, *self._cache, table, zi, zi, tok, zf,
-                        zi, zi, *rows))
+                        self.params, *self._cache, table, zi, zi, tok,
+                        *tail))
                     self._cache = tuple(out[1:])
             if self.recurrent:
                 # the null row onto itself; no match ends mid-page, so
@@ -1209,7 +1376,7 @@ class ContinuousLMServer:
                        and hasattr(self._drafter, "compiled_programs")
                        else 0)
             return 3 + drafter + ship
-        return 2 + (1 if self.prefill_chunk > 1 else 0) + ship
+        return 2 + (1 if self.prefill_chunk > self.block else 0) + ship
 
     def stop(self) -> None:
         with self._cond:
@@ -1325,6 +1492,12 @@ class ContinuousLMServer:
                                        if self._tree is not None else 0),
                     "snapshot_every": self.snapshot_every,
                     "row_bytes": state_row_bytes(self.cfg)})
+            if self.block > 1:
+                out.setdefault("blocks", {}).update({
+                    "block_length": self.block,
+                    "denoise_steps": self.denoise_steps,
+                    "unmask": self.unmask, "tau": self.tau,
+                    "mask_token": self.cfg.mask_token})
             if self._sessions:
                 out["sessions_tracked"] = len(self._sessions)
             if self.preempt or self._pressure is not None:
@@ -1412,6 +1585,7 @@ class ContinuousLMServer:
         # content) that died with the pool — their lanes restart or fail
         # with it, so the pending plane resets wholesale too
         self._pending_install = []
+        self._ahead = None      # its lanes restart or fail with the pool
         for s in self._slots:
             s.table = None
             s.owned = []
@@ -1443,6 +1617,7 @@ class ContinuousLMServer:
         if self._step is None:
             from deeplearning4j_tpu.parallel.generation import (
                 kv_write_path,
+                make_block_step,
                 make_page_copy,
                 make_paged_step,
                 make_spec_step,
@@ -1455,9 +1630,13 @@ class ContinuousLMServer:
                     else self.prefill_chunk)
             path = kv_write_path(self.cfg, self.page_size,
                                  self.paged_kernel)
-            self._write_path = {f"w{w}": path for w in sorted({1, wide})}
-            self._decode_step = make_paged_step(
-                self.cfg, total, self.page_size, 1)
+            self._write_path = {f"w{w}": path
+                                for w in sorted({self.block, wide})}
+            # the narrow program is `[slots, B]` (B = 1: a causal model's
+            # `[slots, 1]`), the wide one the prefill chunk, whole blocks
+            step_of = make_block_step if self.block > 1 else make_paged_step
+            self._decode_step = step_of(
+                self.cfg, total, self.page_size, self.block)
             if self.speculate != "off":
                 # ONE wide program serves chunked prefill AND the
                 # speculative verify — the same chunked-feed ladder,
@@ -1465,9 +1644,9 @@ class ContinuousLMServer:
                 self._chunk_step = make_spec_step(
                     self.cfg, total, self.page_size, self.spec_width)
             else:
-                self._chunk_step = (make_paged_step(
+                self._chunk_step = (step_of(
                     self.cfg, total, self.page_size, self.prefill_chunk)
-                    if self.prefill_chunk > 1 else None)
+                    if self.prefill_chunk > self.block else None)
             if self.recurrent:
                 from deeplearning4j_tpu.parallel.generation import (
                     make_state_copy,
@@ -1522,7 +1701,8 @@ class ContinuousLMServer:
                     # fault-injection tests that stub `self._step`
                     # intercept them all
                     tokens = args[n_pools + 3]
-                    fn = (self._decode_step if tokens.shape[1] == 1
+                    fn = (self._decode_step
+                          if tokens.shape[1] == self.block
                           else self._chunk_step)
                     return fn(params, *args)
 
@@ -1632,8 +1812,17 @@ class ContinuousLMServer:
         total_pages = (-(-plen // self.page_size) if req.export
                        else self._required_pages(plen, req.max_new))
         # cap reuse at plen-1: the LAST prompt token is always re-fed —
-        # its logits are what the first sampled token comes from
-        full, partial = self._tree.match(req.prompt[:plen - 1])
+        # its logits are what the first sampled token comes from.  A
+        # block model reuses whole blocks (a row's K/V saw its whole
+        # block): its prompt's tail rides the first denoise round, and a
+        # match that ends inside a page ends on a block's boundary
+        full, partial = self._tree.match(
+            req.prompt[:self._kv_rows(plen)])
+        if partial is not None and partial[1] % self.block:
+            cut = partial[1] // self.block * self.block
+            if not cut:
+                self._pool.release([partial[0]])
+            partial = (partial[0], cut) if cut else None
         if len(full) > total_pages:     # cannot happen (cap above), but
             raise AssertionError("radix match exceeded the page budget")
         resume = None
@@ -1718,7 +1907,7 @@ class ContinuousLMServer:
         ledger, stamped on THIS request's trace, and the probe keeps
         descending — shallower prefixes may still be intact."""
         plen = len(req.prompt)
-        for k in range((plen - 1) // self.page_size, have, -1):
+        for k in range(self._kv_rows(plen) // self.page_size, have, -1):
             covered = [int(t) for t in req.prompt[:k * self.page_size]]
             key = prefix_key(covered)
             if key not in self._swap:
@@ -1766,6 +1955,7 @@ class ContinuousLMServer:
         req.t_installed = time.perf_counter()
         req.prefix_matched = plan["matched"]
         slot.generated = []
+        slot.block = None
         slot.fed = plan["matched"]
         slot.pos = plan["matched"]
         slot.shared = list(plan["full"])
@@ -1804,6 +1994,7 @@ class ContinuousLMServer:
             slot.fed = len(req.prompt)
             slot.pos = int(ex.pos)
             slot.generated = list(ex.committed)
+            del req.unmask_steps[len(slot.generated):]
             n_ship = ex.n_pages
             stacks = self._padded_stacks(ex, n_ship)
             # radix-matched prefix pages are NOT re-installed: their
@@ -1867,6 +2058,7 @@ class ContinuousLMServer:
             self._pending_cow.append({"src": int(src),
                                       "dst": int(plan["fresh"][0])})
         self.metrics.record_prefix_query(plan["matched"])
+        del req.unmask_steps[:]     # a lane that restarts from its prompt
 
     def _admit_locked(self) -> None:
         """Queued prompts join free slots.  Doomed work is shed first:
@@ -2011,7 +2203,7 @@ class ContinuousLMServer:
             # only positions BEFORE the final sampled token have KV
             # (the last sample is returned, never fed) — park exactly
             # the fully-written pages
-            n_full = (len(tokens) - 1) // self.page_size
+            n_full = self._kv_rows(len(tokens)) // self.page_size
             if n_full == 0:
                 continue
             covered = [int(t) for t in tokens[:n_full * self.page_size]]
@@ -2163,9 +2355,19 @@ class ContinuousLMServer:
         and re-prefills (radix-cached pages make that cheap).  Either
         way the request keeps its original enqueue stamp, so it
         re-enters AHEAD of later arrivals of its own class."""
+        # a lane swaps out what the host has read of it: read the round
+        # in flight first, which may have been this lane's last
+        self._settle_block_round()
+        if not slot.active:
+            return
         req = slot.req
-        mid_decode = (slot.fed >= len(req.prompt) and slot.generated
+        mid_decode = (slot.fed >= req.prefill_len and slot.generated
                       and not req.export)
+        if slot.block is not None and slot.block.step:
+            # the durable state is the committed blocks: the block in
+            # flight is denoised again wherever the lane comes back
+            self.metrics.record_block_redone()
+        slot.block = None
         if (mid_decode and self._swap is not None
                 and self._gather is not None and self._cache is not None):
             n = -(-slot.pos // self.page_size)
@@ -2252,7 +2454,7 @@ class ContinuousLMServer:
                 # token is returned, never fed, so its position has no
                 # KV yet.
                 seq = slot.req.result
-                n_full = (len(seq) - 1) // self.page_size
+                n_full = self._kv_rows(len(seq)) // self.page_size
                 if n_full:
                     self._tree.insert(
                         seq[:n_full * self.page_size],
@@ -2284,8 +2486,7 @@ class ContinuousLMServer:
             # snapshot: they enter the tree with one (`_give_snapshot`)
             return
         slot.inserted = True
-        plen = len(slot.req.prompt)
-        n_full = plen // self.page_size
+        n_full = slot.req.prefill_len // self.page_size
         if n_full:
             self._tree.insert(slot.req.prompt[:n_full * self.page_size],
                               [int(p) for p in slot.table[:n_full]])
@@ -2530,6 +2731,8 @@ class ContinuousLMServer:
             held = [r["held"] for r in restores if r["held"] is not None]
             self._states.release(held)
             self._record_state(hit=len(held), copied=len(restores))
+        if self.block > 1:
+            return self._block_round(active, level)
         # brownout ladder effects (ISSUE-15, docs/robustness.md "The
         # degradation ladder"): level 1 turns speculation off (drafts
         # buy throughput with wide-dispatch compute — under pressure
@@ -2704,6 +2907,232 @@ class ContinuousLMServer:
         self.metrics.record_round(clock.take(), width, self.n_slots, fed,
                                   live_pages, attn_rows, attn_pairs)
         return True
+
+    def _no_block_held(self) -> np.ndarray:
+        """The step program's `held` where no lane carries a block: the
+        shape of its result, zeros."""
+        load = 3 if self.cfg.experts is not None else 0
+        return np.zeros((2 * self.n_slots * self.block + load,), np.int32)
+
+    def _block_round(self, active, level: int = 0) -> bool:
+        """`_dispatch_paged`'s round for a block model (module docstring,
+        "The block round"): prefill lanes feed whole blocks of their
+        prompt, decode lanes their block in flight, as a denoise round
+        while a column is masked and as the commit pass when none is;
+        one dispatch, one host sync.  Under the static schedule the
+        dispatch of round N + 1 comes BEFORE the sync of round N: which
+        lanes denoise, commit, begin a block or end is known from the
+        counts alone, and a lane whose block round N is still unmasking
+        feeds it from round N's result on the device (`carry`), so the
+        device never waits for the host to read a round.  The dynamic
+        schedule's counts come from the device, and its round is read
+        before the next is built."""
+        clock = self._clock
+        flight = self._dispatch_block_round(active, level)
+        before, self._ahead = self._ahead, flight  # noqa: LCK101
+        if before is not None:
+            self._fold_block_round(before)
+        if self.unmask != "static":
+            self._settle_block_round()
+        clock.to("yield")
+        if flight is None:
+            # nothing left to feed: the lanes' last round was read above
+            self.metrics.record_phase_seconds(clock.take())
+        else:
+            self.metrics.record_round(clock.take(), flight["width"],
+                                      self.n_slots, *flight["account"])
+        return True
+
+    def _settle_block_round(self) -> None:
+        """Read the round in flight, if one is: whoever moves a lane's
+        durable state (a preemption's swap-out) first brings the host's
+        account of it up to the device's."""
+        flight, self._ahead = self._ahead, None  # noqa: LCK101
+        if flight is not None:
+            self._fold_block_round(flight)
+
+    def _dispatch_block_round(self, active, level: int) -> Optional[Dict]:
+        """Build and dispatch one block round from what is known WITHOUT
+        the round in flight's result: positions, feeds and how many
+        columns each block has masked.  -> the round in flight (its
+        result still on the device, and each lane's part in it), or None
+        where no lane has anything to feed."""
+        clock = self._clock
+        clock.to("plan")
+        blk = self.block
+        chunk_eff = (max(blk, self.prefill_chunk // 2 // blk * blk)
+                     if level >= 2 else self.prefill_chunk)
+        width = blk
+        if self._chunk_step is not None and any(
+                s.req.prefill_len - s.fed >= chunk_eff for s in active):
+            width = self.prefill_chunk
+        clock.to("marshal")
+        before = self._ahead["lanes"] if self._ahead is not None else {}
+        fed = dict.fromkeys(("prefill", "decode", "draft"), 0)
+        live_pages = attn_rows = attn_pairs = 0
+        tokens = np.zeros((self.n_slots, width), np.int32)
+        known = np.ones((self.n_slots, blk), np.int32)
+        carry = np.zeros((self.n_slots,), np.int32)
+        pos = np.zeros((self.n_slots,), np.int32)
+        n_feed = np.zeros((self.n_slots,), np.int32)
+        # static: B / S columns a step and a threshold no confidence
+        # reaches; dynamic: the threshold, and one column where nothing
+        # passes it (a prefill lane's choice is made and ignored)
+        static = self.unmask == "static"
+        per_step = blk // self.denoise_steps if static else 1
+        quota = np.full((self.n_slots,), per_step, np.int32)
+        tau = np.full((self.n_slots,), 2.0 if static else self.tau,
+                      np.float32)
+        table = np.zeros((self.n_slots, self.max_pages), np.int32)
+        lanes: Dict[int, Dict] = {}
+        for i, slot in enumerate(self._slots):
+            if not slot.active:
+                continue
+            req = slot.req
+            prior = before.get(i)
+            if prior is not None and prior["req"] is not req:
+                prior = None        # the lane has changed hands since
+            remaining = req.prefill_len - slot.fed
+            if remaining > 0:                  # whole blocks of the prompt
+                f = min(remaining, width, chunk_eff)
+                tokens[i, :f] = req.prompt[slot.fed:slot.fed + f]
+                fed["prefill"] += f
+                req.prefill_rounds += 1
+                req.prefill_wide_rounds += width > blk
+                lane = {"req": req, "kind": "prefill",
+                        "ends": remaining == f}
+            else:
+                sent = len(slot.generated) + (
+                    prior["served"] if prior is not None
+                    and prior["kind"] == "commit" else 0)
+                if sent >= req.max_new:
+                    continue    # its last commit is in flight
+                if slot.block is None:
+                    slot.block = _Block(
+                        slot.pos, req.prompt[slot.pos:slot.pos + blk], blk,
+                        int(self.cfg.mask_token))
+                block = slot.block
+                if prior is not None and prior["kind"] == "denoise":
+                    # the block is round N's result, on the device
+                    masked, carry[i] = prior["left"], 1
+                else:
+                    masked = blk - sum(block.known)
+                    tokens[i, :blk] = block.tokens
+                    known[i] = block.known
+                f = blk
+                fed["decode"] += blk
+                if masked:
+                    # what stays masked, by the static schedule's count
+                    lane = {"req": req, "kind": "denoise", "block": block,
+                            "left": masked - min(per_step, masked)}
+                else:
+                    # the commit pass: its tokens are the client's once
+                    # the round is read; the lane is past the block now
+                    own = max(len(req.prompt) - slot.pos, 0)
+                    lane = {"req": req, "kind": "commit", "block": block,
+                            "served": min(blk - own, req.max_new - sent)}
+                    slot.block = None
+            n_feed[i] = f
+            live_pages += -(-(slot.pos + f) // self.page_size)
+            attn_rows += slot.pos + f
+            # a column sees the history and its whole block
+            n = f // blk
+            attn_pairs += f * slot.pos + blk * blk * n * (n + 1) // 2
+            pos[i] = slot.pos
+            table[i] = slot.table
+            lanes[i] = lane
+            if lane["kind"] == "prefill":
+                slot.pos += f
+                slot.fed += f
+            elif lane["kind"] == "commit":
+                slot.pos += blk
+        if not lanes:
+            return None
+        clock.to("dispatch")
+        # the worker thread owns `_cache` between dispatches (as in
+        # `_dispatch_paged`, whose round this is)
+        cache = self._cache  # noqa: LCK101
+        held = (self._ahead["out"] if self._ahead is not None
+                else self._no_block_held())
+        with compile_scope(f"lm:paged[w{width}]"):
+            out, *pools = self._step(
+                self.params, *cache, table, pos, n_feed, tokens, known,
+                quota, tau, held, carry)
+        if self.breaker is not None:
+            self.breaker.record_success()
+        self._cache = tuple(pools)  # noqa: LCK101
+        self._steps += 1
+        self.metrics.record_dispatch(len(active), self.n_slots)
+        return {"out": out, "lanes": lanes, "width": width,
+                "account": (fed, live_pages, attn_rows, attn_pairs)}
+
+    def _fold_block_round(self, flight: Dict) -> None:
+        """Read a dispatched round, ONE host sync (the blocks after the
+        unmasking, their flags and the expert load arrive as one array),
+        and fold it into its lanes: a denoise round's unmasked columns
+        into the block, a commit pass's tokens to the client."""
+        clock = self._clock
+        clock.to("sync")
+        out = np.asarray(flight["out"])
+        if self.cfg.experts is not None:
+            self.metrics.record_expert_load(*(int(x) for x in out[-3:]))
+        clock.to("fold")
+        blk = self.block
+        cells = self.n_slots * blk
+        new_tok = out[:cells].reshape(self.n_slots, blk)
+        new_known = out[cells:2 * cells].reshape(self.n_slots, blk) != 0
+        emitted = 0
+        # the round's account, recorded once: lane-rounds by kind, columns
+        # fed masked and known, columns unmasked
+        denoised = committed = fed_masked = fed_known = unmasked = 0
+        for i, lane in flight["lanes"].items():
+            slot, req = self._slots[i], lane["req"]
+            if slot.req is not req:
+                continue        # abandoned and freed since the dispatch
+            if lane["kind"] == "prefill":
+                if lane["ends"]:
+                    self._insert_prompt_pages(slot)
+                continue
+            block = lane["block"]
+            masked = blk - sum(block.known)
+            fed_masked += masked
+            fed_known += blk - masked
+            if lane["kind"] == "denoise":
+                for c in range(blk):
+                    if new_known[i, c] and not block.known[c]:
+                        block.tokens[c] = int(new_tok[i, c])
+                        block.known[c] = True
+                        block.steps[c] = block.step
+                        unmasked += 1
+                block.step += 1
+                req.denoise_rounds += 1
+                denoised += 1
+                if (self.unmask == "static"
+                        and blk - sum(block.known) != lane["left"]):
+                    raise RuntimeError(
+                        f"lane {i}: the denoise round left "
+                        f"{blk - sum(block.known)} columns masked where "
+                        f"the static schedule counts {lane['left']}")
+                continue
+            # the commit pass wrote the K/V of the block's final tokens:
+            # the block is durable, its answer tokens are the client's
+            own = max(len(req.prompt) - block.first, 0)  # the prompt's tail
+            toks, steps = block.tokens[own:], block.steps[own:]
+            room = lane["served"]
+            req.unmask_steps += steps[:room]
+            req.surplus, req.surplus_steps = toks[room:], steps[room:]
+            req.blocks += 1
+            self._commit_tokens(slot, toks[:room])
+            emitted += room
+            committed += 1
+            if len(slot.generated) >= req.max_new:
+                self._finish_slot(slot)
+        self.metrics.record_block_rounds(denoised, committed, fed_masked,
+                                         fed_known, unmasked)
+        if emitted:
+            self.metrics.record_tokens(emitted)
+        self.metrics.set_pages(self._pool.in_use, self._pool.free,
+                               self.kv_pages)
 
     def _keep_state(self, slot: _Slot, saves: List, prompt: bool) -> int:
         """A recurrent lane just advanced to `slot.pos`.  Where that is a

@@ -60,6 +60,9 @@ ROUND_KINDS = ("w1", "wide")
 EXPERT_PLACES = ("held", "absent")
 # what happened to a recurrent model's state snapshot under the radix tree
 SNAPSHOT_EVENTS = ("taken", "hit", "evicted")
+# the block round of a block-diffusion model (serving/lm.py)
+BLOCK_ROUND_KINDS = ("denoise", "commit")
+BLOCK_COLUMN_STATES = ("masked", "known")
 # host time of one round (everything but `sync`): milliseconds matter
 _ROUND_HOST_BUCKETS = (0.00025, 0.0005, 0.001, 0.002, 0.003, 0.004, 0.005,
                        0.0075, 0.01, 0.02, 0.05, 0.1, 0.25, 1.0)
@@ -313,6 +316,28 @@ class ServingMetrics:
                           "state rows the round's recurrent layers read "
                           "and wrote: its active lanes")
             for kind in ROUND_KINDS}
+        # the block round of a block-diffusion model (ISSUE-42): what a
+        # decode lane's rounds fed, unmasked and made durable
+        self.block_rounds = {
+            kind: Counter("serving_lm_block_rounds_total",
+                          "lane-rounds of a block model's decode phase: "
+                          "denoise rounds and commit passes")
+            for kind in BLOCK_ROUND_KINDS}
+        self.block_positions = {
+            state: Counter("serving_lm_block_positions_total",
+                           "block columns fed by decode lanes, as the "
+                           "mask id or as a known token")
+            for state in BLOCK_COLUMN_STATES}
+        self.block_unmasked_total = Counter(
+            "serving_lm_block_unmasked_total",
+            "block columns unmasked by denoise rounds")
+        self.blocks_committed_total = Counter(
+            "serving_lm_blocks_committed_total",
+            "blocks made durable by a commit pass")
+        self.block_redone_total = Counter(
+            "serving_lm_block_redone_total",
+            "blocks in flight dropped by a preemption and denoised "
+            "again (a lane's durable state is its committed blocks)")
         self.round_host_hist = Histogram(
             "serving_lm_round_host_seconds",
             "host time of one round: every phase but sync",
@@ -387,13 +412,17 @@ class ServingMetrics:
                   self.idle_seconds_total, self.feed_capacity_total,
                   self.live_pages_total, self.round_host_hist,
                   self.expert_peak_total, self.expert_rounds_total,
-                  self.state_rows_gauge, self.state_copy_rows_total):
+                  self.state_rows_gauge, self.state_copy_rows_total,
+                  self.block_unmasked_total, self.blocks_committed_total,
+                  self.block_redone_total):
             registry.register(m, **labels)
         for cells, label in ((self.attn_rows, "round"),
                              (self.attn_pairs, "round"),
                              (self.expert_pairs, "place"),
                              (self.snapshots, "event"),
-                             (self.kda_rows, "round")):
+                             (self.kda_rows, "round"),
+                             (self.block_rounds, "kind"),
+                             (self.block_positions, "state")):
             for value, m in cells.items():
                 registry.register(m, **{label: value}, **labels)
         for (_event, cls), m in self.class_counters.items():
@@ -516,6 +545,24 @@ class ServingMetrics:
 
     def record_kda_rows(self, width: int, lanes: int) -> None:
         self.kda_rows["w1" if int(width) == 1 else "wide"].inc(int(lanes))
+
+    def record_block_rounds(self, denoise: int, commit: int, masked: int,
+                            known: int, unmasked: int) -> None:
+        """One round's decode lanes of a block model: how many rode a
+        denoise round and how many a commit pass (each made a block
+        durable), the columns they fed masked and known, and those the
+        denoise rounds unmasked."""
+        for cell, n in ((self.block_rounds["denoise"], denoise),
+                        (self.block_rounds["commit"], commit),
+                        (self.blocks_committed_total, commit),
+                        (self.block_positions["masked"], masked),
+                        (self.block_positions["known"], known),
+                        (self.block_unmasked_total, unmasked)):
+            if n:
+                cell.inc(int(n))
+
+    def record_block_redone(self, n: int = 1) -> None:
+        self.block_redone_total.inc(int(n))
 
     def record_expert_load(self, held: int, absent: int,
                            peak_x1000: int) -> None:
@@ -908,6 +955,15 @@ class ServingMetrics:
                 "copy_rows": int(self.state_copy_rows_total.value),
                 "kda_rows": {k: int(m.value)
                              for k, m in self.kda_rows.items()}}
+        if any(int(m.value) for m in self.block_rounds.values()):
+            out["blocks"] = {
+                "rounds": {k: int(m.value)
+                           for k, m in self.block_rounds.items()},
+                "positions": {k: int(m.value)
+                              for k, m in self.block_positions.items()},
+                "unmasked": int(self.block_unmasked_total.value),
+                "committed": int(self.blocks_committed_total.value),
+                "redone": int(self.block_redone_total.value)}
         if int(self.expert_rounds_total.value):
             out["experts"] = {
                 "rounds": int(self.expert_rounds_total.value),

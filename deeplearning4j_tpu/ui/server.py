@@ -818,7 +818,10 @@ class UiServer:
                  state_disk_bytes: int = 1 << 30,
                  swap_quantize: bool = True,
                  state_rows: Optional[int] = None,
-                 snapshot_every: Optional[int] = None) -> "UiServer":
+                 snapshot_every: Optional[int] = None,
+                 denoise_steps: Optional[int] = None,
+                 unmask: Optional[str] = None,
+                 tau: Optional[float] = None) -> "UiServer":
         """Register a TransformerLM for POST /lm/generate.  With
         `continuous` (default) greedy/temperature requests decode in a
         `slots`-lane continuous batching pool; `continuous=False` keeps
@@ -853,7 +856,14 @@ class UiServer:
         tree's snapshots share its rows; a prompt leaves a snapshot every
         `snapshot_every` tokens) and are a `ValueError` for any other
         model; such a model is refused `speculate`, `ship`, `preempt` and
-        hibernation (`UnsupportedLayerKind`)."""
+        hibernation (`UnsupportedLayerKind`).  `denoise_steps`, `unmask`
+        ("static" | "dynamic") and `tau` set the unmasking schedule of a
+        block-diffusion model (`cfg.block_length` B > 1): B / steps
+        positions a denoise round, or every position whose confidence
+        passes `tau`; a stream then yields committed blocks, at most B
+        tokens at once; such a model is refused `speculate` and `ship`
+        and a request's temperature, and the options are a `ValueError`
+        for a causal model (docs/performance.md "The block round")."""
         lm_server = None
         if continuous:
             from deeplearning4j_tpu.serving import (
@@ -876,6 +886,7 @@ class UiServer:
                 state_disk_bytes=state_disk_bytes,
                 swap_quantize=swap_quantize,
                 state_rows=state_rows, snapshot_every=snapshot_every,
+                denoise_steps=denoise_steps, unmask=unmask, tau=tau,
                 tracer=self.state.tracer,
                 registry=self.state.registry)
         with self.state.lock:

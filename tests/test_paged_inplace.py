@@ -452,6 +452,57 @@ def test_v5e_latent_step_updates_its_one_pool_in_place(one_chip, width,
     assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 3
 
 
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("width", [4, 64])
+def test_v5e_block_step_compiles_under_the_block_mask(one_chip, width,
+                                                      monkeypatch):
+    """SDAR-30B-A3B's published widths (32 query heads over 4 K/V heads of
+    128, q/k norms, rotary, 128 experts of 768 all held, the whole
+    vocabulary) at the benchmark's 32 lanes and page 16, cut to two layers,
+    compiled for a v5e with the Mosaic kernel under the block mask: the K
+    and V pools aliased whole; a grouped kernel call a layer whose result
+    `bf16[lanes, width, 32, 128]` the trace readers match; the experts'
+    grouped matmuls as `ragged-dot` calls; the step's tail under its
+    scope, and one int32 array out."""
+    monkeypatch.setattr(pk, "_resolve_interpret", lambda interpret: False)
+    cfg = tfm.sdar_30b_a3b(layers=2, max_len=2048)
+    ps, lanes, pages = 16, 32, 1 + 32 * 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = sds((2, pages, ps, 512), jnp.bfloat16)
+    assert gen.pool_layout(cfg).row == 512
+    mp = gen.pages_per_seq(cfg, ps)
+
+    def i32(*s):
+        return sds(s, np.int32)
+
+    step = gen._compiled_block_step.__wrapped__(cfg, pages, ps, width, True)
+    compiled = step.lower(
+        params, pool, pool, i32(lanes, mp), i32(lanes), i32(lanes),
+        i32(lanes, width), i32(lanes, 4), i32(lanes),
+        sds((lanes,), np.float32), i32(2 * lanes * 4 + 3),
+        i32(lanes)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == 2 * int(np.prod(pool.shape)) * 2
+    text = compiled.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if "tpu_custom_call" in ln and "grouped_paged_attention" in ln]
+    assert len(kernels) == cfg.n_layers
+    for ln in kernels:
+        assert re.search(r"= bf16\[%d,%d,32,128\]\S* custom-call\("
+                         % (lanes, width), ln), ln[:300]
+        assert "operand_layout_constraints={s32[%d,%d]" % (lanes, mp) in ln
+    assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 3 * cfg.n_layers
+    assert "blocks:unmask" in text and "attn:rope" in text
+    out = [ln for ln in text.splitlines() if "ROOT" in ln and "tuple(" in ln]
+    assert any("s32[%d]" % (2 * lanes * 4 + 3) in ln for ln in out)
+
+
 # The flash attention kernels at the shapes the benchmark's train cells and
 # the suite run them, compiled by the real Mosaic compiler (ISSUE 27): the
 # interpreter takes blocks, slices and layouts that Mosaic refuses.

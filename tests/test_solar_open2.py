@@ -289,7 +289,8 @@ def test_grouped_paged_kernel_matches_the_gather_oracle(width):
     nf = jnp.minimum(jnp.asarray([12, 5, 0], jnp.int32), width)
     x = jax.random.normal(jax.random.PRNGKey(9), (b, width, cfg.d_model))
     outs = [gen._grouped_paged_attn(p, x, pool["k"], pool["v"], 1, table,
-                                    pos, nf, paged_kernel=kernel)[0]
+                                    pos, nf, paged_kernel=kernel,
+                                    cfg=cfg)[0]
             for kernel in (False, True)]
     fed = (jnp.arange(width)[None] < nf[:, None])[:, :, None]
     assert float(jnp.max(jnp.abs((outs[0] - outs[1]) * fed))) < 1e-5

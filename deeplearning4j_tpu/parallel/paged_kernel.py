@@ -35,7 +35,12 @@ asks the compiler to relay it, so the step updates it in place (PERF.md
 section 4).  The
 price is that a head is no longer a dim of the block: the per-head
 reduction is a block-diagonal matmul on the MXU, one 128-lane tile
-(``128 // K`` heads) at a time (see ``_paged_attn_kernel``).
+(``128 // K`` heads) at a time (see ``_paged_attn_kernel``).  A pool
+with fewer K/V heads than the queries have, or a block mask, takes
+``_grouped_attn_kernel`` through the same entry point: the same walk,
+as many pages a block by the same rule of the shapes
+(``_pages_per_block``: eight pages of 16 rows, one of 128), a plain
+matmul a K/V head.
 
 Chunked feeds (C > 1: chunked prefill and the speculative verify
 dispatch) ride the same kernel: query column ``c`` sits at write
@@ -123,7 +128,11 @@ def _dot_f32(a, b, dims):
 # wait as all of a visit: on a v5e at GPT-2-large's row a page costs
 # 1.67 us walked one a block, 0.85 at two, 0.24 at four, 0.17 at eight
 # and 0.15 at sixteen, where a lane's tail block already fetches more
-# spare slots than pages (PERF.md section 6, PR 29).
+# spare slots than pages (PERF.md section 6, PR 29).  The grouped kernel
+# at SDAR's row (4 K/V heads of 128, 128 lanes x 16 columns, 26 live
+# pages a lane): 1.63, 0.78, 0.46, 0.27 and 0.23 (PR 43): a `[128, 16]`
+# tile of scores fills the 16 vregs a `[128, 128]` one does, so a block
+# costs 1.5-1.9 us whatever it holds up to a tile, and 2.9 at two tiles.
 _KEYS = 128
 
 
@@ -140,6 +149,22 @@ def _pages_per_block(ps: int, hkd: int, itemsize: int, mp: int,
         return 1
     fit = (_DEFAULT_SCOPED_VMEM // 4) // (4 * _buf(ps, hkd, itemsize))
     return max(1, min(_KEYS // ps, mp, fit))
+
+
+def walk_plan(ps: int, row: int, itemsize: int, mp: int, width: int,
+              heads: int, head_dim: int, block: int = 1,
+              latent: bool = False) -> tuple:
+    """(pages a block, fed columns a query block) of the walk the kernel
+    these shapes are sent to takes, for whoever counts the blocks of a
+    round's walk (`serving/lm.py`): the latent kernel walks a page a
+    block; a pool row `[heads * head_dim]` without a block mask is the
+    full-heads kernel's (every column of a lane in one grid step), any
+    other the grouped kernel's, by `paged_flash_attention`'s own rule."""
+    if latent:
+        return 1, _query_block(width, heads)
+    gp = _pages_per_block(ps, row, itemsize, mp, _resolve_interpret(None))
+    grouped = block > 1 or row != heads * head_dim
+    return gp, _grouped_query_block(width) if grouped else width
 
 
 def _paged_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
@@ -398,15 +423,19 @@ def _paged_call(table, pos, n_feed, qf, k_pages, v_pages, *, c, kd,
 # of the page, and the `G = H / Hkv` query heads that read it (times the
 # fed columns of a query block) are the ROWS of one query block against
 # that tile, so a page fetched once serves `G` heads: a plain matmul a
-# K/V head and no block-diagonal layout.  The walk is the latent kernel's:
-# the lane's live pages inside the body, a page a block (a page of 128
-# positions is one tile of scores), the next page's DMA under this page's
-# matmuls.
+# K/V head and no block-diagonal layout.  The walk is `_paged_attn_kernel`'s:
+# the lane's live pages inside the body, `_pages_per_block` pages a block
+# (eight pages of 16 positions, or one of 128: a 128-lane tile of scores
+# either way), each page fetched by its own DMA, the next block's DMAs
+# under this block's matmuls.  Walked a page a block, a page of 16 rows
+# cost a DMA wait, an MXU fill and a rescale of the accumulator a K/V head
+# for 16 keys: 1.4-1.6 us a visit on a v5e at SDAR's row of 512 lanes, 0.27
+# at eight a block (the readings are beside `_KEYS`).
 
 
 def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
                          o_ref, kbuf, vbuf, sem, m_acc, l_acc, acc, out, *,
-                         scale, ps, cq, g, hkv, kd, neg, block=1):
+                         scale, ps, cq, g, hkv, kd, gp, neg, block=1):
     """Grid program (lane b, query block j).  q_ref `[Hkv, cq*G, K]`: for
     a K/V head its `G` query heads of `cq` fed columns, row `ci*G + gi`;
     k_ref/v_ref the whole pool `[L*P, ps, Hkv*K]` in HBM; o_ref
@@ -417,9 +446,22 @@ def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
     `p = pos + ci` sees the rows `t < min(pos + n_feed, (p // B + 1) * B)`,
     which for B = 1 is the causal rule of every fed column and the code
     B = 1 traces is the causal one as it was.  A block past the lane's
-    fed columns reads nothing and writes zeros."""
+    fed columns reads nothing and writes zeros.
+
+    The query block's `n` live pages are walked `gp` a block, as
+    `_paged_attn_kernel` walks its lane's: page `blk*gp + j` lands in rows
+    `[j*ps, (j+1)*ps)` of a buffer slot `[gp*ps, Hkv*K]` by its own DMA,
+    all of a block's DMAs started before any is waited for and the next
+    block's in flight under this block's matmuls; a K/V head's step is
+    `[rows, K] x [K, gp*ps]`, one exponent, `_dot_f32` and one rescale of
+    its accumulator.  The tail block's spare slots fetch the LAST live
+    page again (every row a matmul reads was written by a DMA from a live
+    page: 0 x NaN is NaN on the MXU; no dead table entry is dereferenced);
+    the doubled keys lie past every column's `sees` and are masked.  With
+    `gp == 1` (a page of 128 rows) a block is the page and the slot."""
     b, j = pl.program_id(0), pl.program_id(1)
     rows = cq * g
+    keys = gp * ps
     nf = nf_ref[b]
     first = j * cq
     last = pos_ref[b] + jnp.minimum(first + cq, nf) - 1
@@ -428,12 +470,22 @@ def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
                            pos_ref[b] + nf) - 1
     n = jnp.where(first < nf, last // ps + 1, 0)
 
-    def copies(i, slot):
-        page = table_ref[b, i]
-        return [pltpu.make_async_copy(k_ref.at[page], kbuf.at[slot],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_ref.at[page], vbuf.at[slot],
-                                      sem.at[1, slot])]
+    def copies(blk, slot):
+        if gp == 1:     # the page is the block: one DMA a pool, no spare
+            page = table_ref[b, blk]
+            return [pltpu.make_async_copy(k_ref.at[page], kbuf.at[slot],
+                                          sem.at[0, slot]),
+                    pltpu.make_async_copy(v_ref.at[page], vbuf.at[slot],
+                                          sem.at[1, slot])]
+        out = []
+        for pj in range(gp):
+            page = table_ref[b, jnp.minimum(blk * gp + pj, n - 1)]
+            dst = pl.ds(pj * ps, ps)
+            out += [pltpu.make_async_copy(k_ref.at[page],
+                                          kbuf.at[slot, dst], sem.at[0, slot]),
+                    pltpu.make_async_copy(v_ref.at[page],
+                                          vbuf.at[slot, dst], sem.at[1, slot])]
+        return out
 
     @pl.when(n == 0)
     def _idle():
@@ -446,26 +498,27 @@ def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
         m_acc[...] = jnp.full_like(m_acc, neg)
         l_acc[...] = jnp.zeros_like(l_acc)
         acc[...] = jnp.zeros_like(acc)
-        ci = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) // g
+        ci = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0) // g
         sees = pos_ref[b] + first + ci      # the last row a column sees
         if block > 1:
             sees = jnp.minimum((sees // block + 1) * block,
                                pos_ref[b] + nf) - 1
-        horizon = sees - jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
+        # key t = blk*keys + column is live for a row iff t <= sees
+        horizon = sees - jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
         exact = (None if q_ref.dtype == jnp.bfloat16
                  else jax.lax.Precision.HIGHEST)
 
-        def page_step(i, carry):
-            slot = jax.lax.rem(i, 2)
+        def block_step(blk, carry):
+            slot = jax.lax.rem(blk, 2)
 
-            @pl.when(i + 1 < n)
+            @pl.when((blk + 1) * gp < n)
             def _next():
-                for dma in copies(i + 1, 1 - slot):
+                for dma in copies(blk + 1, 1 - slot):
                     dma.start()
 
-            for dma in copies(i, slot):
+            for dma in copies(blk, slot):
                 dma.wait()
-            live = i * ps <= horizon
+            live = blk * keys <= horizon
             for nh in range(hkv):
                 k_blk = kbuf[slot, :, nh * kd:(nh + 1) * kd]
                 v_blk = vbuf[slot, :, nh * kd:(nh + 1) * kd]
@@ -486,7 +539,7 @@ def _grouped_attn_kernel(table_ref, pos_ref, nf_ref, q_ref, k_ref, v_ref,
                 l_acc[nh] = jnp.broadcast_to(new_l, (rows, REP))
             return carry
 
-        jax.lax.fori_loop(0, n, page_step, 0)
+        jax.lax.fori_loop(0, pl.cdiv(n, gp), block_step, 0)
         for nh in range(hkv):
             o = acc[nh] / jnp.maximum(l_acc[nh][:, :1], 1e-30)
             out[:, nh * g:(nh + 1) * g, :] = o.reshape(cq, g, kd)
@@ -541,6 +594,8 @@ def _grouped_call(table, pos, n_feed, qg, k_pages, v_pages, *, c, cq, g,
     b, hkv, _, kd = qg.shape
     ps = k_pages.shape[1]
     rows = cq * g
+    gp = _pages_per_block(ps, hkv * kd, k_pages.dtype.itemsize,
+                          table.shape[1], interpret)
 
     def _q_map(bi, ji, tbl, pos_, nf):
         return (bi, 0, ji, 0)
@@ -556,9 +611,9 @@ def _grouped_call(table, pos, n_feed, qg, k_pages, v_pages, *, c, cq, g,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((None, min(cq, c), hkv * g, kd), _o_map),
         scratch_shapes=[
-            pltpu.VMEM((2, ps, hkv * kd), k_pages.dtype),
-            pltpu.VMEM((2, ps, hkv * kd), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, gp * ps, hkv * kd), k_pages.dtype),  # K blocks
+            pltpu.VMEM((2, gp * ps, hkv * kd), v_pages.dtype),  # V blocks
+            pltpu.SemaphoreType.DMA((2, 2)),             # [K|V, slot]
             pltpu.VMEM((hkv, rows, REP), jnp.float32),   # running max
             pltpu.VMEM((hkv, rows, REP), jnp.float32),   # running denom
             pltpu.VMEM((hkv, rows, kd), jnp.float32),    # accumulator
@@ -567,7 +622,7 @@ def _grouped_call(table, pos, n_feed, qg, k_pages, v_pages, *, c, cq, g,
     )
     kernel = functools.partial(
         _grouped_attn_kernel, scale=1.0 / (kd ** 0.5), ps=ps, cq=cq, g=g,
-        hkv=hkv, kd=kd, neg=_NEG, block=block)
+        hkv=hkv, kd=kd, gp=gp, neg=_NEG, block=block)
     # as for `_paged_call`: the block table first, the result 4-D with the
     # feed width second; the trace's readers find the kernel by that
     return pl.pallas_call(

@@ -214,7 +214,7 @@ import collections
 import queue as _queue
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -587,6 +587,7 @@ class ContinuousLMServer:
         from deeplearning4j_tpu.parallel import paged_kernel
 
         self.paged_kernel = paged_kernel.paged_kernel_enabled()
+        self._walk_plans: Dict[int, Tuple[int, int]] = {}
         self.speculate = speculate
         self.draft_len = int(draft_len)
         self._drafter = drafter            # built in _start_locked if None
@@ -2690,6 +2691,25 @@ class ContinuousLMServer:
         req.export_result = ex
         self._finish_slot(slot)
 
+    def _walk_plan(self, width: int) -> Tuple[int, int]:
+        """(pages a block, fed columns a query block) of the paged kernel's
+        walk in a round of `width` columns, by the kernel's own rule at
+        this server's shapes: a lane that reads `n` live pages and feeds
+        `f` columns takes `ceil(n / pages) * ceil(f / columns)` blocks a
+        layer, the round's `walk_blocks`."""
+        plan = self._walk_plans.get(width)
+        if plan is None:
+            from deeplearning4j_tpu.parallel import paged_kernel
+            from deeplearning4j_tpu.parallel.generation import pool_layout
+
+            cfg = self.cfg
+            plan = self._walk_plans[width] = paged_kernel.walk_plan(
+                self.page_size, pool_layout(cfg).row,
+                np.dtype(cfg.dtype).itemsize, self.max_pages, width,
+                cfg.n_heads, cfg.head_dim, self.block,
+                cfg.latent is not None)
+        return plan
+
     def _dispatch_paged(self, active, cow, installs,
                         level: int = 0, restores=()) -> bool:
         # land shipped-in pages first (their lane's committed state is
@@ -2763,7 +2783,8 @@ class ContinuousLMServer:
             width = self.prefill_chunk
         clock.to("marshal")
         fed = dict.fromkeys(("prefill", "decode", "draft"), 0)
-        live_pages = attn_rows = attn_pairs = 0
+        live_pages = attn_rows = attn_pairs = walk_blocks = 0
+        walk_pages, walk_columns = self._walk_plan(width)
         tokens = np.zeros((self.n_slots, width), np.int32)
         pos = np.zeros((self.n_slots,), np.int32)
         n_feed = np.zeros((self.n_slots,), np.int32)
@@ -2807,7 +2828,9 @@ class ContinuousLMServer:
             # pages the attention reads for this lane: history and feed;
             # the rows in them, and the (fed column, visible row) pairs
             f = int(n_feed[i])
-            live_pages += -(-(slot.pos + f) // self.page_size)
+            pages = -(-(slot.pos + f) // self.page_size)
+            live_pages += pages
+            walk_blocks += -(-pages // walk_pages) * -(-f // walk_columns)
             attn_rows += slot.pos + f
             attn_pairs += f * slot.pos + f * (f + 1) // 2
             pos[i] = slot.pos
@@ -2905,7 +2928,8 @@ class ContinuousLMServer:
                                self.kv_pages)
         clock.to("yield")
         self.metrics.record_round(clock.take(), width, self.n_slots, fed,
-                                  live_pages, attn_rows, attn_pairs)
+                                  live_pages, attn_rows, attn_pairs,
+                                  walk_blocks)
         return True
 
     def _no_block_held(self) -> np.ndarray:
@@ -2969,7 +2993,8 @@ class ContinuousLMServer:
         clock.to("marshal")
         before = self._ahead["lanes"] if self._ahead is not None else {}
         fed = dict.fromkeys(("prefill", "decode", "draft"), 0)
-        live_pages = attn_rows = attn_pairs = 0
+        live_pages = attn_rows = attn_pairs = walk_blocks = 0
+        walk_pages, walk_columns = self._walk_plan(width)
         tokens = np.zeros((self.n_slots, width), np.int32)
         known = np.ones((self.n_slots, blk), np.int32)
         carry = np.zeros((self.n_slots,), np.int32)
@@ -3033,7 +3058,9 @@ class ContinuousLMServer:
                             "served": min(blk - own, req.max_new - sent)}
                     slot.block = None
             n_feed[i] = f
-            live_pages += -(-(slot.pos + f) // self.page_size)
+            pages = -(-(slot.pos + f) // self.page_size)
+            live_pages += pages
+            walk_blocks += -(-pages // walk_pages) * -(-f // walk_columns)
             attn_rows += slot.pos + f
             # a column sees the history and its whole block
             n = f // blk
@@ -3064,7 +3091,8 @@ class ContinuousLMServer:
         self._steps += 1
         self.metrics.record_dispatch(len(active), self.n_slots)
         return {"out": out, "lanes": lanes, "width": width,
-                "account": (fed, live_pages, attn_rows, attn_pairs)}
+                "account": (fed, live_pages, attn_rows, attn_pairs,
+                            walk_blocks)}
 
     def _fold_block_round(self, flight: Dict) -> None:
         """Read a dispatched round, ONE host sync (the blocks after the

@@ -272,6 +272,11 @@ class ServingMetrics:
             "serving_lm_live_pages_total",
             "per round over active lanes, KV pages the attention has "
             "to read")
+        self.walk_blocks_total = Counter(
+            "serving_lm_walk_blocks_total",
+            "per round over active lanes, blocks of pages the paged "
+            "kernel's walk takes, a layer: live pages over this are the "
+            "pages a block carries")
         # what the attention of a round has to read and to score, by
         # the round's kind (ISSUE-26): rows = history and feed of every
         # active lane; pairs = (fed column, visible row) pairs, a head
@@ -410,7 +415,8 @@ class ServingMetrics:
                   self.latency_hist, self.queue_wait_hist,
                   self.compute_hist,
                   self.idle_seconds_total, self.feed_capacity_total,
-                  self.live_pages_total, self.round_host_hist,
+                  self.live_pages_total, self.walk_blocks_total,
+                  self.round_host_hist,
                   self.expert_peak_total, self.expert_rounds_total,
                   self.state_rows_gauge, self.state_copy_rows_total,
                   self.block_unmasked_total, self.blocks_committed_total,
@@ -510,12 +516,13 @@ class ServingMetrics:
     def record_round(self, seconds: Dict[str, float], width: int,
                      lanes: int, fed: Dict[str, int],
                      live_pages: int, attn_rows: int = 0,
-                     attn_pairs: int = 0) -> None:
+                     attn_pairs: int = 0, walk_blocks: int = 0) -> None:
         """One dispatched round of the LM worker, next to
         `record_dispatch`: the phase seconds since the last call, the
         width dispatched over `lanes` lanes, the tokens fed by kind, the
-        KV pages the active lanes' attention reads, and the rows it
-        reads and the (column, row) pairs it scores."""
+        KV pages the active lanes' attention reads, the rows it reads
+        and the (column, row) pairs it scores, and the blocks of pages
+        the kernel's walk takes."""
         self.record_phase_seconds(seconds)
         self.round_host_hist.observe(sum(
             sec for phase, sec in seconds.items()
@@ -526,6 +533,7 @@ class ServingMetrics:
             if n:
                 self.fed_tokens[kind].inc(int(n))
         self.live_pages_total.inc(int(live_pages))
+        self.walk_blocks_total.inc(int(walk_blocks))
         kind = "w1" if int(width) == 1 else "wide"
         self.attn_rows[kind].inc(int(attn_rows))
         self.attn_pairs[kind].inc(int(attn_pairs))
@@ -940,6 +948,7 @@ class ServingMetrics:
                                for kind, m in self.fed_tokens.items()},
                 "feed_capacity": int(self.feed_capacity_total.value),
                 "live_pages": int(self.live_pages_total.value),
+                "walk_blocks": int(self.walk_blocks_total.value),
                 "attn_rows": {k: int(m.value)
                               for k, m in self.attn_rows.items()},
                 "attn_pairs": {k: int(m.value)

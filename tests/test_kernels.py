@@ -559,24 +559,21 @@ _WALK_CASES = {
 }
 
 
-@pytest.mark.paged_kernel
-@pytest.mark.parametrize("case", list(_WALK_CASES))
-def test_page_walk_reads_live_pages_only(case):
-    lanes, c, n_layers, layer, dtype = _WALK_CASES[case]
-    assert 1 < _WALK_G - 1 and _WALK_G + 1 < _WALK_MP  # five histories
-    ps, mp, h, kd = _WALK_PS, _WALK_MP, 2, 8
+def _walk_pool(name, lanes, c, ps, mp, h, kd, row, n_layers, layer, dtype):
+    """A walk case's operands: queries `[B, C, h, kd]`, a stacked pool
+    `[L, P, ps, row]` as it should read (`clean`) and as the kernel gets it
+    (`dirty`: NaN in every page no live entry of `layer` names), and the
+    block tables to match (dead entries 0, or out of range)."""
     b = len(lanes)
     pos = np.array([p for p, _ in lanes], np.int32)
     nf = np.array([f for _, f in lanes], np.int32)
-    rng = np.random.default_rng(len(case))
+    rng = np.random.default_rng(len(name))
     pages = 1 + b * mp
-    shape = (n_layers, pages, ps, h * kd)
+    shape = (n_layers, pages, ps, row)
     q = jnp.asarray(rng.standard_normal((b, c, h, kd)), dtype)
-    clean_k = rng.standard_normal(shape).astype(np.float32)
-    clean_v = rng.standard_normal(shape).astype(np.float32)
+    clean = [rng.standard_normal(shape).astype(np.float32) for _ in "kv"]
     if dtype == "bfloat16":     # the oracle sees what the pool holds
-        clean_k = np.asarray(jnp.asarray(clean_k, dtype), np.float32)
-        clean_v = np.asarray(jnp.asarray(clean_v, dtype), np.float32)
+        clean = [np.asarray(jnp.asarray(a, dtype), np.float32) for a in clean]
     clean_table = np.zeros((b, mp), np.int32)
     dirty_table = np.full((b, mp), 1 << 20, np.int32)
     live = np.zeros((pages,), bool)
@@ -587,25 +584,35 @@ def test_page_walk_reads_live_pages_only(case):
             ids = 1 + i * mp + rng.permutation(mp)[:n]
             clean_table[i, :n] = dirty_table[i, :n] = ids
             live[ids] = True
-    dirty_k = np.full(shape, np.nan, np.float32)
-    dirty_v = np.full(shape, np.nan, np.float32)
-    dirty_k[layer, live] = clean_k[layer, live]
-    dirty_v[layer, live] = clean_v[layer, live]
+    dirty = [np.full(shape, np.nan, np.float32) for _ in "kv"]
+    for d, a in zip(dirty, clean):
+        d[layer, live] = a[layer, live]
+    return (q, [jnp.asarray(a[layer]) for a in clean],
+            [jnp.asarray(d, dtype) for d in dirty], jnp.asarray(clean_table),
+            jnp.asarray(dirty_table), jnp.asarray(pos), jnp.asarray(nf))
 
-    got = paged_flash_attention(
-        q, jnp.asarray(dirty_k, dtype), jnp.asarray(dirty_v, dtype),
-        jnp.asarray(dirty_table), jnp.asarray(pos), jnp.asarray(nf),
-        layer=layer)
-    want = _gather_oracle(
-        q.astype(jnp.float32),
-        jnp.asarray(clean_k[layer].reshape(pages, ps, h, kd)),
-        jnp.asarray(clean_v[layer].reshape(pages, ps, h, kd)),
-        jnp.asarray(clean_table), jnp.asarray(pos))
+
+def _assert_walk_matches(got, want, nf, dtype):
     got = np.asarray(got.astype(jnp.float32))
     assert np.isfinite(got).all()
-    assert not got[nf == 0].any()               # an idle lane: zeros
+    assert not got[np.asarray(nf) == 0].any()   # an idle lane: zeros
     _assert_fed_columns_match(got, want, nf,
                               atol=2e-2 if dtype == "bfloat16" else 1e-5)
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_page_walk_reads_live_pages_only(case):
+    lanes, c, n_layers, layer, dtype = _WALK_CASES[case]
+    assert 1 < _WALK_G - 1 and _WALK_G + 1 < _WALK_MP  # five histories
+    ps, mp, h, kd = _WALK_PS, _WALK_MP, 2, 8
+    q, clean, dirty, clean_table, dirty_table, pos, nf = _walk_pool(
+        case, lanes, c, ps, mp, h, kd, h * kd, n_layers, layer, dtype)
+    got = paged_flash_attention(q, *dirty, dirty_table, pos, nf, layer=layer)
+    want = _gather_oracle(q.astype(jnp.float32),
+                          *(a.reshape(-1, ps, h, kd) for a in clean),
+                          clean_table, pos)
+    _assert_walk_matches(got, want, nf, dtype)
 
 
 @pytest.mark.paged_kernel
@@ -619,9 +626,104 @@ def test_page_walk_reads_live_pages_only(case):
     ((4, 16, 4, 6, False), 1),          # compiled: no DMA inside a tile
     ((4, 16, 4, 6, True), 6),           # the interpreter takes any page
     ((16, 16384, 2, 64, False), 2),     # the buffers stay inside VMEM
+    # the grouped kernel's rows, `Hkv * K` wide
+    ((16, 512, 2, 128, False), 8),      # SDAR-30B-A3B: 4 K/V heads of 128
+    ((128, 1024, 2, 192, False), 1),    # Solar-Open2: the page is a block
 ])
 def test_pages_per_block_comes_from_the_shapes(shape, want):
     assert _pages_per_block(*shape) == want
+
+
+# The grouped kernel's walk (ISSUE 43): fewer K/V heads than query heads,
+# or the block mask, several pages a block.  The same stacked pool as
+# above: dead table entries out of range, NaN in every page that no live
+# entry of the layer names.  Lanes are (pos, n_feed); `_GROUPED_G` is the
+# kernel's own pages a block at the cases' shapes.
+_GROUPED_PS, _GROUPED_MP = 16, 18
+_GROUPED_H, _GROUPED_HKV, _GROUPED_KD = 4, 2, 16
+_GROUPED_G = _pages_per_block(_GROUPED_PS, _GROUPED_HKV * _GROUPED_KD, 4,
+                              _GROUPED_MP, True)
+
+
+def _grouped_history(pages, feed=1):
+    """One lane whose history is `pages` pages long, ending mid-page at
+    the end of a block of 4: its last fed row is row 11 of the page."""
+    return [(pages * _GROUPED_PS - 4 - feed, feed)]
+
+
+_GROUPED_WALK_CASES = {
+    # name: (lanes [(pos, n_feed)], width, block, layers, layer, dtype)
+    **{f"history_{name}_pages": (_grouped_history(n), 1, 1, 1, 0, "float32")
+       for name, n in (("1", 1), ("g_minus_1", _GROUPED_G - 1),
+                       ("g", _GROUPED_G), ("g_plus_1", _GROUPED_G + 1),
+                       ("2g_plus_1", 2 * _GROUPED_G + 1))},
+    **{f"block_4_history_{name}_pages": (
+        _grouped_history(n, 4), 4, 4, 1, 0, "float32")
+       for name, n in (("1", 1), ("g_minus_1", _GROUPED_G - 1),
+                       ("g", _GROUPED_G), ("g_plus_1", _GROUPED_G + 1),
+                       ("2g_plus_1", 2 * _GROUPED_G + 1))},
+    "width_1_dead_entries_and_nan_pool": (
+        [(0, 1), (37, 1), (5, 1), (150, 1)], 1, 1, 2, 1, "float32"),
+    "width_4_causal": ([(12, 4), (139, 3), (30, 1)], 4, 1, 1, 0, "float32"),
+    "width_16_causal_ragged": (
+        [(10, 16), (15, 1), (126, 3), (0, 0), (200, 9)], 16, 1, 1, 0,
+        "float32"),
+    "width_16_block_4_on_a_boundary": (
+        [(128, 16), (0, 16), (268, 4), (16, 8)], 16, 4, 1, 0, "float32"),
+    "width_16_block_4_off_a_boundary": (
+        [(130, 16), (1, 6), (267, 4), (15, 3)], 16, 4, 1, 0, "float32"),
+    "width_4_block_4_off_a_boundary": (
+        [(126, 4), (3, 2), (255, 4)], 4, 4, 1, 0, "float32"),
+    "idle_lanes_between_busy": (
+        [(0, 0), (21, 4), (0, 0), (0, 0), (130, 2), (0, 0)],
+        4, 4, 1, 0, "float32"),
+    "query_blocks_of_8_width_40": (
+        [(100, 40), (3, 17), (0, 0), (240, 33)], 40, 1, 1, 0, "float32"),
+    "layer_last_of_3": ([(9, 2), (140, 4)], 4, 4, 3, 2, "float32"),
+    "bf16_width_1": ([(0, 1), (37, 1), (150, 1)], 1, 1, 2, 0, "bfloat16"),
+    "bf16_width_16_block_4": (
+        [(128, 16), (0, 0), (4, 8), (264, 4)], 16, 4, 2, 1, "bfloat16"),
+}
+
+
+def _grouped_oracle(q, kp, vp, table, pos, nf, block):
+    """`generation._grouped_paged_attn`'s gather path over one layer's
+    pages `[P, ps, Hkv, K]`: query head j reads K/V head `j // G`; the
+    column at absolute position p sees the rows
+    `< min(pos + n_feed, (p // block + 1) * block)`."""
+    b, c, h, kd = q.shape
+    pages, ps, hkv, _ = kp.shape
+    mp = table.shape[1]
+    gidx = (table[:, :, None] * ps
+            + jnp.arange(ps)[None, None, :]).reshape(b, mp * ps)
+    hk = kp.reshape(pages * ps, hkv, kd)[gidx]
+    hv = vp.reshape(pages * ps, hkv, kd)[gidx]
+    sc = jnp.einsum("bcngk,bsnk->bcngs", q.reshape(b, c, hkv, h // hkv, kd),
+                    hk) * kd ** -0.5
+    wpos = pos[:, None] + jnp.arange(c)[None, :]
+    sees = jnp.minimum((wpos // block + 1) * block, (pos + nf)[:, None]) - 1
+    seen = jnp.arange(mp * ps)[None, None, :] <= sees[:, :, None]
+    sc = jnp.where(seen[:, :, None, None, :], sc, mask_value(sc.dtype))
+    return jnp.einsum("bcngs,bsnk->bcngk", jax.nn.softmax(sc, axis=-1),
+                      hv).reshape(b, c, h, kd)
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", list(_GROUPED_WALK_CASES))
+def test_grouped_page_walk_reads_live_pages_only(case):
+    lanes, c, block, n_layers, layer, dtype = _GROUPED_WALK_CASES[case]
+    assert 1 < _GROUPED_G - 1 and 2 * _GROUPED_G + 1 < _GROUPED_MP
+    ps, mp = _GROUPED_PS, _GROUPED_MP
+    h, hkv, kd = _GROUPED_H, _GROUPED_HKV, _GROUPED_KD
+    q, clean, dirty, clean_table, dirty_table, pos, nf = _walk_pool(
+        case, lanes, c, ps, mp, h, kd, hkv * kd, n_layers, layer, dtype)
+    got = paged_flash_attention(q, *dirty, dirty_table, pos, nf, layer=layer,
+                                block=block)
+    want = _grouped_oracle(q.astype(jnp.float32),
+                           *(a.reshape(-1, ps, hkv, kd) for a in clean),
+                           clean_table, pos, nf, block)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    _assert_walk_matches(got, want, nf, dtype)
 
 
 @pytest.mark.paged_kernel

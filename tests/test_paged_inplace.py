@@ -503,6 +503,51 @@ def test_v5e_block_step_compiles_under_the_block_mask(one_chip, width,
     assert any("s32[%d]" % (2 * lanes * 4 + 3) in ln for ln in out)
 
 
+_GROUPED_SHAPES = {
+    # lanes, width, heads, K/V heads, page, table width, pool pages, block
+    # mask, pages a block of the walk
+    "sdar_block_round": (128, 16, 32, 4, 16, 128, 6 * 27501, 4, 8),
+    "sdar_narrow_round": (128, 4, 32, 4, 16, 128, 6 * 27501, 4, 8),
+    "solar_open2_decode": (4, 1, 64, 8, 128, 160, 641, 1, 1),
+    "solar_open2_chunk": (4, 512, 64, 8, 128, 160, 641, 1, 1),
+}
+
+
+@pytest.mark.paged_kernel
+@pytest.mark.parametrize("case", list(_GROUPED_SHAPES))
+def test_v5e_grouped_kernel_compiles_at_its_pages_a_block(one_chip, case):
+    """`_grouped_call` at the published shapes (ISSUE 43): SDAR-30B-A3B's
+    `[128, 16, 32, 128]` over 4 K/V heads of 128, pages of 16 rows walked
+    eight a block under the block mask, and Solar-Open2's `[4, C, 64, 128]`
+    over 8, a page of 128 rows a block, lower through Mosaic (the
+    interpreter takes DMAs and slices that Mosaic refuses), keep what the
+    trace's readers find the kernel by (`benchmark/readings.py`'s
+    `PAGED_KERNEL`: the block table the first operand, the result 4-D with
+    the feed width second) and hold no copy of the pool."""
+    lanes, c, h, hkv, ps, mp, pages, block, gp = _GROUPED_SHAPES[case]
+    kd, g = 128, h // hkv
+    assert pk._pages_per_block(ps, hkv * kd, 2, mp, False) == gp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cq = pk._grouped_query_block(c)
+    cp = -(-c // cq) * cq
+    pool = sds((pages, ps, hkv * kd), jnp.bfloat16)
+    compiled = pk._grouped_call.lower(
+        sds((lanes, mp), np.int32), sds((lanes,), np.int32),
+        sds((lanes,), np.int32), sds((lanes, hkv, cp * g, kd), jnp.bfloat16),
+        pool, pool, c=c, cq=cq, g=g, interpret=False, block=block).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "grouped_paged_attention" in calls[0]
+    assert re.search(r"= bf16\[%d,%d,%d,%d\]\S* custom-call\(" %
+                     (lanes, c, h, kd), calls[0]), calls[0][:300]
+    assert ("operand_layout_constraints={s32[%d,%d]" % (lanes, mp)
+            in calls[0]), calls[0][:600]
+
+
 # The flash attention kernels at the shapes the benchmark's train cells and
 # the suite run them, compiled by the real Mosaic compiler (ISSUE 27): the
 # interpreter takes blocks, slices and layouts that Mosaic refuses.

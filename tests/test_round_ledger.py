@@ -7,6 +7,7 @@ request's `prefill` span, warm-up by program, and the mesh trainer's
 import glob
 import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
@@ -175,6 +176,69 @@ def test_live_pages_against_a_hand_count(served):
     assert _delta(served, "rounds", "live_pages") == want
 
 
+def _walk_server(kind):
+    """A toy server whose lanes' histories pass a block of the kernel's
+    walk: the classic layer (the full-heads kernel), or grouped heads under
+    a block mask of 4 (the grouped kernel, block rounds)."""
+    from deeplearning4j_tpu.parallel import transformer as tfm
+
+    if kind == "full":
+        cfg, params = _lm(max_len=272)
+        return ContinuousLMServer(cfg, params, slots=3, page_size=4,
+                                  prefill_chunk=4)
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_layers=1, d_ff=48,
+        max_len=320, dtype="float32", norm="rms", mlp="swiglu",
+        head_width=8, kv_heads=2, rope=tfm.YarnRope(theta=1e6),
+        block_length=4, mask_token=63)
+    return ContinuousLMServer(cfg, tfm.init_params(cfg, jax.random.PRNGKey(0)),
+                              slots=3, page_size=8, prefill_chunk=16,
+                              denoise_steps=2)
+
+
+@pytest.mark.parametrize("kind", ["full", "grouped"])
+def test_walk_blocks_against_a_count_of_what_was_dispatched(kind):
+    """`walk_blocks`: over the lanes a round feeds, `ceil(pages / gp)` (a
+    query block: one at these widths), `gp` the kernel's own pages a block
+    at the server's shapes; counted here from the positions and feeds the
+    step programs were handed, three lanes at once."""
+    from deeplearning4j_tpu.parallel import paged_kernel as pk
+    from deeplearning4j_tpu.parallel.generation import pool_layout
+
+    srv = _walk_server(kind)
+    try:
+        srv.warmup()
+        ps, mp = srv.page_size, srv.max_pages
+        gp = pk._pages_per_block(ps, pool_layout(srv.cfg).row, 4, mp, True)
+        assert 1 < gp < mp
+        seen, step = [], srv._step
+
+        def recorded(params, *args):
+            seen.append((np.array(args[3]), np.array(args[4])))
+            return step(params, *args)
+
+        srv._step = recorded
+        before = srv.stats().get("rounds", {})
+        rng = np.random.default_rng(5)
+        lengths = [ps * gp + 9, 7, 2 * ps * gp - 3]     # 2, 1 and 2 blocks
+        with ThreadPoolExecutor(len(lengths)) as pool:
+            for out in pool.map(
+                    lambda p: srv.generate(p, 8, timeout=600),
+                    [[int(t) for t in rng.integers(0, 48, n)]
+                     for n in lengths]):
+                assert len(out) >= 8
+        rounds = srv.stats()["rounds"]
+    finally:
+        srv.stop()
+    pages = [-(-(int(p) + int(f)) // ps)
+             for pos, nf in seen for p, f in zip(pos, nf) if f]
+    assert len(seen) == rounds["count"] - before.get("count", 0)
+    assert sum(pages) == rounds["live_pages"] - before.get("live_pages", 0)
+    want = sum(-(-n // gp) for n in pages)
+    assert sum(pages) / gp < want < sum(pages)      # some tails, some wholes
+    assert rounds["walk_blocks"] - before.get("walk_blocks", 0) == want
+
+
 def test_the_new_series_are_on_metrics_with_their_labels(served):
     registry = MetricsRegistry()
     served["srv"].metrics.register_into(registry, plane="lm")
@@ -186,6 +250,7 @@ def test_the_new_series_are_on_metrics_with_their_labels(served):
                  'serving_lm_fed_tokens_total{kind="prefill",plane="lm"}',
                  'serving_lm_feed_capacity_total{plane="lm"}',
                  'serving_lm_live_pages_total{plane="lm"}',
+                 'serving_lm_walk_blocks_total{plane="lm"}',
                  'serving_lm_idle_seconds_total{plane="lm"}',
                  'serving_lm_round_host_seconds_count{plane="lm"}'):
         assert line in text, line
